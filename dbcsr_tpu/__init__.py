@@ -33,7 +33,12 @@ from dbcsr_tpu.core.config import (
     print_config,
     set_config,
 )
-from dbcsr_tpu.core.lib import init_lib, finalize_lib, print_statistics
+from dbcsr_tpu.core.lib import (
+    finalize_lib,
+    init_lib,
+    place_compile_cache,
+    print_statistics,
+)
 from dbcsr_tpu.core.dist import (
     ProcessGrid,
     Distribution,
@@ -122,6 +127,8 @@ from dbcsr_tpu.ops.tests import TEST_BINARY_IO, TEST_MM, run_tests
 from dbcsr_tpu.parallel.dist_matrix import replicate as replicate_all
 
 __version__ = "0.1.0"
+
+place_compile_cache()
 
 # the public surface (~88 symbols; the dbcsr_api.F analog list,
 # see PARITY.md for the name-by-name mapping)

@@ -39,8 +39,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dbcsr_tpu.utils.compat import enable_x64 as _enable_x64
-
 _SUPPORTED = (np.dtype(np.float32), np.dtype(jnp.bfloat16))
 
 
@@ -283,7 +281,7 @@ def process_stack_pallas(
         # Mosaic fails to legalize scalar-prefetch index maps traced under
         # jax_enable_x64 (i64 SMEM index loads); the kernel only touches
         # f32/bf16 data and i32 indices, so trace with x64 off.
-        with _enable_x64(False):
+        with jax.enable_x64(False):
             c_data = _pallas_process(
                 c_data, a_data, b_data,
                 jnp.asarray(a_c), jnp.asarray(b_c), jnp.asarray(c_c),
@@ -591,6 +589,12 @@ def _pallas_crosspack_vmem(c_data, a_data_t, b_data, ai, bi, cg, cl, alpha,
         scratch_shapes=[pltpu.VMEM((P, m, n), jnp.float32)],
     )
     kernel = functools.partial(_crosspack_vmem_kernel, P=P, R=R)
+    # Mosaic's default scoped-VMEM limit (16 MiB on a v5e) is far below
+    # what two whole-array operands need; ask for what they take plus
+    # the default's worth for C blocks, accumulators and index streams
+    vmem_limit = (_vmem_tiled_bytes(a_data_t.shape, a_data_t.dtype)
+                  + _vmem_tiled_bytes(b_data.shape, b_data.dtype)
+                  + (16 << 20))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -598,6 +602,7 @@ def _pallas_crosspack_vmem(c_data, a_data_t, b_data, ai, bi, cg, cl, alpha,
             jax.ShapeDtypeStruct((nc_out, m, n), c_data.dtype)
             for _ in range(P)
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(
         ai, bi, cg, cl,
@@ -607,14 +612,33 @@ def _pallas_crosspack_vmem(c_data, a_data_t, b_data, ai, bi, cg, cl, alpha,
     )
 
 
-# byte gate for the VMEM-resident variant: A+B (plus headroom for C
-# blocks, accumulators and double-buffered index streams) must fit the
-# ~128 MB v5e VMEM; stay well under it
-_VMEM_RESIDENT_MAX_BYTES = 64 * 1024 * 1024
+def _vmem_tiled_bytes(shape, dtype) -> int:
+    """Bytes an (N, r, c) block array occupies in VMEM: the last two
+    dims pad to the dtype's (sublane, 128) tile — (8, 128) for f32,
+    (16, 128) for bf16 — so a 23x23 f32 block takes 12 KiB, 5.8x its
+    2.1 KB of data."""
+    n, r, c = shape
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 8 * max(1, 4 // itemsize)
+    return n * (-(-r // sublane) * sublane) * (-(-c // 128) * 128) * itemsize
 
 
 def supports_vmem_resident(a_data, b_data) -> bool:
-    return int(a_data.nbytes) + int(b_data.nbytes) <= _VMEM_RESIDENT_MAX_BYTES
+    """Whether both whole operand arrays (A as the kernel holds it,
+    transposed) fit in three quarters of the chip's VMEM, at their
+    TILED size.  The first gate counted raw bytes against "~128 MB" and
+    admitted 26 MB of 23x23 f32 blocks that tile to 147 MiB: Mosaic
+    refused them on a v5e ("Scoped allocation with size 73.34M and
+    limit 16.00M").  Off-TPU the kernel runs interpreted; a v5e's
+    128 MiB stands in."""
+    if jax.devices()[0].platform == "tpu":
+        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+    else:
+        capacity = 128 << 20
+    na, m, k = a_data.shape
+    need = (_vmem_tiled_bytes((na, k, m), a_data.dtype)
+            + _vmem_tiled_bytes(b_data.shape, b_data.dtype))
+    return need <= capacity * 3 // 4
 
 
 def prepare_crosspack_launches(c_idx, a_idx, b_idx, a_pad_row, b_pad_row,
@@ -726,7 +750,7 @@ def process_stack_crosspack(
     alpha_arr = jnp.asarray([[alpha]], dtype=jnp.float32)
     launch_fn = _pallas_crosspack_vmem if vmem_resident else _pallas_crosspack
     for lc in launches:
-        with _enable_x64(False):
+        with jax.enable_x64(False):
             outs = launch_fn(
                 c_data, a_data_t, b_data,
                 jnp.asarray(lc["ai"]), jnp.asarray(lc["bi"]),

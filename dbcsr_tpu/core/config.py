@@ -31,9 +31,11 @@ class Config:
     mm_dense: object = None
     dense_occ_threshold: float = 0.8
     # TPU cost model for EMULATED dtypes (f64/c128): below the occupancy
-    # threshold, still go dense when dense_flops < ratio * true_flops —
-    # the measured dense:grouped-sparse throughput advantage on a v5e is
-    # ~320x for f64 (PERF_NOTES.md); 0 disables the cost model
+    # threshold, still go dense when dense_flops < ratio * true_flops.
+    # The ratio is a prior, not a measurement: at the north star on a
+    # v5e the dense route is 1.5x the grouped stack route end to end
+    # (PERF.md, PR 21; ROADMAP A3 measures the crossover).  0 disables
+    # the cost model
     dense_flop_ratio: float = 250.0
     # ---- adaptive storage-format planner (mm/format_planner.py; env
     #      DBCSR_TPU_MM_FORMAT) ----

@@ -34,11 +34,6 @@ KNOBS = {
                "rows fold into the '(evicted)' aggregate so conservation "
                "survives tenant churn.",
     },
-    "DBCSR_TPU_BENCH_CPU_DRIVER": {
-        "owner": "bench.py",
-        "doc": "stack driver forced when a bench run lands on the CPU "
-               "backend instead of a real TPU (default: config mm_driver).",
-    },
     "DBCSR_TPU_BENCH_DTYPE": {
         "owner": "bench.py",
         "doc": "dtype of the bench.py north-star multiply "
@@ -56,11 +51,6 @@ KNOBS = {
         "owner": "bench.py",
         "doc": "repetitions of the bench north-star multiply (median "
                "reported).",
-    },
-    "DBCSR_TPU_BENCH_PROBE_TIMEOUT": {
-        "owner": "bench.py",
-        "doc": "seconds before the TPU availability probe is declared "
-               "wedged (watchdog deadline).",
     },
     "DBCSR_TPU_BENCH_TIMINGS": {
         "owner": "bench.py",
@@ -509,11 +499,6 @@ KNOBS = {
     "DBCSR_TPU_WATCHDOG_LOG_MAX_BYTES": {
         "owner": "resilience/watchdog.py",
         "doc": "watchdog JSONL log rotation bound in bytes.",
-    },
-    "DBCSR_TPU_WATCHDOG_STATE": {
-        "owner": "resilience/watchdog.py",
-        "doc": "path persisting watchdog wedge-streak state across "
-               "processes.",
     },
     "DBCSR_TPU_WORKLOAD": {
         "owner": "serve/workload.py",

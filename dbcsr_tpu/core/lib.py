@@ -10,12 +10,48 @@ there is no Fortran-style hard ordering requirement in Python.
 
 from __future__ import annotations
 
+import os
+
 import jax
 
 from dbcsr_tpu.core import stats
 from dbcsr_tpu.core import timings
 
 _initialized = False
+
+# the persistent compile cache's home when the environment names none:
+# a FIXED directory in the checkout (git-ignored) — the path is part of
+# the cache key, so one made from tempfile, a pid or the time never hits
+_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def place_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a directory, once, at
+    package import (the one place every process passes).  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already read it: the
+    program keeps its cache there and sets no other.  Returns the
+    directory in effect.
+
+    Every program is cached, however small or quick to compile: JAX's
+    default skips compiles under 1 s, which on a v5e kept 11 of
+    chip_smoke's 146 programs and left a warm run 35.7 s of set-up
+    where caching all of them leaves 24.0 (PERF.md, PR 21).  Two
+    exceptions keep JAX's thresholds: an environment that sets them, and
+    a process pinned to the CPU backend, where serializing an executable
+    means compiling it a second time — a cold tier-1 run took 842 s
+    against 506 with every program cached."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _COMPILE_CACHE_DIR)
+    if jax.config.jax_platforms != "cpu":
+        for option, everything in (
+                ("jax_persistent_cache_min_compile_time_secs", 0.0),
+                ("jax_persistent_cache_min_entry_size_bytes", -1)):
+            if option.upper() not in os.environ:
+                jax.config.update(option, everything)
+    return jax.config.jax_compilation_cache_dir
 
 
 def init_lib(enable_x64: bool = True) -> None:

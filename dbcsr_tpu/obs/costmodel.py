@@ -93,9 +93,9 @@ def intensity(flops: float, nbytes: float) -> float:
 # Modeled efficiency of each execution format relative to its own
 # roofline attainable.  The stack engine's per-entry gathers revisit
 # tile-padded rows and its scatter read-modify-writes C segments, so it
-# lands far below attainable (acc/bench.py measures 5-15% across
-# devices; PERF_NOTES.md's 23^3 f64 case measured 7.3 vs 370 GFLOP/s
-# dense); one big padded GEMM runs near peak.  These constants are the
+# lands far below attainable (on a v5e the 23^3 f32 Pallas kernel does
+# 149 GFLOP/s and the f64 grouped path 6.3 — PERF.md, PR 21); one big
+# padded GEMM runs nearer peak.  These constants are the
 # model's PRIOR — the planner's decision is overridden per device by
 # learned `format`/`format_occ` rows in the tune params table, so a
 # wrong prior costs one mis-crossover window, not the fleet's steady
@@ -324,10 +324,22 @@ def device_kind() -> str:
         return "unknown"
 
 
+def _longest_match(table: dict, kind: str) -> str | None:
+    return max((key for key in table if key in kind), key=len, default=None)
+
+
+def peaks_key(kind: str | None = None) -> str | None:
+    """The `_PEAKS` row a device kind selects (longest lowercase
+    substring match), or None for a kind no row names."""
+    return _longest_match(_PEAKS, (kind or device_kind()).lower())
+
+
 def peaks_for(kind: str | None = None) -> dict:
     """Peak entry for a device kind: longest-matching table row, with
-    env overrides folded in.  Unknown kinds get the conservative
-    generic entry."""
+    env overrides folded in.  A TPU kind no row names is an error — the
+    format planner's cost curves read these, and a guessed peak would
+    steer them silently; other unknown kinds (a backend not yet
+    initialized reports "unknown") get the conservative generic entry."""
     kind = (kind or device_kind()).lower()
     table = dict(_PEAKS)
     for key, row in _env_overrides().items():
@@ -337,11 +349,12 @@ def peaks_for(kind: str | None = None) -> dict:
         base.update(row)
         base["gflops"] = gf
         table[key.lower()] = base
-    best = None
-    for key, row in table.items():
-        if key in kind and (best is None or len(key) > len(best[0])):
-            best = (key, row)
-    entry = dict(best[1]) if best else dict(_DEFAULT_PEAK)
+    best = _longest_match(table, kind)
+    if best is None and "tpu" in kind:
+        raise KeyError(
+            f"no peaks row for TPU device kind {kind!r}: add it to "
+            f"obs.costmodel._PEAKS (have {sorted(_PEAKS)})")
+    entry = dict(table[best] if best else _DEFAULT_PEAK)
     env_gf = os.environ.get("DBCSR_TPU_PEAK_GFLOPS")
     if env_gf:
         entry["gflops"] = {d: float(env_gf) for d in

@@ -27,7 +27,7 @@ Off switch: ``DBCSR_TPU_EVENTS=0`` disables the ring, the sink AND the
 health-window sampling; `publish` then only forwards to trace/flight
 exactly as the call sites did before this module existed — the
 measured bus-off cost is one function call + two attribute checks per
-event site (PERF_NOTES.md).
+event site.
 
 Stdlib-only: `core.stats`/`acc.smm` reach this module from their hot
 paths via `obs.metrics`/`obs.flight`, which must not pull in jax.

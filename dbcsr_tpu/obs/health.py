@@ -14,8 +14,7 @@ prints.
   ``xla`` driver (the chain's backstop) or ≥4 concurrently open
   breakers is critical.
 * ``watchdog`` — wedge streaks per guarded channel
-  (`dbcsr_tpu_watchdog_wedge_streak`): streak ≥1 degrades, ≥3 critical
-  (the capture loop's backoff has reached hours by then).
+  (`dbcsr_tpu_watchdog_wedge_streak`): streak ≥1 degrades, ≥3 critical.
 * ``engine`` — proven numeric corruption (checksum retries classified
   ``deterministic``/``unstable``) is critical; a degraded-to-serial
   world join or an active fallback/recompile storm degrades.
@@ -48,8 +47,7 @@ median/MAD):
 * ``dispatch_latency_spike`` — a multiply's wall time exceeds
   ``median * (1 + max(0.5, 3*MAD/median))`` of the window.
 * ``roofline_collapse`` — a driver's per-multiply roofline fraction
-  drops below half the window median (device silently throttled,
-  tunnel latency regime change).
+  drops below half the window median (device silently throttled).
 * ``shed_storm`` — the serving plane (`dbcsr_tpu.serve`) shed more
   than ``DBCSR_TPU_HEALTH_SHED_RATE`` (0.25) of the last admission
   window (fed per decision by `observe_serve`; surfaces as a

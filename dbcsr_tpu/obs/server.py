@@ -1,6 +1,6 @@
 """Opt-in HTTP introspection endpoint for live long-running jobs.
 
-A tiered capture loop or a multihost perf run used to be a black box:
+A long-lived serve worker or a multihost perf run used to be a black box:
 the only way to inspect it was to kill it and read JSONL off disk.
 With ``DBCSR_TPU_OBS_PORT=<port>`` set (or `start()` called), every
 engine process serves its live observability state over plain stdlib

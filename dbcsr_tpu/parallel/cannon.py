@@ -28,31 +28,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dbcsr_tpu.core import stats
 from dbcsr_tpu.parallel import overlap as _overlap
 from dbcsr_tpu.parallel.overlap import _HashableMesh
-from dbcsr_tpu.utils.compat import shard_map as _shard_map
 from dbcsr_tpu.core.timings import timed
 from dbcsr_tpu.obs import costmodel as _costmodel
 from dbcsr_tpu.obs import tracer as _trace
-
-
-def _resolve_mark_varying():
-    """Resolve the device-varying marker ONCE per process: `pcast`
-    (current jax), the deprecated `pvary`, or — on pre-varying-types
-    jax (the pinned 0.4.37), where shard_map tracks replication itself
-    — the identity."""
-    if hasattr(jax.lax, "pcast"):
-        return lambda x, axes: jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return lambda x, axes: jax.lax.pvary(x, axes)
-    return lambda x, axes: x
-
-
-_mark_varying = _resolve_mark_varying()
-
-
-def mark_varying(x, axes):
-    """Mark an array device-varying over mesh axes (no-op on jax
-    versions whose shard_map has no varying-axes type system)."""
-    return _mark_varying(x, axes)
 
 
 @functools.lru_cache(maxsize=None)
@@ -88,7 +66,7 @@ def _local_cannon(a_loc, b_loc, s: int, acc_dtype):
     c_loc = jnp.zeros((a_loc.shape[0], b_loc.shape[1]), acc_dtype)
     # mark the accumulator as device-varying so the fori_loop carry type
     # matches after the varying a@b lands in it
-    c_loc = mark_varying(c_loc, ("kl", "pr", "pc"))
+    c_loc = jax.lax.pcast(c_loc, ("kl", "pr", "pc"), to="varying")
     # permutation tables hoisted out of the traced tick body
     shift_a = _skew_perm(s, "shift_a")
     shift_b = _skew_perm(s, "shift_b")
@@ -133,7 +111,7 @@ def _dense_permute(a, b, *, s, mesh_ref, kind_a, kind_b):
         return (jax.lax.ppermute(a_loc, axes, _skew_perm(s, kind_a)),
                 jax.lax.ppermute(b_loc, axes, _skew_perm(s, kind_b)))
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh_ref.val,
         in_specs=(_SPEC_A, _SPEC_B), out_specs=(_SPEC_A, _SPEC_B),
     )(a, b)
@@ -153,7 +131,7 @@ def _dense_tick(a, b, c3, *, acc_name, mesh_ref):
         )
         return c.reshape((1,) + c.shape)
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh_ref.val,
         in_specs=(_SPEC_A, _SPEC_B, _SPEC_C3), out_specs=_SPEC_C3,
     )(a, b, c3)
@@ -166,7 +144,7 @@ def _dense_finish(c3, *, mesh_ref):
     def body(c_loc):
         return jax.lax.psum(c_loc.reshape(c_loc.shape[1:]), "kl")
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh_ref.val, in_specs=_SPEC_C3, out_specs=P("pr", "pc"),
     )(c3)
 
@@ -178,7 +156,7 @@ def _fused_cannon_program(mesh_ref, s: int, acc_name: str):
     retrace/recompile every multiply — on the exact path that serves as
     the cheap bitwise-reference fallback."""
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             functools.partial(_local_cannon, s=s,
                               acc_dtype=jnp.dtype(acc_name)),
             mesh=mesh_ref.val,

@@ -50,7 +50,6 @@ from dbcsr_tpu.ops.transformations import desymmetrize
 from dbcsr_tpu.parallel import overlap as _overlap
 from dbcsr_tpu.parallel.overlap import _HashableMesh
 from dbcsr_tpu.resilience import faults as _faults
-from dbcsr_tpu.utils.compat import shard_map as _shard_map
 from dbcsr_tpu.utils.rounding import bucket_size
 
 
@@ -296,10 +295,8 @@ def _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=0, nticks=None):
     stack additionally runs in `_tick_chunks` sub-chunks so peak temp
     memory stays bounded no matter how much product one tick carries."""
     bm, bn = a.shape[1], b.shape[2]
-    from dbcsr_tpu.parallel.cannon import mark_varying
-
     c = jnp.zeros((cap_c, bm, bn), acc_dtype)
-    c = mark_varying(c, ("kl", "pr", "pc"))
+    c = jax.lax.pcast(c, ("kl", "pr", "pc"), to="varying")
     shift_a, shift_b = _ring_perms(s) if s > 1 else ((), ())
 
     def tick(t, carry):
@@ -465,7 +462,7 @@ def _run_sparse_mesh(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
         c = (alpha * c + fac * c_in.astype(acc_dtype)).astype(c_in.dtype)
         return c.reshape((1, 1) + c.shape)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -511,7 +508,7 @@ def _mesh_tick_program(a_panels, b_panels, stacks, c_acc, t, *,
                                   acc_dtype=acc_dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -541,7 +538,7 @@ def _mesh_shift_program(a_panels, b_panels, *, s, mesh_ref):
         return (a.reshape((1, 1, 1) + a.shape),
                 b.reshape((1, 1, 1) + b.shape))
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh_ref.val,
         in_specs=(P("kl", "pr", "pc"), P("kl", "pr", "pc")),
@@ -567,7 +564,7 @@ def _mesh_finish_program(c_acc, c_init, alpha, beta_fac, *,
         c = (alpha * c + fac * c_in.astype(acc_dtype)).astype(c_in.dtype)
         return c.reshape((1, 1) + c.shape)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh_ref.val,
         in_specs=(
@@ -622,7 +619,7 @@ def _gather_shift_program(a_panels, b_panels, *, pr, pc, mesh_ref):
         return (a.reshape((1, 1, 1) + a.shape),
                 b.reshape((1, 1, 1) + b.shape))
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh_ref.val,
         in_specs=(P("kl", "pr", "pc"), P("kl", "pr", "pc")),
@@ -671,7 +668,7 @@ def _gather_tick_program(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
                 b_c.reshape((1, 1, 1) + b_c.shape),
                 c.reshape((1, 1, 1) + c.shape))
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("kl", "pr", "pc"),) * 6 + (P(),),
@@ -1634,14 +1631,12 @@ def _run_grouped_cannon(a_panels, b_panels, stacks, c_init, alpha, beta,
         b = b_p.reshape(b_p.shape[2:])  # (cap_b, bk, bn), replicated on kl
         st = st.reshape(st.shape[3:])  # (s, s_cap, 3) or (s, G_cap, 2*r0+1)
         c_in = c_in.reshape(c_in.shape[3:])  # (cap_c, bm, bn)
-        from dbcsr_tpu.parallel.cannon import mark_varying
-
-        b = mark_varying(b, ("kl",))
+        b = jax.lax.pcast(b, ("kl",), to="varying")
         c = _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=r0)
         c = (alpha * c + beta * c_in.astype(acc_dtype)).astype(c_in.dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -1685,7 +1680,7 @@ def _grouped_shift_program(a_panels, b_panels, *, s, mesh_ref):
         return (a.reshape((1, 1, 1) + a.shape),
                 b.reshape((1, 1) + b.shape))
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh_ref.val,
         in_specs=(P("kl", "pr", "pc"), P("pr", "pc")),
@@ -1706,11 +1701,9 @@ def _grouped_tick_program(a_panels, b_panels, stacks, c_acc, t, *,
     acc_dtype = jnp.dtype(acc_name)
 
     def body(a_p, b_p, st, c_p, t):
-        from dbcsr_tpu.parallel.cannon import mark_varying
-
         a = a_p.reshape(a_p.shape[3:])
         b = b_p.reshape(b_p.shape[2:])
-        b = mark_varying(b, ("kl",))
+        b = jax.lax.pcast(b, ("kl",), to="varying")
         st = st.reshape(st.shape[3:])    # (s, s_cap, w)
         c = c_p.reshape(c_p.shape[3:])   # (q*cap_c, bm, bn)
         entries = jax.lax.dynamic_index_in_dim(st, t, axis=0, keepdims=False)
@@ -1718,7 +1711,7 @@ def _grouped_tick_program(a_panels, b_panels, stacks, c_acc, t, *,
                                   acc_dtype=acc_dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -1746,7 +1739,7 @@ def _grouped_finish_program(c_acc, c_init, alpha, beta, *,
         c = (alpha * c + beta * c_in.astype(acc_dtype)).astype(c_in.dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh_ref.val,
         in_specs=(

@@ -11,7 +11,7 @@ previously hand-kept lists:
   corruption targets (`chaos_corrupt_targets`) from it;
 * the static analyzer (rule ``fault-site-registry``) checks that every
   literal site passed to `resilience.faults.maybe_inject` /
-  ``corrupt`` / ``fail_probe`` in source is registered here, and that
+  ``corrupt`` in source is registered here, and that
   every registered site appears in the docs table.
 
 Fields per site: ``boundary`` (docs-table cell), ``corruptible``
@@ -88,11 +88,6 @@ SITES = {
                     "fall back to a full recompute, nan/flip corrupt the "
                     "spliced C — `docs/resilience.md` § incremental)",
         "corruptible": True, "chaos": True, "dynamic": False,
-    },
-    "probe": {
-        "boundary": "`bench._probe_tpu`",
-        # bench-only boolean site (fail_probe), not a multiply boundary
-        "corruptible": False, "chaos": False, "dynamic": False,
     },
     "attribution": {
         "boundary": "the cost-attribution billing boundary "

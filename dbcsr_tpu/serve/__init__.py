@@ -12,7 +12,7 @@ thin glue over the engine machinery PRs 4–7 proved out:
   `obs.health.verdict()`: shed with a structured rejection on
   CRITICAL, queue with an enforced deadline on DEGRADED, admit on OK;
   per-tenant quotas (in-flight requests, queued bytes) and request
-  deadlines classified with the watchdog taxonomy (OK/SLOW/TRANSIENT/
+  deadlines classified with the watchdog outcome classes (OK/SLOW/TRANSIENT/
   WEDGED).
 * `coalesce` — the cross-request batching window: same-structure
   multiply requests (identical pattern fingerprints, dtype, scalars,

@@ -21,7 +21,7 @@ and a `health.observe_serve` sample feeding the shed-storm detector.
 Requests that expire while queued are dropped at pop time with the
 watchdog's ``WEDGED`` classification (they never ran); completed
 requests classify ``OK``/``SLOW`` (past deadline) /``TRANSIENT``
-(failed) — the watchdog taxonomy reused at request granularity.
+(failed) — the watchdog outcome classes reused at request granularity.
 """
 
 from __future__ import annotations
@@ -62,7 +62,7 @@ class Request:
     Clients block on `wait()`; the engine moves ``state`` through
     queued -> running -> done/failed (or shed/deadline_missed straight
     from admission/expiry) and classifies ``outcome`` with the
-    watchdog taxonomy."""
+    watchdog outcome classes."""
 
     __slots__ = (
         "request_id", "session", "op", "params", "priority", "t_submit",
@@ -199,7 +199,7 @@ class Request:
 
 
 def classify(req: Request) -> str:
-    """Watchdog-taxonomy outcome for a request that finished running:
+    """Watchdog outcome class for a request that finished running:
     OK within deadline, SLOW past it, TRANSIENT on failure (WEDGED is
     reserved for requests that expired before running)."""
     if req.error is not None:

@@ -10,9 +10,8 @@ makes tuning a continuous subsystem that runs INSIDE a serving or
 long-lived process:
 
 * `tune.miner` — scans the live telemetry history store
-  (`obs.timeseries` roofline cells) and committed capture artifacts
-  (``BENCH_CAPTURES.jsonl`` / ``PERF_CAPTURES.jsonl``) for
-  underperforming (driver, m, n, k, dtype) cells and ranks them by
+  (`obs.timeseries` roofline cells), and capture files when given
+  some, for underperforming (driver, m, n, k, dtype) cells and ranks them by
   **wasted FLOP-seconds**, so the tuner always works the most
   expensive cell first.
 * `tune.trials` — bounded, watchdog-guarded tuning trials executed OFF

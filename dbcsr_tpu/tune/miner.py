@@ -7,10 +7,9 @@ Two evidence sources, merged:
   per-(driver, mnk, dtype) flop cells (``dbcsr_tpu_cell_flops_total``)
   joined against their driver's achieved-GFLOP/s and roofline-fraction
   series — the exact substrate PR 11 built for this consumer;
-* COMMITTED capture artifacts (``PERF_CAPTURES.jsonl`` /
-  ``BENCH_CAPTURES.jsonl``): per-kernel micro-benchmark rows whose
-  measured GFLOP/s (or embedded ``modeled.roofline_fraction``) sit
-  below the floor.
+* capture files the caller names (JSONL of `acc.bench` kernel rows):
+  per-kernel micro-benchmark rows whose measured GFLOP/s (or embedded
+  ``modeled.roofline_fraction``) sit below the floor.
 
 A cell is *underperforming* when its driver's roofline fraction is
 below the per-device floor (``DBCSR_TPU_TUNE_FLOOR``, default 0.25) or
@@ -156,7 +155,7 @@ def _capture_rows(path: str) -> List[Dict]:
 
 
 def _mine_captures(paths) -> List[Dict]:
-    """Candidates from committed capture artifacts: per-kernel rows
+    """Candidates from capture files: per-kernel rows
     with a measured GFLOP/s (acc micro-bench schema) whose modeled
     roofline fraction — or donor-predicted rate — shows headroom."""
     out: List[Dict] = []
@@ -202,13 +201,6 @@ def _mine_captures(paths) -> List[Dict]:
                 "reason": "; ".join(reasons),
             })
     return out
-
-
-def _default_capture_paths() -> List[str]:
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    return [os.path.join(root, "PERF_CAPTURES.jsonl"),
-            os.path.join(root, "BENCH_CAPTURES.jsonl")]
 
 
 def mine_format(limit: Optional[int] = None) -> List[Dict]:
@@ -266,19 +258,17 @@ def mine_format(limit: Optional[int] = None) -> List[Dict]:
 
 
 def mine(limit: Optional[int] = None, query=None,
-         capture_paths=None) -> List[Dict]:
+         capture_paths=()) -> List[Dict]:
     """The ranked candidate-cell queue, most wasted FLOP-seconds first.
 
     ``query`` defaults to the live `obs.timeseries.query`;
-    ``capture_paths`` defaults to the repo's committed capture
-    artifacts (pass ``[]`` to mine telemetry only).  Duplicate
-    (m, n, k, dtype) cells keep the most-wasteful sighting."""
+    ``capture_paths`` names capture files to mine beside the telemetry
+    (none by default).  Duplicate (m, n, k, dtype) cells keep the
+    most-wasteful sighting."""
     if query is None:
         from dbcsr_tpu.obs import timeseries as ts
 
         query = ts.query
-    if capture_paths is None:
-        capture_paths = _default_capture_paths()
     cands = _mine_timeseries(query) + _mine_captures(capture_paths)
     best: Dict[tuple, Dict] = {}
     for c in cands:

@@ -389,14 +389,14 @@ def _breaker_open(driver: str, m: int, n: int, k: int, dtype) -> bool:
 
 def select_winner(candidates: List[Dict], m: int, n: int, k: int,
                   dtype) -> Optional[Dict]:
-    """The fastest candidate whose (driver, shape) breaker is not
-    open.  Returns None when every candidate is quarantined (the
-    service then promotes nothing)."""
-    best = None
-    for cand in candidates:
-        driver = cand.get("driver")
-        if driver and _breaker_open(driver, m, n, k, dtype):
-            continue
-        if best is None or cand.get("gflops", 0) > best.get("gflops", 0):
-            best = cand
-    return best
+    """The offline tuner's `winning_row` over the candidates whose
+    (driver, shape) breaker is not open.  Returns None when every
+    native candidate is quarantined (the service then promotes
+    nothing)."""
+    from dbcsr_tpu.acc.tune import winning_row
+
+    return winning_row([
+        cand for cand in candidates
+        if not (cand.get("driver")
+                and _breaker_open(cand["driver"], m, n, k, dtype))
+    ])

@@ -102,3 +102,27 @@ def test_tune_smm_writes_entry(tmp_path, monkeypatch):
     got = params_mod.lookup(4, 4, 4, np.float32)
     assert got is not None and got["gflops"] > 0
     params_mod._cache.clear()
+
+
+def test_winning_row_takes_the_driver_from_native_candidates():
+    """The v5e's 23^3 f64 sweep (PR 21): the demoted ``xla`` leg was
+    the fastest candidate, and as the whole row it sent NATIVE dispatch
+    to plain ``xla`` (1.6) where ``xla_group`` does 6.3.  The driver
+    must come from the native candidates; a faster demoted candidate
+    contributes the ``precision`` column only."""
+    from dbcsr_tpu.acc.tune import winning_row
+
+    cands = [
+        {"driver": "xla", "grouping": None, "gflops": 1.6},
+        {"driver": "xla", "grouping": None, "precision": "f32c",
+         "gflops": 0.7},
+        {"driver": "xla", "grouping": None, "precision": "f32",
+         "gflops": 40.38},
+        {"driver": "xla_group", "grouping": None, "r0": 8, "gflops": 6.3},
+    ]
+    row = winning_row(cands)
+    assert (row["driver"], row["r0"], row["gflops"]) == ("xla_group", 8, 6.3)
+    assert (row["precision"], row["precision_gflops"]) == ("f32", 40.38)
+    # a demoted candidate slower than the native winner stamps nothing
+    assert "precision" not in winning_row(cands[:2] + cands[3:])
+    assert winning_row(cands[1:3]) is None  # no native evidence, no row

@@ -533,6 +533,18 @@ def test_crosspack_vmem_tuned_dispatch(tmp_path, monkeypatch):
         len(kk) > 4 and kk[4] == "crosspack_vmem"
         for kk in smm._validated_kernels
     )
+    # the gate counts TILED bytes: the tuner's S=100000 operands (26 MB
+    # of 23x23 f32 data) tile to 147 MiB, which Mosaic refused on a
+    # v5e; its S=30000 operands (44 MiB tiled) compiled and validated
+    import jax
+
+    from dbcsr_tpu.acc import pallas_smm
+
+    def blocks(n):
+        return jax.ShapeDtypeStruct((n, 23, 23), jnp.float32)
+
+    assert not pallas_smm.supports_vmem_resident(blocks(6250), blocks(6250))
+    assert pallas_smm.supports_vmem_resident(blocks(1875), blocks(1875))
 
 
 def test_crosspack_compile_failure_demotes_to_base(monkeypatch):

@@ -459,7 +459,7 @@ def test_attribution_disabled_by_knob(monkeypatch):
 # ------------------------------------------------ offline artifacts
 
 def test_committed_usage_rollup_feeds_report_and_doctor():
-    """The capture loop's committed USAGE_ROLLUP.jsonl must stay
+    """The committed USAGE_ROLLUP.jsonl must stay
     readable by `tools/usage_report.py` (req/s-per-worker emitted) and
     by the doctor's usage section — the artifact IS the interface."""
     path = os.path.join(REPO, "USAGE_ROLLUP.jsonl")

@@ -1,0 +1,69 @@
+"""chip_smoke.py on the CPU: the leg bodies at a few blocks, the
+no-chip exit, the no-quiet-failover contract, and the compile-cache
+placement rule the smoke reports."""
+
+import os
+
+import jax
+import pytest
+
+import chip_smoke
+from dbcsr_tpu.core import lib
+from dbcsr_tpu.resilience import breaker, faults
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# 8 blocks of 5 and a ragged 3, like the north star's 434 x 23 + 18
+_TINY = dict(n=43, block=5, occupancy=0.4, seed=3)
+
+
+def test_single_device_legs_pass_tiny(capsys):
+    out = chip_smoke.run_legs(**_TINY, mesh=False)
+    assert set(out) == {"f64", "f64_filtered", "f32"}
+    for leg, res in out.items():
+        assert res["leg"] == leg and len(res["steady_s"]) == 2
+        assert res["driver_launches"], leg  # names the driver that ran
+    assert out["f64_filtered"]["checksum"] == pytest.approx(
+        out["f64"]["checksum"], rel=1e-9)
+    lines = capsys.readouterr().out.splitlines()
+    assert sum(line.startswith("CHECK ") for line in lines) == 3
+
+
+def test_mesh_leg_is_never_silently_absent(capsys, monkeypatch):
+    monkeypatch.setattr(jax, "devices", lambda *a: [object()] * 2)
+    assert chip_smoke.leg_mesh4(**_TINY, reference={}) is None
+    assert "mesh4 skipped: 2 device(s)" in capsys.readouterr().out
+
+
+def test_main_without_a_chip_exits_nonzero_naming_the_platform(capsys):
+    assert chip_smoke.main([]) == 2
+    cap = capsys.readouterr()
+    assert "cpu" in cap.err and cap.out == ""
+
+
+def test_leg_fails_when_a_failover_fires():
+    # uniform blocks: one span per C bin, so the per-span
+    # execute_stack site (not the fused one) carries the product
+    try:
+        with faults.inject_faults("execute_stack:raise,times=1"):
+            with pytest.raises(chip_smoke.SmokeFailure, match="failover"):
+                chip_smoke.leg_f64(n=40, block=5, occupancy=0.4, seed=3)
+    finally:
+        breaker.reset_board()
+
+
+def test_compile_cache_placement_rule(monkeypatch):
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        # placed from outside: the program sets no other directory
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        jax.config.update("jax_compilation_cache_dir", "/as/jax/read/it")
+        assert lib.place_compile_cache() == "/as/jax/read/it"
+        # not placed: the fixed, git-ignored directory in the checkout
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert lib.place_compile_cache() == os.path.join(_REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            _REPO, ".jax_cache")
+        with open(os.path.join(_REPO, ".gitignore")) as fh:
+            assert ".jax_cache/" in fh.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -165,38 +165,6 @@ def test_tas_chain_restage_collapse():
 
 # -------------------------------------------- committed A/B evidence
 
-def test_committed_contract_ab_row_gates_pass():
-    """The committed tier-2.10 capture row is the acceptance artifact:
-    the pipelined leg's measured gather-exposed fraction must be
-    strictly lower than the serial leg's, the chained leg's
-    steady-state restage bytes must collapse vs the unchained
-    control, checksums bitwise identical, and tools/perf_gate.py must
-    PASS both leg pairs."""
-    sys.path.insert(0, os.path.join(_REPO, "tools"))
-    import perf_gate
-
-    row = None
-    with open(os.path.join(_REPO, "BENCH_CAPTURES.jsonl")) as fh:
-        for line in fh:
-            try:
-                r = json.loads(line)
-            except ValueError:
-                continue
-            if r.get("tier") == "2.10" and r.get("ab"):
-                row = r
-    assert row is not None, "no committed tier-2.10 contraction A/B row"
-    assert row["checksum_bitwise_match"] is True
-    ab = row["ab"]
-    assert (ab["pipelined"]["exposed_fraction"]
-            < ab["serial"]["exposed_fraction"])
-    assert (max(ab["chained"]["per_iter_bytes"][1:])
-            < max(ab["unchained"]["per_iter_bytes"][1:]))
-    for base, cand in (("serial", "pipelined"), ("unchained", "chained")):
-        report = perf_gate.gate([ab[base]], [ab[cand]])
-        assert report["exit_code"] == 0, (base, cand, report)
-        assert report["regressed"] == 0
-
-
 def test_contract_bench_smoke(tmp_path):
     """The A/B tool runs end to end on a small case: exit 0, all four
     legs present, bitwise identical within each pair."""

@@ -206,8 +206,7 @@ def test_assembly_microbench_1e5_blocks():
     blocks = rng.standard_normal((n, 4, 4))
 
     # best-of-2: a background process stealing the core mid-phase
-    # compresses the ratio (observed under the TPU capture loop's
-    # probes); min-of-two is load-robust while keeping the regression
+    # compresses the ratio; min-of-two is load-robust while keeping the regression
     # bound meaningful
     batched_s = float("inf")
     for _ in range(2):

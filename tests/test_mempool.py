@@ -9,13 +9,10 @@ Covers the `core.mempool` contracts:
   when structure changes (finalize);
 * chaos: injected faults mid-chain must not corrupt donated buffers
   (the PR-4 decompose caveat extended to recycled storage);
-* pool observability (metrics snapshot, health thrash note) and the
-  committed chain A/B artifact gated through tools/perf_gate.py.
+* pool observability (metrics snapshot, health thrash note).
 """
 
-import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -491,56 +488,3 @@ def test_health_pool_thrash_note(monkeypatch):
     assert perf["status"] == health.DEGRADED
     assert any("pool thrash" in r for r in perf["reasons"])
     assert perf["pool"]["evictions"] >= 8
-
-
-# ------------------------------------------------------ committed A/B
-
-def _chain_rows():
-    rows = []
-    with open(os.path.join(REPO, "BENCH_CAPTURES.jsonl")) as fh:
-        for line in fh:
-            try:
-                r = json.loads(line)
-            except ValueError:
-                continue
-            if r.get("tier") == 2.7 and r.get("ab"):
-                rows.append(r)
-    return rows
-
-
-def test_committed_chain_ab_row_collapses_and_gates():
-    """The committed chain A/B artifact: bitwise-identical checksums,
-    restage bytes collapsing after iteration 1 on the pooled leg, and
-    a wall-clock speedup that PASSES tools/perf_gate.py with the
-    unpooled leg as baseline."""
-    rows = _chain_rows()
-    assert rows, "no tier-2.7 chain A/B row committed"
-    row = rows[-1]
-    assert row["checksum_bitwise_match"] is True
-    pooled = row["ab"]["pooled"]
-    unpooled = row["ab"]["unpooled"]
-    assert row["chain_iters"] >= 5
-    assert "23x23 blocks" in row["metric"]
-    # restage collapse: steady-state pooled bytes are a small fraction
-    # of the cold first iteration AND of the unpooled control
-    steady = max(pooled["per_iter_bytes"][1:])
-    assert steady < 0.1 * pooled["per_iter_bytes"][0]
-    assert steady < 0.1 * max(unpooled["per_iter_bytes"][1:])
-    # wall-clock: pooled leg at least as fast as the control
-    assert pooled["value"] >= unpooled["value"]
-    # and the machine gate agrees
-    import tempfile
-
-    with tempfile.TemporaryDirectory() as td:
-        basef = os.path.join(td, "base.json")
-        candf = os.path.join(td, "cand.json")
-        with open(basef, "w") as fh:
-            json.dump(unpooled, fh)
-        with open(candf, "w") as fh:
-            json.dump(pooled, fh)
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-             basef, candf],
-            capture_output=True, text=True, timeout=120,
-        )
-    assert r.returncode == 0, r.stdout + r.stderr

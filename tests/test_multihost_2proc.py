@@ -33,9 +33,8 @@ from jax.sharding import PartitionSpec as P
 def body(x):
     return jax.lax.psum(x, ("kl", "pr", "pc"))
 
-from dbcsr_tpu.utils.compat import shard_map
-fn = shard_map(body, mesh=mesh, in_specs=P(("kl", "pr", "pc")),
-               out_specs=P(("kl", "pr", "pc")))
+fn = jax.shard_map(body, mesh=mesh, in_specs=P(("kl", "pr", "pc")),
+                   out_specs=P(("kl", "pr", "pc")))
 n = int(np.prod(list(mesh.shape.values())))
 out = fn(jnp.ones((n,)))
 local = np.asarray(out.addressable_shards[0].data)

@@ -575,6 +575,11 @@ def test_roofline_peak_table_env_override(monkeypatch):
     rl = costmodel.roofline(1e6, 1e9, 1.0, kind="weird accel",
                             dtype="float64")
     assert rl["attainable_gflops"] == pytest.approx(1e-3 * 10.0)
+    # a TPU kind no row names is an error, never the generic default
+    assert costmodel.peaks_key("TPU v5 lite") == "tpu v5 lite"
+    assert costmodel.peaks_key("TPU v99") is None
+    with pytest.raises(KeyError, match="tpu v99"):
+        costmodel.peaks_for("TPU v99")
     monkeypatch.setattr(costmodel, "_env_table", None)
 
 

@@ -62,7 +62,7 @@ def test_fault_dsl_full_spec():
 
 
 def test_fault_dsl_multiple_specs_and_options():
-    specs = faults.parse("dense:nan,times=1; probe:fail,times=35;"
+    specs = faults.parse("dense:nan,times=1; serve_admit:fail,times=35;"
                          "multihost_init:hang,sleep=5")
     assert [s.kind for s in specs] == ["nan", "fail", "hang"]
     assert specs[1].times == 35 and specs[2].sleep == 5.0
@@ -97,13 +97,6 @@ def test_inject_faults_context_restores():
     with faults.inject_faults("x:raise"):
         assert faults.active()
     assert not faults.active()
-
-
-def test_fail_probe_streak():
-    with faults.inject_faults("probe:fail,times=2"):
-        assert faults.fail_probe("probe") is True
-        assert faults.fail_probe("probe") is True
-        assert faults.fail_probe("probe") is False  # streak healed
 
 
 # ------------------------------------------------------------- breaker

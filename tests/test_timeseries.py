@@ -813,7 +813,7 @@ def test_doctor_trend_cli_offline(tmp_path, capsys):
 def test_doctor_trend_committed_rollup_artifact():
     """The committed TELEMETRY_ROLLUP.jsonl artifact stays readable:
     doctor --trend must surface real per-cell history and the SLO
-    summary from it (the capture loop refreshes it per obs_schema)."""
+    summary from it."""
     path = os.path.join(_REPO, "TELEMETRY_ROLLUP.jsonl")
     assert os.path.exists(path), "committed telemetry rollup missing"
     meta = json.loads(open(path).readline())

@@ -25,9 +25,7 @@ noise-floor estimator).  The ``verify`` leg's final C is asserted
 only read, they never perturb the product.
 
 The output JSON (last stdout line) is a perf_gate-compatible capture
-row with both legs under ``ab`` — the committed-evidence shape of
-tiers 2.7-2.10, consumed by `tools/capture_tiered.py` tier 2.11 and
-committed to BENCH_CAPTURES.jsonl.
+row with both legs under ``ab``.
 
 Usage: python tools/abft_bench.py [--nblk 160] [--bsize 23] [--occ 0.1]
            [--reps 6] [--seed 7]

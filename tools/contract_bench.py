@@ -37,9 +37,7 @@ allocations; neither may change arithmetic.
 The output JSON (last stdout line) carries all four legs under ``ab``
 (``serial``/``pipelined`` and ``unchained``/``chained``) with distinct
 ``metric`` strings per pair, a ``cannon_mode`` stamp on the row and
-the pipeline legs, and the tier-2.7/2.8-style evidence fields —
-consumed by `tools/capture_tiered.py` tier 2.10 and committed to
-BENCH_CAPTURES.jsonl.
+the pipeline legs.
 
 Usage: python tools/contract_bench.py [--nblk 6] [--bsize 5]
            [--occ 0.6] [--nrep 4] [--iters 6] [--nsplit 6] [--seed 7]

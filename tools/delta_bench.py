@@ -32,8 +32,7 @@ is returned from the content-addressed product cache with ZERO engine
 dispatches and a bitwise-identical C.
 
 The output JSON (last stdout line) is a perf_gate-compatible capture
-row with both legs under ``ab``, consumed by `tools/capture_tiered.py`
-tier 2.13 and committed to BENCH_CAPTURES.jsonl.
+row with both legs under ``ab``.
 
 Usage: python tools/delta_bench.py [--nblk 40] [--bsize 32] [--occ 0.6]
            [--iters 8] [--delta 0.25] [--seed 7] [--driver xla]

@@ -16,13 +16,13 @@ Live mode (reads ``/healthz``, ``/metrics``, ``/events``, ``/flight``):
 Artifact mode (any subset; shard bases expand like DBCSR_TPU_TRACE):
 
     python tools/doctor.py --events events.jsonl --trace trace.jsonl \\
-        --probe capture_probe.jsonl --captures BENCH_CAPTURES.jsonl
+        --probe watchdog.jsonl --captures bench_rows.jsonl
 
 Trend mode (``--trend``): sparkline history tables per telemetry cell
 and the SLO burn summary, from a live endpoint's ``/timeseries`` +
 ``/slo`` routes or from committed time-series shard artifacts
-(``--timeseries``, default ``timeseries.jsonl``; the capture loop's
-committed ``TELEMETRY_ROLLUP.jsonl`` works too):
+(``--timeseries``, default ``timeseries.jsonl``; the committed
+``TELEMETRY_ROLLUP.jsonl`` works too):
 
     python tools/doctor.py --port 9100 --trend
     python tools/doctor.py --trend --timeseries TELEMETRY_ROLLUP.jsonl
@@ -72,21 +72,21 @@ HINTS = {
         "a quarantined driver keeps being re-routed; check the open "
         "breakers below and the driver chain", "#anomaly-fallback-storm"),
     "dispatch_latency_spike": (
-        "a multiply ran far over the rolling median; on a remote "
-        "tunnel this is the wedge signature — see the wedged-tunnel "
-        "runbook", "#anomaly-dispatch-latency-spike"),
+        "a multiply ran far over the rolling median; check for a "
+        "recompile, host contention or a device stall",
+        "#anomaly-dispatch-latency-spike"),
     "roofline_collapse": (
         "a driver's achieved fraction of roofline dropped below half "
-        "its window median; device throttled or tunnel latency regime "
-        "changed", "#anomaly-roofline-collapse"),
+        "its window median; the device is throttled or the host is "
+        "starving it", "#anomaly-roofline-collapse"),
     "breaker_open": (
         "a (driver, shape) is quarantined; the chain re-routes it — "
         "fix the kernel or force a safe driver",
         "#driver-failover--circuit-breakers"),
     "wedge_streak": (
-        "a guarded hardware channel is not answering; backoff is "
-        "exponential — check the tunnel before resetting anything",
-        "#runbook-wedged-tunnel"),
+        "a guarded channel is not answering; backoff is "
+        "exponential — find what it waits on before resetting anything",
+        "#watchdog"),
     "checksum_corruption": (
         "a checksum retry classified deterministic/unstable: proven "
         "numeric corruption — quarantine the driver and capture the "
@@ -103,7 +103,7 @@ HINTS = {
     "serve_deadline": (
         "queued requests are expiring before execution; shorten the "
         "coalescing window, raise worker capacity, or relax deadlines",
-        SERVE_RUNBOOK + "#deadlines--the-watchdog-taxonomy"),
+        SERVE_RUNBOOK + "#deadlines--the-watchdog-outcome-classes"),
     "incremental_degrade": (
         "the delta-aware incremental multiply breaker opened after "
         "repeated probe/fault failures and the plane degraded to full "
@@ -359,8 +359,8 @@ def analyze(health: dict | None, prom: dict, events: list,
         report["hints"].append(_hint("breaker_open", detail=", ".join(
             sorted(open_breakers))))
 
-    # watchdog: live gauge, else the LAST persisted probe record per
-    # channel (the capture loop's capture_probe.jsonl)
+    # watchdog: live gauge, else the LAST persisted record per
+    # channel (a `Watchdog(state_path=...)` JSONL)
     watchdog = {}
     for labels, v in prom.get("dbcsr_tpu_watchdog_wedge_streak", []):
         watchdog[labels.get("name", "?")] = {"wedge_streak": int(v)}
@@ -1351,7 +1351,6 @@ def _selftest(repo_root: str) -> int:
         parsed = doc.get("parsed")
         if isinstance(parsed, dict):
             captures.append(parsed)
-    captures += _read_jsonl(os.path.join(repo_root, "BENCH_CAPTURES.jsonl"))
     report = analyze(None, {}, events, [], probe, captures)
     render(report)
 
@@ -1546,10 +1545,11 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", default="trace.jsonl",
                     help="trace JSONL (shard base or file) — instants "
                          "feed the offender tables when no events exist")
-    ap.add_argument("--probe", default="capture_probe.jsonl",
-                    help="watchdog probe JSONL (capture loop)")
-    ap.add_argument("--captures", default="BENCH_CAPTURES.jsonl",
-                    help="bench capture JSONL (roofline fractions)")
+    ap.add_argument("--probe", default="watchdog.jsonl",
+                    help="watchdog outcome JSONL (Watchdog state_path)")
+    ap.add_argument("--captures", default="bench_rows.jsonl",
+                    help="bench.py output rows, JSONL (roofline "
+                         "fractions)")
     ap.add_argument("--bundle",
                     help="incident bundle JSONL (dbcsr_tpu.obs."
                          "incidents, incidents/incident-*.jsonl): "
@@ -1716,7 +1716,7 @@ def main(argv=None) -> int:
 
     report = analyze(health, prom, events, flight, probe, captures,
                      top=args.top, usage=usage, capacity=capacity)
-    # tier-0 lint artifact (tools/capture_tiered.py banks LINT.json):
+    # lint artifact (`python -m tools.lint --json > LINT.json`):
     # a tree that fails its own invariant analyzer taints every other
     # number this report vouches for
     lint_path = os.path.join(repo_root, "LINT.json")

@@ -38,8 +38,7 @@ Hermetic: the params table lands in a temp dir — the bench's learned
 promotions never pollute the user's real table.
 
 The output JSON (last stdout line) is a perf_gate-compatible capture
-row; per-point legs live under ``sweep``.  Committed to
-BENCH_CAPTURES.jsonl (tier: storage formats).
+row; per-point legs live under ``sweep``.
 
 Usage: python tools/format_bench.py [--nblk 24] [--bsize 16]
            [--occs 0.15,0.45,0.9] [--band 2] [--reps 5] [--seed 7]

@@ -1,7 +1,7 @@
 """Rule family 5 — choke-point conformance.
 
 ``fault-site-registry``: every literal site passed to
-`resilience.faults.maybe_inject` / ``corrupt`` / ``fail_probe`` must
+`resilience.faults.maybe_inject` / ``corrupt`` must
 be registered in `dbcsr_tpu/resilience/sites.py` — an unregistered
 site is invisible to the chaos suite and to docs/resilience.md.
 
@@ -36,7 +36,7 @@ RULE_SITE_DOCS = "fault-site-docs"
 RULE_METRIC = "metric-docs"
 RULE_BYPASS = "event-bypass"
 
-FAULT_CALLS = {"maybe_inject", "corrupt", "fail_probe"}
+FAULT_CALLS = {"maybe_inject", "corrupt"}
 FAULTS_IMPL = ("dbcsr_tpu/resilience/faults.py",
                "dbcsr_tpu/resilience/sites.py")
 METRIC_RE = re.compile(r"^dbcsr_tpu_[a-z0-9_]+$")

@@ -20,11 +20,7 @@ Checksums of the two legs are asserted **bitwise identical** (exit 1
 on mismatch): double buffering reorders dispatches, never arithmetic.
 
 The output JSON (last stdout line) is a perf_gate-compatible capture
-row with both legs under ``ab`` and a ``cannon_mode`` stamp, the same
-committed-evidence shape as the tier-2.7 chain A/B — consumed by
-`tools/capture_tiered.py` tier 2.8 and committed to
-BENCH_CAPTURES.jsonl so future bench pickers can select the Cannon
-mode from evidence.
+row with both legs under ``ab`` and a ``cannon_mode`` stamp.
 
 Usage: python tools/overlap_bench.py [--nblk 24] [--bsize 5]
            [--occ 0.4] [--nrep 5] [--seed 7]

@@ -13,8 +13,7 @@ Accepted capture formats (auto-detected, mixable):
   ``value``);
 * the round artifacts ``BENCH_rNN.json`` (a wrapper whose ``parsed``
   field holds the bench dict);
-* JSONL capture logs (``BENCH_CAPTURES.jsonl`` /
-  ``PERF_CAPTURES.jsonl`` — one record per line, torn tail lines
+* JSONL capture logs (one record per line, torn tail lines
   skipped);
 * a JSON list of any of the above records.
 
@@ -91,7 +90,7 @@ def load_records(path: str) -> list:
         try:
             records.extend(_records_of(json.loads(line)))
         except ValueError:
-            continue  # torn tail line (capture loop killed mid-append)
+            continue  # torn tail line (writer killed mid-append)
     return records
 
 

@@ -21,9 +21,7 @@ are asserted **bitwise identical** (exit 1 on mismatch): coalescing
 reorders nothing inside a product's accumulation (docs/serving.md).
 
 The output JSON (last stdout line) is a perf_gate-compatible capture
-row with both legs under ``ab`` — the same committed-evidence shape as
-tiers 2.7/2.8, consumed by `tools/capture_tiered.py` tier 2.9 and
-committed to BENCH_CAPTURES.jsonl.
+row with both legs under ``ab``.
 
 Usage: python tools/serve_bench.py [--tenants 4] [--requests 6]
            [--nblk 8] [--bsize 5] [--occ 0.5] [--seed 7]

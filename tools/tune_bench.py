@@ -21,8 +21,7 @@ the legs whatever row dispatch picks up — asserted per iteration (exit
 checksum-pinnable.  ``value`` is the leg's true-flop GFLOP/s.
 
 The output JSON (last stdout line) is a perf_gate-compatible capture
-row with both legs under ``ab``, consumed by `tools/capture_tiered.py`
-tier 2.14 and committed to BENCH_CAPTURES.jsonl.  The whole run uses a
+row with both legs under ``ab``.  The whole run uses a
 TEMPORARY params dir — the committed device tables are never touched.
 
 Usage: python tools/tune_bench.py [--nblk 12] [--bsize 23] [--occ 0.5]

@@ -1,7 +1,6 @@
 """dbcsr_tpu usage report: tenant cost rollup -> capacity estimate.
 
-Reads the committed ``USAGE_ROLLUP.jsonl`` artifact (written by the
-capture loop's usage tier, `tools/capture_tiered.py`) or any file in
+Reads the committed ``USAGE_ROLLUP.jsonl`` artifact or any file in
 the same shape, and turns the attributed per-request device time plus
 the serving SLO latency target into the number an on-call/capacity
 planner actually wants: **sustainable requests/s per worker**.
