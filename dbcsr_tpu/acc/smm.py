@@ -38,6 +38,7 @@ from dbcsr_tpu.acc import abft as _abft
 from dbcsr_tpu.core import mempool as _mempool
 from dbcsr_tpu.core.config import get_config
 from dbcsr_tpu.core.kinds import real_dtype_of
+from dbcsr_tpu.core.timings import device_scope
 from dbcsr_tpu.obs import costmodel as _costmodel
 from dbcsr_tpu.obs import events as _events
 from dbcsr_tpu.obs import flight as _flight
@@ -126,16 +127,20 @@ def _batch_dot(a, b, acc, prec):
 def _chunk_contrib(a_data, b_data, a_idx, b_idx, c_idx, alpha, nseg,
                    out_dtype, prec=None):
     """One stack chunk: gather -> batched matmul -> sorted segment-sum."""
-    a = jnp.take(a_data, a_idx, axis=0)
-    b = jnp.take(b_data, b_idx, axis=0)
+    with device_scope("stk_gather"):
+        a = jnp.take(a_data, a_idx, axis=0)
+        b = jnp.take(b_data, b_idx, axis=0)
     acc = _accum_dtype(out_dtype)
-    prod = _batch_dot(a, b, acc, prec)
-    prod = (alpha.astype(acc) * prod).astype(out_dtype)
-    return jax.ops.segment_sum(prod, c_idx, num_segments=nseg, indices_are_sorted=True)
+    with device_scope("stk_dot"):
+        prod = _batch_dot(a, b, acc, prec)
+        prod = (alpha.astype(acc) * prod).astype(out_dtype)
+    with device_scope("stk_accum"):
+        return jax.ops.segment_sum(
+            prod, c_idx, num_segments=nseg, indices_are_sorted=True)
 
 
-def _stack_xla_flat_body(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
-                         prec=None):
+def _stack_phases_xla_flat(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
+                           prec=None):
     """Flat-gather variant: A/B are re-laid-out once per call to
     (N, m*k) so the per-entry gathers move lane-packed rows instead of
     tile-padded (m, k) blocks — the TPU HBM layout pads the last two
@@ -145,21 +150,26 @@ def _stack_xla_flat_body(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
     configs).  Toggle: config.flat_gather."""
     nseg, m, n = c_data.shape
     k = a_data.shape[2]
-    a_flat = a_data.reshape(a_data.shape[0], m * k)
-    b_flat = b_data.reshape(b_data.shape[0], k * n)
+    with device_scope("stk_gather"):
+        a_flat = a_data.reshape(a_data.shape[0], m * k)
+        b_flat = b_data.reshape(b_data.shape[0], k * n)
 
     def body(c, idx):
         ai, bi, ci = idx
-        a = jnp.take(a_flat, ai, axis=0).reshape(-1, m, k)
-        b = jnp.take(b_flat, bi, axis=0).reshape(-1, k, n)
+        with device_scope("stk_gather"):
+            a = jnp.take(a_flat, ai, axis=0).reshape(-1, m, k)
+            b = jnp.take(b_flat, bi, axis=0).reshape(-1, k, n)
         acc = _accum_dtype(c.dtype)
-        prod = _batch_dot(a, b, acc, prec)
-        prod = (alpha.astype(acc) * prod).astype(c.dtype)
-        return c + jax.ops.segment_sum(
-            prod, ci, num_segments=nseg, indices_are_sorted=True
-        ), None
+        with device_scope("stk_dot"):
+            prod = _batch_dot(a, b, acc, prec)
+            prod = (alpha.astype(acc) * prod).astype(c.dtype)
+        with device_scope("stk_accum"):
+            return c + jax.ops.segment_sum(
+                prod, ci, num_segments=nseg, indices_are_sorted=True
+            ), None
 
-    c_data, _ = jax.lax.scan(body, c_data, (a_idx, b_idx, c_idx))
+    with device_scope("stk_loop"):
+        c_data, _ = jax.lax.scan(body, c_data, (a_idx, b_idx, c_idx))
     return c_data
 
 
@@ -169,13 +179,29 @@ def _stack_xla_flat_body(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
 # instead).  ``prec`` (the executed-precision spec) is static: each
 # demoted specialization compiles its own program, exactly like the
 # reference's per-(m,n,k,dtype) kernel cache gaining a precision axis.
+#
+# The bodies' `stk_*` phase scopes (`core.timings.device_scope`) are
+# what the benchmark reads the device trace by: `stk_pad`, `stk_gather`,
+# `stk_dot`, `stk_accum` around the four steps, and `stk_loop` around
+# the chunk scan for what the compiler puts at the `while` itself (its
+# copies and converts of the loop-carried C and operands); an op counts
+# under its innermost scope.  The bodies' names are the XLA modules'
+# names (`jit__stack_phases_*`, `jit_fused_superstack`).  The persistent
+# compile cache keys a program on its name and its ops but NOT on their
+# metadata, where a scope lives: an executable cached before a scope
+# was added, moved or renamed would be loaded in the new code's place,
+# and every trace of it would read scopeless.  So whenever the scopes of
+# one of these four programs change, its function gets a new name (keep
+# it under `jit__stack_*` / `jit_fused*`, the benchmark's module
+# patterns); do not turn `jax_compilation_cache_include_metadata_in_key`
+# on instead, which would recompile everything whenever a line moves.
 _process_stack_xla_flat = functools.partial(
     jax.jit, donate_argnums=0, static_argnames=("prec",))(
-    _stack_xla_flat_body)
+    _stack_phases_xla_flat)
 
 
-def _stack_xla_group_body(c_data, a_data, b_data, ga, gb, gc, alpha,
-                          prec=None):
+def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
+                            prec=None):
     """R-tiled ("k-merged") stack layout: entries sharing a C block are
     tiled into groups of R0; each group's A blocks concatenate along k
     into one (m, R0*k) strip, its B blocks into (R0*k, n), and the
@@ -201,24 +227,30 @@ def _stack_xla_group_body(c_data, a_data, b_data, ga, gb, gc, alpha,
     def body(c, idx):
         ia, ib, ic = idx
         ch = ia.shape[0]
-        ablk = jnp.take(a_data, ia.reshape(-1), axis=0).reshape(ch, r0, m, k)
-        bblk = jnp.take(b_data, ib.reshape(-1), axis=0).reshape(ch, r0, k, n)
-        amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, r0 * k)
-        bmat = bblk.reshape(ch, r0 * k, n)
+        with device_scope("stk_gather"):
+            ablk = jnp.take(
+                a_data, ia.reshape(-1), axis=0).reshape(ch, r0, m, k)
+            bblk = jnp.take(
+                b_data, ib.reshape(-1), axis=0).reshape(ch, r0, k, n)
+            amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, r0 * k)
+            bmat = bblk.reshape(ch, r0 * k, n)
         acc = _accum_dtype(c.dtype)
-        prod = _batch_dot(amat, bmat, acc, prec)
-        prod = (alpha.astype(acc) * prod).astype(c.dtype)
-        return c + jax.ops.segment_sum(
-            prod, ic, num_segments=nseg, indices_are_sorted=True
-        ), None
+        with device_scope("stk_dot"):
+            prod = _batch_dot(amat, bmat, acc, prec)
+            prod = (alpha.astype(acc) * prod).astype(c.dtype)
+        with device_scope("stk_accum"):
+            return c + jax.ops.segment_sum(
+                prod, ic, num_segments=nseg, indices_are_sorted=True
+            ), None
 
-    c_data, _ = jax.lax.scan(body, c_data, (ga, gb, gc))
+    with device_scope("stk_loop"):
+        c_data, _ = jax.lax.scan(body, c_data, (ga, gb, gc))
     return c_data
 
 
 _process_stack_xla_group = functools.partial(
     jax.jit, donate_argnums=0, static_argnames=("prec",))(
-    _stack_xla_group_body)
+    _stack_phases_xla_group)
 
 
 def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
@@ -258,8 +290,8 @@ def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
     )
 
 
-def _stack_xla_body(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
-                    prec=None):
+def _stack_phases_xla(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
+                      prec=None):
     """Process a whole stack in one device program.
 
     The chunk loop lives INSIDE jit as a `lax.scan` over (nchunks, L)
@@ -276,15 +308,17 @@ def _stack_xla_body(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
         contrib = _chunk_contrib(
             a_data, b_data, ai, bi, ci, alpha, nseg, c.dtype, prec=prec
         )
-        return c + contrib, None
+        with device_scope("stk_accum"):
+            return c + contrib, None
 
-    c_data, _ = jax.lax.scan(body, c_data, (a_idx, b_idx, c_idx))
+    with device_scope("stk_loop"):
+        c_data, _ = jax.lax.scan(body, c_data, (a_idx, b_idx, c_idx))
     return c_data
 
 
 _process_stack_xla = functools.partial(
     jax.jit, donate_argnums=0, static_argnames=("prec",))(
-    _stack_xla_body)
+    _stack_phases_xla)
 
 
 def _append_pad_row(data):
@@ -292,8 +326,9 @@ def _append_pad_row(data):
     end of a data array (`append_a_pad`/`append_b_pad`) — the ONE
     definition of the pad convention shared by every per-span driver
     branch and the fused superstack program (they must agree bitwise)."""
-    return jnp.concatenate(
-        [data, jnp.zeros((1,) + data.shape[1:], data.dtype)])
+    with device_scope("stk_pad"):
+        return jnp.concatenate(
+            [data, jnp.zeros((1,) + data.shape[1:], data.dtype)])
 
 
 def pad_stack(a_idx, b_idx, c_idx, target_len: int, drop_segment: int):
@@ -1681,41 +1716,51 @@ def _fused_fn(sig):
         return fn
     family, interpret, spans_sig = sig
 
-    def fused(c_data, alpha_dev, *flat):
+    def fused_superstack(c_data, alpha_dev, *flat):
         from dbcsr_tpu.acc import pallas_smm
 
         pos = 0
-        for driver, n_idx, ap_a, ap_b, r_grp, kmerge, prec in spans_sig:
+        for i, (driver, n_idx, ap_a, ap_b, r_grp, kmerge,
+                prec) in enumerate(spans_sig):
             a_data = flat[pos]
             b_data = flat[pos + 1]
             idx = flat[pos + 2: pos + 2 + n_idx]
             pos += 2 + n_idx
-            if ap_a:
-                a_data = _append_pad_row(a_data)
-            if ap_b:
-                b_data = _append_pad_row(b_data)
-            if driver == "xla_group":
-                c_data = _stack_xla_group_body(
-                    c_data, a_data, b_data, *idx, alpha_dev, prec=prec)
-            elif driver == "pallas":
-                launches = [tuple(idx[3 * j: 3 * j + 3])
-                            for j in range(n_idx // 3)]
-                c_data = pallas_smm.process_launches(
-                    c_data, a_data, b_data, launches, alpha_dev,
-                    r_grp=r_grp, kmerge=kmerge, interpret=interpret,
-                )
-            else:
-                body = (_stack_xla_flat_body if driver == "xla_flat"
-                        else _stack_xla_body)
-                c_data = body(c_data, a_data, b_data, *idx, alpha_dev,
-                              prec=prec)
+            # device time per span, driver and (m,n,k) of one launch
+            with device_scope(_span_scope(i, driver, c_data, a_data)):
+                if ap_a:
+                    a_data = _append_pad_row(a_data)
+                if ap_b:
+                    b_data = _append_pad_row(b_data)
+                if driver == "xla_group":
+                    c_data = _stack_phases_xla_group(
+                        c_data, a_data, b_data, *idx, alpha_dev, prec=prec)
+                elif driver == "pallas":
+                    launches = [tuple(idx[3 * j: 3 * j + 3])
+                                for j in range(n_idx // 3)]
+                    c_data = pallas_smm.process_launches(
+                        c_data, a_data, b_data, launches, alpha_dev,
+                        r_grp=r_grp, kmerge=kmerge, interpret=interpret,
+                    )
+                else:
+                    body = (_stack_phases_xla_flat if driver == "xla_flat"
+                            else _stack_phases_xla)
+                    c_data = body(c_data, a_data, b_data, *idx, alpha_dev,
+                                  prec=prec)
         return c_data
 
-    fn = jax.jit(fused, donate_argnums=0)
+    fn = jax.jit(fused_superstack, donate_argnums=0)
     _fused_fns[sig] = fn
     while len(_fused_fns) > _FUSED_FN_MAX:
         _fused_fns.popitem(last=False)
     return fn
+
+
+def _span_scope(i: int, driver: str, c_data, a_data) -> str:
+    """`span<i>.<driver>.<m>x<n>x<k>`: the scope of span ``i`` of a fused
+    bin, its block shape read off the operands while they are traced."""
+    m, n = c_data.shape[1:]
+    return f"span{i}.{driver}.{m}x{n}x{a_data.shape[2]}"
 
 
 def _superstack_key(c_data, nspans: int) -> tuple:

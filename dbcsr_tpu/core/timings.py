@@ -110,6 +110,28 @@ def timed(name: str):
         timestop(name)
 
 
+def device_scope(name: str):
+    """Name a phase INSIDE a device program: the device counterpart of
+    `timed`.
+
+    `timed` brackets host code: its span is host time on the profiler's
+    clock (around an async dispatch that is the dispatch, never the
+    device's work).  This returns `jax.named_scope(name)` and nothing
+    else: ops created under it while JAX traces a jitted function carry
+    the name in their HLO metadata (`op_name`), a device trace hands it
+    back with every op event (`tf_op`), and device time can be summed
+    per phase.  A scope exists only while the function is traced: no
+    timer, no tracer call, nothing per launch.
+
+    The persistent compile cache keys a program WITHOUT its metadata,
+    so an executable cached before a scope was added or renamed is
+    loaded in the new code's place, scopeless: give the jitted function
+    a new name whenever its scopes change (`acc.smm`)."""
+    import jax
+
+    return jax.named_scope(name)
+
+
 def reset() -> None:
     _stats.clear()
     _stack.clear()

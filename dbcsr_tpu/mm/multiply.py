@@ -370,7 +370,11 @@ def _multiply_body(a, b, c, alpha, beta, retain_sparsity, filter_eps,
     if filter_eps is not None and not retain_sparsity:
         with timed("multiply_filter"):
             nblks_pre = c.nblks
-            norms = c.block_norms()
+            # the norms need C: on an async device this call is the
+            # wait for the stack launches, and gets a span of its own
+            # so that multiply_filter's self time is the host's work
+            with timed("multiply_filter_norms"):
+                norms = c.block_norms()
             compress(c, norms.astype(np.float64) ** 2 >= float(filter_eps) ** 2)
             _flight.note("filtered_blocks", nblks_pre - c.nblks)
             _flight.note("kept_blocks", c.nblks)
