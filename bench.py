@@ -249,9 +249,6 @@ def main():
         # config) — GFLOP/s is always TRUE sparse-product flops over
         # wall time either way
         "algorithm": res.get("algorithm"),
-        # dense-carve lowering in effect; null when no dense carve ran
-        "carve": (mm_multiply._carve_choice()
-                  if res.get("algorithm") == "dense" else None),
         # stack execution mode in effect ("auto" resolves to fused
         # superstack launches); null when the dense path ran instead
         "stack_mode": (mm_multiply._superstack_mode()

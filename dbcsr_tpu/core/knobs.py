@@ -100,11 +100,6 @@ KNOBS = {
         "doc": "reference-window samples frozen into a change-point "
                "baseline (default 12).",
     },
-    "DBCSR_TPU_DENSE_CARVE": {
-        "owner": "mm/multiply.py",
-        "doc": "dense-path operand carve lowering: 'gather' (default) or "
-               "'reshape'; read outside jit and threaded as a static arg.",
-    },
     "DBCSR_TPU_DENSE_PROFILE": {
         "owner": "mm/multiply.py",
         "doc": "=1 emits the dense-path per-phase timing breakdown.",

@@ -602,34 +602,11 @@ def _blocks_to_dense(data, rows, cols, nbr, nbc, bm, bn):
     return _scatter_bin_to_canvas(canvas, data, ro, co, bm=bm, bn=bn)
 
 
-def _carve_choice() -> str:
-    """The dense-carve lowering, read OUTSIDE jit at every call site
-    and threaded in as a static argument — so the choice keys the jit
-    cache and an env change mid-process retraces instead of silently
-    keeping the stale lowering (ADVICE r4)."""
-    return os.environ.get("DBCSR_TPU_DENSE_CARVE", "gather")
-
-
-def _carve_full_pattern(cd, nbr, nbc, bm, bn, carve):
-    """Carve a product canvas into the FULL row-major block pattern.
-
-    Two lowerings, selected by ``carve`` (from ``DBCSR_TPU_DENSE_CARVE``
-    via `_carve_choice`, a static jit argument at every caller):
-    * ``gather`` — element-offset advanced-indexing gather (the
-      historical path): builds (nbr*nbc, bm, bn) index tensors, i.e. an
-      element-granular XLA gather over the whole canvas.
-    * ``reshape`` — reshape/transpose/reshape: the full row-major
-      carve is a pure layout permutation, which XLA lowers to a
-      near-bandwidth copy instead of a 10^8-entry gather.  The 4-D
-      intermediate is transient inside one fused program (the round-2
-      HBM-thrash lesson was about MATERIALIZED grid temps across
-      program boundaries) — but until it is A/B-timed on real
-      hardware the measured ``gather`` path stays the default."""
-    if carve == "gather":
-        keys = jnp.arange(nbr * nbc, dtype=jnp.int32)
-        ro = (keys // nbc) * bm
-        co = (keys % nbc) * bn
-        return _gather_bin_from_canvas(cd, ro, co, bm=bm, bn=bn)
+def _carve_full_pattern(cd, nbr, nbc, bm, bn):
+    """Carve a uniformly blocked product canvas into the FULL row-major
+    block pattern: a pure layout permutation (the (s, s) rectangle of
+    `_gather_bin_from_canvas_strided` with no ragged edge), traced
+    inside the caller's program."""
     return (
         cd.reshape(nbr, bm, nbc, bn)
         .transpose(0, 2, 1, 3)
@@ -638,9 +615,9 @@ def _carve_full_pattern(cd, nbr, nbc, bm, bn, carve):
 
 
 @functools.partial(jax.jit, donate_argnums=2,
-                   static_argnames=("nbr", "nbc", "bm", "bn", "carve"))
+                   static_argnames=("nbr", "nbc", "bm", "bn"))
 def _dense_product_to_blocks(ad, bd, c_blocks, c_keys, alpha, beta, nbr, nbc,
-                             bm, bn, carve):
+                             bm, bn):
     """Matmul on 2-D canvases, then carve the FULL row-major block
     pattern straight off the product canvas and scatter-add beta*old
     in block layout (position of old key k in the full pattern = k)."""
@@ -649,7 +626,7 @@ def _dense_product_to_blocks(ad, bd, c_blocks, c_keys, alpha, beta, nbr, nbc,
         ad, bd, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=acc,
     )
-    out = alpha * _carve_full_pattern(cd, nbr, nbc, bm, bn, carve)
+    out = alpha * _carve_full_pattern(cd, nbr, nbc, bm, bn)
     return out.at[c_keys].add(beta * c_blocks.astype(acc), mode="drop")
 
 
@@ -664,11 +641,10 @@ def _dense_dot_only(ad, bd):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=("nbr", "nbc", "bm", "bn", "carve"))
-def _dense_carve_only(cd, c_blocks, c_keys, alpha, beta, nbr, nbc, bm, bn,
-                      carve):
+                   static_argnames=("nbr", "nbc", "bm", "bn"))
+def _dense_carve_only(cd, c_blocks, c_keys, alpha, beta, nbr, nbc, bm, bn):
     """Profile-mode split: carve + beta-merge as its own program."""
-    out = alpha * _carve_full_pattern(cd, nbr, nbc, bm, bn, carve)
+    out = alpha * _carve_full_pattern(cd, nbr, nbc, bm, bn)
     return out.at[c_keys].add(beta * c_blocks.astype(out.dtype), mode="drop")
 
 
@@ -780,9 +756,8 @@ def _dense_multiply_general(a, b, c, alpha, beta) -> int:
     THIS is the production north-star path: m=10000 with (1,23) sizes
     expands to 434x23 + one 18 block (ceil-division blocking), so the
     uniform `_dense_multiply` never fires for it.  The profile buckets
-    and the gather/reshape carve A/B therefore live here too — a
-    hardware window spent profiling the uniform path would attribute
-    the wrong program."""
+    therefore live here too — a hardware window spent profiling the
+    uniform path would attribute the wrong program."""
     profile = os.environ.get("DBCSR_TPU_DENSE_PROFILE") == "1"
     if profile:
         from dbcsr_tpu.utils.sync import fetch_fence as _ff
@@ -790,8 +765,7 @@ def _dense_multiply_general(a, b, c, alpha, beta) -> int:
     t_start = time.perf_counter()
     _metrics.record_jit(
         "mm.multiply._dense_general_dot",
-        (a.nfullrows, b.nfullcols, a.nfullcols, str(np.dtype(c.dtype)),
-         _carve_choice()),
+        (a.nfullrows, b.nfullcols, a.nfullcols, str(np.dtype(c.dtype))),
     )
     with timed("dense_canvas_ab"):
         ad = _dense_canvas_cached(a, lambda: _to_dense_device(a))
@@ -843,33 +817,55 @@ def _dense_multiply_general(a, b, c, alpha, beta) -> int:
     return _true_product_flops(a, b)
 
 
-def _near_uniform(sizes) -> bool:
-    """All block sizes equal except a possibly-smaller LAST one — the
-    shape every ceil-division blocking (the perf driver's (1, s) sizes,
-    `expand_block_sizes`) produces.  Offsets then align to multiples of
-    the leading size, so a zero-padded canvas carves as a pure layout
-    permutation."""
+def _near_uniform(sizes):
+    """(s, q) when all block sizes equal ``s`` except a possibly-smaller
+    LAST one — the shape every ceil-division blocking (the perf
+    driver's (1, s) sizes, `expand_block_sizes`) produces — with ``q``
+    the number of blocks of size ``s``; None for any other blocking.
+    Each shape bin of the full pattern is then one rectangle of the
+    canvas, split at ``q*s``, already in slot order."""
     if len(sizes) == 0:
-        return False
-    s0 = int(sizes[0])
-    return bool(np.all(np.asarray(sizes[:-1]) == s0) and int(sizes[-1]) <= s0)
+        return None
+    s, last = int(sizes[0]), int(sizes[-1])
+    if last > s or not np.all(np.asarray(sizes[:-1]) == s):
+        return None
+    return s, len(sizes) - (last < s)
 
 
-@functools.partial(jax.jit, static_argnames=("nbr", "nbc", "bm", "bn"))
-def _carve_padded_reshape(cd, nbr, nbc, bm, bn):
-    """Pad the canvas to (nbr*bm, nbc*bn) and carve the full row-major
-    pattern via reshape/transpose — a near-bandwidth layout permutation
-    instead of an element-granular gather (the `reshape` leg of the
-    DBCSR_TPU_DENSE_CARVE A/B for near-uniform blockings)."""
-    pm = nbr * bm - cd.shape[0]
-    pn = nbc * bn - cd.shape[1]
-    if pm or pn:
-        cd = jnp.pad(cd, ((0, pm), (0, pn)))
-    return (
-        cd.reshape(nbr, bm, nbc, bn)
-        .transpose(0, 2, 1, 3)
-        .reshape(nbr * nbc, bm, bn)
-    )
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "cols", "bins", "replicated"))
+def _gather_bin_from_canvas_strided(canvas, *, rows, cols, bins,
+                                    replicated=None):
+    """Every shape bin of a near-uniformly blocked canvas's full pattern
+    as a layout permutation of one rectangle each — no index array.
+    ``rows``/``cols`` are `_near_uniform` of the blocking; ``bins`` is
+    ((bm, bn, capacity), ...) in `_bin_entries` order.  Slots are in
+    key order because the full pattern is row-major; each bin comes out
+    at its bucket capacity, zero tail included.  A canvas sharded over
+    a mesh is first gathered to ``replicated`` (its mesh's replicated
+    sharding): every device then holds all of C, as the gather leaves
+    it; unconstrained, GSPMD shards the bins by slot AND inside the
+    blocks.  (The name keeps the `jit__gather_bin_from_canvas*` module
+    prefix the benchmark's `dense_canvas_carve_s` reads.)"""
+    if replicated is not None:
+        canvas = jax.lax.with_sharding_constraint(canvas, replicated)
+    (rs, rq), (cs, cq) = rows, cols
+    r_end, c_end = rs * rq, cs * cq
+    out = []
+    for bm, bn, cap in bins:
+        # row strips first, columns second: the 4-D form of the whole
+        # rectangle would tile-pad its (cq, cs) minor pair 5x on a TPU
+        strip = canvas[:r_end] if bm == rs else canvas[r_end:]
+        nr = rq if bm == rs else 1
+        strip = strip.reshape(nr, bm, canvas.shape[1])
+        rect = strip[:, :, :c_end] if bn == cs else strip[:, :, c_end:]
+        nc = cq if bn == cs else 1
+        data = (rect.reshape(nr, bm, nc, bn).transpose(0, 2, 1, 3)
+                .reshape(nr * nc, bm, bn))
+        if cap > nr * nc:
+            data = jnp.pad(data, ((0, cap - nr * nc), (0, 0), (0, 0)))
+        out.append(data)
+    return tuple(out)
 
 
 def carve_full_pattern(c, cd) -> None:
@@ -877,44 +873,42 @@ def carve_full_pattern(c, cd) -> None:
     by bin (`dbcsr_make_undense`, `dbcsr_mm.F:770-810`); shared by the
     single-chip and mesh dense modes.
 
-    Two lowerings (the production side of the DBCSR_TPU_DENSE_CARVE
-    A/B — `_carve_choice` is read outside jit on every call):
-    * ``gather`` — per-bin element-offset gathers off the canvas (the
-      historical path; at the north star that is ~10^8 index entries).
-    * ``reshape`` — for near-uniform blockings (uniform except a
-      smaller last row/col block, i.e. every ceil-division blocking):
-      one padded reshape/transpose carve, then per-bin BLOCK-granular
-      takes and edge slices.  Falls back to gather when the blocking
-      is genuinely irregular."""
+    A near-uniform blocking (uniform except a smaller last row/col
+    block, i.e. every ceil-division blocking) is carved by one layout
+    program, `_gather_bin_from_canvas_strided`.  Anything else takes
+    per-bin element-offset gathers off the canvas."""
     nbr, nbc = c.nblkrows, c.nblkcols
     new_keys = np.arange(nbr * nbc, dtype=np.int64)
     rows = new_keys // nbc
     cols = new_keys % nbc
     nb, nsl, shapes = _bin_entries(c.row_blk_sizes, c.col_blk_sizes, rows, cols)
-    use_reshape = (
-        _carve_choice() == "reshape"
-        and _near_uniform(c.row_blk_sizes)
-        and _near_uniform(c.col_blk_sizes)
-    )
-    carved = None
-    if use_reshape:
-        carved = _carve_padded_reshape(
-            cd, nbr, nbc,
-            int(c.row_blk_sizes[0]), int(c.col_blk_sizes[0]),
+    counts = np.bincount(nb, minlength=len(shapes))
+    lead_rows = _near_uniform(c.row_blk_sizes)
+    lead_cols = _near_uniform(c.col_blk_sizes)
+    layout = lead_rows is not None and lead_cols is not None
+    _metrics.counter(
+        "dbcsr_tpu_dense_carve_total",
+        "carves of a dense product canvas into C's full block pattern, "
+        "by lowering: 'layout' (near-uniform blocking, a permutation) or "
+        "'gather' (irregular blocking, element offsets)",
+    ).inc(lowering="layout" if layout else "gather")
+    if layout:
+        mesh = getattr(cd.sharding, "mesh", None)
+        datas = _gather_bin_from_canvas_strided(
+            cd, rows=lead_rows, cols=lead_cols,
+            bins=tuple((bm, bn, bucket_size(int(n)))
+                       for (bm, bn), n in zip(shapes, counts)),
+            replicated=(None if mesh is None else
+                        jax.sharding.NamedSharding(
+                            mesh, jax.sharding.PartitionSpec())),
         )
-    roff = c.row_blk_offsets[rows]
-    coff = c.col_blk_offsets[cols]
-    bins = []
-    for b_id, (bm, bn) in enumerate(shapes):
-        sel = np.nonzero(nb == b_id)[0]
-        count = len(sel)
-        if use_reshape:
-            idx = np.empty(count, np.int64)
-            idx[nsl[sel]] = sel  # block-granular: flat key IS the
-            data = jnp.take(carved, jnp.asarray(idx), axis=0)  # carved row
-            if data.shape[1] != bm or data.shape[2] != bn:
-                data = data[:, :int(bm), :int(bn)]  # edge blocks: crop pad
-        else:
+    else:
+        roff = c.row_blk_offsets[rows]
+        coff = c.col_blk_offsets[cols]
+        datas = []
+        for b_id, (bm, bn) in enumerate(shapes):
+            sel = np.nonzero(nb == b_id)[0]
+            count = int(counts[b_id])
             ro = np.empty(count, np.int64)
             co = np.empty(count, np.int64)
             ro[nsl[sel]] = roff[sel]
@@ -922,12 +916,15 @@ def carve_full_pattern(c, cd) -> None:
             data = _gather_bin_from_canvas(
                 cd, jnp.asarray(ro), jnp.asarray(co), bm=int(bm), bn=int(bn)
             )
-        cap = bucket_size(count)
-        if cap > count:
-            data = jnp.concatenate(
-                [data, jnp.zeros((cap - count, int(bm), int(bn)), data.dtype)]
-            )
-        bins.append(_Bin((int(bm), int(bn)), data, count))
+            cap = bucket_size(count)
+            if cap > count:
+                data = jnp.concatenate(
+                    [data,
+                     jnp.zeros((cap - count, int(bm), int(bn)), data.dtype)]
+                )
+            datas.append(data)
+    bins = [_Bin(shape, data, int(n))
+            for shape, data, n in zip(shapes, datas, counts)]
     c.set_structure_from_device(new_keys, bins, binning=(nb, nsl, shapes))
 
 
@@ -961,8 +958,7 @@ def _dense_multiply(a, b, c, alpha, beta) -> int:
         from dbcsr_tpu.utils.sync import fetch_fence as _ff
 
     t_start = time.perf_counter()
-    dense_jit_key = (nbr, nbc, nbk, bm, bn, bk, str(np.dtype(c.dtype)),
-                     _carve_choice())
+    dense_jit_key = (nbr, nbc, nbk, bm, bn, bk, str(np.dtype(c.dtype)))
     dense_compiled = _metrics.record_jit(
         "mm.multiply._dense_product_to_blocks", dense_jit_key,
     )
@@ -1000,7 +996,6 @@ def _dense_multiply(a, b, c, alpha, beta) -> int:
             out = _dense_carve_only(
                 cd, c_blocks, c_keys_dev,
                 alpha_dev, beta_dev, nbr, nbc, bm, bn,
-                carve=_carve_choice(),
             )
             _ff(out)
     else:
@@ -1013,13 +1008,11 @@ def _dense_multiply(a, b, c, alpha, beta) -> int:
                 _dense_product_to_blocks,
                 (ad, bd, c_blocks, c_keys_dev, alpha_dev, beta_dev,
                  nbr, nbc, bm, bn),
-                kwargs={"carve": _carve_choice()},
                 model={"flops": dcost["flops"], "bytes": dcost["bytes"]},
             )
         out = _dense_product_to_blocks(
             ad, bd, c_blocks, c_keys_dev,
             alpha_dev, beta_dev, nbr, nbc, bm, bn,
-            carve=_carve_choice(),
         )
     out = _dense_guard(out)
     if _abft.enabled():
@@ -1082,16 +1075,16 @@ def _dense_strip_matmul(cd, a_data, a_ro, a_co, b_data, b_ro, b_co,
 
 @functools.partial(
     jax.jit, donate_argnums=0,
-    static_argnames=("nbc", "bm", "bn", "rows", "carve"),
+    static_argnames=("nbc", "bm", "bn", "rows"),
 )
 def _dense_strip_to_blocks(cd, c_blocks, strip_pos, alpha, beta,
-                           *, nbc, bm, bn, rows, carve):
+                           *, nbc, bm, bn, rows):
     """Carve one C m-strip canvas into its full row-major block pattern
     and merge beta*old (strip_pos: old block -> strip-local full-pattern
     position, out-of-strip dropped).  A strip is a full row-major
-    pattern over ``rows`` block rows, so it shares the gather/reshape
-    carve selection with the unchunked path."""
-    out = alpha * _carve_full_pattern(cd, rows, nbc, bm, bn, carve)
+    pattern over ``rows`` block rows, so it shares the layout carve
+    with the unchunked path."""
+    out = alpha * _carve_full_pattern(cd, rows, nbc, bm, bn)
     return out.at[strip_pos].add(beta * c_blocks.astype(out.dtype), mode="drop")
 
 
@@ -1179,7 +1172,7 @@ def _dense_multiply_chunked(a, b, c, alpha, beta) -> int:
             )
             out = _dense_strip_to_blocks(
                 cd, c_data, jnp.asarray(tile_pos), alpha_dev, beta_dev,
-                nbc=ncb, bm=bm, bn=bn, rows=mrb, carve=_carve_choice(),
+                nbc=ncb, bm=bm, bn=bn, rows=mrb,
             )
             # (padded-rows x padded-cols) tile pattern -> live blocks
             tiles.append(out.reshape(mrb, ncb, bm, bn)

@@ -510,9 +510,10 @@ def test_dense_chunked_gate_and_feasibility(monkeypatch):
     )
 
 
-def test_dense_carve_variants_equal(monkeypatch):
-    """The reshape carve is element-exact vs the gather carve and vs a
-    manual block slicing of the canvas (full row-major pattern)."""
+def test_uniform_carve_is_block_slicing():
+    """The uniform layout carve (`_carve_full_pattern`: `_dense_multiply`,
+    the chunked strips, the profile split) is element-exact vs a manual
+    block slicing of the canvas (full row-major pattern)."""
     import jax.numpy as jnp
 
     from dbcsr_tpu.mm import multiply as mm
@@ -520,10 +521,7 @@ def test_dense_carve_variants_equal(monkeypatch):
     rng = np.random.default_rng(7)
     nbr, nbc, bm, bn = 3, 4, 5, 7
     cd_np = rng.standard_normal((nbr * bm, nbc * bn))
-    cd = jnp.asarray(cd_np)
-    g = np.asarray(mm._carve_full_pattern(cd, nbr, nbc, bm, bn, "gather"))
-    r = np.asarray(mm._carve_full_pattern(cd, nbr, nbc, bm, bn, "reshape"))
-    assert np.array_equal(g, r)
+    r = np.asarray(mm._carve_full_pattern(jnp.asarray(cd_np), nbr, nbc, bm, bn))
     for bi in range(nbr):
         for bj in range(nbc):
             np.testing.assert_array_equal(
@@ -532,40 +530,114 @@ def test_dense_carve_variants_equal(monkeypatch):
             )
 
 
-def test_carve_choice_keys_jit_cache(monkeypatch):
-    """Changing DBCSR_TPU_DENSE_CARVE mid-process must RETRACE the
-    jitted dense programs, not silently keep the stale lowering
-    (ADVICE r4): the choice is read outside jit at every call site and
-    threaded through as a static argument."""
+def _carve_counts():
+    from dbcsr_tpu.obs import metrics
+
+    got = {"layout": 0.0, "gather": 0.0}
+    for labels, value in metrics.counter_items("dbcsr_tpu_dense_carve_total"):
+        got[labels["lowering"]] = value
+    return got
+
+
+def _gather_carve(c, cd):
+    """What `carve_full_pattern` did before the layout carve: per-bin
+    element-offset gathers in `_bin_entries` slot order, count rows."""
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.core.matrix import _bin_entries
+    from dbcsr_tpu.mm import multiply as mm
+
+    keys = np.arange(c.nblkrows * c.nblkcols)
+    rows, cols = keys // c.nblkcols, keys % c.nblkcols
+    nb, nsl, shapes = _bin_entries(c.row_blk_sizes, c.col_blk_sizes, rows, cols)
+    out = []
+    for b_id, (bm, bn) in enumerate(shapes):
+        sel = np.nonzero(nb == b_id)[0]
+        ro = np.empty(len(sel), np.int64)
+        co = np.empty(len(sel), np.int64)
+        ro[nsl[sel]] = c.row_blk_offsets[rows[sel]]
+        co[nsl[sel]] = c.col_blk_offsets[cols[sel]]
+        out.append(((bm, bn), np.asarray(mm._gather_bin_from_canvas(
+            cd, jnp.asarray(ro), jnp.asarray(co), bm=bm, bn=bn))))
+    return out
+
+
+_CARVE_BLOCKINGS = {
+    "uniform": ([5] * 4, [7] * 3),
+    "ragged_last_row": ([5] * 3 + [2], [7] * 3),
+    "ragged_last_col": ([5] * 4, [7] * 2 + [4]),
+    "both_ragged": ([23] * 3 + [18], [13] * 4 + [6]),
+    "single_block_row": ([6], [4] * 5 + [1]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32", "float64"])
+@pytest.mark.parametrize("blocking", sorted(_CARVE_BLOCKINGS))
+def test_layout_carve_matches_gather(blocking, dtype):
+    """Near-uniform blockings are carved by one layout program; every
+    bin is element-exact to `_gather_bin_from_canvas` in slot order and
+    comes out at its bucket capacity with a zero tail."""
     import jax.numpy as jnp
 
     from dbcsr_tpu.mm import multiply as mm
+    from dbcsr_tpu.utils.rounding import bucket_size
 
-    monkeypatch.setenv("DBCSR_TPU_DENSE_CARVE", "gather")
-    assert mm._carve_choice() == "gather"
-    monkeypatch.setenv("DBCSR_TPU_DENSE_CARVE", "reshape")
-    assert mm._carve_choice() == "reshape"
-    monkeypatch.delenv("DBCSR_TPU_DENSE_CARVE")
-    assert mm._carve_choice() == "gather"
+    rbs, cbs = _CARVE_BLOCKINGS[blocking]
+    rng = np.random.default_rng(11)
+    cd = jnp.asarray(rng.standard_normal((sum(rbs), sum(cbs))), dtype=dtype)
+    c = create("c", rbs, cbs, dtype=dtype)
+    before = _carve_counts()
+    mm.carve_full_pattern(c, cd)
+    after = _carve_counts()
+    assert after["layout"] == before["layout"] + 1
+    assert after["gather"] == before["gather"]
+    want = _gather_carve(c, cd)
+    assert [b.shape for b in c.bins] == [shape for shape, _ in want]
+    for b, (_, ref) in zip(c.bins, want):
+        got = np.asarray(b.data)
+        assert b.count == len(ref) and got.dtype == ref.dtype
+        assert got.shape[0] == bucket_size(b.count)
+        np.testing.assert_array_equal(got[: b.count], ref)
+        assert not np.any(got[b.count:])
+    assert sum(b.count for b in c.bins) == len(rbs) * len(cbs)
+    np.testing.assert_array_equal(to_dense(c), np.asarray(cd))
 
-    nbr, nbc, bm, bn = 2, 2, 3, 3
-    rng = np.random.default_rng(3)
-    cd_np = rng.standard_normal((nbr * bm, nbc * bn))
 
-    def run(carve):
-        # fresh buffers per call: donate_argnums consumes them
-        cd = jnp.asarray(cd_np)
-        cb = jnp.zeros((1, bm, bn))
-        ck = jnp.zeros((1,), jnp.int32)
-        return np.asarray(mm._dense_carve_only(
-            cd, cb, ck, 1.0, 0.0, nbr, nbc, bm, bn, carve=carve))
+def test_irregular_blocking_keeps_the_gather_carve():
+    """A genuinely irregular blocking (odd size in the middle) is no
+    rectangle per bin: it takes the element gather, and
+    `dbcsr_tpu_dense_carve_total` says which lowering each carve took."""
+    import jax.numpy as jnp
 
-    n0 = mm._dense_carve_only._cache_size()
-    g = run("gather")
-    r = run("reshape")
-    # distinct carve values -> distinct compiled programs, equal results
-    assert mm._dense_carve_only._cache_size() == n0 + 2
-    np.testing.assert_array_equal(g, r)
+    from dbcsr_tpu.mm import multiply as mm
+    from dbcsr_tpu.mm.multiply import _near_uniform
+
+    rbs = [23, 11, 23, 23]
+    assert not _near_uniform(np.asarray(rbs))
+    assert not _near_uniform(np.asarray([18, 23, 23]))
+    assert _near_uniform(np.asarray([23] * 3 + [18]))
+    assert _near_uniform(np.asarray([23, 23, 23]))
+    rng = np.random.default_rng(12)
+    cd = jnp.asarray(rng.standard_normal((sum(rbs), sum(rbs))))
+    before = _carve_counts()
+    c = create("c", rbs, rbs)
+    mm.carve_full_pattern(c, cd)
+    mid = _carve_counts()
+    assert (mid["gather"], mid["layout"]) == (before["gather"] + 1,
+                                              before["layout"])
+    np.testing.assert_array_equal(to_dense(c), np.asarray(cd))
+    for b, (_, ref) in zip(c.bins, _gather_carve(c, cd)):
+        np.testing.assert_array_equal(np.asarray(b.data)[: b.count], ref)
+    # near-uniform rows do not make irregular columns a rectangle
+    c2 = create("c2", [23] * 3 + [11], rbs)
+    mm.carve_full_pattern(c2, cd)
+    c3 = create("c3", [23] * 3 + [11], [23] * 3 + [11])
+    mm.carve_full_pattern(c3, cd)
+    end = _carve_counts()
+    assert (end["gather"], end["layout"]) == (mid["gather"] + 1,
+                                              mid["layout"] + 1)
+    np.testing.assert_array_equal(to_dense(c2), np.asarray(cd))
+    np.testing.assert_array_equal(to_dense(c3), np.asarray(cd))
 
 
 def test_dense_profile_mode_matches_default(monkeypatch):
@@ -582,22 +654,27 @@ def test_dense_profile_mode_matches_default(monkeypatch):
     np.testing.assert_array_equal(to_dense(c_ref), to_dense(c_prof))
 
 
-@pytest.mark.parametrize("carve", ["gather", "reshape"])
-def test_dense_general_carve_variants_match_oracle(carve, monkeypatch):
+@pytest.mark.parametrize("blocking", ["near_uniform", "irregular"])
+def test_dense_general_carve_variants_match_oracle(blocking):
     """The PRODUCTION north-star shape is near-uniform (ceil-division
     blocking: uniform 23s + one trailing 18), which routes through
-    _dense_multiply_general/carve_full_pattern — both carve lowerings
-    must be oracle-exact there (the on-chip A/B measures this path)."""
-    monkeypatch.setenv("DBCSR_TPU_DENSE_CARVE", carve)
+    _dense_multiply_general/carve_full_pattern and the layout carve; an
+    irregular blocking takes the gather there.  Both must be
+    oracle-exact."""
     from dbcsr_tpu.core.config import set_config
 
-    rbs = [23] * 6 + [18]   # near-uniform rows
-    cbs = [13] * 5 + [7]    # near-uniform cols, different size
+    if blocking == "near_uniform":
+        rbs = [23] * 6 + [18]   # near-uniform rows
+        cbs = [13] * 5 + [7]    # near-uniform cols, different size
+    else:
+        rbs = [23, 11, 23, 23, 5, 23, 18]
+        cbs = [13, 7, 13, 13, 13, 2]
     kbs = [23] * 4 + [11]
     a = _rand("a", rbs, kbs, 0.6, seed=31)
     b = _rand("b", kbs, cbs, 0.6, seed=32)
     c = _rand("c", rbs, cbs, 0.4, seed=33)
     c0 = to_dense(c)
+    before = _carve_counts()
     set_config(mm_dense=True)
     try:
         multiply("N", "N", 1.5, a, b, 0.5, c)
@@ -605,26 +682,40 @@ def test_dense_general_carve_variants_match_oracle(carve, monkeypatch):
         set_config(mm_dense=None)
     want = 1.5 * (to_dense(a) @ to_dense(b)) + 0.5 * c0
     np.testing.assert_allclose(to_dense(c), want, rtol=1e-12, atol=1e-12)
+    after = _carve_counts()
+    took = "layout" if blocking == "near_uniform" else "gather"
+    assert {k: after[k] - before[k] for k in after} == {
+        "layout": float(took == "layout"), "gather": float(took == "gather")}
 
 
-def test_dense_general_irregular_blocking_reshape_falls_back(monkeypatch):
-    """A genuinely irregular blocking (odd size in the middle) cannot
-    reshape-carve; the choice must silently fall back to gather."""
-    monkeypatch.setenv("DBCSR_TPU_DENSE_CARVE", "reshape")
+def test_dense_mesh_carve_matches_one_chip():
+    """`_dense_multiply_mesh` hands `carve_full_pattern` a canvas that
+    is sharded over the grid: the layout carve must give the one-chip
+    product, bin for bin, at bucket capacity."""
     from dbcsr_tpu.core.config import set_config
-    from dbcsr_tpu.mm.multiply import _near_uniform
+    from dbcsr_tpu.parallel import make_grid, sparse_multiply_distributed
 
-    rbs = [23, 11, 23, 23]
-    assert not _near_uniform(np.asarray(rbs))
-    assert _near_uniform(np.asarray([23] * 3 + [18]))
-    assert _near_uniform(np.asarray([23, 23, 23]))
-    a = _rand("a", rbs, rbs, 0.7, seed=34)
-    b = _rand("b", rbs, rbs, 0.7, seed=35)
-    c = create("c", rbs, rbs)
+    rbs = [5] * 6 + [3]
+    cbs = [4] * 7 + [2]
+    kbs = [5] * 5 + [1]
+    a = _rand("a", rbs, kbs, 0.9, seed=41)
+    b = _rand("b", kbs, cbs, 0.9, seed=42)
+    c_one = create("c", rbs, cbs)
+    before = _carve_counts()
     set_config(mm_dense=True)
     try:
-        multiply("N", "N", 1.0, a, b, 0.0, c)
+        multiply("N", "N", 1.5, a, b, 0.0, c_one)
+        c_mesh = sparse_multiply_distributed(1.5, a, b, 0.0, None,
+                                             make_grid(4))
     finally:
         set_config(mm_dense=None)
+    assert c_mesh._mm_algorithm == "dense" == c_one._mm_algorithm
+    assert _carve_counts()["layout"] == before["layout"] + 2
+    assert [(b_.shape, b_.count, b_.data.shape) for b_ in c_mesh.bins] == [
+        (b_.shape, b_.count, b_.data.shape) for b_ in c_one.bins]
+    for bm_, bo in zip(c_mesh.bins, c_one.bins):
+        np.testing.assert_allclose(np.asarray(bm_.data), np.asarray(bo.data),
+                                   rtol=1e-13, atol=1e-13)
     np.testing.assert_allclose(
-        to_dense(c), to_dense(a) @ to_dense(b), rtol=1e-12, atol=1e-12)
+        to_dense(c_mesh), 1.5 * (to_dense(a) @ to_dense(b)),
+        rtol=1e-12, atol=1e-12)
