@@ -38,6 +38,7 @@ from dbcsr_tpu.core.lib import (
     init_lib,
     place_compile_cache,
     print_statistics,
+    steady_host_allocator,
 )
 from dbcsr_tpu.core.dist import (
     ProcessGrid,
@@ -129,6 +130,7 @@ from dbcsr_tpu.parallel.dist_matrix import replicate as replicate_all
 __version__ = "0.1.0"
 
 place_compile_cache()
+steady_host_allocator()
 
 # the public surface (~88 symbols; the dbcsr_api.F analog list,
 # see PARITY.md for the name-by-name mapping)

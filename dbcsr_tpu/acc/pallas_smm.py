@@ -39,6 +39,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dbcsr_tpu.core.timings import device_scope
+
 _SUPPORTED = (np.dtype(np.float32), np.dtype(jnp.bfloat16))
 
 
@@ -336,6 +338,22 @@ def choose_pack(m: int, n: int, k: int, max_streams: int = 40):
     return P, R
 
 
+def _deal_lanes(run_steps: np.ndarray, P: int):
+    """(lane of each run, grid steps of the longest lane: the launch's
+    step count).  Snake-order dealing over steps-descending runs
+    (0..P-1, P-1..0, ...), the vectorized stand-in for greedy LPT —
+    within one run's steps of perfectly balanced on sorted items, no
+    Python loop."""
+    nruns = len(run_steps)
+    lane_of = np.zeros(nruns, np.int64)
+    if P > 1:
+        order = np.argsort(-run_steps, kind="stable")
+        cyc = np.arange(nruns) % (2 * P)
+        lane_of[order] = np.where(cyc < P, cyc, 2 * P - 1 - cyc)
+    loads = np.bincount(lane_of, weights=run_steps, minlength=P)
+    return lane_of, int(loads.max())
+
+
 def build_crosspack_stack(c_idx: np.ndarray, a_idx: np.ndarray,
                           b_idx: np.ndarray, a_pad_row: int, b_pad_row: int,
                           P: int, R: int):
@@ -356,17 +374,7 @@ def build_crosspack_stack(c_idx: np.ndarray, a_idx: np.ndarray,
     run_lens = np.diff(np.concatenate([run_starts, [s_total]]))
     run_steps = -(-run_lens // R)
     nruns = len(run_lens)
-    # snake-order dealing over steps-descending runs (0..P-1, P-1..0,
-    # ...): the vectorized stand-in for greedy LPT — within one run's
-    # steps of perfectly balanced on sorted items, no Python loop
-    lane_of = np.zeros(nruns, np.int64)
-    if P > 1 and nruns:
-        order = np.argsort(-run_steps, kind="stable")
-        cyc = np.arange(nruns) % (2 * P)
-        lane_of[order] = np.where(cyc < P, cyc, 2 * P - 1 - cyc)
-    lane_loads = np.bincount(lane_of, weights=run_steps, minlength=P) \
-        if nruns else np.zeros(P)
-    nsteps = int(lane_loads.max()) if nruns else 0
+    lane_of, nsteps = _deal_lanes(run_steps, P)
     ai = np.full((nsteps, P, R), a_pad_row, np.int32)
     bi = np.full((nsteps, P, R), b_pad_row, np.int32)
     cg = np.zeros((nsteps, P), np.int32)
@@ -641,52 +649,99 @@ def supports_vmem_resident(a_data, b_data) -> bool:
     return need <= capacity * 3 // 4
 
 
+# A v5e core has 1 MiB of scalar memory (`pltpu.get_tpu_info()
+# .smem_capacity_bytes`; v4 to v6e have the same), and a crosspack
+# launch scalar-prefetches its four index operands into it WHOLE.  A
+# launch may spend three quarters of it on them; the rest is headroom
+# for alpha's block and Mosaic's own scalars (1.1 KB at (4, 4) by the
+# compiler's message) on any chip of that family.
+_SMEM_BYTES = 1 << 20
+_CROSS_PREFETCH_BUDGET = _SMEM_BYTES * 3 // 4
+
+
+def crosspack_prefetch_bytes(nsteps: int, P: int, R: int) -> int:
+    """SMEM bytes of one crosspack launch's scalar-prefetch operands
+    as the chip lays them out: ai and bi (nsteps*P*R,), cg and cl
+    (nsteps*P,), each a 1-D int32 array padded to whole 4 KiB
+    (compiles for a described v5e: s32[104000] takes u8[417792], and
+    (4, 4) at 6460 steps fits where 6480 does not).  Raw entries say
+    little: runs of one or two entries dealt onto (P, R) = (4, 4) take
+    four to eight slots each."""
+    def tiled(n):
+        return -(-n // 1024) * 4096
+
+    return 2 * tiled(nsteps * P * R) + 2 * tiled(nsteps * P)
+
+
+@functools.lru_cache(maxsize=None)
+def _crosspack_max_steps(P: int, R: int, budget: int) -> int:
+    """Most grid steps a crosspack launch may have: the largest
+    `bucket_size` value whose prefetch operands fit ``budget`` (launch
+    shapes are bucketed, so the bucket is what the kernel allocates);
+    0 if not even the smallest does."""
+    from dbcsr_tpu.utils.rounding import bucket_size
+
+    steps, nxt = 0, bucket_size(1)
+    while crosspack_prefetch_bytes(nxt, P, R) <= budget:
+        steps, nxt = nxt, bucket_size(nxt + 1)
+    return steps
+
+
 def prepare_crosspack_launches(c_idx, a_idx, b_idx, a_pad_row, b_pad_row,
-                               P: int, R: int):
+                               P: int, R: int,
+                               budget: int = _CROSS_PREFETCH_BUDGET):
     """Chop the stack at RUN boundaries into SMEM-sized crosspack
     launches, then lane-deal each chunk.
 
     Unlike the base kernel, a C run cannot span launches (lane outputs
     are fresh arrays, so there is no partial sum to reload); chunk
-    boundaries therefore always align to run starts.  Returns a list of
-    launch dicts, or None if any single run exceeds the per-launch
-    entry budget (callers fall back to the base kernel).
+    boundaries therefore always align to run starts.  A chunk is as
+    many runs as deal onto P lanes within `_crosspack_max_steps`, so
+    what the kernel prefetches (`crosspack_prefetch_bytes` of the
+    bucketed step count) fits ``budget``.  Returns a list of launch
+    dicts, or None if a single run alone is longer than a launch
+    (callers take the base kernel, by plan).
     """
     from dbcsr_tpu.utils.rounding import bucket_size
 
     s_total = len(c_idx)
+    if s_total == 0:
+        return []
     run_first = np.flatnonzero(np.diff(c_idx)) + 1
     run_starts = np.concatenate([[0], run_first, [s_total]])
-    if len(run_starts) > 1 and np.diff(run_starts).max() > _MAX_ENTRIES_PER_LAUNCH:
+    run_steps = -(-np.diff(run_starts) // R)
+    max_steps = _crosspack_max_steps(P, R, budget)
+    if run_steps.max() > max_steps:
         return None
+    steps_before = np.concatenate([[0], np.cumsum(run_steps)])
     launches = []
-    lo = 0
-    while lo < s_total:
-        # furthest run start within the entry budget
-        hi_idx = np.searchsorted(run_starts, lo + _MAX_ENTRIES_PER_LAUNCH,
-                                 side="right") - 1
-        hi = int(run_starts[max(hi_idx, 0)])
-        if hi <= lo:
-            hi = int(run_starts[min(hi_idx + 1, len(run_starts) - 1)])
+    r0, nruns = 0, len(run_steps)
+    while r0 < nruns:
+        # as many runs as P full lanes hold; dealing leaves the lanes
+        # uneven by up to a run, so shrink until the longest lane fits
+        room = P * max_steps
+        while True:
+            r1 = int(np.searchsorted(steps_before, steps_before[r0] + room,
+                                     side="right")) - 1
+            r1 = max(r1, r0 + 1)
+            nsteps = _deal_lanes(run_steps[r0:r1], P)[1]
+            if nsteps <= max_steps:
+                break
+            room = min(room - 1, room * max_steps // nsteps)
+        lo, hi = int(run_starts[r0]), int(run_starts[r1])
         ai, bi, cg, cl, lane_c = build_crosspack_stack(
             c_idx[lo:hi], a_idx[lo:hi], b_idx[lo:hi],
             a_pad_row, b_pad_row, P, R,
         )
-        nsteps = ai.shape[0]
-        cap = bucket_size(max(nsteps, 1))
+        cap = bucket_size(nsteps)
         if cap > nsteps:  # pad steps: zero entries into the dummy slot
             pad = cap - nsteps
             ai = np.concatenate([ai, np.full((pad, P, R), a_pad_row, np.int32)])
             bi = np.concatenate([bi, np.full((pad, P, R), b_pad_row, np.int32)])
             cg = np.concatenate([cg, np.zeros((pad, P), np.int32)])
-            cl = np.concatenate(
-                [cl, np.repeat(cl[-1:] if nsteps else
-                               np.zeros((1, P), np.int32), pad, axis=0)]
-            )
+            cl = np.concatenate([cl, np.repeat(cl[-1:], pad, axis=0)])
         # bucketed so the jitted launch shape recurs across patterns
-        nc_out = bucket_size(
-            (max(len(c) for c in lane_c) if lane_c else 0) + 1
-        )
+        nc_out = bucket_size(max(len(c) for c in lane_c) + 1)
         launches.append({
             "ai": np.ascontiguousarray(ai.reshape(-1)),
             "bi": np.ascontiguousarray(bi.reshape(-1)),
@@ -694,8 +749,9 @@ def prepare_crosspack_launches(c_idx, a_idx, b_idx, a_pad_row, b_pad_row,
             "cl": np.ascontiguousarray(cl.reshape(-1)),
             "lane_c": lane_c,
             "nc_out": nc_out,
+            "scatter_idx": lane_scatter_index(lane_c, nc_out),
         })
-        lo = hi
+        r0 = r1
     return launches
 
 
@@ -711,6 +767,7 @@ def process_stack_crosspack(
     b_pad_row: int | None = None,
     pack: tuple | None = None,
     vmem_resident: bool = False,
+    prefetch_budget: int = _CROSS_PREFETCH_BUDGET,
 ):
     """Cross-packed stack processing (host entry point).
 
@@ -718,6 +775,7 @@ def process_stack_crosspack(
     contributions added onto ``c_data``.  ``pack`` forces (P, R).
     ``vmem_resident`` selects the whole-array-in-VMEM gather variant
     (caller responsibility: `supports_vmem_resident`).
+    ``prefetch_budget`` is `prepare_crosspack_launches`'s ``budget``.
     Returns updated c_data, or None if the stack is crosspack-ineligible
     (degenerate packing or an over-long run) — callers then use the
     base kernel.
@@ -741,7 +799,7 @@ def process_stack_crosspack(
         b_pad_row = b_data.shape[0] - 1
     launches = prepare_crosspack_launches(
         np.asarray(c_idx), np.asarray(a_idx), np.asarray(b_idx),
-        a_pad_row, b_pad_row, P, R,
+        a_pad_row, b_pad_row, P, R, budget=prefetch_budget,
     )
     if launches is None:
         return None
@@ -758,32 +816,43 @@ def process_stack_crosspack(
                 alpha_arr, P=P, R=R, nc_out=lc["nc_out"],
                 interpret=interpret,
             )
-        c_data = scatter_lane_outputs(
-            c_data, outs, [len(c) for c in lc["lane_c"]],
-            lane_scatter_index(lc["lane_c"]),
-        )
+        c_data = scatter_lane_outputs(c_data, outs, lc["scatter_idx"])
     return c_data
 
 
-def scatter_lane_outputs(c_data, outs, lane_len, idx):
-    """Write each lane's finished C blocks back into the global array.
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _pallas_cross_scatter(c_data, outs, idx):
+    """The crosspack write-back as ONE named device program (the stack
+    metrics read modules `jit__pallas*`): lane p's finished C blocks
+    ``outs[p]`` (nc_out, m, n) go to rows ``idx[p*nc_out:(p+1)*nc_out]``
+    of the donated ``c_data``.  Lanes own disjoint C blocks, so this is
+    a plain scatter-set; a slot past a lane's runs (the dummy slot, the
+    bucket's tail) carries `_DROP_ROW` and is dropped.  Its shapes are
+    the launch's buckets, so it compiles once per (C bin, P, nc_out).
+    A change of its scope needs a new function name (`device_scope`)."""
+    with device_scope("stk_cross_scatter"):
+        vals = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
+        return c_data.at[idx].set(vals, mode="drop")
 
-    Lanes own disjoint C blocks, so this is a plain scatter-set (no
-    accumulation).  ``lane_len[p]`` = lane p's valid slot count; ``idx``
-    = the concatenated global C indices in lane order (host or device).
-    """
-    parts = [outs[p][:ln] for p, ln in enumerate(lane_len) if ln]
-    if not parts:
-        return c_data
-    vals = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-    return c_data.at[jnp.asarray(idx)].set(vals)
+
+_DROP_ROW = np.iinfo(np.int32).max  # past any C array: never written
 
 
-def lane_scatter_index(lane_c):
-    """Concatenated global C ids of the non-empty lanes (scatter order
-    matching `scatter_lane_outputs`)."""
-    arrs = [c for c in lane_c if len(c)]
-    return np.concatenate(arrs) if arrs else np.empty(0, np.int32)
+def scatter_lane_outputs(c_data, outs, idx):
+    """Write each lane's finished C blocks back into the global array
+    (``idx``: the launch's ``scatter_idx``, host or device); ``c_data``
+    is consumed."""
+    return _pallas_cross_scatter(c_data, tuple(outs), jnp.asarray(idx))
+
+
+def lane_scatter_index(lane_c, nc_out: int) -> np.ndarray:
+    """(P*nc_out,) int32 for `scatter_lane_outputs`: the global C id of
+    every lane slot in lane order, `_DROP_ROW` where a slot holds no
+    run."""
+    idx = np.full((len(lane_c), nc_out), _DROP_ROW, np.int32)
+    for p, c in enumerate(lane_c):
+        idx[p, :len(c)] = c
+    return idx.reshape(-1)
 
 
 def process_launches(c_data, a_data, b_data, launches, alpha_arr, *,

@@ -812,10 +812,7 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
                             "cl": jnp.asarray(lc["cl"]),
                             # one concatenated scatter per launch: lanes
                             # own disjoint C blocks, so set (not add)
-                            "scatter_idx": jnp.asarray(
-                                pallas_smm.lane_scatter_index(lc["lane_c"])
-                            ),
-                            "lane_len": [len(c) for c in lc["lane_c"]],
+                            "scatter_idx": jnp.asarray(lc["scatter_idx"]),
                             "nc_out": lc["nc_out"],
                         }
                         for lc in cross
@@ -1488,8 +1485,7 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
                         interpret=interpret,
                     )
                 c_out = pallas_smm.scatter_lane_outputs(
-                    c_out, outs, lc["lane_len"], lc["scatter_idx"]
-                )
+                    c_out, outs, lc["scatter_idx"])
             # kernel proven on this backend: drop the demotion payload
             # (host index copies kept only until the first success)
             plan.cross_src = None
@@ -1502,8 +1498,8 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
             # as a base-kernel plan from the retained source indices —
             # the reference's unsupported-kernel fallback
             # (`libsmm_acc.cpp:227-249`)
-            if plan.cross_src is None:
-                raise
+            if plan.cross_src is None or _is_deleted(c_data):
+                raise  # nothing to rebuild from, or C went with a scatter
             import warnings
 
             shape_key = _stack_shape_key(c_data, a_data, b_data)
@@ -1514,6 +1510,11 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
                 # a lowering gap is deterministic — blacklist the shape;
                 # resource pressure is not — fall back this time only
                 _cross_disabled.add(shape_key)
+            _metrics.counter(
+                "dbcsr_tpu_crosspack_fallback_total",
+                "crosspack plans demoted to the base kernel after the "
+                "kernel failed to compile or run, by reason",
+            ).inc(reason="transient" if transient else "lowering")
             warnings.warn(
                 f"crosspack kernel failed on this backend for shape "
                 f"{shape_key} ({msg}); falling back to the base kernel"

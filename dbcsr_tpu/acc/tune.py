@@ -375,8 +375,7 @@ def _tune_smm_x64(m, n, k, dtype_enum, stack_size, nrep, out, seed, jax, jnp,
             dev_launches = [
                 (jnp.asarray(lc["ai"]), jnp.asarray(lc["bi"]),
                  jnp.asarray(lc["cg"]), jnp.asarray(lc["cl"]),
-                 jnp.asarray(pallas_smm.lane_scatter_index(lc["lane_c"])),
-                 [len(c) for c in lc["lane_c"]], lc["nc_out"])
+                 jnp.asarray(lc["scatter_idx"]), lc["nc_out"])
                 for lc in cross
             ]
             alpha32 = jnp.asarray([[1.0]], jnp.float32)
@@ -390,14 +389,13 @@ def _tune_smm_x64(m, n, k, dtype_enum, stack_size, nrep, out, seed, jax, jnp,
                 def run_v(P=P, R=R, dev_launches=dev_launches, vfn=vfn):
                     c = jnp.zeros((nc, m, n), dtype)
                     with jax.enable_x64(False):
-                        for dai, dbi, dcg, dcl, sidx, lens, nc_out in dev_launches:
+                        for dai, dbi, dcg, dcl, sidx, nc_out in dev_launches:
                             outs = vfn(
                                 c, a_t, b, dai, dbi, dcg, dcl, alpha32,
                                 P=P, R=R, nc_out=nc_out, interpret=interpret,
                             )
                             c = pallas_smm.scatter_lane_outputs(
-                                c, outs, lens, sidx
-                            )
+                                c, outs, sidx)
                     return c
 
                 tag = f"pallas {vname} P={P} R={R}"

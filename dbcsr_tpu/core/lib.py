@@ -54,6 +54,44 @@ def place_compile_cache() -> str:
     return jax.config.jax_compilation_cache_dir
 
 
+# glibc `mallopt` parameters: M_MMAP_THRESHOLD at the largest value a
+# 64-bit host takes (HEAP_MAX_SIZE / 2), M_TRIM_THRESHOLD at 1 GiB
+_MALLOPT = ((-3, 32 << 20), (-1, 1 << 30))
+
+
+def steady_host_allocator() -> bool:
+    """Fix glibc malloc's mmap threshold at its 32 MiB ceiling and its
+    trim threshold at 1 GiB, once, at package import.  Returns whether
+    the C library took both (False off glibc: nothing is changed there).
+
+    The index phase of a multiply allocates and frees NumPy arrays of
+    several MB each (0.83 M candidate triples at the north star).  Left
+    alone, glibc serves an allocation over its threshold (128 KiB, and
+    then whatever the largest mmapped chunk freed so far happened to be)
+    with a fresh `mmap`: the kernel zero-fills every page again on every
+    product.  On a v5e host that is 25 ms of a 225 ms f32 north-star
+    product, and WHICH processes pay it is an accident: one that
+    compiled any program (the compiler frees a chunk near 32 MiB, which
+    lifts the dynamic thresholds for good) ran every product at 0.225 s,
+    one that loaded all its executables from the compile cache at
+    0.250 s (my chip runs, PR 26: PERF.md section 6).  With the mmap
+    threshold fixed, those arrays come from the heap and every process
+    is the fast one.  Fixing it switches the dynamic adjustment off and
+    leaves the trim threshold at 128 KiB, where the heap's top is handed
+    back and faulted in again all the time (mixed blocks at 10 000 went
+    0.411 -> 0.447 s a product): so the trim threshold is set too, and
+    the process keeps up to 1 GiB of freed heap.  Arrays over 32 MiB are
+    mmapped as before."""
+    import ctypes
+
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):  # no dlopen(NULL), or no mallopt
+        return False
+    mallopt.argtypes, mallopt.restype = (ctypes.c_int, ctypes.c_int), ctypes.c_int
+    return all([bool(mallopt(param, value)) for param, value in _MALLOPT])
+
+
 def init_lib(enable_x64: bool = True) -> None:
     global _initialized
     if _initialized:

@@ -645,3 +645,195 @@ def test_crosspack_numpy_input_not_blacklisted(recwarn):
     assert key not in smm._cross_disabled
     np.testing.assert_allclose(got, _oracle(c, a, b, ai, bi, ci, 1.0),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# Cross-pack launches are chopped by what the kernel prefetches into SMEM
+# ---------------------------------------------------------------------------
+
+def _run_stack(run_lens):
+    """c_idx of a stack whose C block r has run_lens[r] entries."""
+    run_lens = np.asarray(run_lens)
+    return np.repeat(np.arange(len(run_lens)), run_lens).astype(np.int32)
+
+
+@pytest.mark.parametrize("name,pack,budget", [
+    # the shape that ran out of smem on the v5e at 32 768 raw entries a
+    # launch: (5,5,23), pack (4, 4), 40 000 entries in runs of one and two
+    ("short_runs", (4, 4), None),
+    ("long_runs", (4, 4), 64 << 10),
+    ("one_run_a_lane", (3, 5), 16 << 10),
+])
+def test_crosspack_launches_fit_the_prefetch_budget(name, pack, budget):
+    """Every launch prices under the budget by the function the gate
+    uses, the launches cover every entry once, and chunks start at run
+    starts."""
+    from dbcsr_tpu.acc import pallas_smm
+
+    rng = np.random.default_rng(71)
+    P, R = pack
+    if name == "short_runs":
+        run_lens = rng.integers(1, 3, 27000)
+        run_lens = run_lens[:np.searchsorted(np.cumsum(run_lens), 40000)]
+    elif name == "long_runs":
+        run_lens = rng.integers(200, 400, 120)
+    else:
+        run_lens = np.full(9, 64 * R)  # each run is max_steps deep
+    c_idx = _run_stack(run_lens)
+    s = len(c_idx)
+    a_idx = np.arange(s, dtype=np.int32)  # an entry's own position
+    b_idx = a_idx[::-1].copy()
+    # None: the default budget, as the planner calls it
+    launches = pallas_smm.prepare_crosspack_launches(
+        c_idx, a_idx, b_idx, s, s, P, R,
+        **({} if budget is None else {"budget": budget}))
+    budget = budget or pallas_smm._CROSS_PREFETCH_BUDGET
+    assert launches is not None and len(launches) > 1
+    run_starts = set(np.concatenate(
+        [[0], np.flatnonzero(np.diff(c_idx)) + 1]).tolist())
+    seen_a, next_lo = [], 0
+    for lc in launches:
+        nsteps = lc["cg"].size // P
+        assert lc["ai"].size == nsteps * P * R == lc["bi"].size
+        assert lc["cl"].size == nsteps * P
+        assert pallas_smm.crosspack_prefetch_bytes(nsteps, P, R) <= budget
+        mine = np.sort(lc["ai"][lc["ai"] != s])
+        # a contiguous range of the stack that starts where the last
+        # launch ended, at a run start
+        assert mine[0] == next_lo and mine[0] in run_starts
+        np.testing.assert_array_equal(mine, np.arange(mine[0], mine[-1] + 1))
+        np.testing.assert_array_equal(
+            np.sort(lc["bi"][lc["bi"] != s]), np.sort(b_idx[mine]))
+        np.testing.assert_array_equal(
+            np.sort(np.concatenate(lc["lane_c"])), np.unique(c_idx[mine]))
+        assert lc["nc_out"] > max(len(c) for c in lc["lane_c"])
+        next_lo = mine[-1] + 1
+        seen_a.append(mine)
+    np.testing.assert_array_equal(np.concatenate(seen_a), a_idx)
+    if name == "one_run_a_lane":
+        assert len(launches) == len(run_lens) // P
+
+
+def test_crosspack_run_longer_than_a_launch_is_left_to_the_base_kernel():
+    """A single run that no launch can hold returns None: the planner
+    takes the base kernel, by plan and without a warning."""
+    from dbcsr_tpu.acc import pallas_smm
+
+    P, R = 4, 4
+    max_steps = pallas_smm._crosspack_max_steps(
+        P, R, pallas_smm._CROSS_PREFETCH_BUDGET)
+    assert pallas_smm.crosspack_prefetch_bytes(max_steps, P, R) \
+        <= pallas_smm._CROSS_PREFETCH_BUDGET < pallas_smm._SMEM_BYTES
+    c_idx = _run_stack([3, max_steps * R + 1, 2])
+    idx = np.zeros(len(c_idx), np.int32)
+    assert pallas_smm.prepare_crosspack_launches(
+        c_idx, idx, idx, 0, 0, P, R) is None
+    c_idx = _run_stack([3, max_steps * R, 2])
+    idx = np.zeros(len(c_idx), np.int32)
+    (launch,) = pallas_smm.prepare_crosspack_launches(
+        c_idx, idx, idx, 0, 0, P, R)  # a lane of its own, the launch full
+    assert launch["cg"].size // P == max_steps
+
+
+@pytest.mark.parametrize("vmem_resident", [False, True])
+def test_crosspack_several_launches_match_numpy_and_themselves(vmem_resident):
+    """With a small budget handed in as an argument the (5,5,23) stack
+    takes several launches (and as many named write-backs): C agrees
+    with the NumPy f64 block product and is bitwise equal on a second
+    call."""
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import pallas_smm
+    from dbcsr_tpu.obs import costmodel
+
+    m, n, k = 5, 5, 23
+    rng = np.random.default_rng(73)
+    run_lens = rng.integers(1, 3, 1400)
+    c_idx = _run_stack(run_lens)
+    s, nc = len(c_idx), len(run_lens) + 7  # some C blocks get nothing
+    a_h = rng.standard_normal((50, m, k)).astype(np.float32)
+    b_h = rng.standard_normal((60, k, n)).astype(np.float32)
+    c_h = rng.standard_normal((nc, m, n)).astype(np.float32)
+    ai = rng.integers(0, 50, s).astype(np.int32)
+    bi = rng.integers(0, 60, s).astype(np.int32)
+    budget = 16 << 10
+    assert len(pallas_smm.prepare_crosspack_launches(
+        c_idx, ai, bi, 50, 60, 4, 4, budget=budget)) >= 5
+
+    def run():
+        return np.asarray(pallas_smm.process_stack_crosspack(
+            jnp.asarray(c_h), jnp.asarray(a_h), jnp.asarray(b_h),
+            ai, bi, c_idx, 1.0, pack=(4, 4), vmem_resident=vmem_resident,
+            prefetch_budget=budget))
+
+    got = run()
+    want = c_h.astype(np.float64)
+    np.add.at(want, c_idx, np.einsum(
+        "sij,sjk->sik", a_h[ai].astype(np.float64), b_h[bi].astype(np.float64)))
+    err = np.abs(got - want).max() / np.abs(want).max()
+    tol = costmodel.kernel_validation_tolerance("float32", k, 2)
+    assert err < tol, (err, tol)
+    np.testing.assert_array_equal(got[len(run_lens):], c_h[len(run_lens):])
+    np.testing.assert_array_equal(run(), got)
+
+
+@pytest.mark.parametrize("error,reason", [
+    (None, None),
+    ("simulated Mosaic lowering failure", "lowering"),
+    ("RESOURCE_EXHAUSTED: simulated: ran out of memory in memory space smem",
+     "transient"),
+])
+def test_crosspack_fallback_is_counted_by_reason(monkeypatch, error, reason):
+    """`dbcsr_tpu_crosspack_fallback_total{reason}` moves by one beside
+    the demotion's RuntimeWarning, and by none on a clean launch."""
+    import warnings
+
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import pallas_smm, smm
+    from dbcsr_tpu.core.config import set_config
+    from dbcsr_tpu.obs import metrics
+
+    def total():
+        return {lab["reason"]: v for lab, v in metrics.counter_items(
+            "dbcsr_tpu_crosspack_fallback_total")}
+
+    if error is not None:
+        def boom(*a, **k):
+            raise RuntimeError(error)
+
+        monkeypatch.setattr(pallas_smm, "_pallas_crosspack", boom)
+    key = (11, 11, 11, "float32")
+    smm._cross_disabled.discard(key)
+    rng = np.random.default_rng(75)
+    a, b, c, ai, bi, ci = _random_stack(rng, 16, 16, 10, 300, 11, 11, 11,
+                                        np.float32)
+    before = total()
+    set_config(mm_driver="pallas_cross", validate_kernels=False)
+    try:
+        plan = smm.prepare_stack(jnp.asarray(c), jnp.asarray(a),
+                                 jnp.asarray(b), ai, bi, ci)
+        assert plan.driver == "pallas_cross"
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            got = np.asarray(smm.execute_stack(
+                jnp.asarray(c), jnp.asarray(a), jnp.asarray(b), plan, 1.0))
+    finally:
+        set_config(mm_driver="auto", validate_kernels=True)
+        blacklisted = key in smm._cross_disabled
+        smm._cross_disabled.discard(key)
+    np.testing.assert_allclose(got, _oracle(c, a, b, ai, bi, ci, 1.0),
+                               rtol=2e-4, atol=2e-4)
+    after = total()
+    moved = {r: after.get(r, 0) - before.get(r, 0)
+             for r in ("lowering", "transient")}
+    fell_back = [w for w in caught if issubclass(w.category, RuntimeWarning)
+                 and "falling back to the base kernel" in str(w.message)]
+    if reason is None:
+        assert moved == {"lowering": 0, "transient": 0} and not fell_back
+        assert plan.driver == "pallas_cross"
+    else:
+        assert moved == {"lowering": 0, "transient": 0, reason: 1}
+        assert len(fell_back) == 1 and plan.driver == "pallas"
+    # only a lowering gap blacklists the shape for the session
+    assert blacklisted == (reason == "lowering")

@@ -1,0 +1,189 @@
+"""Compile-only guards: the Pallas stack kernels at the real shapes of
+the `mixed10k` deployment, lowered and compiled for a DESCRIBED TPU v5e
+(no chip attached, nothing runs).  Interpret mode cannot see what this
+sees: the chip's 1 MiB of scalar memory, which a crosspack launch's
+prefetched index operands overflowed at (5,5,23) until PR 26.
+
+All chip compiles of the suite live in this one file; the topology is
+described inside a fixture (one process at a time may load the TPU's
+library), which skips where it cannot be.
+"""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile is written to the persistent cache but cannot be
+    # read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def mixed10k():
+    """Block sizes and the A and B block patterns of the deployment, as
+    `benchmark/generators/repeat_product.py` draws them."""
+    import sys
+
+    sys.path.insert(0, REPO)
+    from benchmark import arithmetic
+
+    with open(os.path.join(REPO, "benchmark/configs/mixed10k.json")) as fh:
+        cfg = json.load(fh)
+    sizes = {d: arithmetic.expand_block_sizes(cfg[d], cfg["blocks"][d])
+             for d in "mnk"}
+    rng = np.random.default_rng(cfg["pattern_seed"])
+    pa = rng.random((len(sizes["m"]), len(sizes["k"]))) \
+        < cfg["occupancy"]["a"]
+    pb = rng.random((len(sizes["k"]), len(sizes["n"]))) \
+        < cfg["occupancy"]["b"]
+    return sizes, pa, pb
+
+
+def _stack_runs(mixed10k, m, n, k):
+    """(entries per C block of the (m,n,k) stack in C's block order,
+    A blocks of the (m,k) bin, B blocks of the (k,n) bin)."""
+    sizes, pa, pb = mixed10k
+    a_mk = pa[sizes["m"] == m][:, sizes["k"] == k]
+    b_kn = pb[sizes["k"] == k][:, sizes["n"] == n]
+    counts = a_mk.astype(np.int32) @ b_kn.astype(np.int32)
+    return counts[counts > 0], int(a_mk.sum()), int(b_kn.sum())
+
+
+def _shape(one_chip, shape, dtype):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile_crosspack(one_chip, m, n, k, P, R, nsteps, nc_out, na, nb, nc):
+    import jax
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import pallas_smm
+
+    idx = _shape(one_chip, (nsteps * P * R,), jnp.int32)
+    lane = _shape(one_chip, (nsteps * P,), jnp.int32)
+    with jax.enable_x64(False):
+        return pallas_smm._pallas_crosspack.lower(
+            _shape(one_chip, (nc, m, n), jnp.float32),
+            _shape(one_chip, (na, k, m), jnp.float32),
+            _shape(one_chip, (nb, k, n), jnp.float32),
+            idx, idx, lane, lane, _shape(one_chip, (1, 1), jnp.float32),
+            P=P, R=R, nc_out=nc_out, interpret=False,
+        ).compile()
+
+
+@pytest.mark.parametrize("mnk", [(5, 5, 23), (13, 5, 5)])
+def test_largest_crosspack_launch_of_mixed10k_compiles(one_chip, mixed10k,
+                                                       mnk):
+    """The launch with the most prefetched bytes that
+    `prepare_crosspack_launches` makes of this stack fits the chip."""
+    from dbcsr_tpu.acc import pallas_smm
+    from dbcsr_tpu.utils.rounding import bucket_size
+
+    m, n, k = mnk
+    runs, na, nb = _stack_runs(mixed10k, m, n, k)
+    assert runs.sum() > 35000 and runs.max() <= 8  # the shape that overflowed
+    c_idx = np.repeat(np.arange(len(runs)), runs).astype(np.int32)
+    zeros = np.zeros(len(c_idx), np.int32)
+    P, R = pallas_smm.choose_pack(m, n, k)
+    launches = pallas_smm.prepare_crosspack_launches(
+        c_idx, zeros, zeros, 0, 0, P, R)
+    assert launches and len(launches) > 1
+    big = max(launches, key=lambda lc: lc["ai"].size)
+    nsteps = big["cg"].size // P
+    assert pallas_smm.crosspack_prefetch_bytes(nsteps, P, R) \
+        <= pallas_smm._CROSS_PREFETCH_BUDGET
+    compiled = _compile_crosspack(
+        one_chip, m, n, k, P, R, nsteps, big["nc_out"],
+        bucket_size(na) + 1, bucket_size(nb) + 1, bucket_size(len(runs)))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_crosspack_prefetch_price_is_the_chips(one_chip):
+    """`crosspack_prefetch_bytes` prices what the compiler allocates:
+    at (4, 4) the last step count it puts under the chip's SMEM
+    compiles, and the first it puts over is refused for smem."""
+    from dbcsr_tpu.acc import pallas_smm
+
+    price = pallas_smm.crosspack_prefetch_bytes
+    fits, over = 6400, 6480  # 983 040 and 1 048 576 B of operands
+    assert price(fits, 4, 4) < pallas_smm._SMEM_BYTES <= price(over, 4, 4)
+    _compile_crosspack(one_chip, 5, 5, 23, 4, 4, fits, 8192,
+                       20000, 20000, 20000)
+    with pytest.raises(Exception, match="smem"):
+        _compile_crosspack(one_chip, 5, 5, 23, 4, 4, over, 8192,
+                           20000, 20000, 20000)
+
+
+def test_crosspack_write_back_compiles_as_one_named_program(one_chip):
+    """The lane write-back at a full launch's shapes: one module whose
+    name the benchmark's `jit__pallas*` glob sees, C updated in place."""
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import pallas_smm
+
+    nc_out = 4096
+    lowered = pallas_smm._pallas_cross_scatter.lower(
+        _shape(one_chip, (57344, 5, 5), jnp.float32),
+        tuple(_shape(one_chip, (nc_out, 5, 5), jnp.float32)
+              for _ in range(4)),
+        _shape(one_chip, (4 * nc_out,), jnp.int32))
+    text = lowered.compile().as_text()
+    assert "HloModule jit__pallas_cross_scatter" in text
+    assert "stk_cross_scatter" in text
+    assert "input_output_alias" in text
+
+
+def test_base_kernel_launch_of_mixed10k_compiles(one_chip, mixed10k):
+    """The base kernel under the donor row (kmerge, R = 8) at a full
+    (13,13,23) launch of the deployment: 4096 steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import pallas_smm
+    from dbcsr_tpu.utils.rounding import bucket_size
+
+    runs, na, nb = _stack_runs(mixed10k, 13, 13, 23)
+    c_idx = np.repeat(np.arange(len(runs)), runs).astype(np.int32)
+    zeros = np.zeros(len(c_idx), np.int32)
+    ai2, bi2, ci2, r_grp = pallas_smm.build_grouped_stack(
+        c_idx, zeros, zeros, 0, 0, grouping=8)
+    launches = pallas_smm.prepare_launches(ai2, bi2, ci2, r_grp, 0, 0)
+    nsteps = max(len(lc[2]) for lc in launches)
+    assert nsteps == 4096
+    with jax.enable_x64(False):
+        compiled = pallas_smm._pallas_process.lower(
+            _shape(one_chip, (bucket_size(len(runs)), 13, 13), jnp.float32),
+            _shape(one_chip, (bucket_size(na) + 1, 13, 23), jnp.float32),
+            _shape(one_chip, (bucket_size(nb) + 1, 23, 13), jnp.float32),
+            _shape(one_chip, (nsteps * r_grp,), jnp.int32),
+            _shape(one_chip, (nsteps * r_grp,), jnp.int32),
+            _shape(one_chip, (nsteps,), jnp.int32),
+            _shape(one_chip, (1, 1), jnp.float32),
+            r_grp=r_grp, interpret=False, kmerge=True,
+        ).compile()
+    assert "tpu_custom_call" in compiled.as_text()

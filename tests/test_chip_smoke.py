@@ -67,3 +67,32 @@ def test_compile_cache_placement_rule(monkeypatch):
             assert ".jax_cache/" in fh.read().split()
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_host_allocator_serves_index_sized_arrays_from_the_heap():
+    """`import dbcsr_tpu` fixes glibc's mmap threshold at 32 MiB, so the
+    index phase's arrays (several MB each) are not mmapped and
+    zero-filled anew every product; larger ones still are."""
+    import ctypes
+
+    import numpy as np
+
+    libc = ctypes.CDLL(None)
+    if not hasattr(libc, "mallinfo2"):
+        pytest.skip("no glibc mallinfo2 here")
+    assert lib.steady_host_allocator() is True  # again: same answer
+
+    class MallInfo(ctypes.Structure):
+        _fields_ = [(name, ctypes.c_size_t) for name in (
+            "arena", "ordblks", "smblks", "hblks", "hblkhd", "usmblks",
+            "fsmblks", "uordblks", "fordblks", "keepcost")]
+
+    libc.mallinfo2.argtypes, libc.mallinfo2.restype = (), MallInfo
+
+    def mmapped_chunks_after(nbytes):
+        before = libc.mallinfo2().hblks
+        held = np.empty(nbytes, np.uint8)
+        return libc.mallinfo2().hblks - before, held
+
+    assert mmapped_chunks_after(8 << 20)[0] == 0
+    assert mmapped_chunks_after(48 << 20)[0] == 1
