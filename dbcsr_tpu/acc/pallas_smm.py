@@ -25,7 +25,7 @@ Design (vs the CUDA design, by intent):
   tail entries.
 
 Only real float32/bfloat16 stacks take this path (`supports`); f64 and
-complex fall back to the XLA gather/segment-sum path in
+complex fall back to the XLA gather/scatter-add path in
 `dbcsr_tpu.acc.smm` (TPU has no native f64 MXU path to win with).
 """
 

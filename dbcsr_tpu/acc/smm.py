@@ -13,14 +13,16 @@ reference enumerates block sizes the same way, `dbcsr_mm_common.F:309`).
 Key differences from the CUDA design, by intent:
 
 * The reference relies on ``atomicAdd`` into C; TPU wants deterministic
-  accumulation, so stacks arrive **sorted by c_idx** and accumulation is
-  a sorted ``segment_sum`` (bit-reproducible for fixed stack order —
-  the "bit-identical checksums" north star).
+  accumulation, so stacks arrive **sorted by c_idx** and each chunk is
+  added into C by one sorted scatter-add, in place: a block's products
+  are summed in stack order (bit-reproducible for fixed stack order —
+  the "bit-identical checksums" north star) and a chunk touches only
+  the blocks it names, never the whole bin (`_accumulate_chunk`).
 * The per-(m,n,k) NVRTC JIT cache (`libsmm_acc.cpp:89-224`) becomes the
   XLA jit cache: each (m, n, k, dtype, stack-bucket) specializes once.
 * Stack entries are padded up to a size bucket with ``c_idx == Nc``;
-  out-of-range segment ids are dropped by XLA, giving masked no-op
-  entries with static shapes.
+  the scatter drops an out-of-range id, giving masked no-op entries
+  with static shapes.
 """
 
 from __future__ import annotations
@@ -124,19 +126,14 @@ def _batch_dot(a, b, acc, prec):
     return dot(ah, bh) + (dot(ah, bl) + dot(al, bh))
 
 
-def _chunk_contrib(a_data, b_data, a_idx, b_idx, c_idx, alpha, nseg,
-                   out_dtype, prec=None):
-    """One stack chunk: gather -> batched matmul -> sorted segment-sum."""
-    with device_scope("stk_gather"):
-        a = jnp.take(a_data, a_idx, axis=0)
-        b = jnp.take(b_data, b_idx, axis=0)
-    acc = _accum_dtype(out_dtype)
-    with device_scope("stk_dot"):
-        prod = _batch_dot(a, b, acc, prec)
-        prod = (alpha.astype(acc) * prod).astype(out_dtype)
+def _accumulate_chunk(c, prod, c_idx):
+    """``c[c_idx[s]] += prod[s]`` for one chunk, in place in the
+    loop-carried C: a sorted scatter-add that touches the blocks the
+    chunk names and nothing else of the bin.  Products of one block
+    are added in stack order (deterministic); an id outside the bin
+    (a padded entry, a dead group: ``nseg``) is dropped."""
     with device_scope("stk_accum"):
-        return jax.ops.segment_sum(
-            prod, c_idx, num_segments=nseg, indices_are_sorted=True)
+        return c.at[c_idx].add(prod, indices_are_sorted=True, mode="drop")
 
 
 def _stack_phases_xla_flat(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
@@ -148,7 +145,7 @@ def _stack_phases_xla_flat(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
     its bytes; a 529-lane row moves ~1.2x.  The relayout is paid once
     per multiply, the gather savings S times (S >> N on the hot
     configs).  Toggle: config.flat_gather."""
-    nseg, m, n = c_data.shape
+    _, m, n = c_data.shape
     k = a_data.shape[2]
     with device_scope("stk_gather"):
         a_flat = a_data.reshape(a_data.shape[0], m * k)
@@ -163,10 +160,7 @@ def _stack_phases_xla_flat(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
         with device_scope("stk_dot"):
             prod = _batch_dot(a, b, acc, prec)
             prod = (alpha.astype(acc) * prod).astype(c.dtype)
-        with device_scope("stk_accum"):
-            return c + jax.ops.segment_sum(
-                prod, ci, num_segments=nseg, indices_are_sorted=True
-            ), None
+        return _accumulate_chunk(c, prod, ci), None
 
     with device_scope("stk_loop"):
         c_data, _ = jax.lax.scan(body, c_data, (a_idx, b_idx, c_idx))
@@ -205,8 +199,8 @@ def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
     """R-tiled ("k-merged") stack layout: entries sharing a C block are
     tiled into groups of R0; each group's A blocks concatenate along k
     into one (m, R0*k) strip, its B blocks into (R0*k, n), and the
-    whole group contracts in ONE dot — k grows R0-fold, and the
-    per-entry segment-sum collapses to a per-group one.
+    whole group contracts in ONE dot — k grows R0-fold, and a chunk's
+    scatter-add into C takes one update per group, not per entry.
 
     This is the f64 answer to the MXU-utilization problem the reference
     solves with kernel `grouping` (`smm_acc_dnt_*.h`: one thread block
@@ -218,9 +212,13 @@ def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
     ``ga``/``gb`` are (nchunks, CH, R0) gather indices, padded with a
     guaranteed-zero row id; ``gc`` is (nchunks, CH) segment ids with
     nseg for dead groups (dropped).  Groups of one segment stay in
-    index order -> deterministic accumulation.
+    index order -> deterministic accumulation.  Per chunk the body
+    touches C only through `_accumulate_chunk`: on a TPU the carried
+    bin is tile-padded (2.4 GB for each f32 half of the north star's
+    emulated f64), so one more pass over it per chunk costs 11 ms
+    (`tests/test_chip_compiles.py` holds the compiler to that).
     """
-    nseg, m, n = c_data.shape
+    _, m, n = c_data.shape
     k = a_data.shape[2]
     r0 = ga.shape[2]
 
@@ -238,10 +236,7 @@ def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
         with device_scope("stk_dot"):
             prod = _batch_dot(amat, bmat, acc, prec)
             prod = (alpha.astype(acc) * prod).astype(c.dtype)
-        with device_scope("stk_accum"):
-            return c + jax.ops.segment_sum(
-                prod, ic, num_segments=nseg, indices_are_sorted=True
-            ), None
+        return _accumulate_chunk(c, prod, ic), None
 
     with device_scope("stk_loop"):
         c_data, _ = jax.lax.scan(body, c_data, (ga, gb, gc))
@@ -259,7 +254,7 @@ def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
     into runs of ``r0`` (pad the last run with zero-row ids), returning
     (nchunks, CH, r0) a/b gather arrays + (nchunks, CH) segment ids.
     ``c_idx`` must be sorted ascending; dead/pad groups carry segment id
-    ``c_pad`` (= nseg), keeping ids sorted and dropped by segment_sum."""
+    ``c_pad`` (= nseg), keeping ids sorted and dropped by the scatter-add."""
     s = len(c_idx)
     seg_starts = np.concatenate([[0], np.nonzero(np.diff(c_idx))[0] + 1])
     seg_len = np.diff(np.append(seg_starts, s))
@@ -299,17 +294,18 @@ def _stack_phases_xla(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
     stream-cycled stack buffers (`dbcsr_mm_accdrv.F:279-326`): one
     dispatch and one compilation per (m,n,k,bucket) instead of a Python
     loop of per-chunk launches.  Entries padded with c_idx == Nc are
-    dropped by the segment-sum.
+    dropped by the scatter-add.
     """
-    nseg = c_data.shape[0]
-
     def body(c, idx):
         ai, bi, ci = idx
-        contrib = _chunk_contrib(
-            a_data, b_data, ai, bi, ci, alpha, nseg, c.dtype, prec=prec
-        )
-        with device_scope("stk_accum"):
-            return c + contrib, None
+        with device_scope("stk_gather"):
+            a = jnp.take(a_data, ai, axis=0)
+            b = jnp.take(b_data, bi, axis=0)
+        acc = _accum_dtype(c.dtype)
+        with device_scope("stk_dot"):
+            prod = _batch_dot(a, b, acc, prec)
+            prod = (alpha.astype(acc) * prod).astype(c.dtype)
+        return _accumulate_chunk(c, prod, ci), None
 
     with device_scope("stk_loop"):
         c_data, _ = jax.lax.scan(body, c_data, (a_idx, b_idx, c_idx))

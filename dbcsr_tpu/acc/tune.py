@@ -3,7 +3,7 @@
 Analog of `src/acc/libsmm_acc/tune/` (tune_setup/submit/collect/merge)
 collapsed into one loop: for a given (m, n, k, dtype), time every
 candidate launch config of the stack kernel — the Pallas kernel at each
-grouping R plus the XLA gather/segment-sum path — and write the winner
+grouping R plus the XLA gather/scatter-add path — and write the winner
 into the device parameter table (`dbcsr_tpu.acc.params`), which
 dispatch consults.  The reference's tuning space (algorithm family,
 tile_m/n, w, v, threads, grouping, minblocks per `kernels/smm_acc.py`)
@@ -160,7 +160,7 @@ def _tune_smm_x64(m, n, k, dtype_enum, stack_size, nrep, out, seed, jax, jnp,
     candidates = _Candidates(m, n, k, dtype, stack_size, out,
                              persist=persist, mirror=candidates_out)
 
-    # XLA gather/segment-sum path (always available)
+    # XLA gather/scatter-add path (always available)
     chunk = bucket_size(min(stack_size, 30000))
     nchunks = -(-stack_size // chunk)
     from dbcsr_tpu.acc.smm import pad_stack

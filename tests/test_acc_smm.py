@@ -103,7 +103,7 @@ def test_block_norms(dtype):
 
 def test_flat_gather_matches_default():
     """config.flat_gather relayout must not change results (same
-    accumulation order: scan over chunks + sorted segment-sum)."""
+    accumulation order: scan over chunks + sorted scatter-add)."""
     from dbcsr_tpu.core.config import set_config
 
     rng = np.random.default_rng(11)

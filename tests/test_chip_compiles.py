@@ -1,8 +1,11 @@
 """Compile-only guards: the Pallas stack kernels at the real shapes of
-the `mixed10k` deployment, lowered and compiled for a DESCRIBED TPU v5e
-(no chip attached, nothing runs).  Interpret mode cannot see what this
-sees: the chip's 1 MiB of scalar memory, which a crosspack launch's
-prefetched index operands overflowed at (5,5,23) until PR 26.
+the `mixed10k` deployment and the emulated-f64 XLA stack body at the
+north star's, lowered and compiled for a DESCRIBED TPU v5e (no chip
+attached, nothing runs).  Interpret mode cannot see what this sees: the
+chip's 1 MiB of scalar memory, which a crosspack launch's prefetched
+index operands overflowed at (5,5,23) until PR 26; the whole-bin passes
+the compiler put into every chunk of a filtered f64 product until
+PR 27.
 
 All chip compiles of the suite live in this one file; the topology is
 described inside a fixture (one process at a time may load the TPU's
@@ -11,6 +14,7 @@ library), which skips where it cannot be.
 
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -187,3 +191,100 @@ def test_base_kernel_launch_of_mixed10k_compiles(one_chip, mixed10k):
             r_grp=r_grp, interpret=False, kmerge=True,
         ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# `northstar.scf_f64`'s 23^3 span: the C bin, the A and B bins with
+# their pad row, 56 chunks of 3 750 groups of 8
+_NS_BIN, _NS_AB, _NS_CHUNKS, _NS_GROUPS, _NS_R0 = 196608, 18901, 56, 3750, 8
+
+
+def _while_bodies(hlo_text):
+    """{computation name: its instruction lines} of every computation
+    that is the body of a `while` in ``hlo_text``."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line.strip())
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo_text))
+    return {name: comps[name] for name in bodies}
+
+
+def test_f64_group_body_touches_the_bin_only_in_its_scatter(one_chip):
+    """Inside the chunk loop of `_stack_phases_xla_group`, at the north
+    star's shapes, nothing but the scatter fusion produces an array of
+    the C bin's shape: no zero-fill, add or copy of the whole bin per
+    chunk (PR 26's program had five: 2.8 s of a 6.79 s product)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import smm
+
+    bin_shape = f"[{_NS_BIN},23,23]"
+    idx = _shape(one_chip, (_NS_CHUNKS, _NS_GROUPS, _NS_R0), jnp.int32)
+    with jax.enable_x64(True):
+        text = smm._process_stack_xla_group.lower(
+            _shape(one_chip, (_NS_BIN, 23, 23), jnp.float64),
+            _shape(one_chip, (_NS_AB, 23, 23), jnp.float64),
+            _shape(one_chip, (_NS_AB, 23, 23), jnp.float64),
+            idx, idx, _shape(one_chip, (_NS_CHUNKS, _NS_GROUPS), jnp.int32),
+            _shape(one_chip, (), jnp.float64),
+        ).compile().as_text()
+    chunk_loops = {name: lines for name, lines in _while_bodies(text).items()
+                   if any(bin_shape in ln for ln in lines)}
+    assert len(chunk_loops) == 1, sorted(chunk_loops)
+    (body,) = chunk_loops.values()
+    # `%name = <result type> <opcode>(<operands>`: a layout's `T(8,128)`
+    # follows a colon, an opcode a space
+    made = [(ln, re.search(r" ([a-z][\w\-]*)\(", ln.split(" = ", 1)[1]))
+            for ln in body if " = " in ln]
+    makers = [ln for ln, op in made
+              if bin_shape in ln.split(" = ", 1)[1][:op.start()]
+              and op.group(1) not in ("get-tuple-element", "parameter",
+                                      "tuple")]
+    assert len(makers) == 1, [ln[:160] for ln in makers]
+    assert "stk_accum/scatter-add" in makers[0], makers[0][:400]
+
+
+@pytest.mark.parametrize("body", ["xla", "xla_flat", "xla_group"])
+def test_stack_body_scatter_adds_into_its_carry(body):
+    """What the guard above holds the compiler to, read off the jaxpr
+    (no topology): the scan body's only bin-shaped equation is a
+    `scatter-add` whose operand is the loop-carried C."""
+    import jax
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import smm
+
+    nseg, m, n, k = 64, 5, 4, 3
+    c = jax.ShapeDtypeStruct((nseg, m, n), jnp.float32)
+    a = jax.ShapeDtypeStruct((9, m, k), jnp.float32)
+    b = jax.ShapeDtypeStruct((9, k, n), jnp.float32)
+    flat = jax.ShapeDtypeStruct((3, 16), jnp.int32)
+    grouped = jax.ShapeDtypeStruct((3, 16, 2), jnp.int32)
+    idx = (grouped, grouped, flat) if body == "xla_group" else (flat,) * 3
+    fn = {"xla": smm._stack_phases_xla, "xla_flat": smm._stack_phases_xla_flat,
+          "xla_group": smm._stack_phases_xla_group}[body]
+    jaxpr = jax.make_jaxpr(fn)(
+        c, a, b, *idx, jax.ShapeDtypeStruct((), jnp.float32))
+    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    step = scan.params["jaxpr"].jaxpr
+    carry = step.invars[scan.params["num_consts"]]
+    assert carry.aval.shape == c.shape
+
+    def eqns(jp):
+        for e in jp.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    makers = [e for e in eqns(step)
+              if any(getattr(v.aval, "shape", None) == c.shape
+                     for v in e.outvars)]
+    assert [e.primitive.name for e in makers] == ["scatter-add"], makers
+    assert makers[0].invars[0] is carry
+    assert makers[0].params["indices_are_sorted"]
