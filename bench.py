@@ -35,7 +35,7 @@ def run_chain_bench() -> None:
     iteration 1.
 
     Production-shaped configuration: the stack engine is forced
-    (``mm_dense=False`` — the dense path would densify the near-full
+    (``mm_format="stack"`` — the dense path would densify the near-full
     steady-state pattern on CPU and hide the staging story), the
     device-side ``xla`` driver is forced (the CPU-tuned native host
     driver computes ON host, so its per-multiply C round-trips are
@@ -58,7 +58,7 @@ def run_chain_bench() -> None:
     from dbcsr_tpu.ops.test_methods import to_dense
 
     init_lib()
-    set_config(mm_dense=False, mm_driver="xla")
+    set_config(mm_format="stack", mm_driver="xla")
     iters = max(5, int(os.environ.get("DBCSR_TPU_CHAIN_ITERS", "6")))
     nblk = int(os.environ.get("DBCSR_TPU_CHAIN_BLOCKS", "32"))
     filter_eps = float(os.environ.get("DBCSR_TPU_CHAIN_FILTER_EPS", "1e-9"))
@@ -198,9 +198,8 @@ def main():
         flight.dump()
         raise
     if os.environ.get("DBCSR_TPU_BENCH_TIMINGS") == "1":
-        # phase breakdown to stderr (with DBCSR_TPU_DENSE_PROFILE=1 the
-        # dense path fences between phases so the buckets are honest
-        # on-chip times, not async dispatch)
+        # phase breakdown to stderr (host spans: device time is read
+        # from the benchmark's trace, by XLA module)
         from dbcsr_tpu.core import timings
 
         timings.report(out=lambda s: print(s, file=sys.stderr))

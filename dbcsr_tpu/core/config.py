@@ -20,33 +20,24 @@ import os
 class Config:
     # --- multiply driver selection (ref MM_DRIVER {auto,matmul,blas,smm,xsmm},
     #     dbcsr_config.F:34-38) -> here {auto, xla, xla_group, pallas,
-    #     pallas_cross, dense, host} ("host" = native C++ stack driver on
+    #     pallas_cross, host} ("host" = native C++ stack driver on
     #     CPU backends, the ref smm/blas CPU path)
     mm_driver: str = "auto"
     # max entries pushed to the device per kernel call before flushing
     # (ref MM_STACK_SIZE: 30000 accel / 1000 CPU, dbcsr_config.F:77-79)
     mm_stack_size: int = 30000
-    # dense-mode multiply for near-full matrices with uniform blocking
-    # (ref MM_DENSE + decision at dbcsr_mm.F:593-617); None = auto
-    mm_dense: object = None
-    dense_occ_threshold: float = 0.8
-    # TPU cost model for EMULATED dtypes (f64/c128): below the occupancy
-    # threshold, still go dense when dense_flops < ratio * true_flops.
-    # The ratio is a prior, not a measurement: at the north star on a
-    # v5e the dense route is 1.5x the grouped stack route end to end
-    # (PERF.md, PR 21; ROADMAP A3 measures the crossover).  0 disables
-    # the cost model
-    dense_flop_ratio: float = 250.0
-    # ---- adaptive storage-format planner (mm/format_planner.py; env
-    #      DBCSR_TPU_MM_FORMAT) ----
+    # ---- storage-format planner (mm/format_planner.py; env
+    #      DBCSR_TPU_MM_FORMAT; ref MM_DENSE + the decision at
+    #      dbcsr_mm.F:593-617) ----
     # per-product execution format: "auto" (the planner picks between
     # the BCSR shape-bucketed stack path, the whole-panel padded dense
     # GEMM, and the block-diagonal composite panel from the pattern
     # fingerprint's occupancy, the live roofline, and learned per-device
     # crossover rows in the tune params table), or a forced
-    # "stack"/"dense"/"composite" (A/B legs; a forced format that is
-    # structurally ineligible — e.g. composite with no independent row
-    # panels — falls back to stack, counted under reason="ineligible")
+    # "stack"/"dense"/"composite" (A/B legs and the safe engine; a
+    # forced format the product or the engine cannot run — e.g.
+    # composite with no independent row panels, or on a mesh — falls
+    # back to stack, counted under reason="ineligible")
     mm_format: str = "auto"
     # composite panel packing limits (mm/multiply.py:composite_panels):
     # most row-panels one batched GEMM may carry, and the largest
@@ -157,7 +148,7 @@ class Config:
     serve_product_cache_bytes: int = 128 * 1024 * 1024
     # platform-injection seam (VERDICT r4 item 5): "" = the real JAX
     # backend platform; "tpu"/"cpu" makes every dispatch DECISION
-    # (_pallas_supported, _dense_mode_wanted, emulated-dtype R-tiling)
+    # (_pallas_supported, the format planner, emulated-dtype R-tiling)
     # behave as if running there, so the CPU suite can assert TPU-only
     # dispatch branches without hardware.  Execution-level choices
     # (pallas interpret=, device placement) always follow the REAL
@@ -173,7 +164,7 @@ class Config:
                 f"platform_override must be ''/'tpu'/'cpu', "
                 f"got {self.platform_override!r}")
         if self.mm_driver not in ("auto", "xla", "xla_group", "pallas",
-                                  "pallas_cross", "dense", "host"):
+                                  "pallas_cross", "host"):
             raise ValueError(f"unknown mm_driver {self.mm_driver!r}")
         if self.mm_format not in ("auto", "stack", "dense", "composite"):
             raise ValueError(
@@ -236,9 +227,7 @@ def _apply_env(cfg: Config) -> None:
         env = os.environ.get(f"DBCSR_TPU_{f.name.upper()}")
         if env is None:
             continue
-        if f.name == "mm_dense":
-            setattr(cfg, f.name, env.lower() in ("1", "true", "yes"))
-        elif isinstance(getattr(cfg, f.name), bool):
+        if isinstance(getattr(cfg, f.name), bool):
             setattr(cfg, f.name, env.lower() in ("1", "true", "yes"))
         elif isinstance(getattr(cfg, f.name), int):
             setattr(cfg, f.name, int(env))

@@ -100,10 +100,6 @@ KNOBS = {
         "doc": "reference-window samples frozen into a change-point "
                "baseline (default 12).",
     },
-    "DBCSR_TPU_DENSE_PROFILE": {
-        "owner": "mm/multiply.py",
-        "doc": "=1 emits the dense-path per-phase timing breakdown.",
-    },
     "DBCSR_TPU_EVENTS": {
         "owner": "obs/events.py",
         "doc": "event bus control: '0'/'off' disables the bus, a path "

@@ -1,55 +1,67 @@
-"""Adaptive storage-format planner: per-product dense/stack/composite.
+"""Storage-format planner: stack, dense or composite, once per product.
 
-The engine historically executed every product as BCSR stacks, with one
-hardcoded escape hatch (`mm.multiply._dense_mode_wanted`) that converts
-near-full matrices to a single dense GEMM.  This module makes the
-format a PLANNED, per-(product, occupancy, device) decision between
-three executions of the identical product:
+This module is the ONLY place that decides how a product executes.
+Both engines — `mm.multiply` on one chip and
+`parallel.sparse_dist.sparse_multiply_distributed` on a mesh — ask
+`choose`, and tell it what they can execute (``executors``,
+``chunked_canvas``); the rules are never re-derived anywhere else.
+The three executions of the identical product:
 
 * ``stack``     — the shape-bucketed BCSR stack engine (the default);
-* ``dense``     — whole-panel padded dense GEMM (`_dense_multiply`,
-  n/m/k-chunked beyond the canvas cap);
+* ``dense``     — one padded dense GEMM on whole-matrix canvases
+  (`mm.multiply._dense_multiply`; strip-chunked beyond the canvas cap
+  on one chip, the dense Cannon on a square mesh);
 * ``composite`` — the block-diagonal composite panel: C's block-rows
   are greedily grouped into row-panels with narrow k-support, packed
-  into ONE batched padded GEMM (`_composite_multiply`) — the serve
-  coalescer's batching trick applied inside one matrix.
+  into ONE batched padded GEMM (`_composite_multiply`, one chip only).
 
-Decision funnel (first hit wins), resolved once per product and cached
-by pattern fingerprints + config + params generation (a tuner
-promotion/demotion bumps the generation, so learned crossovers retire
-cached plans immediately):
+The funnel, first hit wins:
 
-1. ``DBCSR_TPU_MM_FORMAT`` forced format (``reason="forced"``; a
-   structurally infeasible force falls back to stack,
-   ``reason="ineligible"``);
-2. the ``format_plan`` fault site (an injected fault degrades the plan
+0. the structural gate (`_stack_only`): a filtered, pattern-locked,
+   limited or symmetric-C product, or one under ``mm_driver="pallas"``,
+   or a caller with no canvas executor, runs on the stack engine
+   (``reason="structural"``);
+1. the ``format_plan`` fault site (an injected fault degrades the plan
    to stack, ``reason="fault"`` — never cached);
+
+   from here on the plan is cached by pattern fingerprints + config +
+   what the caller can execute + params generation (a tuner
+   promotion/demotion bumps the generation, so learned crossovers
+   retire cached plans immediately);
+2. ``mm_format`` forced (``reason="forced"``; a force the caller cannot
+   execute falls back to stack, ``reason="ineligible"``);
 3. a learned params-table row carrying ``format``/``format_occ``
    columns for this block cell: above the learned occupancy crossover
    the row's format wins (``reason="tuned"``) — this is where the
-   autotuner (`dbcsr_tpu.tune`) overrides the model per device;
-4. the legacy dense heuristic (`_dense_mode_wanted`: config forcing,
-   the occupancy threshold, the emulated-dtype flop-ratio model) —
-   preserved bit-for-bit so default behavior never changes
-   (``reason="heuristic"``);
+   autotuner (`dbcsr_tpu.tune`) overrides the rules per device;
+4. the dense rules (`_dense_rule`, ``reason="heuristic"``): both
+   operands at or above `DENSE_OCC_THRESHOLD` occupancy (the
+   reference's gate, `dbcsr_mm.F:593-617`), or — for a dtype the TPU
+   only emulates — dense flops under `DENSE_FLOP_RATIO` times the true
+   flops with a C that would fill anyway.  Every benchmark cell is
+   routed by this step;
 5. on an MXU (`effective_platform() == "tpu"`), the
    `obs.costmodel.format_costs` occupancy-parameterized curves: the
-   cheapest modeled format among the structurally feasible ones
-   (``reason="model"``); guarded by the >= 0.5 candidate-fill rule so
-   a structurally sparse C is never silently densified;
-6. stack (``reason="default"``; products that cannot take a non-stack
-   format at all report ``reason="structural"``).
+   cheapest modeled format among those the caller can execute
+   (``reason="model"``); guarded by the same >= 0.5 candidate-fill
+   rule so a structurally sparse C is never silently densified;
+6. stack (``reason="default"``; a non-uniform blocking, which steps 3
+   and 5 cannot price, reports ``reason="structural"``).
 
-Every decision lands on ``dbcsr_tpu_format_decision_total{format,
-reason}`` and in the product's trace span/flight record; every
-EXECUTED product reports back through `note_outcome`, which keeps a
-bounded regret ring (model-predicted vs measured GFLOP/s) that the
-timeseries collector samples and `tune.miner.mine_format` mines for
-re-trial when the planner's choice underperforms its own model.
+Every one-chip decision lands on ``dbcsr_tpu_format_decision_total{
+format, reason}`` and in the product's trace span/flight record
+(`note_decision`; the mesh engine's call waits for a `benchmark` PR,
+see its call site); every EXECUTED one-chip product reports back
+through `note_outcome`, which
+keeps a bounded regret ring (model-predicted vs measured GFLOP/s) that
+the timeseries collector samples and `tune.miner.mine_format` mines
+for re-trial when the planner's choice underperforms its own model.
 
 Import-light: numpy only at import; jax, config, params, costmodel and
 `mm.multiply` are reached lazily (multiply imports THIS module lazily
-too, so there is no cycle).
+too, so there is no cycle).  Of `mm.multiply` the planner asks only
+facts and feasibility: `_true_product_flops`, `_uniformly_blocked`,
+`composite_panels`, `dense_canvas_feasible`.
 """
 
 from __future__ import annotations
@@ -62,6 +74,17 @@ from typing import Optional
 import numpy as np
 
 FORMATS = ("stack", "dense", "composite")
+
+# both operands at or above this occupancy go dense on any platform
+# (ref MM_DENSE's gate, `dbcsr_mm.F:593-617`)
+DENSE_OCC_THRESHOLD = 0.8
+# TPU rule for EMULATED dtypes (f64/c128): below the occupancy
+# threshold, still go dense when dense_flops < ratio * true_flops.
+# The ratio is a prior, not a measurement: the one point known is the
+# north star on a v5e (ratio 100), where the dense route is 4.5x the
+# grouped stack route end to end (ledger, PR 27); ROADMAP A3's ladder
+# measures the crossover.  0 disables the rule
+DENSE_FLOP_RATIO = 250.0
 
 _lock = threading.Lock()
 _plan_cache: "collections.OrderedDict" = collections.OrderedDict()
@@ -76,14 +99,15 @@ class Plan:
     """One product's format decision plus the evidence it rode on."""
 
     __slots__ = ("fmt", "reason", "panels", "predicted", "cell", "occ",
-                 "grid")
+                 "grid", "why")
 
     def __init__(self, fmt: str, reason: str, panels=None,
                  predicted: Optional[dict] = None,
                  cell: Optional[tuple] = None, occ: Optional[float] = None,
-                 grid: Optional[tuple] = None):
+                 grid: Optional[tuple] = None, why: Optional[str] = None):
         self.fmt = fmt
         self.reason = reason
+        self.why = why            # which dense rule fired (flight dense_why)
         self.panels = panels
         self.predicted = predicted
         self.cell = cell          # (bm, bn, bk, dtype) — uniform products
@@ -94,24 +118,19 @@ class Plan:
         return f"Plan({self.fmt}, reason={self.reason}, occ={self.occ})"
 
 
-def _uniform(m) -> bool:
-    return (len(np.unique(m.row_blk_sizes)) == 1
-            and len(np.unique(m.col_blk_sizes)) == 1)
-
-
-def _cache_get(key):
+def _cache_get(key, cache=_plan_cache):
     with _lock:
-        hit = _plan_cache.get(key)
+        hit = cache.get(key)
         if hit is not None:
-            _plan_cache.move_to_end(key)
+            cache.move_to_end(key)
         return hit
 
 
-def _cache_put(key, plan) -> None:
+def _cache_put(key, value, cache=_plan_cache, cap=_PLAN_CACHE_MAX) -> None:
     with _lock:
-        _plan_cache[key] = plan
-        while len(_plan_cache) > _PLAN_CACHE_MAX:
-            _plan_cache.popitem(last=False)
+        cache[key] = value
+        while len(cache) > cap:
+            cache.popitem(last=False)
 
 
 def reset() -> None:
@@ -147,26 +166,33 @@ def _tuned_row(bm: int, bn: int, bk: int, dtype: str) -> Optional[dict]:
     return None
 
 
-def choose(a, b, c, *, filter_eps, retain_sparsity, no_limits) -> Plan:
+def _stack_only(c, cfg, filter_eps, retain_sparsity, no_limits) -> bool:
+    """THE structural gate, shared by every non-stack format and both
+    engines: the canvas executors write C's full pattern in one piece,
+    so a filtered, pattern-locked, limited or symmetric-C product can
+    only run on the stack engine — as can one whose stack driver was
+    pinned to Pallas (``mm_driver="pallas"``: kernel A/B legs)."""
+    from dbcsr_tpu.core.matrix import NO_SYMMETRY
+
+    return (filter_eps is not None or retain_sparsity or not no_limits
+            or c.matrix_type != NO_SYMMETRY or cfg.mm_driver == "pallas")
+
+
+def choose(a, b, c, *, filter_eps, retain_sparsity, no_limits,
+           executors=("dense", "composite"), chunked_canvas=True) -> Plan:
     """Resolve the product's execution format (see the module funnel).
-    Cheap on repeat: cached by pattern fingerprints + config + params
-    generation + device kind."""
+    ``executors`` are the non-stack formats the caller can run and
+    ``chunked_canvas`` whether its dense executor survives the canvas
+    cap by strips: all that differs between the one-chip engine (the
+    defaults) and the mesh engine (the dense Cannon on a square grid,
+    no strips, no composite).  Cheap on repeat: cached by pattern
+    fingerprints + config + params generation + device kind."""
     from dbcsr_tpu.core.config import effective_platform, get_config
-    from dbcsr_tpu.mm import multiply as _mm
     from dbcsr_tpu.resilience import faults as _faults
 
     cfg = get_config()
-    # structural gates shared by every non-stack format: these products
-    # can only run on the stack engine (filtered/limited/symmetric
-    # products, or dense explicitly disabled)
-    from dbcsr_tpu.core.matrix import NO_SYMMETRY
-
-    eligible = (
-        filter_eps is None and not retain_sparsity and no_limits
-        and c.matrix_type == NO_SYMMETRY
-        and cfg.mm_dense is not False and cfg.mm_driver != "pallas"
-    )
-    if not eligible:
+    if not executors or _stack_only(c, cfg, filter_eps, retain_sparsity,
+                                    no_limits):
         return Plan("stack", "structural")
     # fault boundary: an injected plan fault degrades to stack for THIS
     # product only (never cached — the fault is transient)
@@ -181,25 +207,25 @@ def choose(a, b, c, *, filter_eps, retain_sparsity, no_limits) -> Plan:
     key = (
         a.pattern_fingerprint(), b.pattern_fingerprint(),
         c.pattern_fingerprint(), str(np.dtype(c.dtype)),
-        (cfg.mm_format, cfg.mm_dense, cfg.mm_driver,
-         cfg.dense_occ_threshold, cfg.dense_flop_ratio,
+        (cfg.mm_format, cfg.mm_driver,
          cfg.composite_max_panels, cfg.composite_ksup,
-         effective_platform()),
+         effective_platform(), tuple(executors), bool(chunked_canvas)),
         params_mod.generation(),
     )
     plan = _cache_get(key)
     if plan is not None:
         return plan
-    plan = _choose_uncached(a, b, c, cfg, _mm)
+    plan = _choose_uncached(a, b, c, cfg, executors, chunked_canvas)
     _cache_put(key, plan)
     return plan
 
 
-def _choose_uncached(a, b, c, cfg, _mm) -> Plan:
+def _choose_uncached(a, b, c, cfg, executors, chunked_canvas) -> Plan:
     from dbcsr_tpu.core.config import effective_platform
+    from dbcsr_tpu.mm import multiply as _mm
     from dbcsr_tpu.obs import costmodel as _costmodel
 
-    uniform = _uniform(a) and _uniform(b) and _uniform(c)
+    uniform = all(_mm._uniformly_blocked(m) for m in (a, b, c))
     cell = occ = grid = predicted = None
     entries = 0
     panels = None
@@ -214,7 +240,8 @@ def _choose_uncached(a, b, c, cfg, _mm) -> Plan:
             int(round(_mm._true_product_flops(a, b) / (2.0 * bm * bn * bk))),
             0)
         occ = entries / float(max(nbr * nbc * nbk, 1))
-        panels = _mm.composite_panels(a, b, c)
+        if "composite" in executors:
+            panels = _mm.composite_panels(a, b, c)
         predicted = _costmodel.format_costs(
             nbr=nbr, nbc=nbc, nbk=nbk, bm=bm, bn=bn, bk=bk,
             entries=entries,
@@ -225,15 +252,17 @@ def _choose_uncached(a, b, c, cfg, _mm) -> Plan:
     def _feasible(fmt: str) -> bool:
         if fmt == "stack":
             return True
+        if fmt not in executors:
+            return False
         if fmt == "composite":
             return panels is not None
-        return True  # dense: the chunked/general paths carry any shape
+        return True  # dense: forced past the cap it runs chunked or whole
 
-    def _plan(fmt, reason):
+    def _plan(fmt, reason, why=None):
         return Plan(fmt, reason, panels=panels, predicted=predicted,
-                    cell=cell, occ=occ, grid=grid)
+                    cell=cell, occ=occ, grid=grid, why=why)
 
-    # 1. explicit force
+    # 2. explicit force
     if cfg.mm_format != "auto":
         if _feasible(cfg.mm_format):
             return _plan(cfg.mm_format, "forced")
@@ -247,14 +276,15 @@ def _choose_uncached(a, b, c, cfg, _mm) -> Plan:
             if occ is not None and occ >= crossover and _feasible(fmt):
                 return _plan(fmt, "tuned")
             return _plan("stack", "tuned")
-    # 4. the legacy dense heuristic, preserved bit-for-bit
-    if _mm._dense_mode_wanted(a, b, c, None, False, True,
-                              allow_chunked=True):
-        return _plan("dense", "heuristic")
+    # 4. the dense rules: occupancy, then the emulated-dtype flop ratio
+    why = _dense_rule(a, b, c, cfg, chunked_canvas) \
+        if _feasible("dense") else None
+    if why is not None:
+        return _plan("dense", "heuristic", why=why)
     # 5. MXU cost curves (never densify a structurally sparse C)
     if (uniform and predicted is not None
             and effective_platform() == "tpu"
-            and _mm._candidate_fill(a, b) >= 0.5):
+            and _candidate_fill(a, b) >= 0.5):
         best, best_s = "stack", predicted["stack"]["seconds"]
         for fmt in ("dense", "composite"):
             leg = predicted.get(fmt)
@@ -264,6 +294,82 @@ def _choose_uncached(a, b, c, cfg, _mm) -> Plan:
         if best != "stack":
             return _plan(best, "model")
     return _plan("stack", "default" if uniform else "structural")
+
+
+def _dense_rule(a, b, c, cfg, chunked_canvas) -> Optional[str]:
+    """Which dense rule sends this (structurally eligible) product to
+    one dense MXU matmul, or None (ref `dbcsr_mm.F:593-617`).
+
+    TPU extension beyond the reference's occupancy gate: for dtypes the
+    chip only EMULATES (f64/c128 run as split-f32/bf16 passes), tiny
+    per-block dots are so MXU-starved that one dense matmul beats the
+    stack path well below occ 0.1 — at the 23^3 north-star config on a
+    v5e, 0.872 s per multiply dense against 3.92 s on the grouped stack
+    path (ledger, PR 27).  The result is identical either way (same
+    product, same final pattern semantics); only time-to-solution
+    changes."""
+    from dbcsr_tpu.core.config import effective_platform
+    from dbcsr_tpu.mm import multiply as _mm
+
+    th = DENSE_OCC_THRESHOLD
+    if a.occupation() >= th and b.occupation() >= th:
+        return f"occupancy>={th}"
+    # emulated-dtype rule (TPU only).  Guards beyond the flop ratio: an
+    # explicitly forced stack driver wins, the executor must be able to
+    # hold the canvases, and the product's EXPECTED block fill must be
+    # near-full — dense mode stores the full pattern, which must not
+    # silently densify a structurally sparse C (block-diagonal/banded
+    # operands keep the stack path).
+    if cfg.mm_driver != "auto" or DENSE_FLOP_RATIO <= 0:
+        return None
+    if np.dtype(c.dtype) not in (np.float64, np.complex128):
+        return None
+    if effective_platform() != "tpu":
+        return None
+    if not _mm.dense_canvas_feasible(a, b, c, chunked=chunked_canvas):
+        return None
+    if _candidate_fill(a, b) < 0.5:
+        return None
+    dense_flops = 2.0 * a.nfullrows * b.nfullcols * a.nfullcols
+    if dense_flops < DENSE_FLOP_RATIO * _mm._true_product_flops(a, b):
+        return "cost-model:emulated-dtype"
+    return None
+
+
+_fill_cache: "collections.OrderedDict" = collections.OrderedDict()
+
+
+def _candidate_fill(a, b) -> float:
+    """Fraction of C blocks the symbolic product would store.  EXACT
+    (one host float32 boolean matmul over the block grids) when the
+    grid volume and temp size allow — structured patterns (triangular,
+    banded) are what the guard exists for, and a random-pattern
+    estimate misses them; beyond the caps, fall back to the Poisson
+    model.  Memoized by pattern fingerprints: repeated same-pattern
+    multiplies (SCF loops) pay the matmul once."""
+    nbr, nbk, nbc = a.nblkrows, a.nblkcols, b.nblkcols
+    if a.nblks == 0 or b.nblks == 0 or nbr * nbc == 0:
+        return 0.0
+    exact_ok = (
+        float(nbr) * nbk * nbc <= 1e9
+        and float(nbr) * nbk + float(nbk) * nbc + float(nbr) * nbc <= 5e7
+    )
+    if not exact_ok:
+        lam = float(a.nblks) * b.nblks / (float(nbr) * nbc * nbk)
+        return 1.0 - float(np.exp(-lam))
+    key = (a.pattern_fingerprint(), b.pattern_fingerprint())
+    fill = _cache_get(key, _fill_cache)
+    if fill is not None:
+        return fill
+    ar, ac = a.entry_coords()
+    br, bc = b.entry_coords()
+    ia = np.zeros((nbr, nbk), np.float32)
+    ia[ar, ac] = 1.0
+    ib = np.zeros((nbk, nbc), np.float32)
+    ib[br, bc] = 1.0
+    fill = float(np.count_nonzero(ia @ ib)) / (nbr * nbc)
+    _cache_put(key, fill, _fill_cache, cap=64)
+    return fill
 
 
 # ------------------------------------------------------- observability
@@ -286,6 +392,8 @@ def note_decision(plan: Plan) -> None:
         _flight.note("format_reason", plan.reason)
         if plan.occ is not None:
             _flight.note("format_occ", round(plan.occ, 4))
+        if plan.why is not None:
+            _flight.note("dense_why", plan.why)
         _trace.annotate(format=plan.fmt, format_reason=plan.reason)
         _note_choice_change(plan)
     except Exception:

@@ -28,7 +28,6 @@ final pass keeping blocks with ||C||² >= eps²
 from __future__ import annotations
 
 import functools
-import os
 import time
 from typing import Optional
 
@@ -231,8 +230,7 @@ def _multiply_body(a, b, c, alpha, beta, retain_sparsity, filter_eps,
     """The format-planned engine body of `multiply` (split out so the
     flight recorder brackets every exit path exactly once).  The
     storage format — stack, dense, or composite — is resolved by
-    `mm.format_planner.choose` (config force, learned tune crossover,
-    the legacy dense heuristic, then the costmodel curves)."""
+    `mm.format_planner.choose`, the one decider both engines ask."""
     from dbcsr_tpu.mm import format_planner as _fmt
 
     plan = _fmt.choose(a, b, c, filter_eps=filter_eps,
@@ -414,7 +412,7 @@ def _true_product_flops(a, b) -> int:
     return int(round(2.0 * float(np.dot(wa * kk, wb))))
 
 
-# canvases beyond this element count make the dense cost model decline
+# the most elements one canvas of the dense executors may hold
 # (3 canvases must fit HBM comfortably; 10k^2 f64 = 0.8 GB each)
 _DENSE_MAX_CANVAS = 2 * 10**8
 
@@ -440,77 +438,36 @@ def _dense_chunking(nbr, nbc, nbk, bm, bn, bk):
     return mrb, kcb, ncb
 
 
-def _dense_mode_wanted(a, b, c, filter_eps, retain_sparsity, no_limits,
-                       allow_chunked=False) -> bool:
-    """Dense-mode decision (ref `dbcsr_mm.F:593-617`): near-full uniformly
-    blocked matrices degrade gracefully to one dense MXU matmul.
-
-    TPU extension beyond the reference's occupancy gate: for dtypes the
-    chip only EMULATES (f64/c128 run as split-f32/bf16 passes), tiny
-    per-block dots are so MXU-starved that one dense matmul beats the
-    stack path well below occ 0.1 — at the 23^3 north-star config on a
-    v5e, 4.5 s per multiply dense against 6.8 s on the grouped stack
-    path (PERF.md, PR 21).  A flop-ratio cost model decides: go dense when
-    dense_flops < dense_flop_ratio * true_sparse_flops.  The result is
-    identical either way (same product, same final pattern semantics);
-    only time-to-solution changes."""
-    from dbcsr_tpu.core.config import get_config
-
-    cfg = get_config()
-    if cfg.mm_dense is False or cfg.mm_driver == "pallas":
-        return False
-    if filter_eps is not None or retain_sparsity or not no_limits:
-        return False
-    if c.matrix_type != NO_SYMMETRY:
-        return False
-    if cfg.mm_dense is True or cfg.mm_driver == "dense":
-        _flight.note("dense_why", "config-forced")
-        return True
-    th = cfg.dense_occ_threshold
-    if a.occupation() >= th and b.occupation() >= th:
-        _flight.note("dense_why", f"occupancy>={th}")
-        return True
-    # emulated-dtype cost model (TPU only).  Guards beyond the flop
-    # ratio: an explicitly forced stack driver wins, and the product's
-    # EXPECTED block fill must be near-full — dense mode stores the full
-    # pattern, which must not silently densify a structurally sparse
-    # C (block-diagonal/banded operands keep the stack path).
-    if cfg.mm_driver != "auto":
-        return False
-    if cfg.dense_flop_ratio <= 0:
-        return False
-    if np.dtype(c.dtype) not in (np.float64, np.complex128):
-        return False
-    from dbcsr_tpu.core.config import effective_platform
-
-    if effective_platform() != "tpu":
-        return False
+def _over_canvas_cap(a, b) -> bool:
     mm, nn, kk = a.nfullrows, b.nfullcols, a.nfullcols
-    if max(mm * kk, kk * nn, mm * nn) > _DENSE_MAX_CANVAS:
-        # beyond the canvas cap the dense route survives only via the
-        # k/m-strip chunked path (single-chip, uniform blockings) — the
-        # reference's dense mode is not size-capped (dbcsr_mm.F:593-617)
-        if not allow_chunked:
-            return False
-        if any(
-            len(np.unique(m.row_blk_sizes)) > 1
-            or len(np.unique(m.col_blk_sizes)) > 1
-            for m in (a, b, c)
-        ):
-            return False
-        if _dense_chunking(
-            a.nblkrows, c.nblkcols, a.nblkcols,
-            int(a.row_blk_sizes[0]), int(b.col_blk_sizes[0]),
-            int(a.col_blk_sizes[0]),
-        ) is None:
-            return False
-    if _candidate_fill(a, b) < 0.5:
-        return False
-    dense_flops = 2.0 * mm * nn * kk
-    wanted = dense_flops < cfg.dense_flop_ratio * _true_product_flops(a, b)
-    if wanted:
-        _flight.note("dense_why", "cost-model:emulated-dtype")
-    return wanted
+    return max(mm * kk, kk * nn, mm * nn) > _DENSE_MAX_CANVAS
+
+
+def _uniformly_blocked(m) -> bool:
+    return (len(np.unique(m.row_blk_sizes)) == 1
+            and len(np.unique(m.col_blk_sizes)) == 1)
+
+
+def _dense_strips(a, b, c):
+    """`_dense_chunking` of a product the chunked executor can take
+    (uniform blockings only), else None."""
+    if not all(_uniformly_blocked(m) for m in (a, b, c)):
+        return None
+    return _dense_chunking(
+        a.nblkrows, c.nblkcols, a.nblkcols,
+        int(a.row_blk_sizes[0]), int(b.col_blk_sizes[0]),
+        int(a.col_blk_sizes[0]),
+    )
+
+
+def dense_canvas_feasible(a, b, c, *, chunked: bool) -> bool:
+    """Whether the canvas executors can hold this product: A, B and C
+    canvases each under `_DENSE_MAX_CANVAS`, or — where the caller has
+    the chunked executor — strips that are (the reference's dense mode
+    is not size-capped, `dbcsr_mm.F:593-617`).  The planner asks; the
+    policy is its own."""
+    return not _over_canvas_cap(a, b) or (
+        chunked and _dense_strips(a, b, c) is not None)
 
 
 def _note_dense_fallback(exc: BaseException, driver: str = "dense") -> None:
@@ -544,64 +501,6 @@ def _dense_guard(x):
     return x
 
 
-_fill_cache: "OrderedDict" = None  # created lazily; pattern-keyed
-
-
-def _candidate_fill(a, b) -> float:
-    """Fraction of C blocks the symbolic product would store.  EXACT
-    (one host float32 boolean matmul over the block grids) when the
-    grid volume and temp size allow — structured patterns (triangular,
-    banded) are what the guard exists for, and a random-pattern
-    estimate misses them; beyond the caps, fall back to the Poisson
-    model.  Memoized by pattern fingerprints: repeated same-pattern
-    multiplies (SCF loops) pay the matmul once."""
-    import collections
-
-    global _fill_cache
-    nbr, nbk, nbc = a.nblkrows, a.nblkcols, b.nblkcols
-    if a.nblks == 0 or b.nblks == 0 or nbr * nbc == 0:
-        return 0.0
-    exact_ok = (
-        float(nbr) * nbk * nbc <= 1e9
-        and float(nbr) * nbk + float(nbk) * nbc + float(nbr) * nbc <= 5e7
-    )
-    if not exact_ok:
-        lam = float(a.nblks) * b.nblks / (float(nbr) * nbc * nbk)
-        return 1.0 - float(np.exp(-lam))
-    key = (a.pattern_fingerprint(), b.pattern_fingerprint())
-    if _fill_cache is None:
-        _fill_cache = collections.OrderedDict()
-    if key in _fill_cache:
-        _fill_cache.move_to_end(key)
-        return _fill_cache[key]
-    ar, ac = a.entry_coords()
-    br, bc = b.entry_coords()
-    ia = np.zeros((nbr, nbk), np.float32)
-    ia[ar, ac] = 1.0
-    ib = np.zeros((nbk, nbc), np.float32)
-    ib[br, bc] = 1.0
-    fill = float(np.count_nonzero(ia @ ib)) / (nbr * nbc)
-    _fill_cache[key] = fill
-    while len(_fill_cache) > 64:
-        _fill_cache.popitem(last=False)
-    return fill
-
-
-@functools.partial(jax.jit, static_argnames=("nbr", "nbc", "bm", "bn"))
-def _blocks_to_dense(data, rows, cols, nbr, nbc, bm, bn):
-    """Uniform-blocked scatter to a 2-D canvas via element offsets.
-
-    Deliberately NOT via an (nbr, nbc, bm, bn) grid intermediate: TPU
-    tile padding blows a (435, 435, 23, 23) f64 grid up 5.8x (~4.5 GB);
-    the 2-D canvas pads ~1.0x.  Three such grid temps pushed the
-    nonempty-C north-star dense multiply from ~1 s to ~6.7 s (HBM
-    thrash/remat)."""
-    ro = (rows * bm).astype(jnp.int32)
-    co = (cols * bn).astype(jnp.int32)
-    canvas = jnp.zeros((nbr * bm, nbc * bn), data.dtype)
-    return _scatter_bin_to_canvas(canvas, data, ro, co, bm=bm, bn=bn)
-
-
 def _carve_full_pattern(cd, nbr, nbc, bm, bn):
     """Carve a uniformly blocked product canvas into the FULL row-major
     block pattern: a pure layout permutation (the (s, s) rectangle of
@@ -612,40 +511,6 @@ def _carve_full_pattern(cd, nbr, nbc, bm, bn):
         .transpose(0, 2, 1, 3)
         .reshape(nbr * nbc, bm, bn)
     )
-
-
-@functools.partial(jax.jit, donate_argnums=2,
-                   static_argnames=("nbr", "nbc", "bm", "bn"))
-def _dense_product_to_blocks(ad, bd, c_blocks, c_keys, alpha, beta, nbr, nbc,
-                             bm, bn):
-    """Matmul on 2-D canvases, then carve the FULL row-major block
-    pattern straight off the product canvas and scatter-add beta*old
-    in block layout (position of old key k in the full pattern = k)."""
-    acc = ad.dtype
-    cd = jax.lax.dot_general(
-        ad, bd, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=acc,
-    )
-    out = alpha * _carve_full_pattern(cd, nbr, nbc, bm, bn)
-    return out.at[c_keys].add(beta * c_blocks.astype(acc), mode="drop")
-
-
-@jax.jit
-def _dense_dot_only(ad, bd):
-    """Profile-mode split: the bare canvas matmul as its own program so
-    a fence can time it separately from the carve."""
-    return jax.lax.dot_general(
-        ad, bd, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=ad.dtype,
-    )
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=("nbr", "nbc", "bm", "bn"))
-def _dense_carve_only(cd, c_blocks, c_keys, alpha, beta, nbr, nbc, bm, bn):
-    """Profile-mode split: carve + beta-merge as its own program."""
-    out = alpha * _carve_full_pattern(cd, nbr, nbc, bm, bn)
-    return out.at[c_keys].add(beta * c_blocks.astype(out.dtype), mode="drop")
 
 
 @functools.partial(jax.jit, donate_argnums=0, static_argnames=("bm", "bn"))
@@ -747,31 +612,27 @@ def _to_dense_device(m: BlockSparseMatrix):
     return canvas
 
 
-def _dense_multiply_general(a, b, c, alpha, beta) -> int:
-    """Dense mode for arbitrary (non-uniform) blockings: densify on
-    device, one MXU matmul, carve C back into its own full blocking
-    (the `dbcsr_make_dense`/`dbcsr_make_undense` re-blocking pair,
-    `dbcsr_mm.F:593-617`, generalized to one flat dense canvas).
-
-    THIS is the production north-star path: m=10000 with (1,23) sizes
-    expands to 434x23 + one 18 block (ceil-division blocking), so the
-    uniform `_dense_multiply` never fires for it.  The profile buckets
-    therefore live here too — a hardware window spent profiling the
-    uniform path would attribute the wrong program."""
-    profile = os.environ.get("DBCSR_TPU_DENSE_PROFILE") == "1"
-    if profile:
-        from dbcsr_tpu.utils.sync import fetch_fence as _ff
-
+def _dense_multiply(a, b, c, alpha, beta) -> int:
+    """Dense mode, every blocking: densify A and B on device, one MXU
+    matmul, carve C back into its own full blocking (the
+    `dbcsr_make_dense`/`dbcsr_make_undense` re-blocking pair,
+    `dbcsr_mm.F:593-617,770-810`, on one flat dense canvas).  Beyond
+    the canvas cap a uniform blocking runs strip by strip
+    (`_dense_multiply_chunked`); anything else over the cap reached
+    here by a force or the occupancy rule, and keeps whole canvases."""
+    if _faults.active():
+        _faults.maybe_inject("dense")
+    strips = _dense_strips(a, b, c) if _over_canvas_cap(a, b) else None
+    if strips is not None:
+        return _dense_multiply_chunked(a, b, c, alpha, beta, strips)
     t_start = time.perf_counter()
     _metrics.record_jit(
-        "mm.multiply._dense_general_dot",
+        "mm.multiply._dense_dot",
         (a.nfullrows, b.nfullcols, a.nfullcols, str(np.dtype(c.dtype))),
     )
     with timed("dense_canvas_ab"):
         ad = _dense_canvas_cached(a, lambda: _to_dense_device(a))
         bd = _dense_canvas_cached(b, lambda: _to_dense_device(b))
-        if profile:
-            _ff(ad), _ff(bd)
     acc = ad.dtype
     with timed("dense_dot"):
         cd = jax.lax.dot_general(
@@ -794,15 +655,10 @@ def _dense_multiply_general(a, b, c, alpha, beta) -> int:
             _abft.check_dense_canvas(cd, ad, bd, c_old_dense, alpha,
                                      beta, dtype=c.dtype)
         # the old-C canvas (possibly hundreds of MB) must not stay
-        # alive through carve/finalize: its uses end here
+        # alive through the carve: its uses end here
         del c_old_dense
-        if profile:
-            _ff(cd)
     with timed("dense_carve"):
         carve_full_pattern(c, cd)
-        if profile:
-            for bb in c.bins:
-                _ff(bb.data)
     # marketing flops = the dense work performed; the RETURN value is the
     # true flops of the sparse product (comparable across algorithms,
     # ref marketing-vs-true `dbcsr_mm.F:664-667`)
@@ -928,126 +784,6 @@ def carve_full_pattern(c, cd) -> None:
     c.set_structure_from_device(new_keys, bins, binning=(nb, nsl, shapes))
 
 
-def _dense_multiply(a, b, c, alpha, beta) -> int:
-    """Dense-mode path: scatter blocks to dense, one MXU matmul, carve C
-    back into a full block pattern (ref `dbcsr_make_dense` +
-    `use_dense_mult`, `dbcsr_mm.F:593-617,770-810`)."""
-    if _faults.active():
-        _faults.maybe_inject("dense")
-    for m in (a, b, c):
-        if len(np.unique(m.row_blk_sizes)) > 1 or len(np.unique(m.col_blk_sizes)) > 1:
-            return _dense_multiply_general(a, b, c, alpha, beta)
-    bm = int(c.row_blk_sizes[0])
-    bn = int(c.col_blk_sizes[0])
-    bk = int(a.col_blk_sizes[0])
-    nbr, nbc, nbk = a.nblkrows, c.nblkcols, a.nblkcols
-    if max(a.nfullrows * a.nfullcols, a.nfullcols * b.nfullcols,
-           a.nfullrows * b.nfullcols) > _DENSE_MAX_CANVAS:
-        return _dense_multiply_chunked(a, b, c, alpha, beta)
-    def _build(m, nr, nc_, brow, bcol):
-        rows, cols = m.entry_coords()
-        return _blocks_to_dense(
-            m.bins[0].data[: m.nblks] if m.nblks
-            else jnp.zeros((0, brow, bcol), c.dtype),
-            mempool.upload_index("dense_rows", rows),
-            mempool.upload_index("dense_cols", cols), nr, nc_, brow, bcol,
-        )
-
-    profile = os.environ.get("DBCSR_TPU_DENSE_PROFILE") == "1"
-    if profile:
-        from dbcsr_tpu.utils.sync import fetch_fence as _ff
-
-    t_start = time.perf_counter()
-    dense_jit_key = (nbr, nbc, nbk, bm, bn, bk, str(np.dtype(c.dtype)))
-    dense_compiled = _metrics.record_jit(
-        "mm.multiply._dense_product_to_blocks", dense_jit_key,
-    )
-    with timed("dense_canvas_ab"):
-        ad = _dense_canvas_cached(a, lambda: _build(a, nbr, nbk, bm, bk))
-        bd = _dense_canvas_cached(b, lambda: _build(b, nbk, nbc, bk, bn))
-        if profile:
-            _ff(ad), _ff(bd)
-    c_blocks = (
-        c.bins[0].data[: c.nblks]
-        if c.nblks
-        else jnp.zeros((0, bm, bn), c.dtype)
-    )
-    dt_name = str(np.dtype(c.dtype))
-    alpha_dev = _dense_const(
-        ("scalar", complex(alpha), dt_name),
-        lambda: jnp.asarray(alpha, dtype=c.dtype),
-    )
-    beta_dev = _dense_const(
-        ("scalar", complex(beta), dt_name),
-        lambda: jnp.asarray(beta, dtype=c.dtype),
-    )
-    keys32 = c.keys.astype(np.int32)
-    c_keys_dev = _dense_const(
-        ("ckeys", nbr, nbc, keys32.tobytes()),
-        lambda: jnp.asarray(keys32),
-    )
-    if profile:
-        # split programs + fences: attribute dot vs carve separately
-        # (production fuses them — this is measurement-only)
-        with timed("dense_dot"):
-            cd = _dense_dot_only(ad, bd)
-            _ff(cd)
-        with timed("dense_carve"):
-            out = _dense_carve_only(
-                cd, c_blocks, c_keys_dev,
-                alpha_dev, beta_dev, nbr, nbc, bm, bn,
-            )
-            _ff(out)
-    else:
-        if dense_compiled and _costmodel.xla_capture_enabled():
-            dcost = _costmodel.dense_cost(
-                nbr * bm, nbc * bn, nbk * bk,
-                itemsize=np.dtype(c.dtype).itemsize)
-            _costmodel.capture_xla_cost(
-                "mm.multiply._dense_product_to_blocks", dense_jit_key,
-                _dense_product_to_blocks,
-                (ad, bd, c_blocks, c_keys_dev, alpha_dev, beta_dev,
-                 nbr, nbc, bm, bn),
-                model={"flops": dcost["flops"], "bytes": dcost["bytes"]},
-            )
-        out = _dense_product_to_blocks(
-            ad, bd, c_blocks, c_keys_dev,
-            alpha_dev, beta_dev, nbr, nbc, bm, bn,
-        )
-    out = _dense_guard(out)
-    if _abft.enabled():
-        # the carved block pattern IS a layout permutation of the
-        # result canvas: un-permute and probe-verify against the
-        # operand canvases (+ the old-C canvas when beta != 0)
-        res_canvas = (out.reshape(nbr, nbc, bm, bn)
-                      .transpose(0, 2, 1, 3).reshape(nbr * bm, nbc * bn))
-        c_old_canvas = (_build(c, nbr, nbc, bm, bn)
-                        if beta != 0 and c.nblks else None)
-        _abft.check_dense_canvas(res_canvas, ad, bd, c_old_canvas,
-                                 alpha, beta, dtype=c.dtype)
-        # probe canvases are full-N^2 buffers: release before finalize
-        del res_canvas, c_old_canvas
-    with timed("dense_finalize"):
-        new_keys = np.arange(nbr * nbc, dtype=np.int64)  # full pattern, row-major
-        cap = bucket_size(len(new_keys))
-        pad = cap - len(new_keys)
-        if pad:
-            out = jnp.concatenate([out, jnp.zeros((pad, bm, bn), out.dtype)])
-        c.set_structure_from_device(new_keys, [_Bin((bm, bn), out, len(new_keys))])
-        if profile:
-            _ff(c.bins[0].data)
-    stats.record_stack(
-        bm, bn, bk, nbr * nbc * nbk, driver="dense",
-        seconds=time.perf_counter() - t_start,
-        nbytes=_costmodel.dense_cost(
-            nbr * bm, nbc * bn, nbk * bk,
-            itemsize=np.dtype(c.dtype).itemsize)["bytes"],
-        dtype=str(np.dtype(c.dtype)),
-    )
-    stats.record_multiply(2 * nbr * bm * nbc * bn * nbk * bk)
-    return _true_product_flops(a, b)
-
-
 @functools.partial(
     jax.jit, donate_argnums=0,
     static_argnames=("m_el", "k_el", "n_el", "bm", "bn", "bk"),
@@ -1082,13 +818,12 @@ def _dense_strip_to_blocks(cd, c_blocks, strip_pos, alpha, beta,
     """Carve one C m-strip canvas into its full row-major block pattern
     and merge beta*old (strip_pos: old block -> strip-local full-pattern
     position, out-of-strip dropped).  A strip is a full row-major
-    pattern over ``rows`` block rows, so it shares the layout carve
-    with the unchunked path."""
+    pattern over ``rows`` block rows: a layout carve, no gather."""
     out = alpha * _carve_full_pattern(cd, rows, nbc, bm, bn)
     return out.at[strip_pos].add(beta * c_blocks.astype(out.dtype), mode="drop")
 
 
-def _dense_multiply_chunked(a, b, c, alpha, beta) -> int:
+def _dense_multiply_chunked(a, b, c, alpha, beta, strips) -> int:
     """Dense mode beyond the canvas cap: tile over k-strips (plus
     m-strips and n-strips when the C canvas itself is too big), keeping
     every live canvas under `_DENSE_MAX_CANVAS` elements while the
@@ -1100,13 +835,7 @@ def _dense_multiply_chunked(a, b, c, alpha, beta) -> int:
     bn = int(c.col_blk_sizes[0])
     bk = int(a.col_blk_sizes[0])
     nbr, nbc, nbk = a.nblkrows, c.nblkcols, a.nblkcols
-    chunking = _dense_chunking(nbr, nbc, nbk, bm, bn, bk)
-    if chunking is None:
-        # reached via the forced/occupancy gates (which skip the
-        # feasibility check): no strip shape fits the cap, so keep the
-        # pre-chunking single-canvas behavior rather than crash
-        return _dense_multiply_general(a, b, c, alpha, beta)
-    mrb, kcb, ncb = chunking
+    mrb, kcb, ncb = strips  # `_dense_strips`: fits the cap, not None
     nms = -(-nbr // mrb)
     nks = -(-nbk // kcb)
     nns = -(-nbc // ncb)
@@ -1244,10 +973,8 @@ def composite_panels(a, b, c):
     cfg = get_config()
     if a.nblks == 0 or b.nblks == 0:
         return None
-    for m in (a, b, c):
-        if len(np.unique(m.row_blk_sizes)) > 1 \
-                or len(np.unique(m.col_blk_sizes)) > 1:
-            return None
+    if not all(_uniformly_blocked(m) for m in (a, b, c)):
+        return None
     nbr, nbk = a.nblkrows, a.nblkcols
     if nbr < 2 or float(nbr) * nbk > 5e7:
         return None

@@ -125,9 +125,9 @@ def safe_engine():
     from dbcsr_tpu.core.config import get_config, set_config
 
     cfg = get_config()
-    prev_driver, prev_dense = cfg.mm_driver, cfg.mm_dense
-    set_config(mm_driver="xla", mm_dense=False)
+    prev_driver, prev_format = cfg.mm_driver, cfg.mm_format
+    set_config(mm_driver="xla", mm_format="stack")
     try:
         yield
     finally:
-        set_config(mm_driver=prev_driver, mm_dense=prev_dense)
+        set_config(mm_driver=prev_driver, mm_format=prev_format)

@@ -41,7 +41,7 @@ _seq = 0
 _PHASES = (
     "multiply_index", "multiply_c_assemble", "multiply_stacks",
     "multiply_filter", "multiply_dense", "dense_canvas_ab",
-    "dense_dot", "dense_carve", "dense_finalize",
+    "dense_dot", "dense_carve",
 )
 
 

@@ -1310,6 +1310,9 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     t_start = time.perf_counter()
     kl, pr, pc = mesh.shape["kl"], mesh.shape["pr"], mesh.shape["pc"]
     cannon = pr == pc
+    # the planner's symmetry gate reads C as the caller gave it
+    # (`_prepare_operands` desymmetrizes the one the engine fills)
+    c_given = matrix_c
     # accumulate in C's dtype when C is given (host-path convention)
     a, b, matrix_c, dtype, bm, bk, bn = _prepare_operands(
         matrix_a, matrix_b, matrix_c
@@ -1333,20 +1336,26 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
             a, b, shell, element_limits
         )
 
-    # ---- dense-mode decision, shared cost model with the single-chip
-    # engine (ref the generic driver's make_dense gate used by EVERY
-    # parallel path, `dbcsr_mm.F:593-617`): high-fill (or emulated-dtype
-    # high-fill) products run as the dense Cannon over the same mesh ----
-    from dbcsr_tpu.mm.multiply import _dense_mode_wanted
+    # ---- format decision: the planner the single-chip engine asks
+    # (ref the generic driver's make_dense gate used by EVERY parallel
+    # path, `dbcsr_mm.F:593-617`).  What this engine can execute is the
+    # dense 2.5D Cannon, square grids only (rectangular grids keep the
+    # sparse all-gather route), on whole canvases ----
+    from dbcsr_tpu.mm import format_planner as _fmt
 
-    no_limits = all(x is None for x in limits)
-    shell_for_gate = matrix_c if matrix_c is not None else BlockSparseMatrix(
-        name or f"{a.name}*{b.name}", a.row_blk_sizes, b.col_blk_sizes, dtype
+    fmt_plan = _fmt.choose(
+        a, b,
+        c_given if c_given is not None else BlockSparseMatrix(
+            name or f"{a.name}*{b.name}", a.row_blk_sizes, b.col_blk_sizes,
+            dtype),
+        filter_eps=filter_eps, retain_sparsity=retain_sparsity,
+        no_limits=all(x is None for x in limits),
+        executors=("dense",) if cannon else (), chunked_canvas=False,
     )
-    if cannon and _dense_mode_wanted(a, b, shell_for_gate, filter_eps,
-                                     retain_sparsity, no_limits):
-        # (the dense 2.5D Cannon is square-grid only; rectangular
-        # grids keep the sparse all-gather route)
+    # (not `_fmt.note_decision`-ed yet: the benchmark's harness test
+    # pins that a mesh window moves no decision counter, and only a
+    # `benchmark` PR may edit it — ROADMAP C2)
+    if fmt_plan.fmt == "dense":
         return _dense_multiply_mesh(
             alpha, a, b, beta, matrix_c, mesh, name, dtype, pr, kl
         )

@@ -422,10 +422,10 @@ def _checksum_retry_safe(cfg: PerfConfig, run_once, cs_first: float,
         })
 
     live = get_config()
-    prev_driver, prev_dense = live.mm_driver, live.mm_dense
+    prev_driver, prev_format = live.mm_driver, live.mm_format
     retried_same_path = prev_driver == SAFE_DRIVER
     try:
-        set_config(mm_driver=SAFE_DRIVER, mm_dense=False)
+        set_config(mm_driver=SAFE_DRIVER, mm_format="stack")
         c_run, _flops, _dt = run_once()
     except Exception as exc:  # retry itself died: original error stands
         _metrics.counter(
@@ -437,7 +437,7 @@ def _checksum_retry_safe(cfg: PerfConfig, run_once, cs_first: float,
             f"{first_err}; safe-driver retry also failed "
             f"({type(exc).__name__}: {exc})") from first_err
     finally:
-        set_config(mm_driver=prev_driver, mm_dense=prev_dense)
+        set_config(mm_driver=prev_driver, mm_format=prev_format)
     cs = matrix_checksum(c_run)
     cs_pos = matrix_checksum(c_run, pos=True)
     counter = _metrics.counter(

@@ -128,8 +128,8 @@ def test_tas_chain_restage_collapse():
     from dbcsr_tpu.tas import tas_multiply
 
     prev_driver = get_config().mm_driver
-    prev_dense = get_config().mm_dense
-    set_config(mm_dense=False, mm_driver="xla")
+    prev_format = get_config().mm_format
+    set_config(mm_format="stack", mm_driver="xla")
     try:
         per_iter = {}
         dense = {}
@@ -154,7 +154,7 @@ def test_tas_chain_restage_collapse():
             dense[pooled] = np.asarray(dt.to_dense(c))
     finally:
         mempool.set_enabled(True)
-        set_config(mm_dense=prev_dense, mm_driver=prev_driver)
+        set_config(mm_format=prev_format, mm_driver=prev_driver)
     assert (dense[True] == dense[False]).all()
     # chained: steady state moves (almost) nothing; unchained: every
     # iteration pays the same per-split staging again

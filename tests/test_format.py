@@ -39,9 +39,8 @@ def _clean_slate(tmp_path, monkeypatch):
     monkeypatch.setenv("DBCSR_TPU_PARAMS_DIR", str(tmp_path))
     params_mod.invalidate()
     cfg0 = {f: getattr(get_config(), f)
-            for f in ("abft", "mm_driver", "mm_dense", "mm_format",
+            for f in ("abft", "mm_driver", "mm_format",
                       "composite_max_panels", "composite_ksup",
-                      "dense_occ_threshold", "dense_flop_ratio",
                       "incremental")}
     faults.clear()
     breaker.reset_board()
@@ -104,6 +103,14 @@ def _choose(a, b, c):
                      no_limits=True)
 
 
+def _dense_rules_off(monkeypatch):
+    """No product passes either dense rule (the planner's constants are
+    patched, as `_DENSE_MAX_CANVAS` is; neither is in the plan key)."""
+    monkeypatch.setattr(fp, "DENSE_OCC_THRESHOLD", 2.0)
+    monkeypatch.setattr(fp, "DENSE_FLOP_RATIO", 0)
+    fp.reset()
+
+
 def _ctr(name, **labels):
     total = 0.0
     for lb, v in metrics.counter_items(name):
@@ -136,7 +143,7 @@ def test_every_format_bitwise_identical():
 
 def test_occupancy_ladder_heuristic_and_default():
     """No learned rows: a near-full product goes dense through the
-    preserved legacy heuristic, a sparse one stays on the stack path,
+    occupancy rule, a sparse one stays on the stack path,
     and both land on the decision counter."""
     set_config(mm_format="auto")
     full_a, full_b, bs = _pair(nblk=6, bsize=4, fill=1.0, seed=1)
@@ -155,7 +162,7 @@ def test_occupancy_ladder_heuristic_and_default():
                 format="dense", reason="heuristic") >= 1
 
 
-def test_occupancy_ladder_learned_crossover():
+def test_occupancy_ladder_learned_crossover(monkeypatch):
     """A promoted format row steers the planner by triple-occupancy:
     above the learned crossover the row's format wins, below it the
     stack default holds (reason='tuned' both ways)."""
@@ -163,8 +170,8 @@ def test_occupancy_ladder_learned_crossover():
                            "stack_size": 0, "format": "dense",
                            "format_occ": 0.5, "format_gflops": 9.9,
                            "tuned_by": "test"})
-    set_config(mm_format="auto", dense_occ_threshold=2.0,
-               dense_flop_ratio=0)  # heuristic off: isolate the row
+    set_config(mm_format="auto")
+    _dense_rules_off(monkeypatch)  # isolate the row
     lo_a, lo_b, bs = _pair(nblk=6, bsize=4, fill=0.4, seed=4)
     plan = _choose(lo_a, lo_b, dt.create("fC", bs, bs))
     assert (plan.fmt, plan.reason) == ("stack", "tuned")
@@ -188,10 +195,10 @@ def test_forced_infeasible_falls_back_to_stack():
 
 # --------------------------------------- plan cache vs the generation
 
-def test_promotion_generation_bump_retires_cached_plans():
+def test_promotion_generation_bump_retires_cached_plans(monkeypatch):
     a, b, bs = _pair(nblk=6, bsize=4, fill=1.0, seed=7)
-    set_config(mm_format="auto", dense_occ_threshold=2.0,
-               dense_flop_ratio=0)
+    set_config(mm_format="auto")
+    _dense_rules_off(monkeypatch)
     c = dt.create("fC", bs, bs)
     p1 = _choose(a, b, c)
     assert (p1.fmt, p1.reason) == ("stack", "default")
@@ -206,10 +213,10 @@ def test_promotion_generation_bump_retires_cached_plans():
     assert (p2.fmt, p2.reason) == ("dense", "tuned")
 
 
-def test_demotion_on_regression_restores_stack():
+def test_demotion_on_regression_restores_stack(monkeypatch):
     a, b, bs = _pair(nblk=6, bsize=4, fill=1.0, seed=8)
-    set_config(mm_format="auto", dense_occ_threshold=2.0,
-               dense_flop_ratio=0)
+    set_config(mm_format="auto")
+    _dense_rules_off(monkeypatch)
     c = dt.create("fC", bs, bs)
     store.promote({"m": 4, "n": 4, "k": 4, "dtype": "float64",
                    "stack_size": 0, "format": "dense",

@@ -29,7 +29,7 @@ def _clean_slate():
     from dbcsr_tpu.mm import multiply as mm_mod
 
     cfg0 = {f: getattr(get_config(), f)
-            for f in ("abft", "mm_driver", "mm_dense", "use_pallas",
+            for f in ("abft", "mm_driver", "mm_format", "use_pallas",
                       "serve_coalesce")}
     faults.clear()
     breaker.reset_board()
@@ -225,7 +225,7 @@ def test_dense_flip_degrades_to_stack_engine():
     # value-correct vs a clean stack-engine run (dense vs stack differ
     # only in accumulation order, so compare relative)
     ref_a, ref_b, ref_c = _mats(occ=0.95, occ_c=0.95, seed=4)
-    set_config(abft="off", mm_dense=False)
+    set_config(abft="off", mm_format="stack")
     multiply("N", "N", 2.0, ref_a, ref_b, 0.5, ref_c)
     rel = abs(checksum(c) - checksum(ref_c)) / abs(checksum(ref_c))
     assert rel < 1e-11

@@ -289,7 +289,7 @@ def test_chain_multiply_steady_state_uploads_collapse():
     from dbcsr_tpu.core.config import get_config, set_config
 
     old_driver = get_config().mm_driver
-    set_config(mm_driver="xla", mm_dense=False)
+    set_config(mm_driver="xla", mm_format="stack")
     try:
         per_iter = {}
         for pooled in (False, True):
@@ -317,7 +317,7 @@ def test_chain_multiply_steady_state_uploads_collapse():
         assert per_iter[True][-1] == 0
         assert per_iter[False][-1] > 0
     finally:
-        set_config(mm_driver=old_driver, mm_dense=None)
+        set_config(mm_driver=old_driver, mm_format="auto")
 
 
 def test_added_out_of_place_matches_add_and_keeps_ownership():

@@ -308,11 +308,11 @@ def test_dense_mode_matches_sparse_path():
     c_dense = _rand("c", rbs, rbs, 0.5, seed=52)
     c_sparse = c_dense.copy()
     multiply("N", "N", 1.5, a, b, 0.5, c_dense)  # auto -> dense mode
-    set_config(mm_dense=False)
+    set_config(mm_format="stack")
     try:
         multiply("N", "N", 1.5, a, b, 0.5, c_sparse)
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
     np.testing.assert_allclose(to_dense(c_dense), to_dense(c_sparse),
                                rtol=1e-12, atol=1e-12)
 
@@ -329,16 +329,16 @@ def test_dense_mode_nonuniform_blocking_matches_sparse_path():
     b = _rand("b", kbs, cbs, 1.0, seed=61)
     c_dense = _rand("c", rbs, cbs, 0.5, seed=62)
     c_sparse = c_dense.copy()
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     try:
         multiply("N", "N", 1.5, a, b, 0.5, c_dense)
     finally:
-        set_config(mm_dense=None)
-    set_config(mm_dense=False)
+        set_config(mm_format="auto")
+    set_config(mm_format="stack")
     try:
         multiply("N", "N", 1.5, a, b, 0.5, c_sparse)
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
     # dense mode leaves a full pattern; values must agree everywhere
     np.testing.assert_allclose(to_dense(c_dense), to_dense(c_sparse),
                                rtol=1e-12, atol=1e-12)
@@ -409,7 +409,7 @@ def test_dense_canvas_cache_hits_and_invalidates():
     rbs = [4] * 5
     a = _rand("a", rbs, rbs, 1.0, seed=80)
     b = _rand("b", rbs, rbs, 1.0, seed=81)
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     try:
         c1 = create("c", rbs, rbs)
         multiply("N", "N", 1.0, a, b, 0.0, c1)
@@ -425,7 +425,7 @@ def test_dense_canvas_cache_hits_and_invalidates():
         np.testing.assert_allclose(to_dense(c3), 2.0 * to_dense(c1),
                                    rtol=1e-12, atol=1e-12)
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
 
 
 def test_alpha_beta_scalar_typing():
@@ -465,11 +465,11 @@ def test_dense_chunked_matches_stack_path(monkeypatch):
                                rng=np.random.default_rng(3))
     want = 1.5 * (dt.to_dense(a) @ dt.to_dense(b)) + 0.5 * dt.to_dense(c0)
     assert mm._dense_chunking(13, 11, 17, 7, 7, 7) == (9, 9, 11)
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     try:
         dt.multiply("N", "N", 1.5, a, b, 0.5, c0)
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
     assert c0._mm_algorithm == "dense"
     np.testing.assert_allclose(dt.to_dense(c0), want, rtol=1e-12, atol=1e-12)
 
@@ -495,13 +495,26 @@ def test_dense_chunked_gate_and_feasibility(monkeypatch):
     # is closed (occupancy below threshold, cost model needs uniform)
     rbs = [7] * 9
     kbs = [7, 5] * 5
-    a = dt.make_random_matrix("A", rbs, kbs, occupation=0.3,
+    a = dt.make_random_matrix("A", rbs, kbs, occupation=0.5,
                               rng=np.random.default_rng(4))
-    b = dt.make_random_matrix("B", kbs, rbs, occupation=0.3,
+    b = dt.make_random_matrix("B", kbs, rbs, occupation=0.5,
                               rng=np.random.default_rng(5))
     c = dt.create("C", rbs, rbs, dtype=np.float64)
-    assert not mm._dense_mode_wanted(a, b, c, None, False, True,
-                                     allow_chunked=True)
+    from dbcsr_tpu.core.config import get_config, set_config
+    from dbcsr_tpu.mm import format_planner as fp
+
+    # on the (seamed) TPU the emulated-dtype rule would take this
+    # product — fill and flop ratio pass — were its canvases feasible
+    set_config(platform_override="tpu")
+    try:
+        assert not mm.dense_canvas_feasible(a, b, c, chunked=True)
+        assert fp._dense_rule(a, b, c, get_config(), True) is None
+        monkeypatch.setattr(mm, "_DENSE_MAX_CANVAS", 2 * 10 ** 8)
+        assert fp._dense_rule(a, b, c, get_config(), True) \
+            == "cost-model:emulated-dtype"
+        monkeypatch.setattr(mm, "_DENSE_MAX_CANVAS", 2000)
+    finally:
+        set_config(platform_override="")
     dt.multiply("N", "N", 1.0, a, b, 0.0, c)
     assert c._mm_algorithm == "stack"
     np.testing.assert_allclose(
@@ -511,9 +524,9 @@ def test_dense_chunked_gate_and_feasibility(monkeypatch):
 
 
 def test_uniform_carve_is_block_slicing():
-    """The uniform layout carve (`_carve_full_pattern`: `_dense_multiply`,
-    the chunked strips, the profile split) is element-exact vs a manual
-    block slicing of the canvas (full row-major pattern)."""
+    """The uniform layout carve (`_carve_full_pattern`: the chunked
+    executor's strips) is element-exact vs a manual block slicing of
+    the canvas (full row-major pattern)."""
     import jax.numpy as jnp
 
     from dbcsr_tpu.mm import multiply as mm
@@ -640,50 +653,39 @@ def test_irregular_blocking_keeps_the_gather_carve():
     np.testing.assert_array_equal(to_dense(c3), np.asarray(cd))
 
 
-def test_dense_profile_mode_matches_default(monkeypatch):
-    """DBCSR_TPU_DENSE_PROFILE=1 (split programs + fences) must give
-    bit-identical results to the fused production path."""
-    rbs = [4] * 6
-    a = _rand("a", rbs, rbs, 1.0, seed=60)
-    b = _rand("b", rbs, rbs, 1.0, seed=61)
-    c_ref = _rand("c", rbs, rbs, 0.5, seed=62)
-    c_prof = c_ref.copy()
-    multiply("N", "N", 1.5, a, b, 0.5, c_ref)  # auto -> dense mode
-    monkeypatch.setenv("DBCSR_TPU_DENSE_PROFILE", "1")
-    multiply("N", "N", 1.5, a, b, 0.5, c_prof)
-    np.testing.assert_array_equal(to_dense(c_ref), to_dense(c_prof))
-
-
-@pytest.mark.parametrize("blocking", ["near_uniform", "irregular"])
+@pytest.mark.parametrize("blocking", ["uniform", "near_uniform", "irregular"])
 def test_dense_general_carve_variants_match_oracle(blocking):
     """The PRODUCTION north-star shape is near-uniform (ceil-division
-    blocking: uniform 23s + one trailing 18), which routes through
-    _dense_multiply_general/carve_full_pattern and the layout carve; an
-    irregular blocking takes the gather there.  Both must be
-    oracle-exact."""
+    blocking: uniform 23s + one trailing 18), which `_dense_multiply`
+    carves by the layout program — as it does a uniform blocking, the
+    case with no ragged edge; an irregular blocking takes the gather.
+    All must be oracle-exact, beta merge into a non-empty C included."""
     from dbcsr_tpu.core.config import set_config
 
-    if blocking == "near_uniform":
+    if blocking == "uniform":
+        rbs, cbs, kbs = [23] * 7, [13] * 6, [23] * 5
+    elif blocking == "near_uniform":
         rbs = [23] * 6 + [18]   # near-uniform rows
         cbs = [13] * 5 + [7]    # near-uniform cols, different size
     else:
         rbs = [23, 11, 23, 23, 5, 23, 18]
         cbs = [13, 7, 13, 13, 13, 2]
-    kbs = [23] * 4 + [11]
+    if blocking != "uniform":
+        kbs = [23] * 4 + [11]
     a = _rand("a", rbs, kbs, 0.6, seed=31)
     b = _rand("b", kbs, cbs, 0.6, seed=32)
     c = _rand("c", rbs, cbs, 0.4, seed=33)
     c0 = to_dense(c)
     before = _carve_counts()
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     try:
         multiply("N", "N", 1.5, a, b, 0.5, c)
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
     want = 1.5 * (to_dense(a) @ to_dense(b)) + 0.5 * c0
     np.testing.assert_allclose(to_dense(c), want, rtol=1e-12, atol=1e-12)
     after = _carve_counts()
-    took = "layout" if blocking == "near_uniform" else "gather"
+    took = "gather" if blocking == "irregular" else "layout"
     assert {k: after[k] - before[k] for k in after} == {
         "layout": float(took == "layout"), "gather": float(took == "gather")}
 
@@ -702,13 +704,13 @@ def test_dense_mesh_carve_matches_one_chip():
     b = _rand("b", kbs, cbs, 0.9, seed=42)
     c_one = create("c", rbs, cbs)
     before = _carve_counts()
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     try:
         multiply("N", "N", 1.5, a, b, 0.0, c_one)
         c_mesh = sparse_multiply_distributed(1.5, a, b, 0.0, None,
                                              make_grid(4))
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
     assert c_mesh._mm_algorithm == "dense" == c_one._mm_algorithm
     assert _carve_counts()["layout"] == before["layout"] + 2
     assert [(b_.shape, b_.count, b_.data.shape) for b_ in c_mesh.bins] == [

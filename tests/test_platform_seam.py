@@ -6,7 +6,7 @@ interpret-mode Pallas driver off-TPU (~1000x slowdown masquerading as a
 hang, fix f874263) — the branch lived behind `platform != "tpu"` and
 was untestable on the CPU suite.  `config.platform_override` now lets
 these tests fake the platform for every decision site
-(_pallas_supported, _dense_mode_wanted, emulated_dtype_on_tpu /
+(_pallas_supported, the format planner, emulated_dtype_on_tpu /
 _stack_r0, _host_smm_available) while execution still follows the real
 backend.  Reference analog: the careful-mode dispatch asserts of
 `dbcsr_mm_sched.F:295-321`, which stay testable off-GPU.
@@ -176,29 +176,38 @@ def _fill_pair(occ=0.5, nblk=20, bs=8):
     return a, b, c
 
 
+def _plan(a, b, c, filter_eps=None, retain_sparsity=False):
+    from dbcsr_tpu.mm import format_planner as fp
+
+    return fp.choose(a, b, c, filter_eps=filter_eps,
+                     retain_sparsity=retain_sparsity, no_limits=True)
+
+
 def test_dense_cost_model_routes_f64_on_fake_tpu(fake_tpu):
     """The emulated-dtype cost model (dense beats MXU-starved sparse
     stacks by ~320x for f64) is TPU-only; the seam makes the routing
     assertable on the CPU suite."""
-    from dbcsr_tpu.mm.multiply import _dense_mode_wanted
-
     a, b, c = _fill_pair()
     set_config(mm_driver="auto")
-    assert _dense_mode_wanted(a, b, c, None, False, True)
+    plan = _plan(a, b, c)
+    assert (plan.fmt, plan.reason, plan.why) == (
+        "dense", "heuristic", "cost-model:emulated-dtype")
 
 
 def test_dense_cost_model_refusals(fake_tpu):
-    from dbcsr_tpu.mm.multiply import _dense_mode_wanted
+    from dbcsr_tpu.mm import format_planner as fp
 
     a, b, c = _fill_pair()
     set_config(mm_driver="auto")
     # filter_eps produces a filtered C: dense mode must refuse
-    assert not _dense_mode_wanted(a, b, c, 1e-9, False, True)
+    plan = _plan(a, b, c, filter_eps=1e-9)
+    assert (plan.fmt, plan.reason) == ("stack", "structural")
     # retain_sparsity keeps C's pattern: refuse
-    assert not _dense_mode_wanted(a, b, c, None, True, True)
+    plan = _plan(a, b, c, retain_sparsity=True)
+    assert (plan.fmt, plan.reason) == ("stack", "structural")
     # a forced stack driver wins over the cost model
     set_config(mm_driver="xla")
-    assert not _dense_mode_wanted(a, b, c, None, False, True)
+    assert fp._dense_rule(a, b, c, get_config(), True) is None
     set_config(mm_driver="auto")
     # structurally sparse C (block-diagonal operands): expected fill
     # far below 0.5 — must not silently densify
@@ -212,13 +221,13 @@ def test_dense_cost_model_refusals(fake_tpu):
     ad.finalize()
     bd.finalize()
     cd = dt.create("Cd", rbs, rbs, dtype=np.float64)
-    assert not _dense_mode_wanted(ad, bd, cd, None, False, True)
+    assert fp._dense_rule(ad, bd, cd, get_config(), True) is None
+    assert _plan(ad, bd, cd).fmt != "dense"
 
 
 def test_dense_cost_model_off_tpu_is_dead():
     """f64 is native on CPU; the emulated-dtype branch must not fire."""
-    from dbcsr_tpu.mm.multiply import _dense_mode_wanted
-
     a, b, c = _fill_pair()
     set_config(mm_driver="auto")
-    assert not _dense_mode_wanted(a, b, c, None, False, True)
+    plan = _plan(a, b, c)
+    assert (plan.fmt, plan.reason) == ("stack", "default")

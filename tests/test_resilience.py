@@ -24,7 +24,7 @@ def _clean_slate():
     from dbcsr_tpu.mm import multiply as mm_mod
 
     cfg0 = {f: getattr(get_config(), f)
-            for f in ("mm_driver", "mm_dense", "use_pallas", "flat_gather",
+            for f in ("mm_driver", "mm_format", "use_pallas", "flat_gather",
                       "validate_kernels")}
     faults.clear()
     breaker.reset_board()
@@ -282,7 +282,7 @@ def test_e2e_prepare_failure_replans_safely():
 
 
 def test_e2e_dense_failure_degrades_to_stack():
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     a, b, c = _mats(occ=0.9)
     multiply("N", "N", 1.0, a, b, 0.0, c)
     assert c._mm_algorithm == "dense"
@@ -297,7 +297,7 @@ def test_e2e_dense_failure_degrades_to_stack():
 
 
 def test_e2e_dense_nan_canvas_detected():
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     a, b, c = _mats(occ=0.9)
     with faults.inject_faults("dense:nan"):
         multiply("N", "N", 1.0, a, b, 0.0, c)

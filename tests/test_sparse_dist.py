@@ -561,14 +561,14 @@ def test_mesh_dense_mode_high_fill_routes_dense(mesh8):
     a = _rand("A", rbs, rbs, 0.95, 60)
     b = _rand("B", rbs, rbs, 0.95, 61)
     c0 = _rand("C", rbs, rbs, 0.3, 62)
-    # occupation >= dense_occ_threshold (0.8) routes dense on any platform
+    # occupation >= DENSE_OCC_THRESHOLD (0.8) routes dense on any platform
     c_dense = sparse_multiply_distributed(1.5, a, b, 0.5, c0, mesh8)
     assert c_dense._mm_algorithm == "dense"
-    set_config(mm_dense=False)
+    set_config(mm_format="stack")
     try:
         c_stack = sparse_multiply_distributed(1.5, a, b, 0.5, c0, mesh8)
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
     assert c_stack._mm_algorithm == "stack"
     want = 1.5 * (to_dense(a) @ to_dense(b)) + 0.5 * to_dense(c0)
     np.testing.assert_allclose(to_dense(c_dense), want, rtol=1e-12, atol=1e-12)
@@ -590,11 +590,11 @@ def test_mesh_dense_mode_mixed_blockings(mesh4):
     cbs = list(rng.choice([3, 6], 5))
     a = _rand("A", rbs, kbs, 0.9, 64)
     b = _rand("B", kbs, cbs, 0.9, 65)
-    set_config(mm_dense=True)
+    set_config(mm_format="dense")
     try:
         c = sparse_multiply_distributed(-2.0, a, b, 0.0, None, mesh4)
     finally:
-        set_config(mm_dense=None)
+        set_config(mm_format="auto")
     assert c._mm_algorithm == "dense"
     np.testing.assert_allclose(
         to_dense(c), -2.0 * (to_dense(a) @ to_dense(b)), rtol=1e-12, atol=1e-12
@@ -614,6 +614,61 @@ def test_mesh_dense_mode_never_on_filtered_products(mesh4):
         1.0, a, b, 1.0, c0, mesh4, retain_sparsity=True
     )
     assert c2._mm_algorithm == "stack"
+
+
+@pytest.mark.parametrize("case", [
+    "format_stack", "format_dense", "format_auto",
+    "gate_filter", "gate_retain_sparsity", "gate_limits", "gate_symmetric_c",
+])
+def test_mesh_and_one_chip_ask_one_decider(mesh4, case):
+    """`multiply` and `sparse_multiply_distributed` take the format from
+    the same `mm.format_planner.choose`: every spelling of `mm_format`
+    and every structural gate gives the same `_mm_algorithm` on both.
+    The operands are dense-eligible (occupancy over
+    `DENSE_OCC_THRESHOLD`), so it is the gate or the force that
+    decides."""
+    from dbcsr_tpu import multiply, set_config
+    from dbcsr_tpu.mm import format_planner as fp
+
+    rbs = [4] * 8
+    a = _rand("A", rbs, rbs, 0.95, 70)
+    b = _rand("B", rbs, rbs, 0.95, 71)
+    kw = {}
+    c_type = "N"
+    want = "stack"
+    if case.startswith("format_"):
+        fmt = case[len("format_"):]
+        want = "stack" if fmt == "stack" else "dense"
+    else:
+        fmt = "auto"
+        if case == "gate_filter":
+            kw["filter_eps"] = 1e-8
+        elif case == "gate_retain_sparsity":
+            kw["retain_sparsity"] = True
+        elif case == "gate_limits":
+            kw.update(first_row=1, last_row=5)
+        else:
+            # A*A^T: a product that a symmetric C can hold
+            from dbcsr_tpu.ops.transformations import new_transposed
+
+            c_type, b = "S", new_transposed(a)
+
+    def c_in():
+        return _rand("C", rbs, rbs, 0.4, 72, matrix_type=c_type)
+
+    set_config(mm_format=fmt)
+    fp.reset()
+    try:
+        c_one = c_in()
+        multiply("N", "N", 1.0, a, b, 1.0, c_one, **kw)
+        c_mesh = sparse_multiply_distributed(1.0, a, b, 1.0, c_in(), mesh4,
+                                             **kw)
+    finally:
+        set_config(mm_format="auto")
+        fp.reset()
+    assert c_mesh._mm_algorithm == c_one._mm_algorithm == want
+    np.testing.assert_allclose(to_dense(c_mesh), to_dense(c_one),
+                               rtol=1e-12, atol=1e-12)
 
 
 def test_sparse_cannon_r_tiled_filtering(mesh8):

@@ -24,7 +24,7 @@ def _clean_slate():
     from dbcsr_tpu.mm import incremental as _inc
 
     cfg0 = {f: getattr(get_config(), f)
-            for f in ("mm_driver", "superstack", "mm_dense", "use_pallas",
+            for f in ("mm_driver", "superstack", "mm_format", "use_pallas",
                       "flat_gather", "incremental")}
     faults.clear()
     breaker.reset_board()

@@ -215,7 +215,7 @@ def main(argv=None) -> int:
 
     # production-shaped: stack engine + device-side driver (see module
     # docstring; matches bench.py --chain)
-    set_config(mm_dense=False, mm_driver="xla")
+    set_config(mm_format="stack", mm_driver="xla")
 
     # ---- pipeline A/B (rectangular-grid gather route) ----
     bs = [args.bsize] * args.nblk

@@ -117,11 +117,11 @@ def main(nconfigs: int = 200, seed: int = 2026_0730) -> int:
                 got = dt.to_dense(out)
             elif feature == "chunked_dense":
                 mm._DENSE_MAX_CANVAS = int(rng.choice([700, 2000, 5000]))
-                set_config(mm_dense=True)
+                set_config(mm_format="dense")
                 try:
                     dt.multiply("N", "N", alpha, a, b, beta, c)
                 finally:
-                    set_config(mm_dense=None)
+                    set_config(mm_format="auto")
                     mm._DENSE_MAX_CANVAS = cap0
                 got = dt.to_dense(c)
             elif feature == "host":
