@@ -194,6 +194,36 @@ _process_stack_xla_flat = functools.partial(
     _stack_phases_xla_flat)
 
 
+# the layout `_stack_phases_xla_group` gathers A and B from
+GROUP_GATHER_LAYOUT = "row"
+
+
+def _note_group_gather() -> None:
+    """Count one launched `xla_group` span by its gather layout."""
+    _metrics.counter(
+        "dbcsr_tpu_stack_gather_total",
+        "xla_group spans launched (per span or inside a fused launch), "
+        "by the layout their A/B gathers read: 'row' = whole blocks as "
+        "lane-dense (N, m*k) rows",
+    ).inc(layout=GROUP_GATHER_LAYOUT)
+
+
+def _block_rows(data):
+    """(N, r, c) blocks as (N, r*c) rows, one block per row.  A TPU
+    keeps a bin of small blocks with the block index along lanes
+    (`f32[N,23,23]{0,2,1}`), where gathering blocks fetches every
+    element of every block on its own; a row is lane-dense, so a
+    gather moves a block as a few whole lane pieces.  A temporary of
+    the program that makes it, once per launch."""
+    return data.reshape(data.shape[0], -1)
+
+
+def _take_rows(rows, ids):
+    """``rows[ids]`` for ids the plan guarantees in range
+    (`build_group_tiles`): no bounds compare, no fill select."""
+    return rows.at[ids].get(mode="promise_in_bounds")
+
+
 def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
                             prec=None):
     """R-tiled ("k-merged") stack layout: entries sharing a C block are
@@ -212,7 +242,11 @@ def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
     ``ga``/``gb`` are (nchunks, CH, R0) gather indices, padded with a
     guaranteed-zero row id; ``gc`` is (nchunks, CH) segment ids with
     nseg for dead groups (dropped).  Groups of one segment stay in
-    index order -> deterministic accumulation.  Per chunk the body
+    index order -> deterministic accumulation.  A and B are gathered
+    as whole block rows (`_block_rows`) in the host's (group, slot)
+    order; on a v5e that is 0.18 s of a filtered f64 north-star product
+    where the element gather along the bins' slot-minor layout was
+    1.58 (PERF.md, PR 29).  Per chunk the body
     touches C only through `_accumulate_chunk`: on a TPU the carried
     bin is tile-padded (2.4 GB for each f32 half of the north star's
     emulated f64), so one more pass over it per chunk costs 11 ms
@@ -221,15 +255,16 @@ def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
     _, m, n = c_data.shape
     k = a_data.shape[2]
     r0 = ga.shape[2]
+    with device_scope("stk_gather"):
+        a_rows = _block_rows(a_data)
+        b_rows = _block_rows(b_data)
 
     def body(c, idx):
         ia, ib, ic = idx
         ch = ia.shape[0]
         with device_scope("stk_gather"):
-            ablk = jnp.take(
-                a_data, ia.reshape(-1), axis=0).reshape(ch, r0, m, k)
-            bblk = jnp.take(
-                b_data, ib.reshape(-1), axis=0).reshape(ch, r0, k, n)
+            ablk = _take_rows(a_rows, ia.reshape(-1)).reshape(ch, r0, m, k)
+            bblk = _take_rows(b_rows, ib.reshape(-1))
             amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, r0 * k)
             bmat = bblk.reshape(ch, r0 * k, n)
         acc = _accum_dtype(c.dtype)
@@ -253,8 +288,11 @@ def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
     """Host side of the grouped layout: split each C segment's entries
     into runs of ``r0`` (pad the last run with zero-row ids), returning
     (nchunks, CH, r0) a/b gather arrays + (nchunks, CH) segment ids.
-    ``c_idx`` must be sorted ascending; dead/pad groups carry segment id
-    ``c_pad`` (= nseg), keeping ids sorted and dropped by the scatter-add."""
+    Every a/b id lies in ``[0, a_pad]`` / ``[0, b_pad]``: the body's
+    gathers promise the compiler that and check nothing.  ``c_idx``
+    must be sorted ascending; dead/pad groups carry segment id
+    ``c_pad`` (= nseg), keeping ids sorted and dropped by the
+    scatter-add."""
     s = len(c_idx)
     seg_starts = np.concatenate([[0], np.nonzero(np.diff(c_idx))[0] + 1])
     seg_len = np.diff(np.append(seg_starts, s))
@@ -1429,6 +1467,7 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
             b_data = _append_pad_row(b_data)
         ga, gb, gc = plan.group_idx
         alpha_dev = jnp.asarray(alpha, dtype=c_data.dtype)
+        _note_group_gather()
         if want_xla_cost:
             _capture_stack_xla_cost(
                 jit_fn_name, jit_key, _process_stack_xla_group,
@@ -1889,6 +1928,7 @@ def _dispatch_superstack(c_data, a_datas, b_datas, splan: SuperstackPlan,
             flat.extend(plan.xla_idx)
         elif plan.driver == "xla_group":
             flat.extend(plan.group_idx)
+            _note_group_gather()
         else:
             for lc in plan.launches:
                 flat.extend(lc)

@@ -196,11 +196,11 @@ def test_base_kernel_launch_of_mixed10k_compiles(one_chip, mixed10k):
 # `northstar.scf_f64`'s 23^3 span: the C bin, the A and B bins with
 # their pad row, 56 chunks of 3 750 groups of 8
 _NS_BIN, _NS_AB, _NS_CHUNKS, _NS_GROUPS, _NS_R0 = 196608, 18901, 56, 3750, 8
+_NS_BIN_SHAPE = f"[{_NS_BIN},23,23]"
 
 
-def _while_bodies(hlo_text):
-    """{computation name: its instruction lines} of every computation
-    that is the body of a `while` in ``hlo_text``."""
+def _computations(hlo_text):
+    """{computation name: its instruction lines}."""
     comps, cur = {}, None
     for line in hlo_text.splitlines():
         head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
@@ -210,21 +210,18 @@ def _while_bodies(hlo_text):
             cur = None
         elif cur is not None:
             cur.append(line.strip())
-    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo_text))
-    return {name: comps[name] for name in bodies}
+    return comps
 
 
-def test_f64_group_body_touches_the_bin_only_in_its_scatter(one_chip):
-    """Inside the chunk loop of `_stack_phases_xla_group`, at the north
-    star's shapes, nothing but the scatter fusion produces an array of
-    the C bin's shape: no zero-fill, add or copy of the whole bin per
-    chunk (PR 26's program had five: 2.8 s of a 6.79 s product)."""
+@pytest.fixture(scope="module")
+def ns_group_hlo(one_chip):
+    """`_process_stack_xla_group` compiled at the north star's shapes:
+    (the program's computations, the lines of its chunk loop's body)."""
     import jax
     import jax.numpy as jnp
 
     from dbcsr_tpu.acc import smm
 
-    bin_shape = f"[{_NS_BIN},23,23]"
     idx = _shape(one_chip, (_NS_CHUNKS, _NS_GROUPS, _NS_R0), jnp.int32)
     with jax.enable_x64(True):
         text = smm._process_stack_xla_group.lower(
@@ -234,20 +231,64 @@ def test_f64_group_body_touches_the_bin_only_in_its_scatter(one_chip):
             idx, idx, _shape(one_chip, (_NS_CHUNKS, _NS_GROUPS), jnp.int32),
             _shape(one_chip, (), jnp.float64),
         ).compile().as_text()
-    chunk_loops = {name: lines for name, lines in _while_bodies(text).items()
-                   if any(bin_shape in ln for ln in lines)}
+    comps = _computations(text)
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text))
+    chunk_loops = [name for name in bodies
+                   if any(_NS_BIN_SHAPE in ln for ln in comps[name])]
     assert len(chunk_loops) == 1, sorted(chunk_loops)
-    (body,) = chunk_loops.values()
+    return comps, comps[chunk_loops[0]]
+
+
+def test_f64_group_body_touches_the_bin_only_in_its_scatter(ns_group_hlo):
+    """Inside the chunk loop of `_stack_phases_xla_group`, at the north
+    star's shapes, nothing but the scatter fusion produces an array of
+    the C bin's shape: no zero-fill, add or copy of the whole bin per
+    chunk (PR 26's program had five: 2.8 s of a 6.79 s product)."""
+    _, body = ns_group_hlo
     # `%name = <result type> <opcode>(<operands>`: a layout's `T(8,128)`
     # follows a colon, an opcode a space
     made = [(ln, re.search(r" ([a-z][\w\-]*)\(", ln.split(" = ", 1)[1]))
             for ln in body if " = " in ln]
     makers = [ln for ln, op in made
-              if bin_shape in ln.split(" = ", 1)[1][:op.start()]
+              if _NS_BIN_SHAPE in ln.split(" = ", 1)[1][:op.start()]
               and op.group(1) not in ("get-tuple-element", "parameter",
                                       "tuple")]
     assert len(makers) == 1, [ln[:160] for ln in makers]
     assert "stk_accum/scatter-add" in makers[0], makers[0][:400]
+
+
+def test_f64_group_body_gathers_whole_block_rows(ns_group_hlo):
+    """Inside the same chunk loop every `gather` reads an operand whose
+    minor-most dimension is not the block index (until PR 29 it was:
+    `f32[18901,23,23]{0,2,1}`, 529 x 30 000 single elements fetched
+    along lanes per gather, 1.3 s of a 3.9 s product); no NaN fill is
+    selected over what was gathered (the ids are promised in bounds);
+    and the dot is fed the strips it was fed."""
+    comps, body = ns_group_hlo
+    called = [comps[name] for ln in body
+              for name in re.findall(r"calls=%?([\w.\-]+)", ln)]
+    reached = [body] + called
+    gathers = 0
+    for lines in reached:
+        layout_of = {}
+        for ln in lines:
+            made = re.match(r"(?:ROOT )?%?([\w.\-]+) = \w+\[[\d,]*\]"
+                            r"\{([\d,]+)", ln)
+            if made:
+                layout_of[made.group(1)] = made.group(2).split(",")
+        for ln in lines:
+            op = re.search(r" gather\(%?([\w.\-]+),", ln)
+            if op and "f32[" in ln.split(" = ", 1)[1][:8]:
+                gathers += 1
+                assert layout_of[op.group(1)][0] != "0", ln[:300]
+    assert gathers >= 4  # A and B, both halves of the emulated f64
+    assert not any("constant(nan)" in ln for lines in reached
+                   for ln in lines)
+    text = "\n".join(body)
+    # the eight f32 pieces the emulation makes of each f64 strip
+    strips = (f"f32[8,{_NS_GROUPS},23,{_NS_R0 * 23}]{{3,2,1,0:",
+              f"f32[8,{_NS_GROUPS},{_NS_R0 * 23},23]{{1,2,3,0:")
+    assert all(strip in text for strip in strips), strips
 
 
 @pytest.mark.parametrize("body", ["xla", "xla_flat", "xla_group"])

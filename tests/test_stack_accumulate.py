@@ -5,6 +5,7 @@ the chunkings that exercise the scatter's edges: ids that must drop, a
 C block whose entries lie in two chunks, a chunk with nothing live.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -176,3 +177,82 @@ def test_fused_equals_span_by_span_bitwise(driver):
     for a_s, b_s, idx in spans:
         by_span = np.asarray(_run_span(driver, by_span, a_s, b_s, idx, 0.75))
     np.testing.assert_array_equal(np.asarray(fused), by_span)
+
+
+def _lane_gather_group_body(c, a, b, ga, gb, gc, alpha):
+    """The grouped body as it gathered until PR 29: `jnp.take` of whole
+    (m, k) blocks out of the 3-D bins, then the relayout to strips.
+    Kept as the reference the block-row gather is held to bit for
+    bit."""
+    r0 = ga.shape[2]
+
+    def step(c, idx):
+        ia, ib, ic = idx
+        ch = ia.shape[0]
+        ablk = jnp.take(a, ia.reshape(-1), axis=0)
+        bblk = jnp.take(b, ib.reshape(-1), axis=0)
+        amat = jnp.swapaxes(ablk.reshape((ch, r0) + a.shape[1:]), 1, 2)
+        prod = smm._batch_dot(amat.reshape(ch, a.shape[1], -1),
+                              bblk.reshape(ch, -1, b.shape[2]),
+                              c.dtype, None)
+        return smm._accumulate_chunk(c, alpha * prod, ic), None
+
+    return jax.lax.scan(step, c, (ga, gb, gc))[0]
+
+
+def _real_stack(m, n, k, seed=29, nseg=40, na=30, nb=31, entries=500):
+    rng = np.random.default_rng(seed)
+    a = rng.standard_normal((na, m, k))
+    b = rng.standard_normal((nb, k, n))
+    c = rng.standard_normal((nseg, m, n))
+    ci = np.sort(rng.integers(0, nseg, entries)).astype(np.int32)
+    ai = rng.integers(0, na, entries).astype(np.int32)
+    bi = rng.integers(0, nb, entries).astype(np.int32)
+    return c, a, b, ai, bi, ci
+
+
+@pytest.mark.parametrize("mnk", [(23, 23, 23), (23, 23, 18), (5, 13, 23),
+                                 (5, 5, 5)])
+def test_group_body_is_the_numpy_product_and_the_lane_gather_bitwise(mnk):
+    """Gathering A and B as whole block rows gives the NumPy product
+    into a non-zero C, and the bits the gather of 3-D blocks gave."""
+    c, a, b, ai, bi, ci = _real_stack(*mnk)
+    na, nb, nseg = len(a), len(b), len(c)
+    idx = tuple(map(jnp.asarray, smm.build_group_tiles(
+        ci, ai, bi, 8, na, nb, nseg, 16)))
+    a_dev = smm._append_pad_row(jnp.asarray(a))
+    b_dev = smm._append_pad_row(jnp.asarray(b))
+    got = smm._process_stack_xla_group(
+        jnp.array(c), a_dev, b_dev, *idx, jnp.asarray(1.5))
+    want = _numpy_loop(c, a, b, ai, bi, ci, 1.5)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-13, atol=1e-12)
+    old = _lane_gather_group_body(
+        jnp.array(c), a_dev, b_dev, *idx, jnp.asarray(1.5))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(old))
+
+
+@pytest.mark.parametrize("r0,chunk_groups,entries", [
+    (8, 16, 500), (4, 7, 333), (2, 64, 1), (16, 5, 90)])
+def test_group_tiles_name_only_rows_up_to_the_pad_row(r0, chunk_groups,
+                                                      entries):
+    """What the body's `promise_in_bounds` gathers rest on: every id
+    `build_group_tiles` emits lies in [0, pad row], live slots hold the
+    stack's own ids in stack order, and every other slot the pad row."""
+    _, a, b, ai, bi, ci = _real_stack(5, 4, 3, seed=entries,
+                                      entries=entries)
+    na, nb, nseg = len(a), len(b), 40
+    ga, gb, gc = smm.build_group_tiles(ci, ai, bi, r0, na, nb, nseg,
+                                       chunk_groups)
+    assert ga.shape == gb.shape == gc.shape + (r0,)
+    assert gc.shape[1] == chunk_groups
+    assert ga.dtype == gb.dtype == gc.dtype == np.int32
+    assert ga.min() >= 0 and ga.max() <= na
+    assert gb.min() >= 0 and gb.max() <= nb
+    assert gc.min() >= 0 and gc.max() <= nseg
+    assert (np.diff(gc.reshape(-1)) >= 0).all()
+    live = ga != na
+    assert (live == (gb != nb)).all()
+    np.testing.assert_array_equal(ga[live], ai)
+    np.testing.assert_array_equal(gb[live], bi)
+    np.testing.assert_array_equal(
+        np.repeat(gc.reshape(-1), live.sum(axis=2).reshape(-1)), ci)

@@ -3,6 +3,8 @@ dispatch accounting, plan-cache byte budgeting, decomposition-on-
 failure (chaos), synchronized timing, and the dispatch microbench.
 All tier-1, CPU-only."""
 
+import json
+
 import numpy as np
 import pytest
 
@@ -75,8 +77,6 @@ def _dispatches(snap):
     vals = snap["counters"].get("dbcsr_tpu_dispatches_total", {})
     out = {"fused": 0, "per_span": 0}
     for key, v in vals.items():
-        import json
-
         out[json.loads(key)["mode"]] = v
     return out
 
@@ -113,6 +113,21 @@ def test_fused_xla_family_matches_per_span(driver):
     assert np.array_equal(ref, got)
     assert _dispatches(snap)["fused"] >= 1
     assert "acc.smm._fused_superstack" in snap["jit"]
+
+
+def test_group_spans_are_counted_by_their_gather_layout():
+    """`dbcsr_tpu_stack_gather_total{layout}` moves once per launched
+    `xla_group` span, inside a fused launch as span by span, and reads
+    the layout the body gathers from."""
+    def counted(mode):
+        _, snap, _ = _run(mode, mm_driver="xla_group")
+        spans = snap["counters"].get("dbcsr_tpu_stack_gather_total", {})
+        return {json.loads(key)["layout"]: v for key, v in spans.items()}
+
+    by_span = counted("per_span")
+    assert set(by_span) == {smm.GROUP_GATHER_LAYOUT} == {"row"}
+    assert by_span["row"] >= 2  # several spans into one C bin
+    assert counted("fused") == by_span
 
 
 def test_fused_beta0_zero_bins_first_touch():

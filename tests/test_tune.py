@@ -20,6 +20,20 @@ from dbcsr_tpu.tune import miner, predictor, store, trials
 from dbcsr_tpu.tune import service as tune_service
 
 
+@pytest.fixture(autouse=True)
+def _healthy_process():
+    """The tune cycle defers on the process-wide health verdict, which
+    an earlier test file of the same worker can leave DEGRADED or
+    CRITICAL (open breakers, a fallback storm in the rolling windows):
+    start every test from a clean board."""
+    from dbcsr_tpu.obs import health
+    from dbcsr_tpu.resilience import breaker
+
+    breaker.reset_board()
+    metrics.reset()
+    health.reset()
+
+
 @pytest.fixture
 def params_dir(tmp_path, monkeypatch):
     """Hermetic parameter directory: the committed device tables are
