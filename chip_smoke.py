@@ -14,18 +14,24 @@ every chip of the host and starts no child that touches JAX:
   mesh4         the f64 product on the 2x2 grid `make_grid(4)` builds,
                 serial and double-buffered Cannon; skipped, loudly, with
                 fewer than four devices
+  mesh4_filtered  the f64_filtered product on the same grid: the sparse
+                mesh engine (per-product plan, sparse panels ring-shifted,
+                stacks, collect, norm filter), serial and double-buffered
 
 Each leg runs one first call (set-up: compile + staging) and two fenced
 repeats, requires bit-identical checksums across them, and is checked
 against plain NumPy on sampled block rows (f64, f32) or against the f64
-leg's checksum (f64_filtered, mesh4).  The engine's failover code is
+leg's checksum (f64_filtered, mesh4, mesh4_filtered).  The engine's failover code is
 safety code; the smoke FAILS when any of it fires.
 
 It sets no platform: without a TPU it exits non-zero before any work.
 The last stdout line of a passing run is one JSON object,
 {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}.
 
-    python chip_smoke.py [--seed N]
+    python chip_smoke.py [--seed N] [--legs f64,mesh4_filtered]
+
+`--legs` runs a subset (a four-chip call is charged four times: give it
+the mesh legs and the f64 leg they are checked against).
 """
 
 from __future__ import annotations
@@ -266,18 +272,21 @@ def leg_f32(**size):
     return _single_chip_leg("f32", "float32", **size)
 
 
-def leg_mesh4(*, n, block, occupancy, seed, reference):
+def leg_mesh4(*, n, block, occupancy, seed, reference, filter_eps=None):
     """The f64 product through `sparse_multiply_distributed` on the 2x2
     grid, once per Cannon tick schedule; both must match the single-chip
-    checksum and each other bit for bit."""
+    checksum and each other bit for bit.  With ``filter_eps`` (the
+    `mesh4_filtered` leg) the filter forbids the dense Cannon and the
+    sparse mesh engine runs."""
     import jax
 
     import dbcsr_tpu as dt
     from dbcsr_tpu.parallel import make_grid, sparse_multiply_distributed
 
     ndev = len(jax.devices())
+    leg = "mesh4" if filter_eps is None else "mesh4_filtered"
     if ndev < 4:
-        print(f"LEG mesh4 skipped: {ndev} device(s)", flush=True)
+        print(f"LEG {leg} skipped: {ndev} device(s)", flush=True)
         return None
     mesh = make_grid(4)
     a, b = make_operands("float64", n, block, occupancy, seed)
@@ -288,10 +297,11 @@ def leg_mesh4(*, n, block, occupancy, seed, reference):
             dt.set_config(cannon_overlap=mode)
 
             def run():
-                c = sparse_multiply_distributed(1.0, a, b, 0.0, None, mesh)
+                c = sparse_multiply_distributed(1.0, a, b, 0.0, None, mesh,
+                                                filter_eps=filter_eps)
                 return c, getattr(c, "_last_flops", 0)
 
-            res, _ = _timed_repeats(f"mesh4_{mode}", run)
+            res, _ = _timed_repeats(f"{leg}_{mode}", run)
             res["grid"] = dict(mesh.shape)
             _report("LEG", res)
             _report("CHECK", _check_against(res["leg"], res, reference))
@@ -300,15 +310,22 @@ def leg_mesh4(*, n, block, occupancy, seed, reference):
         dt.set_config(cannon_overlap=prev)
     if by_mode["serial"]["checksum"] != by_mode["double_buffer"]["checksum"]:
         raise SmokeFailure(
-            "mesh4: serial and double-buffered Cannon checksums differ: "
+            f"{leg}: serial and double-buffered Cannon checksums differ: "
             f"{by_mode['serial']['checksum']!r} vs "
             f"{by_mode['double_buffer']['checksum']!r}")
     return by_mode
 
 
-def run_legs(*, n, block, occupancy, seed, mesh=True) -> dict:
-    """All legs at one size, under the no-quiet-failover contract.  A
-    failed leg does not stop the later ones (a chip run should say
+def leg_mesh4_filtered(**kw):
+    return leg_mesh4(**kw, filter_eps=FILTER_EPS)
+
+
+LEGS = ("f64", "f64_filtered", "f32", "mesh4", "mesh4_filtered")
+
+
+def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
+    """The ``legs`` at one size, under the no-quiet-failover contract.
+    A failed leg does not stop the later ones (a chip run should say
     everything that is wrong); any failure raises at the end."""
     import traceback
 
@@ -325,6 +342,8 @@ def run_legs(*, n, block, occupancy, seed, mesh=True) -> dict:
     out, failures = {}, []
 
     def attempt(leg, fn, **kw):
+        if leg not in legs:
+            return
         if "reference" in kw and kw["reference"] is None:
             failures.append(f"{leg}: not run, the f64 leg gave no reference")
             return
@@ -348,6 +367,8 @@ def run_legs(*, n, block, occupancy, seed, mesh=True) -> dict:
             attempt("f32", leg_f32)
             if mesh:
                 attempt("mesh4", leg_mesh4, reference=out.get("f64"))
+                attempt("mesh4_filtered", leg_mesh4_filtered,
+                        reference=out.get("f64"))
     finally:
         dt.set_config(incremental=prev_inc)
     here = os.path.dirname(os.path.abspath(__file__))
@@ -407,7 +428,12 @@ def _environment(device: dict) -> dict:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=12341313)
+    ap.add_argument("--legs", default=",".join(LEGS),
+                    help="comma-separated subset of %(default)s")
     args = ap.parse_args(argv)
+    legs = tuple(args.legs.split(","))
+    if not set(legs) <= set(LEGS):
+        ap.error(f"--legs: unknown leg in {legs}; have {LEGS}")
 
     import jax
 
@@ -424,7 +450,7 @@ def main(argv=None) -> int:
     _report("DEVICE", device)
     _report("ENV", _environment(device))
     try:
-        run_legs(**NORTH_STAR, seed=args.seed)
+        run_legs(**NORTH_STAR, seed=args.seed, legs=legs)
     except SmokeFailure as exc:
         for line in str(exc).splitlines():
             print(f"FAILED {line}", flush=True)

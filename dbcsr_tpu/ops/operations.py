@@ -84,12 +84,16 @@ def compress(matrix: BlockSparseMatrix, keep: np.ndarray) -> BlockSparseMatrix:
     return matrix
 
 
-def filter_matrix(matrix: BlockSparseMatrix, eps: float) -> BlockSparseMatrix:
+def filter_matrix(matrix: BlockSparseMatrix, eps: float,
+                  norms: Optional[np.ndarray] = None) -> BlockSparseMatrix:
     """Drop blocks with Frobenius norm below eps (ref `dbcsr_filter`,
     `dbcsr_operations.F:1887`; criterion ||blk||² >= eps² as in
-    `multrec_filtering`, `dbcsr_mm_multrec.F:694-748`)."""
+    `multrec_filtering`, `dbcsr_mm_multrec.F:694-748`).  ``norms``:
+    the matrix's `block_norms()` where the caller already holds them
+    (the mesh engine times the wait for them apart from the filter)."""
     _require_valid(matrix)
-    norms = matrix.block_norms()
+    if norms is None:
+        norms = matrix.block_norms()
     return compress(matrix, norms.astype(np.float64) ** 2 >= float(eps) ** 2)
 
 

@@ -43,9 +43,10 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dbcsr_tpu.core.matrix import NO_SYMMETRY, BlockSparseMatrix
-from dbcsr_tpu.core.timings import timed
+from dbcsr_tpu.core.timings import device_scope, timed
 from dbcsr_tpu.obs import costmodel as _costmodel
 from dbcsr_tpu.obs import events as _events
+from dbcsr_tpu.obs import metrics as _metrics
 from dbcsr_tpu.ops.transformations import desymmetrize
 from dbcsr_tpu.parallel import overlap as _overlap
 from dbcsr_tpu.parallel.overlap import _HashableMesh
@@ -243,29 +244,39 @@ def _stack_contrib(a, b, c, entries, *, r0, cap_c, acc_dtype):
     """One stack chunk's contribution: gather → batched matmul →
     sorted segment-sum.  ONE implementation shared by the fused
     metronome body (`_cannon_tick_loop`) and the split per-tick
-    program (`_mesh_tick_program`) so the two execution modes are
-    bitwise identical by construction."""
+    program (`_stack_mesh_tick`) so the two execution modes are
+    bitwise identical by construction.
+
+    The three phases carry the one-chip bodies' `device_scope` names
+    (`acc/smm.py`), so a device trace splits a mesh program's time the
+    way it splits `jit_fused_superstack`'s.  Every jitted program that
+    traces this body is named `_stack_*` for the reason given there: a
+    program whose scopes change needs a new name, or a compile cache
+    written before them answers with the scopeless executable."""
     bm, bk, bn = a.shape[1], a.shape[2], b.shape[2]
-    if r0:
-        ia = entries[:, :r0]
-        ib = entries[:, r0:2 * r0]
-        ic = entries[:, 2 * r0]
-        pa = jnp.take(a, ia.reshape(-1), axis=0).reshape(-1, r0, bm, bk)
-        pa = jnp.swapaxes(pa, 1, 2).reshape(-1, bm, r0 * bk)
-        pb = jnp.take(b, ib.reshape(-1), axis=0).reshape(-1, r0 * bk, bn)
-    else:
-        pa = jnp.take(a, entries[:, 0], axis=0)
-        pb = jnp.take(b, entries[:, 1], axis=0)
-        ic = entries[:, 2]
-    prod = jax.lax.dot_general(
-        pa, pb, (((2,), (1,)), ((0,), (0,))),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=acc_dtype,
-    )
-    return c + jax.ops.segment_sum(
-        prod, ic, num_segments=cap_c,
-        indices_are_sorted=True,
-    )
+    with device_scope("stk_gather"):
+        if r0:
+            ia = entries[:, :r0]
+            ib = entries[:, r0:2 * r0]
+            ic = entries[:, 2 * r0]
+            pa = jnp.take(a, ia.reshape(-1), axis=0).reshape(-1, r0, bm, bk)
+            pa = jnp.swapaxes(pa, 1, 2).reshape(-1, bm, r0 * bk)
+            pb = jnp.take(b, ib.reshape(-1), axis=0).reshape(-1, r0 * bk, bn)
+        else:
+            pa = jnp.take(a, entries[:, 0], axis=0)
+            pb = jnp.take(b, entries[:, 1], axis=0)
+            ic = entries[:, 2]
+    with device_scope("stk_dot"):
+        prod = jax.lax.dot_general(
+            pa, pb, (((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=acc_dtype,
+        )
+    with device_scope("stk_accum"):
+        return c + jax.ops.segment_sum(
+            prod, ic, num_segments=cap_c,
+            indices_are_sorted=True,
+        )
 
 
 def _tick_contrib_chunked(a, b, c, st_tick, *, r0, cap_c, acc_dtype):
@@ -424,8 +435,8 @@ def _resolve_maps(a, b, matrix_c, pr: int, pc: int, kl: int):
     static_argnames=("s", "nticks", "gather", "cap_c", "acc_name",
                      "mesh_ref", "r0"),
 )
-def _run_sparse_mesh(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
-                     *, s, nticks, gather, cap_c, acc_name, mesh_ref, r0=0):
+def _stack_mesh_run(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
+                    *, s, nticks, gather, cap_c, acc_name, mesh_ref, r0=0):
     """The one mesh runner behind both sparse engines.
 
     ``gather=False``: square-grid skewed Cannon — s alignment ticks,
@@ -491,8 +502,8 @@ def _run_sparse_mesh(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
 @functools.partial(
     jax.jit, static_argnames=("cap_c", "acc_name", "mesh_ref", "r0"),
 )
-def _mesh_tick_program(a_panels, b_panels, stacks, c_acc, t, *,
-                       cap_c, acc_name, mesh_ref, r0=0):
+def _stack_mesh_tick(a_panels, b_panels, stacks, c_acc, t, *,
+                     cap_c, acc_name, mesh_ref, r0=0):
     """One Cannon tick's chunked contribution into the per-layer
     accumulator ``c_acc`` (global (kl, pr, pc, cap_c, bm, bn))."""
     mesh = mesh_ref.val
@@ -633,9 +644,9 @@ def _gather_shift_program(a_panels, b_panels, *, pr, pc, mesh_ref):
     static_argnames=("pr", "pc", "seg_a", "seg_b", "cap_c", "acc_name",
                      "mesh_ref", "r0"),
 )
-def _gather_tick_program(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
-                         pr, pc, seg_a, seg_b, cap_c, acc_name, mesh_ref,
-                         r0=0):
+def _stack_gather_tick(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
+                       pr, pc, seg_a, seg_b, cap_c, acc_name, mesh_ref,
+                       r0=0):
     """One gather-pipeline tick: append the shard pair at ring distance
     ``t`` into the concatenations (A at column (j+t)%pc * seg_a, B at
     row (i+t)%pr * seg_b — the tiled-all_gather layout), then contract
@@ -681,7 +692,7 @@ def _gather_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
                   alpha_dev, beta_fac, mode: str, measure: bool,
                   timings: list):
     """Host-driven chunked all-gather pipeline behind the rectangular-
-    grid route — bitwise identical to `_run_sparse_mesh` with
+    grid route — bitwise identical to `_stack_mesh_run` with
     ``gather=True``.  The carried state is (a_cat, b_cat, c_acc): the
     incrementally built operand concatenations plus the accumulator."""
     from dbcsr_tpu.acc.smm import record_dispatch
@@ -705,7 +716,7 @@ def _gather_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
         return _gather_shift_program(aa, bb, pr=pr, pc=pc, mesh_ref=mref)
 
     def tick(aa, bb, carry, t):
-        return _gather_tick_program(
+        return _stack_gather_tick(
             aa, bb, carry[0], carry[1], plan.stacks_dev, carry[2],
             jnp.asarray(t, jnp.int32), pr=pr, pc=pc, seg_a=seg_a,
             seg_b=seg_b, cap_c=plan.cap_c, acc_name=plan.acc_name,
@@ -732,7 +743,7 @@ def _mesh_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
                 timings: list):
     """Host-driven tick loop behind the double-buffered (and
     measured-serial) sparse mesh Cannon — bitwise identical to
-    `_run_sparse_mesh` with ``gather=False``.  Appends the measured
+    `_stack_mesh_run` with ``gather=False``.  Appends the measured
     (shift_exposed_s, compute_s) split to ``timings`` — published by
     the caller only when the pipeline delivered the result
     (overlap.run_split_pipeline)."""
@@ -750,7 +761,7 @@ def _mesh_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
         return _mesh_shift_program(aa, bb, s=s, mesh_ref=mref)
 
     def tick(aa, bb, cc, t):
-        return _mesh_tick_program(
+        return _stack_mesh_tick(
             aa, bb, plan.stacks_dev, cc, jnp.asarray(t, jnp.int32),
             cap_c=plan.cap_c, acc_name=plan.acc_name, mesh_ref=mref,
             r0=plan.r0,
@@ -906,9 +917,12 @@ def _make_bin_asm(m: BlockSparseMatrix, flat: np.ndarray, nflat: int,
         fp[: len(sel)] = flat[sel]
         ss = np.zeros(cap, np.int32)  # pads: any in-range gather slot
         ss[: len(sel)] = m.ent_slot[sel]
-        fps.append(jnp.asarray(fp))
-        sss.append(jnp.asarray(ss))
-    return _BinAsm(tuple(bin_ids), tuple(fps), tuple(sss), nflat, bm, bn)
+        fps.append(fp)
+        sss.append(ss)
+    with timed("mesh_plan_upload"):
+        fps = tuple(jnp.asarray(fp) for fp in fps)
+        sss = tuple(jnp.asarray(ss) for ss in sss)
+    return _BinAsm(tuple(bin_ids), fps, sss, nflat, bm, bn)
 
 
 def _run_bin_asm(asm: _BinAsm, m: BlockSparseMatrix, dtype) -> object:
@@ -979,6 +993,27 @@ class _MeshPlan:
 _mesh_plan_cache: "_OrderedDict[tuple, _MeshPlan]" = _OrderedDict()
 _MESH_PLAN_MAX = 8
 _MESH_PLAN_MAX_BYTES = 512 * 1024 * 1024
+
+
+def _mesh_plan_lookup(plan_key):
+    """The cached plan under ``plan_key`` or None, counted by outcome:
+    ``hit`` or ``miss`` of `_mesh_plan_cache`, or ``uncacheable`` where
+    the key is None: a filtered product, whose candidates follow the
+    operands' values (the norm skip), so its plan is rebuilt every
+    time."""
+    plan = None
+    if plan_key is not None:
+        plan = _mesh_plan_cache.get(plan_key)
+        if plan is not None:
+            _mesh_plan_cache.move_to_end(plan_key)
+    _metrics.counter(
+        "dbcsr_tpu_mesh_plan_total",
+        "mesh plan lookups of the distributed sparse engines, by "
+        "outcome: hit / miss of the pattern-keyed plan cache, or "
+        "uncacheable (a filtered product: rebuilt every product)",
+    ).inc(cache="uncacheable" if plan_key is None
+          else "miss" if plan is None else "hit")
+    return plan
 
 
 def clear_mesh_plans() -> None:
@@ -1082,9 +1117,10 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
     shell_c = matrix_c if matrix_c is not None else BlockSparseMatrix(
         f"{a.name}*{b.name}", a.row_blk_sizes, b.col_blk_sizes, dtype
     )
-    rows_t, cols_t, a_ent, b_ent = _candidates(
-        a, b, shell_c, filter_eps, *limits
-    )
+    with timed("mesh_candidates"):
+        rows_t, cols_t, a_ent, b_ent = _candidates(
+            a, b, shell_c, filter_eps, *limits
+        )
     old_keys = matrix_c.keys if matrix_c is not None else np.empty(0, np.int64)
     if retain_sparsity:
         from dbcsr_tpu.mm.multiply import mask_in_sorted
@@ -1175,12 +1211,15 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
         st_a = ka_col[k_t] * (cap_a + xtr) + a_slots[a_ent]
         st_b = kb_row[k_t] * (cap_b + xtr) + b_slots[b_ent]
     group = (((layer * pr + i_dev) * pc + j_dev) * nticks) + tick_t
-    stacks = _fill_stacks(
-        group, st_a, st_b, c_slots[ent_c],
-        kl * pr * pc * nticks, cap_c, r0=r0, pad_a=cap_a, pad_b=cap_b,
-    )
+    with timed("mesh_stack_fill"):
+        stacks = _fill_stacks(
+            group, st_a, st_b, c_slots[ent_c],
+            kl * pr * pc * nticks, cap_c, r0=r0, pad_a=cap_a, pad_b=cap_b,
+        )
     stacks = stacks.reshape(kl, pr, pc, nticks, -1, stacks.shape[-1])
-    stacks_dev = jax.device_put(stacks, NamedSharding(mesh, P("kl", "pr", "pc")))
+    with timed("mesh_plan_upload"):
+        stacks_dev = jax.device_put(
+            stacks, NamedSharding(mesh, P("kl", "pr", "pc")))
 
     # ---- device-side panel assembly maps ----
     al, ai_, akc = a_panel // (pr * pc), (a_panel // pc) % pr, a_panel % pc
@@ -1264,10 +1303,13 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
         fp[: len(sel)] = c_flat_pos[sel]
         sl = np.full(cap, cap, np.int32)
         sl[: len(sel)] = nsl[sel]
-        collect_pos.append(jnp.asarray(fp))
-        collect_slots.append(jnp.asarray(sl))
+        collect_pos.append(fp)
+        collect_slots.append(sl)
         collect_caps.append(cap)
         collect_counts.append(len(sel))
+    with timed("mesh_plan_upload"):
+        collect_pos = [jnp.asarray(fp) for fp in collect_pos]
+        collect_slots = [jnp.asarray(sl) for sl in collect_slots]
 
     from dbcsr_tpu.core.dist import Distribution, ProcessGrid
 
@@ -1366,7 +1408,6 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     # ---- plan lookup (pattern-keyed; filtered products depend on
     # VALUES via the norm skip, so they rebuild every time — the
     # single-chip `_plan_cache` convention) ----
-    plan = None
     plan_key = None
     if filter_eps is None:
         plan_key = (
@@ -1377,9 +1418,7 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
             np.dtype(dtype).name, retain_sparsity, limits, beta_window,
             _HashableMesh(mesh), r0,
         )
-        plan = _mesh_plan_cache.get(plan_key)
-        if plan is not None:
-            _mesh_plan_cache.move_to_end(plan_key)
+    plan = _mesh_plan_lookup(plan_key)
     if plan is None:
         with timed("mesh_plan_build"):
             plan = _build_mesh_plan(
@@ -1396,30 +1435,32 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
 
     # ---- device-side panel assembly (cached by bin data identity) ----
     spec3 = P("kl", "pr", "pc")
-    a_panels = _cached_panels(
-        plan, "a", a, mesh, (kl, pr, pc, cap_a + xtr, bm, bk), spec3
-    )
-    b_panels = _cached_panels(
-        plan, "b", b, mesh, (kl, pr, pc, cap_b + xtr, bk, bn), spec3
-    )
-
-    keep_old = beta != 0 or (plan.has_window and not plan.inside_all)
-    if plan.cinit_asm is not None and keep_old:
-        c_flat = _run_bin_asm(plan.cinit_asm, matrix_c, dtype)
-    else:
-        c_flat = jnp.zeros((pr * pc * cap_c, bm, bn), dtype)
-    c_init = jax.device_put(
-        c_flat.reshape(pr, pc, cap_c, bm, bn), NamedSharding(mesh, P("pr", "pc"))
-    )
-
-    if plan.inside_dev is not None:
-        beta_fac = jnp.where(
-            plan.inside_dev,
-            jnp.asarray(beta, dtype), jnp.asarray(1, dtype),
+    with timed("mesh_panels"):
+        a_panels = _cached_panels(
+            plan, "a", a, mesh, (kl, pr, pc, cap_a + xtr, bm, bk), spec3
         )
-    else:
-        beta_fac = jnp.full((pr, pc, cap_c), beta, dtype)
-    beta_fac = jax.device_put(beta_fac, NamedSharding(mesh, P("pr", "pc")))
+        b_panels = _cached_panels(
+            plan, "b", b, mesh, (kl, pr, pc, cap_b + xtr, bk, bn), spec3
+        )
+
+    with timed("mesh_c_init"):
+        keep_old = beta != 0 or (plan.has_window and not plan.inside_all)
+        if plan.cinit_asm is not None and keep_old:
+            c_flat = _run_bin_asm(plan.cinit_asm, matrix_c, dtype)
+        else:
+            c_flat = jnp.zeros((pr * pc * cap_c, bm, bn), dtype)
+        c_init = jax.device_put(
+            c_flat.reshape(pr, pc, cap_c, bm, bn),
+            NamedSharding(mesh, P("pr", "pc")),
+        )
+        if plan.inside_dev is not None:
+            beta_fac = jnp.where(
+                plan.inside_dev,
+                jnp.asarray(beta, dtype), jnp.asarray(1, dtype),
+            )
+        else:
+            beta_fac = jnp.full((pr, pc, cap_c), beta, dtype)
+        beta_fac = jax.device_put(beta_fac, NamedSharding(mesh, P("pr", "pc")))
 
     # ---- run on the mesh ----
     grid = f"{kl}x{pr}x{pc}"
@@ -1450,7 +1491,7 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     mref = _HashableMesh(mesh)
 
     def serial_fn():
-        out = _run_sparse_mesh(
+        out = _stack_mesh_run(
             a_panels, b_panels, plan.stacks_dev, c_init,
             alpha_dev, beta_fac,
             s=pr, nticks=plan.nticks, gather=not cannon, cap_c=cap_c,
@@ -1460,22 +1501,23 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
         return out
 
     measure = pipe_s > 1 and _overlap.measuring()
-    if _overlap.use_split_pipeline(mode, why, measure):
-        # double-buffered ticks / chunked gather, or the measured
-        # serial reference (same per-tick op sequence, one dispatch per
-        # region — the DBCSR_TPU_SYNC_TIMING seam); both guarded: an
-        # open pipeline breaker or a split-pipeline failure falls back
-        # to serial_fn
-        ticks_fn = _mesh_ticks if cannon else _gather_ticks
-        c_out = _overlap.run_split_pipeline(
-            "mesh", grid, mode,
-            lambda timings: ticks_fn(
-                plan, mesh, a_panels, b_panels, c_init, alpha_dev,
-                beta_fac, mode, measure, timings),
-            serial_fn, measure, driver=pipe_driver,
-        )
-    else:
-        c_out = serial_fn()
+    with timed("mesh_ticks"):
+        if _overlap.use_split_pipeline(mode, why, measure):
+            # double-buffered ticks / chunked gather, or the measured
+            # serial reference (same per-tick op sequence, one dispatch
+            # per region — the DBCSR_TPU_SYNC_TIMING seam); both
+            # guarded: an open pipeline breaker or a split-pipeline
+            # failure falls back to serial_fn
+            ticks_fn = _mesh_ticks if cannon else _gather_ticks
+            c_out = _overlap.run_split_pipeline(
+                "mesh", grid, mode,
+                lambda timings: ticks_fn(
+                    plan, mesh, a_panels, b_panels, c_init, alpha_dev,
+                    beta_fac, mode, measure, timings),
+                serial_fn, measure, driver=pipe_driver,
+            )
+        else:
+            c_out = serial_fn()
 
     # ---- device-side collect into shape bins (C stays resident) ----
     out = BlockSparseMatrix(
@@ -1483,28 +1525,36 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
         a.row_blk_sizes, b.col_blk_sizes, dtype,
         dist=plan.out_dist,
     )
-    if len(plan.c_keys):
-        bin_datas = _collect_bins(
-            c_out.reshape(pr * pc * cap_c, bm, bn),
-            plan.collect_pos, plan.collect_slots,
-            caps=plan.collect_caps, shapes=plan.collect_shapes,
-        )
-        bins = [
-            _mk_bin(shape, data, count)
-            for shape, data, count in zip(
-                plan.collect_shapes, bin_datas, plan.collect_counts
+    with timed("mesh_collect"):
+        if len(plan.c_keys):
+            bin_datas = _collect_bins(
+                c_out.reshape(pr * pc * cap_c, bm, bn),
+                plan.collect_pos, plan.collect_slots,
+                caps=plan.collect_caps, shapes=plan.collect_shapes,
             )
-        ]
-    else:
-        bins = []
-    out.set_structure_from_device(plan.c_keys, bins, binning=plan.c_binning)
+            bins = [
+                _mk_bin(shape, data, count)
+                for shape, data, count in zip(
+                    plan.collect_shapes, bin_datas, plan.collect_counts
+                )
+            ]
+        else:
+            bins = []
+        out.set_structure_from_device(plan.c_keys, bins,
+                                      binning=plan.c_binning)
     if filter_eps is not None and not retain_sparsity:
         # final ||C|| >= eps pass (ref multrec_filtering,
         # dbcsr_mm_multrec.F:694-748) — shared criterion with the
         # single-chip engine so filtered patterns agree exactly
         from dbcsr_tpu.ops.operations import filter_matrix
 
-        filter_matrix(out, filter_eps)
+        with timed("mesh_filter"):
+            # the norms need C: on an async device this call is the
+            # wait for the ticks and the collect, and gets a span of
+            # its own so that mesh_filter's self time is the host's work
+            with timed("mesh_filter_norms"):
+                norms = out.block_norms()
+            filter_matrix(out, filter_eps, norms=norms)
 
     stats.record_stack(
         bm, bn, bk, plan.n_cand, driver="mesh",
@@ -1624,8 +1674,8 @@ def _dense_multiply_mesh(alpha, a, b, beta, matrix_c, mesh, name, dtype,
 @functools.partial(
     jax.jit, static_argnames=("s", "cap_c", "acc_name", "mesh_ref", "r0"),
 )
-def _run_grouped_cannon(a_panels, b_panels, stacks, c_init, alpha, beta,
-                        *, s, cap_c, acc_name, mesh_ref, r0=0):
+def _stack_grouped_run(a_panels, b_panels, stacks, c_init, alpha, beta,
+                       *, s, cap_c, acc_name, mesh_ref, r0=0):
     """nsplit independent Cannon multiplies, one per 'kl' group, in a
     single SPMD program.  The short matrix (B) arrives replicated over
     'kl' (spec without the axis) — the `dbcsr_tas_replicate` analog —
@@ -1663,7 +1713,7 @@ def _run_grouped_cannon(a_panels, b_panels, stacks, c_init, alpha, beta,
 
 # --------------------------------------------------------------------------
 # Grouped-TAS split per-tick programs: the per-group Cannons advance in
-# lockstep inside one fused program (`_run_grouped_cannon`); staggering
+# lockstep inside one fused program (`_stack_grouped_run`); staggering
 # them through the double-buffer metronome dispatches the group
 # ensemble's tick-(t+1) ring shift before tick t's contraction is
 # consumed, so every group's shift overlaps every group's compute.  Op
@@ -1701,8 +1751,8 @@ def _grouped_shift_program(a_panels, b_panels, *, s, mesh_ref):
 @functools.partial(
     jax.jit, static_argnames=("cap_c", "acc_name", "mesh_ref", "r0"),
 )
-def _grouped_tick_program(a_panels, b_panels, stacks, c_acc, t, *,
-                          cap_c, acc_name, mesh_ref, r0=0):
+def _stack_grouped_tick(a_panels, b_panels, stacks, c_acc, t, *,
+                        cap_c, acc_name, mesh_ref, r0=0):
     """One grouped tick's chunked contribution into the per-group
     accumulator (global (kl, s, s, q*cap_c, bm, bn); ``cap_c`` here is
     the chunk-expanded q*cap_c capacity)."""
@@ -1766,7 +1816,7 @@ def _tas_ticks(plan: "_GroupedPlan", mesh, a_panels, b_panels, c_init,
                alpha_dev, beta_dev, mode: str, measure: bool,
                timings: list):
     """Host-driven staggered grouped-TAS metronome — bitwise identical
-    to `_run_grouped_cannon` (shared per-tick op code, same tail)."""
+    to `_stack_grouped_run` (shared per-tick op code, same tail)."""
     from dbcsr_tpu.acc.smm import record_dispatch
 
     mref = _HashableMesh(mesh)
@@ -1781,7 +1831,7 @@ def _tas_ticks(plan: "_GroupedPlan", mesh, a_panels, b_panels, c_init,
         return _grouped_shift_program(aa, bb, s=s, mesh_ref=mref)
 
     def tick(aa, bb, cc, t):
-        return _grouped_tick_program(
+        return _stack_grouped_tick(
             aa, bb, plan.stacks_dev, cc, jnp.asarray(t, jnp.int32),
             cap_c=q * plan.cap_c, acc_name=plan.acc_name, mesh_ref=mref,
             r0=plan.r0,
@@ -2020,7 +2070,6 @@ def _tas_grouped_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     r0 = _stack_r0(dtype)
     from dbcsr_tpu.core import stats
 
-    plan = None
     plan_key = None
     if filter_eps is None:
         plan_key = (
@@ -2028,9 +2077,7 @@ def _tas_grouped_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
             matrix_c.pattern_fingerprint() if matrix_c is not None else None,
             np.dtype(dtype).name, nsplit, _HashableMesh(mesh), r0,
         )
-        plan = _mesh_plan_cache.get(plan_key)
-        if plan is not None:
-            _mesh_plan_cache.move_to_end(plan_key)
+    plan = _mesh_plan_lookup(plan_key)
     if plan is None:
         with timed("mesh_plan_build"):
             plan = _build_grouped_plan(
@@ -2077,7 +2124,7 @@ def _tas_grouped_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     mref = _HashableMesh(mesh)
 
     def serial_fn():
-        out = _run_grouped_cannon(
+        out = _stack_grouped_run(
             a_panels, b_panels, plan.stacks_dev, c_init,
             alpha_dev, beta_dev,
             s=s, cap_c=q * cap_c, acc_name=plan.acc_name,

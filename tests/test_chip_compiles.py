@@ -1,7 +1,8 @@
 """Compile-only guards: the Pallas stack kernels at the real shapes of
-the `mixed10k` deployment and the emulated-f64 XLA stack body at the
-north star's, lowered and compiled for a DESCRIBED TPU v5e (no chip
-attached, nothing runs).  Interpret mode cannot see what this sees: the
+the `mixed10k` deployment, the emulated-f64 XLA stack body at the
+north star's and the sparse mesh engine's programs at
+`northstar_2x2_filtered`'s panels, lowered and compiled for a DESCRIBED
+TPU v5e (no chip attached, nothing runs).  Interpret mode cannot see what this sees: the
 chip's 1 MiB of scalar memory, which a crosspack launch's prefetched
 index operands overflowed at (5,5,23) until PR 26; the whole-bin passes
 the compiler put into every chunk of a filtered f64 product until
@@ -23,12 +24,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The described four-chip host; every chip compile of the file
+    hangs on it."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -40,9 +42,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -329,3 +338,139 @@ def test_stack_body_scatter_adds_into_its_carry(body):
     assert [e.primitive.name for e in makers] == ["scatter-add"], makers
     assert makers[0].invars[0] is carry
     assert makers[0].params["indices_are_sorted"]
+
+
+# ---------------------------------------------------------------------------
+# The sparse mesh engine on the 2x2 grid (`northstar_2x2.scf_f64`): the
+# programs a filtered f64 product runs, at the panels the deployment's
+# pattern gives.
+# ---------------------------------------------------------------------------
+
+_MESH_R0 = 8
+
+
+@pytest.fixture(scope="module")
+def northstar_2x2_caps():
+    """(cap_a, cap_b, cap_c, s_cap) of the deployment on the 2x2 grid,
+    from its pattern as `_build_mesh_plan` sizes them: blocks go to
+    devices cyclically, tick = the k parity that meets on a device, a
+    C block's candidates of one tick fill ceil(count / r0) group rows."""
+    import sys
+
+    sys.path.insert(0, REPO)
+    from benchmark import arithmetic
+    from dbcsr_tpu.utils.rounding import bucket_size
+
+    with open(os.path.join(
+            REPO, "benchmark/configs/northstar_2x2_filtered.json")) as fh:
+        cfg = json.load(fh)
+    assert cfg["grid"] == [2, 2]
+    nblk = len(arithmetic.expand_block_sizes(cfg["m"], cfg["blocks"]["m"]))
+    rng = np.random.default_rng(cfg["pattern_seed"])
+    pa = rng.random((nblk, nblk)) < cfg["occupancy"]["a"]
+    pb = rng.random((nblk, nblk)) < cfg["occupancy"]["b"]
+    assert (pa.sum(), pb.sum()) == (19115, 18989)  # the cell's operands
+    par = np.arange(nblk) % 2
+    panels = [(p, q) for p in (0, 1) for q in (0, 1)]
+    cap_a = bucket_size(max(int(pa[par == p][:, par == q].sum())
+                            for p, q in panels))
+    cap_b = bucket_size(max(int(pb[par == p][:, par == q].sum())
+                            for p, q in panels))
+    by_k = [pa[:, par == t].astype(np.int32) @ pb[par == t].astype(np.int32)
+            for t in (0, 1)]
+    reached = (by_k[0] + by_k[1]) > 0
+    cap_c = bucket_size(max(int(reached[par == p][:, par == q].sum())
+                            for p, q in panels))
+    rows = max(int((-(-cnt[par == p][:, par == q] // _MESH_R0)).sum())
+               for cnt in by_k for p, q in panels)
+    return cap_a, cap_b, cap_c, bucket_size(rows)
+
+
+@pytest.fixture(scope="module")
+def mesh_shapes(v5e_2x2, northstar_2x2_caps):
+    """The (1, 2, 2) mesh of the described chips and the engine's
+    arguments on it, sharded as `_sparse_multiply_impl` places them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dbcsr_tpu.parallel.overlap import _HashableMesh
+
+    cap_a, cap_b, cap_c, s_cap = northstar_2x2_caps
+    # what the builder's chip run of PR 30 ran at
+    assert (cap_a, cap_b, cap_c, s_cap) == (5120, 5120, 49152, 49152)
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(1, 2, 2),
+                ("kl", "pr", "pc"))
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    grid3 = ("kl", "pr", "pc")
+    return {
+        "mref": _HashableMesh(mesh), "cap_c": cap_c,
+        "a": arg((1, 2, 2, cap_a + 1, 23, 23), jnp.float64, *grid3),
+        "b": arg((1, 2, 2, cap_b + 1, 23, 23), jnp.float64, *grid3),
+        "stacks": arg((1, 2, 2, 2, s_cap, 2 * _MESH_R0 + 1), jnp.int32,
+                      *grid3),
+        "c_acc": arg((1, 2, 2, cap_c, 23, 23), jnp.float64, *grid3),
+        "c_init": arg((2, 2, cap_c, 23, 23), jnp.float64, "pr", "pc"),
+        "beta_fac": arg((2, 2, cap_c), jnp.float64, "pr", "pc"),
+        "alpha": arg((), jnp.float64), "tick": arg((), jnp.int32),
+    }
+
+
+@pytest.mark.parametrize("program", ["tick", "run"])
+def test_mesh_stack_program_of_northstar_2x2_compiles(mesh_shapes, program):
+    """The split per-tick program and the fused serial one at the
+    deployment's panels, emulated f64, r0 = 8: they compile, under the
+    names the benchmark's `jit__stack_*` globs read, with the three
+    phase scopes in their ops, and their temporaries (3.13 and 4.08 GiB
+    a device here in PR 30: the gathered strips and the whole-panel
+    arrays `segment_sum` makes per chunk) stay under a third of a
+    chip's 16 GB."""
+    import jax
+
+    from dbcsr_tpu.parallel import sparse_dist as sd
+
+    sh = mesh_shapes
+    kw = dict(cap_c=sh["cap_c"], acc_name="float64", mesh_ref=sh["mref"],
+              r0=_MESH_R0)
+    with jax.enable_x64(True):
+        if program == "tick":
+            lowered = sd._stack_mesh_tick.lower(
+                sh["a"], sh["b"], sh["stacks"], sh["c_acc"], sh["tick"], **kw)
+        else:
+            lowered = sd._stack_mesh_run.lower(
+                sh["a"], sh["b"], sh["stacks"], sh["c_init"], sh["alpha"],
+                sh["beta_fac"], s=2, nticks=2, gather=False, **kw)
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    name = {"tick": "_stack_mesh_tick", "run": "_stack_mesh_run"}[program]
+    assert f"HloModule jit_{name}" in text
+    for scope in ("stk_gather", "stk_dot", "stk_accum"):
+        assert f"/{scope}/" in text, scope
+    # the ring shift rides in the fused program and not in a split tick
+    assert ("collective-permute" in text) == (program == "run")
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2 ** 30
+
+
+def test_mesh_shift_and_finish_of_northstar_2x2_compile(mesh_shapes):
+    """The double-buffered pipeline's other two programs: the ring
+    shift is the collective-permute of both sparse panels and nothing
+    else; the finish holds no collective on a one-layer grid."""
+    import jax
+
+    from dbcsr_tpu.parallel import sparse_dist as sd
+
+    sh = mesh_shapes
+    with jax.enable_x64(True):
+        shift = sd._mesh_shift_program.lower(
+            sh["a"], sh["b"], s=2, mesh_ref=sh["mref"]).compile()
+        finish = sd._mesh_finish_program.lower(
+            sh["c_acc"], sh["c_init"], sh["alpha"], sh["beta_fac"],
+            acc_name="float64", mesh_ref=sh["mref"]).compile()
+    assert "collective-permute" in shift.as_text()
+    assert shift.memory_analysis().temp_size_in_bytes < 2 ** 28
+    assert "collective-permute" not in finish.as_text()
+    assert "all-reduce" not in finish.as_text()

@@ -28,10 +28,37 @@ def test_single_device_legs_pass_tiny(capsys):
     assert sum(line.startswith("CHECK ") for line in lines) == 3
 
 
-def test_mesh_leg_is_never_silently_absent(capsys, monkeypatch):
+@pytest.mark.parametrize("leg", ["mesh4", "mesh4_filtered"])
+def test_mesh_leg_is_never_silently_absent(capsys, monkeypatch, leg):
     monkeypatch.setattr(jax, "devices", lambda *a: [object()] * 2)
-    assert chip_smoke.leg_mesh4(**_TINY, reference={}) is None
-    assert "mesh4 skipped: 2 device(s)" in capsys.readouterr().out
+    fn = getattr(chip_smoke, f"leg_{leg}")
+    assert fn(**_TINY, reference={}) is None
+    assert f"{leg} skipped: 2 device(s)" in capsys.readouterr().out
+
+
+def test_filtered_mesh_leg_runs_the_sparse_engine_in_both_modes(capsys):
+    """`mesh4_filtered` on four of conftest's virtual devices, chosen
+    with `legs=` beside the f64 leg it is checked against."""
+    out = chip_smoke.run_legs(
+        **_TINY, legs=("f64", "mesh4_filtered"))
+    assert set(out) == {"f64", "mesh4_filtered"}
+    by_mode = out["mesh4_filtered"]
+    assert set(by_mode) == {"serial", "double_buffer"}
+    for mode, res in by_mode.items():
+        assert res["leg"] == f"mesh4_filtered_{mode}"
+        assert res["algorithm"] == "stack" and res["grid"]["pr"] == 2
+        assert res["checksum"] == pytest.approx(
+            out["f64"]["checksum"], rel=1e-9)
+    assert by_mode["serial"]["checksum"] == \
+        by_mode["double_buffer"]["checksum"]
+    lines = capsys.readouterr().out.splitlines()
+    assert sum(line.startswith("CHECK ") for line in lines) == 3
+
+
+def test_main_rejects_an_unknown_leg(capsys):
+    with pytest.raises(SystemExit):
+        chip_smoke.main(["--legs", "f64,mesh5"])
+    assert "unknown leg" in capsys.readouterr().err
 
 
 def test_main_without_a_chip_exits_nonzero_naming_the_platform(capsys):

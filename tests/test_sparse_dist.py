@@ -878,3 +878,229 @@ def test_tick_chunks_bound_temp_memory():
                 assert nchunk == 1
     assert _tick_chunks(bucket_size(823000), 0)[1] <= 32768
     assert _tick_chunks(bucket_size(823000), 8)[1] <= 4096
+
+
+# ---------------------------------------------------------------------------
+# The filtered f64 product on the 2x2 grid (the `northstar_2x2.scf_f64`
+# deployment in miniature) against a dense NumPy f64 product with the
+# filter applied by its definition: a C block is kept iff its Frobenius
+# norm is at least eps.
+# ---------------------------------------------------------------------------
+
+_FILTER_EPS = 1e-7
+# 23-blocks and a ragged 18, as 434 x 23 + 18: the north star's values,
+# every block norm near its side length, nothing for the filter to drop
+_NORTHSTAR_LIKE = dict(sizes=[23] * 5 + [18], occ=0.5, decay=0.0, seed=71)
+# blocks of 5 and a ragged 3 that decay by 10**-3.4 a block off the
+# diagonal: the on-the-fly skip leaves candidates out and whole blocks
+# of C with them
+_DECAYING = dict(sizes=[5] * 11 + [3], occ=0.6, decay=3.4, seed=73)
+# north-star values with one block of C that cancels to 1e-9: both its
+# candidates pass the skip, so only the final pass can drop it
+_CANCELLING = dict(sizes=[5] * 7 + [3], occ=0.5, decay=0.0, seed=75,
+                   cancel=dict(i=1, j=2, k=(3, 5), delta=1e-10))
+_FILTERED_CASES = {"northstar_like": _NORTHSTAR_LIKE, "decaying": _DECAYING,
+                   "cancelling": _CANCELLING}
+
+
+def _draw(sizes, occ, decay, rng):
+    """Dense standard-normal blocks scaled by 10**(-decay * |i - j|),
+    and which blocks are there (the diagonal always)."""
+    n = len(sizes)
+    off = np.concatenate([[0], np.cumsum(sizes)])
+    dense = np.zeros((off[-1], off[-1]))
+    present = np.zeros((n, n), bool)
+    for i in range(n):
+        for j in range(n):
+            if i == j or rng.random() < occ:
+                present[i, j] = True
+                dense[off[i]:off[i + 1], off[j]:off[j + 1]] = (
+                    rng.standard_normal((sizes[i], sizes[j]))
+                    * 10.0 ** (-decay * abs(i - j)))
+    return dense, present, off
+
+
+def _stage(name, dense, present, sizes, off):
+    from dbcsr_tpu import create
+
+    m = create(name, sizes, sizes, "float64")
+    for i, j in zip(*np.nonzero(present)):
+        m.put_block(i, j, dense[off[i]:off[i + 1], off[j]:off[j + 1]])
+    return m.finalize()
+
+
+def _block_norms(dense, off):
+    n = len(off) - 1
+    return np.array([[np.linalg.norm(dense[off[i]:off[i + 1],
+                                           off[j]:off[j + 1]])
+                      for j in range(n)] for i in range(n)])
+
+
+@pytest.fixture(scope="module", params=sorted(_FILTERED_CASES))
+def filtered_case(request):
+    case = _FILTERED_CASES[request.param]
+    sizes = case["sizes"]
+    rng = np.random.default_rng(case["seed"])
+    a_dense, a_there, off = _draw(sizes, case["occ"], case["decay"], rng)
+    b_dense, b_there, _ = _draw(sizes, case["occ"], case["decay"], rng)
+    if "cancel" in case:
+        # C[i, j] = Y X - Y X (1 - delta): column j of B holds X and
+        # -X (1 - delta) and nothing else, row i of A holds Y twice
+        i, j, (k1, k2), delta = (case["cancel"][key]
+                                 for key in ("i", "j", "k", "delta"))
+
+        def blk(r, c):
+            return slice(off[r], off[r + 1]), slice(off[c], off[c + 1])
+
+        b_there[:, j] = False
+        b_dense[:, off[j]:off[j + 1]] = 0.0
+        b_there[[k1, k2], j] = a_there[i, [k1, k2]] = True
+        b_dense[blk(k1, j)] = rng.standard_normal((sizes[k1], sizes[j]))
+        b_dense[blk(k2, j)] = -(1.0 - delta) * b_dense[blk(k1, j)]
+        a_dense[blk(i, k1)] = rng.standard_normal((sizes[i], sizes[k1]))
+        a_dense[blk(i, k2)] = a_dense[blk(i, k1)]
+    a = _stage("A", a_dense, a_there, sizes, off)
+    b = _stage("B", b_dense, b_there, sizes, off)
+    return request.param, a, b, a_dense, b_dense, off
+
+
+def _filtered_on_mesh(a, b, mesh, overlap="auto"):
+    """The product as the deployment runs it on a TPU: r0 = 8 grouped
+    stacks (forced here, where f64 is native), `filter_eps` 1e-7."""
+    from dbcsr_tpu import get_config, set_config
+
+    cfg = get_config()
+    prev = dict(mm_driver=cfg.mm_driver, cannon_overlap=cfg.cannon_overlap)
+    set_config(mm_driver="xla_group", cannon_overlap=overlap)
+    try:
+        return sparse_multiply_distributed(1.0, a, b, 0.0, None, mesh,
+                                           filter_eps=_FILTER_EPS)
+    finally:
+        set_config(**prev)
+
+
+def _plan_lookups():
+    """`dbcsr_tpu_mesh_plan_total` by its ``cache`` label."""
+    from dbcsr_tpu.obs import metrics
+
+    got = {"hit": 0, "miss": 0, "uncacheable": 0}
+    got.update((labels["cache"], v) for labels, v in
+               metrics.counter_items("dbcsr_tpu_mesh_plan_total"))
+    return got
+
+
+def test_filtered_product_on_2x2_matches_numpy_with_the_filter_by_definition(
+        mesh4, filtered_case):
+    name, a, b, a_dense, b_dense, off = filtered_case
+    n = len(off) - 1
+    want = a_dense @ b_dense
+    norms = _block_norms(want, off)
+    kept = norms >= _FILTER_EPS
+    # what the on-the-fly skip may leave out of block (i, j): a
+    # candidate (i, k, j) is skipped iff |A_ik| |B_kj| < eps / n_i, n_i
+    # the blocks of A's row i (`mm.multiply._candidates`), so the
+    # skipped products of one C block sum to less than eps in norm, and
+    # to exactly `skipped` here
+    na, nb = _block_norms(a_dense, off), _block_norms(b_dense, off)
+    pair = na[:, :, None] * nb[None, :, :]
+    row_eps = _FILTER_EPS / np.maximum(1, (na > 0).sum(axis=1))
+    skipped = np.where((pair > 0) & (pair < row_eps[:, None, None]),
+                       pair, 0.0).sum(axis=1)
+    assert skipped.max() < _FILTER_EPS
+    # the data leaves a gap around eps, so that neither rounding nor
+    # the skipped products can move a block across it
+    gap = (norms < _FILTER_EPS / 10) | (norms > _FILTER_EPS * 10)
+    assert gap.all() and skipped.max() < _FILTER_EPS / 10
+    reached = (norms > 0).sum()
+    if name == "northstar_like":
+        # every block the product reaches is kept, no candidate skipped
+        assert kept.sum() == reached > n and skipped.max() == 0.0
+    elif name == "decaying":
+        assert 0 < kept.sum() < reached and skipped.max() > 0.0
+    else:
+        # the one block that cancels, and nothing was skipped for it
+        assert kept.sum() == reached - 1 and skipped.max() == 0.0
+        assert 0 < norms[1, 2] < _FILTER_EPS / 10
+
+    c = _filtered_on_mesh(a, b, mesh4)
+    rows, cols = c.entry_coords()
+    got_kept = np.zeros((n, n), bool)
+    got_kept[rows, cols] = True
+    np.testing.assert_array_equal(got_kept, kept)
+    got = to_dense(c)
+    assert got.dtype == np.float64
+    # f64 rounding of a k = 133 (or 58) dot, relative to max|C|: a
+    # product computed in f32 misses it by six orders
+    round_off = 1e-13 * np.abs(want).max()
+    for i, j in zip(*np.nonzero(kept)):
+        blk = (slice(off[i], off[i + 1]), slice(off[j], off[j + 1]))
+        err = np.linalg.norm(got[blk] - want[blk])
+        assert err <= skipped[i, j] + round_off * got[blk].size ** 0.5, \
+            (i, j, err, skipped[i, j])
+    for i, j in zip(*np.nonzero(~kept)):
+        assert not got[off[i]:off[i + 1], off[j]:off[j + 1]].any()
+
+
+def test_filtered_serial_and_double_buffered_ticks_are_bit_identical(
+        mesh4, filtered_case):
+    _, a, b, *_ = filtered_case
+    by_mode = {mode: _filtered_on_mesh(a, b, mesh4, overlap=mode)
+               for mode in ("serial", "double_buffer")}
+    serial, db = by_mode["serial"], by_mode["double_buffer"]
+    np.testing.assert_array_equal(serial.keys, db.keys)
+    assert checksum(serial) == checksum(db)
+    assert np.array_equal(to_dense(serial), to_dense(db))
+
+
+def test_second_filtered_product_rebuilds_the_plan_and_compiles_nothing(
+        mesh4, filtered_case):
+    import jax
+    import jax.monitoring as mon
+
+    from dbcsr_tpu.core import timings
+
+    _, a, b, *_ = filtered_case
+    compiles = []
+
+    def listener(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    def builds():
+        st = timings._stats.get("mesh_plan_build")
+        return st.calls if st else 0
+
+    first = _filtered_on_mesh(a, b, mesh4)
+    mon.register_event_duration_secs_listener(listener)
+    try:
+        before, built = _plan_lookups(), builds()
+        second = _filtered_on_mesh(a, b, mesh4)
+        jax.block_until_ready([bn.data for bn in second.bins])
+    finally:
+        mon.unregister_event_duration_listener(listener)
+    after = _plan_lookups()
+    # a filtered plan follows the operands' values, so it is never
+    # cached: the second product pays the whole host plan again
+    assert {k: after[k] - before[k] for k in after} == \
+        {"hit": 0, "miss": 0, "uncacheable": 1}
+    assert builds() == built + 1
+    assert compiles == []
+    np.testing.assert_array_equal(first.keys, second.keys)
+    assert checksum(first) == checksum(second)
+    assert np.array_equal(to_dense(first), to_dense(second))
+
+
+def test_unfiltered_mesh_plan_lookups_are_counted_miss_then_hit(mesh4):
+    from dbcsr_tpu.parallel.sparse_dist import clear_mesh_plans
+
+    rbs = [4] * 9
+    a = _rand("A", rbs, rbs, 0.3, 73)
+    b = _rand("B", rbs, rbs, 0.3, 74)
+    clear_mesh_plans()
+    start = _plan_lookups()
+    for _ in range(2):
+        c = sparse_multiply_distributed(1.0, a, b, 0.0, None, mesh4)
+        assert c._mm_algorithm == "stack"
+    end = _plan_lookups()
+    assert {k: end[k] - start[k] for k in end} == \
+        {"miss": 1, "hit": 1, "uncacheable": 0}
