@@ -113,6 +113,8 @@ def bench_smm(nrep=5, stack_size=30000, m=23, n=23, k=23, dtype_enum=3,
                     else ("crosspack_vmem" if plan.cross_vmem
                           else ("crosspack" if plan.pack else None))),
         "r_grp": plan.r_grp,
+        # xla_group: [width, groups] of every class the plan opened
+        "group_classes": [list(c) for c in plan.group_classes],
         "pack": list(plan.pack) if plan.pack else None,
     }
     out(f"typename (id={dtype_enum}): {result['dtype']}")

@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -208,6 +208,30 @@ def _note_group_gather() -> None:
     ).inc(layout=GROUP_GATHER_LAYOUT)
 
 
+def _note_group_slots(tiles: "GroupTiles") -> None:
+    """Count what one planned `xla_group` span launches: the slots that
+    hold a stack entry against the slots of the chunks its loop runs
+    (fill = live / launched; the rest gathers the zero pad row)."""
+    slots = _metrics.counter(
+        "dbcsr_tpu_stack_slots_total",
+        "slots of the xla_group spans planned: 'live' hold a stack "
+        "entry, 'launched' are gathered and multiplied (the live chunks "
+        "of every width class, pad rows included)",
+    )
+    slots.inc(tiles.entries, kind="live")
+    slots.inc(tiles.slots_launched, kind="launched")
+    from dbcsr_tpu.core import stats
+
+    stats.record_group_tiles(tiles.widths, tiles.groups, tiles.entries,
+                             tiles.slots_launched)
+
+
+def _group_idx_shapes(plan) -> tuple:
+    """The shapes that key an `xla_group` plan's program: the gather
+    ids of every width class."""
+    return tuple(x.shape for x in plan.group_idx[1::3])
+
+
 def _block_rows(data):
     """(N, r, c) blocks as (N, r*c) rows, one block per row.  A TPU
     keeps a bin of small blocks with the block index along lanes
@@ -224,58 +248,82 @@ def _take_rows(rows, ids):
     return rows.at[ids].get(mode="promise_in_bounds")
 
 
-def _stack_phases_xla_group(c_data, a_data, b_data, ga, gb, gc, alpha,
+def _stack_phases_xla_group(c_data, a_data, b_data, live, *tiles_alpha,
                             prec=None):
     """R-tiled ("k-merged") stack layout: entries sharing a C block are
-    tiled into groups of R0; each group's A blocks concatenate along k
-    into one (m, R0*k) strip, its B blocks into (R0*k, n), and the
-    whole group contracts in ONE dot — k grows R0-fold, and a chunk's
-    scatter-add into C takes one update per group, not per entry.
+    tiled into groups; each group's A blocks concatenate along k into
+    one (m, w*k) strip, its B blocks into (w*k, n), and the whole group
+    contracts in ONE dot — k grows w-fold, and a chunk's scatter-add
+    into C takes one update per group, not per entry.
 
     This is the f64 answer to the MXU-utilization problem the reference
     solves with kernel `grouping` (`smm_acc_dnt_*.h`: one thread block
     processes `grouping` stack entries): on TPU, f64 is emulated in
     split-f32/bf16 passes, so per-entry 23^3 dots run at 1.6 GFLOP/s;
-    R0=8 merging measured 6.3 (the tuner at S=100000 on a v5e,
+    groups of R0 = 8 measured 6.3 (the tuner at S=100000 on a v5e,
+    whose synthetic stack has runs of mean 8 entries a C block;
     PERF.md, PR 21).
 
-    ``ga``/``gb`` are (nchunks, CH, R0) gather indices, padded with a
-    guaranteed-zero row id; ``gc`` is (nchunks, CH) segment ids with
-    nseg for dead groups (dropped).  Groups of one segment stay in
-    index order -> deterministic accumulation.  A and B are gathered
-    as whole block rows (`_block_rows`) in the host's (group, slot)
-    order; on a v5e that is 0.18 s of a filtered f64 north-star product
-    where the element gather along the bins' slot-minor layout was
-    1.58 (PERF.md, PR 29).  Per chunk the body
-    touches C only through `_accumulate_chunk`: on a TPU the carried
-    bin is tile-padded (2.4 GB for each f32 half of the north star's
-    emulated f64), so one more pass over it per chunk costs 11 ms
-    (`tests/test_chip_compiles.py` holds the compiler to that).
+    ``tiles_alpha`` is what `build_group_tiles` planned, flattened, and
+    alpha: one ``(ga, gb, gc)`` triple per width class, widest (R0)
+    first, ``ga``/``gb`` (nchunks, CH_w, w) gather ids padded with a
+    guaranteed-zero row id, ``gc`` (nchunks, CH_w) segment ids with
+    nseg for dead groups (dropped).  ``live`` is the number of chunks
+    that hold a group, a device scalar: the arrays' extent is bucketed
+    (the program's shapes hold still while the pattern moves) and the
+    chunks past ``live`` are never read.  ONE loop carries the bin
+    whatever the number of classes; a step gathers, multiplies and adds
+    chunk t of every class, widest first.  A C block's groups all lie
+    in one class in stack order, so its products are added in stack
+    order, full groups first and the remainder of its run last
+    (deterministic: the order is the plan's).  A strip whose depth w*k
+    is no whole number of sublanes is filled up with zero columns: on a
+    v5e the emulated-f64 dot of a ragged depth (92, 138, 276 at k = 23)
+    takes 2.4-4.2x the time of depth 184 for the same entries, the zero
+    columns change no bit (PERF.md, PR 31).  A and B are gathered as
+    whole block rows (`_block_rows`) in the host's (group, slot) order;
+    on a v5e that is 0.18 s of a filtered f64 north-star product where
+    the element gather along the bins' slot-minor layout was 1.58
+    (PERF.md, PR 29).  Per chunk the body touches C only through
+    `_accumulate_chunk`: on a TPU the carried bin is tile-padded
+    (2.4 GB for each f32 half of the north star's emulated f64), so one
+    more pass over it per chunk costs 11 ms, and what the compiler puts
+    at a `while` over it 0.3 s a loop (`tests/test_chip_compiles.py`
+    holds the compiler to one loop and to the scatter-adds).
     """
+    *flat, alpha = tiles_alpha
+    tiles = [flat[i:i + 3] for i in range(0, len(flat), 3)]
+    live = jnp.reshape(live, ())
     _, m, n = c_data.shape
     k = a_data.shape[2]
-    r0 = ga.shape[2]
     with device_scope("stk_gather"):
         a_rows = _block_rows(a_data)
         b_rows = _block_rows(b_data)
+    acc = _accum_dtype(c_data.dtype)
 
-    def body(c, idx):
-        ia, ib, ic = idx
-        ch = ia.shape[0]
-        with device_scope("stk_gather"):
-            ablk = _take_rows(a_rows, ia.reshape(-1)).reshape(ch, r0, m, k)
-            bblk = _take_rows(b_rows, ib.reshape(-1))
-            amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, r0 * k)
-            bmat = bblk.reshape(ch, r0 * k, n)
-        acc = _accum_dtype(c.dtype)
-        with device_scope("stk_dot"):
-            prod = _batch_dot(amat, bmat, acc, prec)
-            prod = (alpha.astype(acc) * prod).astype(c.dtype)
-        return _accumulate_chunk(c, prod, ic), None
+    def body(t, c):
+        for ga, gb, gc in tiles:
+            _, ch, w = ga.shape
+            with device_scope("stk_gather"):
+                ia = jax.lax.dynamic_index_in_dim(ga, t, keepdims=False)
+                ib = jax.lax.dynamic_index_in_dim(gb, t, keepdims=False)
+                ic = jax.lax.dynamic_index_in_dim(gc, t, keepdims=False)
+                ablk = _take_rows(a_rows, ia.reshape(-1)).reshape(ch, w, m, k)
+                bblk = _take_rows(b_rows, ib.reshape(-1))
+                amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, w * k)
+                bmat = bblk.reshape(ch, w * k, n)
+                ragged = -(w * k) % 8
+                if ragged:  # zeros up to whole sublanes (see docstring)
+                    amat = jnp.pad(amat, ((0, 0), (0, 0), (0, ragged)))
+                    bmat = jnp.pad(bmat, ((0, 0), (0, ragged), (0, 0)))
+            with device_scope("stk_dot"):
+                prod = _batch_dot(amat, bmat, acc, prec)
+                prod = (alpha.astype(acc) * prod).astype(c.dtype)
+            c = _accumulate_chunk(c, prod, ic)
+        return c
 
     with device_scope("stk_loop"):
-        c_data, _ = jax.lax.scan(body, c_data, (ga, gb, gc))
-    return c_data
+        return jax.lax.fori_loop(0, live, body, c_data)
 
 
 _process_stack_xla_group = functools.partial(
@@ -283,44 +331,165 @@ _process_stack_xla_group = functools.partial(
     _stack_phases_xla_group)
 
 
+# a narrower width class is opened only where it takes this share of
+# the slots the plan would launch without it
+GROUP_CLASS_MIN_SAVING = 0.05
+# operand bytes (A and B blocks, as values) one chunk of the grouped
+# loop gathers: 2 048 slots at 23^3 in f64, where a v5e runs the north
+# star's span in 0.95 s against 1.60 s at `mm_stack_size` = 30 000
+# slots a chunk and 1.01 s at 1 536 (PERF.md, PR 31); never more slots
+# than `mm_stack_size`
+GROUP_CHUNK_BYTES = 2048 * 2 * 23 * 23 * 8
+
+
+def group_chunk_groups(r0: int, m: int, n: int, k: int, itemsize: int,
+                       stack_size: int) -> int:
+    """Groups of ``r0`` the slots of one chunk come to."""
+    slots = min(stack_size, GROUP_CHUNK_BYTES // ((m + n) * k * itemsize))
+    return max(16, slots // r0)
+
+
+class GroupTiles(NamedTuple):
+    """What `build_group_tiles` plans for one stack."""
+
+    live: int      # chunks that hold a group; past it: bucket slack
+    tiles: tuple   # per width class, widest first: (ga, gb, gc) host arrays
+    groups: tuple  # per class: groups that hold an entry
+    entries: int   # stack entries = slots that hold one
+
+    @property
+    def widths(self) -> tuple:
+        return tuple(ga.shape[2] for ga, _, _ in self.tiles)
+
+    @property
+    def slots_launched(self) -> int:
+        """Slots of the chunks the loop runs: what is gathered and
+        multiplied, live or pad row."""
+        return self.live * sum(ga.shape[1] * ga.shape[2]
+                               for ga, _, _ in self.tiles)
+
+    def flat(self) -> list:
+        return [x for tile in self.tiles for x in tile]
+
+
+def _narrowest_fit(widths, r0: int):
+    """``fit[l]``: the narrowest of ``widths`` (r0 among them) that
+    holds a run of l <= r0 entries; 0 for the empty run."""
+    ws = np.sort(np.asarray(widths))
+    fit = ws[np.searchsorted(ws, np.arange(r0 + 1))]
+    fit[0] = 0
+    return fit
+
+
+def _group_widths(short_hist, other_slots: int, r0: int) -> list:
+    """The width classes of a stack, widest first, from the histogram
+    of its runs of at most ``r0`` entries (``short_hist[l]`` runs of l;
+    longer runs launch ``other_slots`` whatever the classes).  Each
+    such run is one group of the narrowest class that holds it.
+    Candidates are r0 halved down to 1 (on a v5e a class of 3/4 r0
+    costs more than its slots save: PERF.md, PR 31); the one that saves
+    most is opened while it takes `GROUP_CLASS_MIN_SAVING` of the slots
+    launched without it, so runs that fill r0 keep the single width."""
+    def slots(widths):
+        return other_slots + int((short_hist * _narrowest_fit(widths,
+                                                              r0)).sum())
+
+    widths = [r0]
+    candidates = {r0 >> i for i in range(1, r0.bit_length())}
+    now = slots(widths)
+    while candidates:
+        best = min(candidates, key=lambda w: (slots(widths + [w]), w))
+        with_best = slots(widths + [best])
+        if now - with_best < GROUP_CLASS_MIN_SAVING * now:
+            break
+        widths.append(best)
+        candidates.discard(best)
+        now = with_best
+    return sorted(widths, reverse=True)
+
+
 def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
-                      c_pad: int, chunk_groups: int):
-    """Host side of the grouped layout: split each C segment's entries
-    into runs of ``r0`` (pad the last run with zero-row ids), returning
-    (nchunks, CH, r0) a/b gather arrays + (nchunks, CH) segment ids.
-    Every a/b id lies in ``[0, a_pad]`` / ``[0, b_pad]``: the body's
-    gathers promise the compiler that and check nothing.  ``c_idx``
-    must be sorted ascending; dead/pad groups carry segment id
-    ``c_pad`` (= nseg), keeping ids sorted and dropped by the
-    scatter-add."""
+                      c_pad: int, chunk_groups: int) -> GroupTiles:
+    """Host side of the grouped layout: tile each C segment's run of
+    entries into groups and the groups into the chunks of the body's
+    loop, so that what is launched is what holds entries.
+
+    A run of more than ``r0`` entries (the tuned width: 6.3 GFLOP/s at
+    r0 = 8 on a v5e, PR 21, on runs of mean 8) is cut into groups of r0,
+    the last one filled up with zero-row ids.  A run of at most r0 is
+    ONE group of the narrowest width class that holds it.  The classes
+    come from the stack's own run lengths (`_group_widths`): runs that
+    fill r0 give one class and the arrays this function always gave;
+    the north star's runs of mean 4.4 give three (8, 4, 2) and launch
+    0.65 of the slots.  All groups of a C block lie in one class, in
+    stack order.
+
+    Each class holds its groups sorted by C block, cut into chunks of
+    ``CH_w`` groups; chunk t of every class is one step of the body's
+    loop.  ``CH_w`` follows the class's share of the slots in coarse
+    steps and not its count, so a pattern that grows keeps its shapes
+    and takes more chunks (``chunk_groups`` x r0 slots a chunk; a stack
+    that fills no chunk gets one of its own bucketed size).  Per class
+    the result holds (nchunks, CH_w, w) a/b gather arrays and
+    (nchunks, CH_w) segment ids, nchunks the bucketed count of chunks
+    and ``live`` the chunks that hold a group.  Every a/b id lies in
+    ``[0, a_pad]`` / ``[0, b_pad]``: the body's gathers promise the
+    compiler that and check nothing.  ``c_idx`` must be sorted
+    ascending; dead groups carry segment id ``c_pad`` (= nseg) after
+    the live ones, keeping ids sorted and dropped by the scatter-add."""
     s = len(c_idx)
     seg_starts = np.concatenate([[0], np.nonzero(np.diff(c_idx))[0] + 1])
     seg_len = np.diff(np.append(seg_starts, s))
+    run_groups = -(-seg_len // r0)
+    short = run_groups == 1
+    widths = _group_widths(np.bincount(seg_len[short], minlength=r0 + 1),
+                           int(run_groups[~short].sum()) * r0, r0)
+    run_width = np.where(
+        short, _narrowest_fit(widths, r0)[np.minimum(seg_len, r0)], r0)
+    # groups in stack order; a group's row among its class's groups
+    group_base = np.cumsum(run_groups) - run_groups
+    width_of = np.repeat(run_width, run_groups)
+    c_of = np.repeat(c_idx[seg_starts], run_groups)
+    members = [np.nonzero(width_of == w)[0] for w in widths]
+    widths, members = zip(*[(w, mem) for w, mem in zip(widths, members)
+                            if len(mem)])  # r0 itself may hold nothing
+    counts = [len(mem) for mem in members]
+    slots = sum(cnt * w for cnt, w in zip(counts, widths))
+    if slots <= chunk_groups * r0:
+        # one chunk holds the stack: its size is the stack's, bucketed
+        caps = [bucket_size(cnt) for cnt in counts]
+    elif widths == (r0,):
+        caps = [chunk_groups]
+    else:
+        step = max(1, 1 << max((chunk_groups // 16).bit_length() - 1, 0))
+        per_chunk = chunk_groups * r0 / slots
+        caps = [-(-int(np.ceil(cnt * per_chunk)) // step) * step
+                for cnt in counts]
+    live = max(-(-cnt // cap) for cnt, cap in zip(counts, caps))
+    nchunks = bucket_size(live, minimum=1)
+    # every class's rows in one buffer, so the ids are written once
+    rows = [nchunks * cap for cap in caps]
+    row_base = np.cumsum([0] + rows)
+    slot_base = np.cumsum([0] + [r * w for r, w in zip(rows, widths)])
+    row_of = np.empty(len(width_of), np.int64)   # a group's row among all
+    slot0_of = np.empty(len(width_of), np.int64)  # its first slot among all
+    for i, (w, mem) in enumerate(zip(widths, members)):
+        row_of[mem] = row_base[i] + np.arange(len(mem))
+        slot0_of[mem] = slot_base[i] + np.arange(len(mem)) * w
     off_in_seg = np.arange(s) - np.repeat(seg_starts, seg_len)
-    # group index: consecutive per (segment, run-of-r0) in entry order
-    is_new_group = np.ones(s, bool)
-    is_new_group[1:] = (off_in_seg[1:] % r0 == 0) | (c_idx[1:] != c_idx[:-1])
-    gidx = np.cumsum(is_new_group) - 1
-    n_groups = int(gidx[-1]) + 1
-    ga = np.full((n_groups, r0), a_pad, np.int32)
-    gb = np.full((n_groups, r0), b_pad, np.int32)
-    slot = off_in_seg % r0
-    ga[gidx, slot] = a_idx
-    gb[gidx, slot] = b_idx
-    gc = np.empty(n_groups, np.int32)
-    gc[gidx] = c_idx
-    nchunks = bucket_size(-(-n_groups // chunk_groups), minimum=1)
-    total = nchunks * chunk_groups
-    if total > n_groups:
-        pad = total - n_groups
-        ga = np.concatenate([ga, np.full((pad, r0), a_pad, np.int32)])
-        gb = np.concatenate([gb, np.full((pad, r0), b_pad, np.int32)])
-        gc = np.concatenate([gc, np.full(pad, c_pad, np.int32)])
-    return (
-        ga.reshape(nchunks, chunk_groups, r0),
-        gb.reshape(nchunks, chunk_groups, r0),
-        gc.reshape(nchunks, chunk_groups),
-    )
+    gidx = np.repeat(group_base, seg_len) + off_in_seg // r0
+    dest = slot0_of[gidx] + off_in_seg % r0
+    ga = np.full(slot_base[-1], a_pad, np.int32)
+    gb = np.full(slot_base[-1], b_pad, np.int32)
+    gc = np.full(row_base[-1], c_pad, np.int32)
+    ga[dest] = a_idx
+    gb[dest] = b_idx
+    gc[row_of] = c_of
+    tiles = [(ga[slot_base[i]:slot_base[i + 1]].reshape(nchunks, cap, w),
+              gb[slot_base[i]:slot_base[i + 1]].reshape(nchunks, cap, w),
+              gc[row_base[i]:row_base[i + 1]].reshape(nchunks, cap))
+             for i, (w, cap) in enumerate(zip(widths, caps))]
+    return GroupTiles(live, tuple(tiles), tuple(counts), s)
 
 
 def _stack_phases_xla(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,
@@ -464,8 +633,9 @@ class StackPlan:
     Built by `prepare_stack`, run by `execute_stack`."""
 
     __slots__ = ("driver", "nseg", "xla_idx", "launches", "r_grp",
-                 "a_pad_row", "b_pad_row", "append_a_pad", "append_b_pad",
-                 "val_idx", "group_idx", "kmerge", "pack", "cross_launches",
+                 "group_classes", "group_launched", "a_pad_row",
+                 "b_pad_row", "append_a_pad", "append_b_pad", "val_idx",
+                 "group_idx", "kmerge", "pack", "cross_launches",
                  "cross_vmem", "cross_src", "host_idx", "src_idx",
                  "src_pads", "precision")
 
@@ -475,12 +645,15 @@ class StackPlan:
         self.xla_idx = None      # (ai, bi, ci) device (nchunks, chunk)
         self.launches = None     # pallas: [(ai_flat, bi_flat, ci) device]
         self.r_grp = 1
+        self.group_classes = ()  # xla_group: ((width, live groups), ...)
+        self.group_launched = 0  # xla_group: slots its live chunks launch
         self.a_pad_row = None
         self.b_pad_row = None
         self.append_a_pad = False  # pallas/group: append a zero row at execute
         self.append_b_pad = False
         self.val_idx = None      # host prefix for first-use validation
-        self.group_idx = None    # xla_group: (ga, gb, gc) device arrays
+        self.group_idx = None    # xla_group: device (live chunks, then
+                                 # ga, gb, gc per width class)
         self.kmerge = False      # pallas: k-merged MXU dot variant
         self.pack = None         # pallas_cross: (P, R) MXU packing
         self.cross_launches = None  # pallas_cross: launch dicts
@@ -708,13 +881,18 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
         if b_pad_row is None:
             plan.append_b_pad = True
             b_pad_row = b_data.shape[0]
-        chunk_groups = max(256, cfg.mm_stack_size // r0)
-        ga, gb, gc = build_group_tiles(
+        chunk_groups = group_chunk_groups(
+            r0, a_data.shape[1], b_data.shape[2], a_data.shape[2],
+            jnp.dtype(c_data.dtype).itemsize, cfg.mm_stack_size)
+        tiles = build_group_tiles(
             np.asarray(c_idx), np.asarray(a_idx), np.asarray(b_idx),
             r0, a_pad_row, b_pad_row, plan.nseg, chunk_groups,
         )
         plan.driver = "xla_group"
-        plan.r_grp = r0  # metadata: the R-tile grouping actually used
+        plan.r_grp = r0  # metadata: the widest R-tile grouping used
+        plan.group_classes = tuple(zip(tiles.widths, tiles.groups))
+        plan.group_launched = tiles.slots_launched
+        _note_group_slots(tiles)
         plan.precision = prec
         plan.a_pad_row = a_pad_row
         plan.b_pad_row = b_pad_row
@@ -722,9 +900,9 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
         # repeats (incl. filtered products the plan cache skips)
         # re-upload nothing
         plan.group_idx = (
-            _mempool.upload_index("grp_a", ga),
-            _mempool.upload_index("grp_b", gb),
-            _mempool.upload_index("grp_c", gc),
+            _mempool.upload_index("grp_live", np.int32(tiles.live)),
+            *(_mempool.upload_index(tag, x) for tile in tiles.tiles
+              for tag, x in zip(("grp_a", "grp_b", "grp_c"), tile)),
         )
         _note_driver(
             "xla_group",
@@ -955,9 +1133,9 @@ def _record_stack_jit(plan: StackPlan, c_data, a_data, b_data):
         dev_entries = int(plan.xla_idx[0].size)
     elif drv == "xla_group":
         key = (c_data.shape, a_data.shape, b_data.shape, dt,
-               plan.group_idx[0].shape, plan.precision)
+               _group_idx_shapes(plan), plan.precision)
         fn = "_process_stack_xla_group"
-        dev_entries = int(plan.group_idx[0].size)
+        dev_entries = plan.group_launched
     elif drv == "pallas":
         from dbcsr_tpu.acc import pallas_smm
 
@@ -1465,18 +1643,17 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
             a_data = _append_pad_row(a_data)
         if plan.append_b_pad:
             b_data = _append_pad_row(b_data)
-        ga, gb, gc = plan.group_idx
         alpha_dev = jnp.asarray(alpha, dtype=c_data.dtype)
         _note_group_gather()
         if want_xla_cost:
             _capture_stack_xla_cost(
                 jit_fn_name, jit_key, _process_stack_xla_group,
-                (c_data, a_data, b_data, ga, gb, gc, alpha_dev),
-                c_data, a_data, b_data, int(ga.size),
+                (c_data, a_data, b_data, *plan.group_idx, alpha_dev),
+                c_data, a_data, b_data, plan.group_launched,
                 prec=plan.precision,
             )
         return _process_stack_xla_group(
-            c_data, a_data, b_data, ga, gb, gc, alpha_dev,
+            c_data, a_data, b_data, *plan.group_idx, alpha_dev,
             prec=plan.precision,
         )
     if plan.driver == "pallas_cross":
@@ -1726,7 +1903,8 @@ def prepare_superstack(plans) -> Optional[SuperstackPlan]:
     sig = tuple(
         (
             p.driver,
-            3 if p.driver in _XLA_FAMILY else 3 * len(p.launches),
+            (len(p.group_idx) if p.driver == "xla_group"
+             else 3 if p.driver in _XLA_FAMILY else 3 * len(p.launches)),
             bool(p.append_a_pad), bool(p.append_b_pad),
             p.r_grp, bool(p.kmerge), p.precision,
         )
@@ -1843,8 +2021,8 @@ def _record_superstack_jit(splan: SuperstackPlan, c_data, a_datas,
             idx_shapes.append(plan.xla_idx[0].shape)
             dev_entries = int(plan.xla_idx[0].size)
         elif plan.driver == "xla_group":
-            idx_shapes.append(plan.group_idx[0].shape)
-            dev_entries = int(plan.group_idx[0].size)
+            idx_shapes.append(_group_idx_shapes(plan))
+            dev_entries = plan.group_launched
         else:  # pallas
             idx_shapes.append(tuple(lc[0].shape for lc in plan.launches))
             dev_entries = pallas_smm.launch_entries(plan.launches,
@@ -1874,7 +2052,7 @@ def _superstack_model(splan: SuperstackPlan, c_data, a_datas,
         if plan.driver in ("xla", "xla_flat"):
             entries = int(plan.xla_idx[0].size)
         elif plan.driver == "xla_group":
-            entries = int(plan.group_idx[0].size)
+            entries = plan.group_launched
         else:
             entries = pallas_smm.launch_entries(plan.launches, plan.r_grp)
         spans.append((m, n, k, entries))

@@ -265,16 +265,24 @@ def _tune_smm_x64(m, n, k, dtype_enum, stack_size, nrep, out, seed, jax, jnp,
         out(f"  host: {flops / t / 1e9:.1f} GFLOP/s")
 
     # R-tiled grouped layout (k-merged dots; see _process_stack_xla_group)
-    from dbcsr_tpu.acc.smm import _process_stack_xla_group, build_group_tiles
+    from dbcsr_tpu.acc.smm import (
+        _process_stack_xla_group,
+        build_group_tiles,
+        group_chunk_groups,
+    )
 
     a_padded = jnp.concatenate([a, jnp.zeros((1, m, k), dtype)])
     b_padded = jnp.concatenate([b, jnp.zeros((1, k, n), dtype)])
     for r0 in (4, 8, 16):
         # chunking mirrors prepare_stack's production choice
-        ga, gb, gc = build_group_tiles(
-            ci, ai, bi, r0, na, nb, nc, max(256, stack_size // r0)
+        tiles = build_group_tiles(
+            ci, ai, bi, r0, na, nb, nc,
+            group_chunk_groups(r0, m, n, k, np.dtype(dtype).itemsize,
+                               stack_size),
         )
-        grp_args = (jnp.asarray(ga), jnp.asarray(gb), jnp.asarray(gc))
+        grp_args = tuple(map(jnp.asarray, (np.int32(tiles.live),
+                                           *tiles.flat())))
+        fill = tiles.entries / tiles.slots_launched
 
         def run_group(grp_args=grp_args):
             return _process_stack_xla_group(
@@ -291,7 +299,8 @@ def _tune_smm_x64(m, n, k, dtype_enum, stack_size, nrep, out, seed, jax, jnp,
             {"driver": "xla_group", "grouping": None, "r0": r0,
              "gflops": flops / t / 1e9}
         )
-        out(f"  xla_group r0={r0}: {flops / t / 1e9:.1f} GFLOP/s")
+        out(f"  xla_group r0={r0}: {flops / t / 1e9:.1f} GFLOP/s "
+            f"(widths {list(tiles.widths)}, fill {fill:.2f})")
 
     # off-TPU, Pallas runs in INTERPRET mode (~1000x): timing it at
     # production stack sizes burns the whole sweep budget producing

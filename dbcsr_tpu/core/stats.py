@@ -48,6 +48,11 @@ class _DriverAgg:
     stacks: int = 0
     sync_stacks: int = 0
     by_dtype: dict = dataclasses.field(default_factory=dict)
+    # xla_group plans: slots that hold an entry, slots the live chunks
+    # launch, and the groups of each width class
+    slots_live: int = 0
+    slots_launched: int = 0
+    groups_by_width: dict = dataclasses.field(default_factory=dict)
 
 
 _by_mnk: dict = collections.defaultdict(_MnkStat)
@@ -105,10 +110,26 @@ def record_driver(driver: str, flops: int, *, nbytes: int = 0,
     _agg_driver(driver, flops, nbytes, seconds, dtype, stacks, sync=sync)
 
 
+def record_group_tiles(widths, groups, slots_live: int,
+                       slots_launched: int) -> None:
+    """One planned `xla_group` span: its width classes with the groups
+    of each, and the slots it fills of those it launches."""
+    from dbcsr_tpu.core.config import get_config
+
+    if not get_config().keep_stats:
+        return
+    agg = _driver_agg["xla_group"]
+    agg.slots_live += slots_live
+    agg.slots_launched += slots_launched
+    for w, n in zip(widths, groups):
+        agg.groups_by_width[w] = agg.groups_by_width.get(w, 0) + n
+
+
 def driver_rollup() -> dict:
     """Plain-dict view of the per-driver attribution aggregates."""
-    return {
-        d: {
+    out = {}
+    for d, a in _driver_agg.items():
+        out[d] = {
             "flops": a.flops,
             "bytes": a.nbytes,
             "seconds": a.seconds,
@@ -116,8 +137,11 @@ def driver_rollup() -> dict:
             "sync_stacks": a.sync_stacks,
             "by_dtype": dict(a.by_dtype),
         }
-        for d, a in _driver_agg.items()
-    }
+        if a.slots_launched:
+            out[d].update(slots_live=a.slots_live,
+                          slots_launched=a.slots_launched,
+                          groups_by_width=dict(a.groups_by_width))
+    return out
 
 
 def record_stack(m: int, n: int, k: int, nentries: int, *,

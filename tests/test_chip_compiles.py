@@ -203,9 +203,15 @@ def test_base_kernel_launch_of_mixed10k_compiles(one_chip, mixed10k):
 
 
 # `northstar.scf_f64`'s 23^3 span: the C bin, the A and B bins with
-# their pad row, 56 chunks of 3 750 groups of 8
-_NS_BIN, _NS_AB, _NS_CHUNKS, _NS_GROUPS, _NS_R0 = 196608, 18901, 56, 3750, 8
+# their pad row, and the plan `build_group_tiles` makes of its stack
+_NS_BIN, _NS_AB, _NS_R0 = 196608, 18901, 8
 _NS_BIN_SHAPE = f"[{_NS_BIN},23,23]"
+# `generated_code_size_in_bytes` of the span's program: the loaded
+# executable lives in HBM and `peak_hbm_gib` counts it (PR 29).  The
+# one-class program was 10.9 MB and the cell's peak 1 988.6 MB; the
+# index arrays shrank by 1.5 MB, so 1% of the peak leaves 21 MB more.
+# Three classes compiled to 17.4 MB here and on the chip (PR 31).
+_NS_CODE_BUDGET = 21 * 10 ** 6
 
 
 def _computations(hlo_text):
@@ -223,36 +229,92 @@ def _computations(hlo_text):
 
 
 @pytest.fixture(scope="module")
-def ns_group_hlo(one_chip):
-    """`_process_stack_xla_group` compiled at the north star's shapes:
-    (the program's computations, the lines of its chunk loop's body)."""
+def ns_plan():
+    """The plan of the cell's 23^3 stack, from its pattern as the
+    generator draws it (ids zero: only the shapes are used)."""
+    import sys
+
+    sys.path.insert(0, REPO)
+    from benchmark import arithmetic
+    from dbcsr_tpu.acc import smm
+
+    with open(os.path.join(REPO, "benchmark/configs/northstar.json")) as fh:
+        cfg = json.load(fh)
+    sizes = arithmetic.expand_block_sizes(cfg["m"], cfg["blocks"]["m"])
+    rng = np.random.default_rng(cfg["pattern_seed"])
+    pa = rng.random((len(sizes), len(sizes))) < cfg["occupancy"]["a"]
+    pb = rng.random((len(sizes), len(sizes))) < cfg["occupancy"]["b"]
+    full = sizes == 23
+    counts = pa[full][:, full].astype(np.int32) \
+        @ pb[full][:, full].astype(np.int32)
+    runs = counts[counts > 0]
+    assert runs.sum() == 828537  # the stack PERF.md counts
+    c_idx = np.repeat(np.arange(len(runs)), runs).astype(np.int32)
+    zeros = np.zeros(len(c_idx), np.int32)
+    return smm.build_group_tiles(
+        c_idx, zeros, zeros, _NS_R0, _NS_AB - 1, _NS_AB - 1, _NS_BIN,
+        smm.group_chunk_groups(_NS_R0, 23, 23, 23, 8, 30000))
+
+
+def test_north_star_plan_launches_the_slots_that_hold_entries(ns_plan):
+    """Counts, not rates: until PR 31 the plan launched 1 680 000
+    slots for these 828 537 entries (fill 49%)."""
+    assert ns_plan.widths == (8, 4, 2)
+    assert ns_plan.slots_launched < 1_120_000
+    assert ns_plan.entries / ns_plan.slots_launched >= 0.74
+    assert [ga.shape[1] for ga, _, _ in ns_plan.tiles] == [176, 144, 64]
+
+
+@pytest.fixture(scope="module")
+def ns_group_program(one_chip, ns_plan):
+    """`_process_stack_xla_group` compiled at the north star's shapes
+    and plan: (the compiled program, its computations, the lines of its
+    chunk loop's body)."""
     import jax
     import jax.numpy as jnp
 
     from dbcsr_tpu.acc import smm
 
-    idx = _shape(one_chip, (_NS_CHUNKS, _NS_GROUPS, _NS_R0), jnp.int32)
+    idx = [_shape(one_chip, x.shape, jnp.int32) for x in ns_plan.flat()]
     with jax.enable_x64(True):
-        text = smm._process_stack_xla_group.lower(
+        compiled = smm._process_stack_xla_group.lower(
             _shape(one_chip, (_NS_BIN, 23, 23), jnp.float64),
             _shape(one_chip, (_NS_AB, 23, 23), jnp.float64),
             _shape(one_chip, (_NS_AB, 23, 23), jnp.float64),
-            idx, idx, _shape(one_chip, (_NS_CHUNKS, _NS_GROUPS), jnp.int32),
+            _shape(one_chip, (1,), jnp.int32), *idx,
             _shape(one_chip, (), jnp.float64),
-        ).compile().as_text()
+        ).compile()
+    text = compiled.as_text()
     comps = _computations(text)
     bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text))
+    # ONE `while` carries the bin, whatever the number of classes: what
+    # the compiler puts at such a loop (a convert and copies of the
+    # carry) cost 0.3 s a product (PERF.md, PR 29)
     chunk_loops = [name for name in bodies
                    if any(_NS_BIN_SHAPE in ln for ln in comps[name])]
     assert len(chunk_loops) == 1, sorted(chunk_loops)
-    return comps, comps[chunk_loops[0]]
+    return compiled, comps, comps[chunk_loops[0]]
 
 
-def test_f64_group_body_touches_the_bin_only_in_its_scatter(ns_group_hlo):
+@pytest.fixture(scope="module")
+def ns_group_hlo(ns_group_program):
+    _, comps, body = ns_group_program
+    return comps, body
+
+
+def test_f64_group_program_fits_the_peak_hbm_bound(ns_group_program):
+    compiled, _, _ = ns_group_program
+    size = compiled.memory_analysis().generated_code_size_in_bytes
+    assert size < _NS_CODE_BUDGET, size
+
+
+def test_f64_group_body_touches_the_bin_only_in_its_scatters(ns_group_hlo,
+                                                             ns_plan):
     """Inside the chunk loop of `_stack_phases_xla_group`, at the north
-    star's shapes, nothing but the scatter fusion produces an array of
-    the C bin's shape: no zero-fill, add or copy of the whole bin per
-    chunk (PR 26's program had five: 2.8 s of a 6.79 s product)."""
+    star's shapes, nothing but the scatter fusions, one a width class,
+    produces an array of the C bin's shape: no zero-fill, add or copy
+    of the whole bin per chunk (PR 26's program had five: 2.8 s of a
+    6.79 s product)."""
     _, body = ns_group_hlo
     # `%name = <result type> <opcode>(<operands>`: a layout's `T(8,128)`
     # follows a colon, an opcode a space
@@ -262,17 +324,18 @@ def test_f64_group_body_touches_the_bin_only_in_its_scatter(ns_group_hlo):
               if _NS_BIN_SHAPE in ln.split(" = ", 1)[1][:op.start()]
               and op.group(1) not in ("get-tuple-element", "parameter",
                                       "tuple")]
-    assert len(makers) == 1, [ln[:160] for ln in makers]
-    assert "stk_accum/scatter-add" in makers[0], makers[0][:400]
+    assert len(makers) == len(ns_plan.widths), [ln[:160] for ln in makers]
+    assert all("stk_accum/scatter-add" in ln for ln in makers), \
+        [ln[:400] for ln in makers]
 
 
-def test_f64_group_body_gathers_whole_block_rows(ns_group_hlo):
+def test_f64_group_body_gathers_whole_block_rows(ns_group_hlo, ns_plan):
     """Inside the same chunk loop every `gather` reads an operand whose
     minor-most dimension is not the block index (until PR 29 it was:
     `f32[18901,23,23]{0,2,1}`, 529 x 30 000 single elements fetched
     along lanes per gather, 1.3 s of a 3.9 s product); no NaN fill is
     selected over what was gathered (the ids are promised in bounds);
-    and the dot is fed the strips it was fed."""
+    and the dot of every class is fed strips of whole sublanes."""
     comps, body = ns_group_hlo
     called = [comps[name] for ln in body
               for name in re.findall(r"calls=%?([\w.\-]+)", ln)]
@@ -290,21 +353,25 @@ def test_f64_group_body_gathers_whole_block_rows(ns_group_hlo):
             if op and "f32[" in ln.split(" = ", 1)[1][:8]:
                 gathers += 1
                 assert layout_of[op.group(1)][0] != "0", ln[:300]
-    assert gathers >= 4  # A and B, both halves of the emulated f64
+    # A and B, both halves of the emulated f64, of every class
+    assert gathers >= 4 * len(ns_plan.widths)
     assert not any("constant(nan)" in ln for lines in reached
                    for ln in lines)
     text = "\n".join(body)
     # the eight f32 pieces the emulation makes of each f64 strip
-    strips = (f"f32[8,{_NS_GROUPS},23,{_NS_R0 * 23}]{{3,2,1,0:",
-              f"f32[8,{_NS_GROUPS},{_NS_R0 * 23},23]{{1,2,3,0:")
-    assert all(strip in text for strip in strips), strips
+    for (ga, _, _), w in zip(ns_plan.tiles, ns_plan.widths):
+        depth = -(-w * 23 // 8) * 8
+        strips = (f"f32[8,{ga.shape[1]},23,{depth}]{{",
+                  f"f32[8,{ga.shape[1]},{depth},23]{{")
+        assert all(strip in text for strip in strips), strips
 
 
 @pytest.mark.parametrize("body", ["xla", "xla_flat", "xla_group"])
 def test_stack_body_scatter_adds_into_its_carry(body):
     """What the guard above holds the compiler to, read off the jaxpr
-    (no topology): the scan body's only bin-shaped equation is a
-    `scatter-add` whose operand is the loop-carried C."""
+    (no topology): the loop body's only bin-shaped equations are the
+    `scatter-add`s (one a width class of the grouped body) whose
+    operand is the loop-carried C."""
     import jax
     import jax.numpy as jnp
 
@@ -316,15 +383,19 @@ def test_stack_body_scatter_adds_into_its_carry(body):
     b = jax.ShapeDtypeStruct((9, k, n), jnp.float32)
     flat = jax.ShapeDtypeStruct((3, 16), jnp.int32)
     grouped = jax.ShapeDtypeStruct((3, 16, 2), jnp.int32)
-    idx = (grouped, grouped, flat) if body == "xla_group" else (flat,) * 3
+    narrow = jax.ShapeDtypeStruct((3, 16, 1), jnp.int32)
+    idx = (jax.ShapeDtypeStruct((), jnp.int32), grouped, grouped, flat,
+           narrow, narrow, flat) if body == "xla_group" else (flat,) * 3
     fn = {"xla": smm._stack_phases_xla, "xla_flat": smm._stack_phases_xla_flat,
           "xla_group": smm._stack_phases_xla_group}[body]
     jaxpr = jax.make_jaxpr(fn)(
         c, a, b, *idx, jax.ShapeDtypeStruct((), jnp.float32))
-    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
-    step = scan.params["jaxpr"].jaxpr
-    carry = step.invars[scan.params["num_consts"]]
-    assert carry.aval.shape == c.shape
+    (loop,) = [e for e in jaxpr.eqns if e.primitive.name in ("scan", "while")]
+    if loop.primitive.name == "scan":
+        step = loop.params["jaxpr"].jaxpr
+    else:  # the grouped body: a `while` bounded by the live chunk count
+        step = loop.params["body_jaxpr"].jaxpr
+    (carry,) = [v for v in step.invars if v.aval.shape == c.shape]
 
     def eqns(jp):
         for e in jp.eqns:
@@ -335,9 +406,14 @@ def test_stack_body_scatter_adds_into_its_carry(body):
     makers = [e for e in eqns(step)
               if any(getattr(v.aval, "shape", None) == c.shape
                      for v in e.outvars)]
-    assert [e.primitive.name for e in makers] == ["scatter-add"], makers
-    assert makers[0].invars[0] is carry
-    assert makers[0].params["indices_are_sorted"]
+    # one scatter-add a width class, each into what the last one left
+    assert [e.primitive.name for e in makers] \
+        == ["scatter-add"] * (2 if body == "xla_group" else 1), makers
+    into = carry
+    for e in makers:
+        assert e.invars[0] is into
+        assert e.params["indices_are_sorted"]
+        (into,) = e.outvars
 
 
 # ---------------------------------------------------------------------------

@@ -33,8 +33,12 @@ def _flat_idx():
 
 
 def _group_idx():
-    g = jnp.zeros((2, 3, 2), jnp.int32)
-    return g, g, jnp.zeros((2, 3), jnp.int32)
+    """Live chunk count, then two width classes' (ga, gb, gc)."""
+    wide = jnp.zeros((2, 3, 2), jnp.int32)
+    narrow = jnp.zeros((2, 4, 1), jnp.int32)
+    return (jnp.asarray(2, jnp.int32),
+            wide, wide, jnp.zeros((2, 3), jnp.int32),
+            narrow, narrow, jnp.zeros((2, 4), jnp.int32))
 
 
 def _lower_span(fn, idx):
@@ -44,7 +48,7 @@ def _lower_span(fn, idx):
 
 def _lower_fused():
     # span 0: xla_group with both pad rows appended; span 1: xla, k = 3
-    sig = ("xla", False, (("xla_group", 3, True, True, 1, False, None),
+    sig = ("xla", False, (("xla_group", 7, True, True, 1, False, None),
                           ("xla", 3, False, False, 1, False, None)))
     c, a0, b0 = _operands(5, 5, 5)
     _, a1, b1 = _operands(5, 5, 3)
