@@ -17,11 +17,18 @@ every chip of the host and starts no child that touches JAX:
   mesh4_filtered  the f64_filtered product on the same grid: the sparse
                 mesh engine (per-product plan, sparse panels ring-shifted,
                 stacks, collect, norm filter), serial and double-buffered
+  sign_chain    three Newton-Schulz steps of `models.sign.sign_iteration`
+                at 2 000 x 2 000 on the H of `h2o_ls_chain` (the cell's
+                recipe, 38 neighbours a molecule): filtered products on
+                the result of the last, the union add, the pool; checked
+                against the benchmark's NumPy chain (flops, blocks,
+                checksum)
 
 Each leg runs one first call (set-up: compile + staging) and two fenced
 repeats, requires bit-identical checksums across them, and is checked
-against plain NumPy on sampled block rows (f64, f32) or against the f64
-leg's checksum (f64_filtered, mesh4, mesh4_filtered).  The engine's failover code is
+against plain NumPy on sampled block rows (f64, f32), against the f64
+leg's checksum (f64_filtered, mesh4, mesh4_filtered) or against the NumPy
+chain (sign_chain).  The engine's failover code is
 safety code; the smoke FAILS when any of it fires.
 
 It sets no platform: without a TPU it exits non-zero before any work.
@@ -44,6 +51,7 @@ import time
 import warnings
 
 NORTH_STAR = {"n": 10000, "block": 23, "occupancy": 0.1}
+SIGN_CHAIN = {"n": 2000, "steps": 3}
 FILTER_EPS = 1e-7
 N_SAMPLE_ROWS = 4
 CHECKSUM_RTOL = 1e-9  # filtered / mesh legs vs the f64 leg
@@ -320,7 +328,79 @@ def leg_mesh4_filtered(**kw):
     return leg_mesh4(**kw, filter_eps=FILTER_EPS)
 
 
-LEGS = ("f64", "f64_filtered", "f32", "mesh4", "mesh4_filtered")
+def _sign_chain_recipe():
+    """The configuration `h2o_ls_chain` and the benchmark's own chain
+    (`benchmark/generators/sign_chain.py`: NumPy, nothing of the
+    program), loaded by path as the harness loads them."""
+    import importlib.util
+
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark")
+    with open(os.path.join(here, "configs", "h2o_ls_chain.json")) as fh:
+        cfg = json.load(fh)
+    spec = importlib.util.spec_from_file_location(
+        "_smoke_sign_chain", os.path.join(here, "generators", "sign_chain.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    return cfg, ref
+
+
+def leg_sign_chain(*, n, block, occupancy, seed):
+    """Three steps of the sign chain on the cell's H at 2 000 x 2 000
+    (smaller where the caller's ``n`` is), as many stored neighbours a
+    molecule as the configuration has.  ``occupancy`` is the product
+    legs'; H's comes from the configuration."""
+    import numpy as np
+
+    import dbcsr_tpu as dt
+    from dbcsr_tpu.models.sign import sign_iteration
+
+    cfg, ref = _sign_chain_recipe()
+    sizes = np.asarray(_block_sizes(min(n, SIGN_CHAIN["n"]), block))
+    neighbours = cfg["occupancy"]["a"] * len(_block_sizes(
+        cfg["m"], cfg["blocks"]["m"][0][1]))
+    recipe = {k: cfg["assumed"][k]["value"] for k in
+              ("occupied_per_block", "coupling", "decay_length",
+               "virtual_width")}
+    h = ref.draw_hamiltonian(sizes, min(1.0, neighbours / len(sizes)),
+                             cfg["pattern_seed"], seed, **recipe)
+    mat = dt.create("H", sizes.astype(np.int32), sizes.astype(np.int32),
+                    "float64")
+    for rows, cols, data in h.by_shape():
+        mat.put_blocks(rows, cols, data)
+    mat = mat.finalize()
+    steps, eps = SIGN_CHAIN["steps"], float(cfg["filter_eps"])
+
+    def run():
+        x, history = sign_iteration(mat, steps=steps, filter_eps=eps,
+                                    tol=0.0)
+        assert len(history) == steps
+        return x, x._last_flops
+
+    res, _ = _timed_repeats("sign_chain", run)
+    _report("LEG", res)
+    want = ref.reference_chain(h, filter_eps=eps, tol=0.0, max_steps=steps)
+    flops = sum(p["flops"] for p in want.products)
+    checksum = float((want.x.data ** 2).sum())
+    rel = abs(res["checksum"] - checksum) / checksum
+    pruned = sum(p["pruned"] for p in want.products)
+    dropped = sum(p["c_dropped"] for p in want.products)
+    if (res["flops"] != flops or res["nblks"] != len(want.x.rows)
+            or not rel <= CHECKSUM_RTOL):
+        raise SmokeFailure(
+            f"sign_chain: flops {res['flops']} (NumPy chain {flops}), "
+            f"blocks {res['nblks']} ({len(want.x.rows)}), checksum "
+            f"{res['checksum']!r} ({checksum!r}, relative {rel:.3e})")
+    _report("CHECK", {"leg": "sign_chain", "check": "numpy_chain",
+                      "steps": steps, "flops": flops, "rel_diff": rel,
+                      "tol": CHECKSUM_RTOL, "candidates_pruned": pruned,
+                      "blocks_dropped": dropped,
+                      "history": want.history})
+    return res
+
+
+LEGS = ("f64", "f64_filtered", "f32", "mesh4", "mesh4_filtered",
+        "sign_chain")
 
 
 def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
@@ -369,6 +449,7 @@ def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
                 attempt("mesh4", leg_mesh4, reference=out.get("f64"))
                 attempt("mesh4_filtered", leg_mesh4_filtered,
                         reference=out.get("f64"))
+            attempt("sign_chain", leg_sign_chain)
     finally:
         dt.set_config(incremental=prev_inc)
     here = os.path.dirname(os.path.abspath(__file__))

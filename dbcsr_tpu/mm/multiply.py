@@ -376,6 +376,12 @@ def _multiply_body(a, b, c, alpha, beta, retain_sparsity, filter_eps,
             compress(c, norms.astype(np.float64) ** 2 >= float(filter_eps) ** 2)
             _flight.note("filtered_blocks", nblks_pre - c.nblks)
             _flight.note("kept_blocks", c.nblks)
+            fates = _metrics.counter(
+                "dbcsr_tpu_filter_blocks_total",
+                "C blocks of filtered products by what the norm filter "
+                "made of them")
+            fates.inc(c.nblks, fate="kept")
+            fates.inc(nblks_pre - c.nblks, fate="dropped")
 
     mflops = 2 * c.nfullrows * c.nfullcols * a.nfullcols
     stats.record_multiply(mflops)
@@ -1288,9 +1294,25 @@ def _candidates(a, b, c, filter_eps, fr, lr, fc, lc, fk, lk):
         sym_c=c.matrix_type != NO_SYMMETRY,
         fr=fr, lr=lr, fc=fc, lc=lc, fk=fk, lk=lk,
     )
-    if res is not None:
-        return res
-    return _candidates_numpy(a, b, c, na2, nb2, row_eps, fr, lr, fc, lc, fk, lk)
+    if res is None:
+        res = _candidates_numpy(a, b, c, na2, nb2, row_eps,
+                                fr, lr, fc, lc, fk, lk)
+    if (filter_eps is not None and c.matrix_type == NO_SYMMETRY
+            and all(v is None for v in (fr, lr, fc, lc, fk, lk))):
+        # counted where the patterns alone say what the test chose
+        # from: B's blocks in row k, summed over A's blocks (i,k).  A
+        # symmetric or limited product would have to enumerate twice
+        # to know, so it is left out of both fates
+        cols_a = (a.keys % a.nblkcols).astype(np.int64)
+        structural = int(np.diff(b.row_ptr)[cols_a].sum())
+        fates = _metrics.counter(
+            "dbcsr_tpu_candidates_total",
+            "(i,k,j) candidates of filtered products (no limits, C not "
+            "symmetric) by what the norm test made of them (kept = "
+            "multiplied, pruned = dropped before any flop)")
+        fates.inc(len(res[0]), fate="kept")
+        fates.inc(structural - len(res[0]), fate="pruned")
+    return res
 
 
 def _candidates_numpy(a, b, c, na2, nb2, row_eps, fr, lr, fc, lc, fk, lk):
@@ -1481,6 +1503,14 @@ class _CachedSpans:
         return splan
 
 
+def _plan_cache_counter():
+    return _metrics.counter(
+        "dbcsr_tpu_plan_cache_total",
+        "stack-plan cache outcomes: per multiply hit, miss or "
+        "uncacheable (a filtered product with the pool off), and "
+        "evicted per entry the LRU or the byte budget pushed out")
+
+
 def _plan_cache_insert(key, entry: "_CachedSpans") -> None:
     """Insert + LRU/byte-budget eviction in O(evicted): the running
     byte counter replaces the old re-sum of every cached plan inside
@@ -1498,6 +1528,7 @@ def _plan_cache_insert(key, entry: "_CachedSpans") -> None:
     ):
         _, evicted = _plan_cache.popitem(last=False)
         _plan_cache_bytes -= evicted.nbytes
+        _plan_cache_counter().inc(result="evicted")
 
 
 def _superstack_mode() -> str:
@@ -1536,12 +1567,9 @@ def _run_stacks(c, a, b, cand_keys, a_ent, b_ent, alpha, plan_key=None,  # lint:
         cached = _plan_cache[plan_key]
         # plans heal/demote in place: keep the byte budget honest
         _plan_cache_bytes += cached.refresh_nbytes()
-    _metrics.counter(
-        "dbcsr_tpu_plan_cache_total",
-        "stack-plan cache outcomes per multiply (uncacheable = "
-        "value-dependent filtered products)",
-    ).inc(result=("hit" if cached is not None
-                  else "miss" if plan_key is not None else "uncacheable"))
+    _plan_cache_counter().inc(
+        result=("hit" if cached is not None
+                else "miss" if plan_key is not None else "uncacheable"))
     if cached is not None:
         _flight.note("plan_cache", "hit")
         # a cache hit skips prepare_stack (where decisions are noted);

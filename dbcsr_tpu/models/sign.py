@@ -16,6 +16,7 @@ from typing import Optional
 from dbcsr_tpu.acc import precision as _precision
 from dbcsr_tpu.core import mempool
 from dbcsr_tpu.core.matrix import BlockSparseMatrix
+from dbcsr_tpu.core.timings import timed
 from dbcsr_tpu.mm import incremental as _incremental
 from dbcsr_tpu.mm.multiply import multiply
 from dbcsr_tpu.obs import events as _events
@@ -41,15 +42,17 @@ def sign_step(
     with mempool.chain() as ch:
         x2 = BlockSparseMatrix("X2", x.row_blk_sizes, x.col_blk_sizes,
                                x.dtype, x.dist)
-        multiply("N", "N", 1.0, x, x, 0.0, x2, filter_eps=filter_eps)
+        flops = multiply("N", "N", 1.0, x, x, 0.0, x2, filter_eps=filter_eps)
         # T = 3I - X²  (in place on X²'s storage)
         scale(x2, -1.0)
         add_on_diag(x2, 3.0)
         out = BlockSparseMatrix("X'", x.row_blk_sizes, x.col_blk_sizes,
                                 x.dtype, x.dist)
-        multiply("N", "N", 0.5, x, x2, 0.0, out, filter_eps=filter_eps)
+        flops += multiply("N", "N", 0.5, x, x2, 0.0, out,
+                          filter_eps=filter_eps)
         ch.retire(x2)
         ch.detach(out)
+    out._last_flops = int(flops)  # true flops of the step's two products
     return out
 
 
@@ -78,6 +81,7 @@ def sign_iteration(
     # recompute on the safe engine on violation
     guard = _integrity.guard_enabled()
     history = []
+    flops = 0  # true flops of every product of the chain
     # adaptive-precision chain scope: demoted Newton–Schulz steps
     # promote to native once ||X_k - X_{k-1}||_F tightens past the
     # demoted error floor (see models/purify.py)
@@ -87,56 +91,62 @@ def sign_iteration(
     ) as psc:
         x_norm = frobenius_norm(x) if guard else None
         for step_i in range(steps):
-            reuse0 = _incremental.stats_snapshot()
-            snap = ch.snapshot(x) if guard else None
-            x_new = sign_step(x, filter_eps=filter_eps)
-            # out-of-place diff: no copy, so neither iterate is ever
-            # marked shared and both keep donating to the pool
-            diff = added(x_new, x, 1.0, -1.0, name="diff")
-            metric = frobenius_norm(diff)
-            if guard:
-                nn = frobenius_norm(x_new)
-                # ||X(3I - X²)/2||_F <= (3*sqrt(N)*||X|| + ||X||³)/2
-                # (Frobenius submultiplicativity — valid on any input)
-                limit = 0.5 * (3.0 * x.nfullrows ** 0.5 * x_norm
-                               + x_norm ** 3)
-                if not (_integrity.norm_ok(nn, limit)
-                        and math.isfinite(metric)):
-                    _integrity.record_rollback(
-                        "sign", step_i, "invariant",
-                        detail=f"norm {nn:.3e} ref {x_norm:.3e}")
-                    ch.retire(diff)
-                    ch.retire(x_new)
-                    x = ch.restore(snap)
-                    seen = {}
+            with timed("sign_step"):
+                reuse0 = _incremental.stats_snapshot()
+                snap = ch.snapshot(x) if guard else None
+                x_new = sign_step(x, filter_eps=filter_eps)
+                flops += x_new._last_flops
+                # out-of-place diff: no copy, so neither iterate is ever
+                # marked shared and both keep donating to the pool
+                diff = added(x_new, x, 1.0, -1.0, name="diff")
+                metric = frobenius_norm(diff)
+                if guard:
+                    nn = frobenius_norm(x_new)
+                    # ||X(3I - X²)/2||_F <= (3*sqrt(N)*||X|| + ||X||³)/2
+                    # (Frobenius submultiplicativity — valid on any input)
+                    limit = 0.5 * (3.0 * x.nfullrows ** 0.5 * x_norm
+                                   + x_norm ** 3)
+                    if not (_integrity.norm_ok(nn, limit)
+                            and math.isfinite(metric)):
+                        _integrity.record_rollback(
+                            "sign", step_i, "invariant",
+                            detail=f"norm {nn:.3e} ref {x_norm:.3e}")
+                        ch.retire(diff)
+                        ch.retire(x_new)
+                        x = ch.restore(snap)
+                        seen = {}
 
-                    def _build(x=x):
-                        xn = sign_step(x, filter_eps=filter_eps)
-                        return xn, added(xn, x, 1.0, -1.0, name="diff")
+                        def _build(x=x):
+                            xn = sign_step(x, filter_eps=filter_eps)
+                            return xn, added(xn, x, 1.0, -1.0, name="diff")
 
-                    def _validate(cand, limit=limit):
-                        xn, df = cand
-                        seen["metric"] = frobenius_norm(df)
-                        seen["nn"] = frobenius_norm(xn)
-                        return (_integrity.norm_ok(seen["nn"], limit)
-                                and math.isfinite(seen["metric"]))
+                        def _validate(cand, limit=limit):
+                            xn, df = cand
+                            seen["metric"] = frobenius_norm(df)
+                            seen["nn"] = frobenius_norm(xn)
+                            return (_integrity.norm_ok(seen["nn"], limit)
+                                    and math.isfinite(seen["metric"]))
 
-                    x_new, diff = _integrity.recompute_step(
-                        ch, _build, _validate, "sign", step_i,
-                        "invariant")
-                    metric, nn = seen["metric"], seen["nn"]
-                x_norm = nn
-            history.append(metric)
-            psc.observe(metric)
-            # per-iteration value-reuse fraction (delta plane)
-            _events.publish("model_reuse", dict(
-                model="sign", step=step_i,
-                **_incremental.reuse_delta(reuse0)))
-            ch.retire(diff)
-            if x is not x0:
-                ch.retire(x)
-            x = x_new
+                        x_new, diff = _integrity.recompute_step(
+                            ch, _build, _validate, "sign", step_i,
+                            "invariant")
+                        metric, nn = seen["metric"], seen["nn"]
+                        flops += x_new._last_flops  # the recomputed step
+                    x_norm = nn
+                history.append(metric)
+                psc.observe(metric)
+                # per-iteration value-reuse fraction (delta plane)
+                _events.publish("model_reuse", dict(
+                    model="sign", step=step_i,
+                    **_incremental.reuse_delta(reuse0)))
+                ch.retire(diff)
+                if x is not x0:
+                    ch.retire(x)
+                x = x_new
             if history[-1] < tol:
                 break
         ch.detach(x)
+    # what the chain did, where the mesh engine and tas/mm.py say it
+    x._last_flops = int(flops)
+    x._last_steps = len(history)
     return x, history

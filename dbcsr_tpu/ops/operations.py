@@ -25,6 +25,7 @@ from dbcsr_tpu.core.matrix import (
     BlockSparseMatrix,
     _Bin,
 )
+from dbcsr_tpu.core.timings import device_scope, timed
 from dbcsr_tpu.utils.rounding import bucket_size
 
 
@@ -98,11 +99,18 @@ def filter_matrix(matrix: BlockSparseMatrix, eps: float,
 
 
 # ------------------------------------------------------------------ scaling
+@jax.jit
+def _scale_bin(data, factor):
+    """One bin times a scalar, as a program a device trace can name."""
+    with device_scope("scale"):
+        return data * factor
+
+
 def scale(matrix: BlockSparseMatrix, factor) -> BlockSparseMatrix:
     """In-place A <- factor*A (ref `dbcsr_scale`)."""
     _require_valid(matrix)
     f = jnp.asarray(factor, dtype=matrix.dtype)
-    matrix.map_bin_data(lambda d: d * f)
+    matrix.map_bin_data(lambda d: _scale_bin(d, f))
     return matrix
 
 
@@ -283,41 +291,63 @@ def _add_checks(matrix_a, matrix_b) -> None:
         raise ValueError("mixed symmetry add not supported")
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _add_union_bin(data, terms):
+    """One bin of the union add: every (source bin, source slots,
+    destination slots, factor) of ``terms`` gathered, scaled and added
+    into the zeroed ``data``, in order.  One named program a bin, so a
+    device trace reads the union add by module."""
+    with device_scope("add_union"):
+        for src, src_slots, dst_slots, fac in terms:
+            # the slot lists are padded to a bucketed length (source
+            # slot 0 to a destination past the end): dropped here
+            data = data.at[dst_slots].add(
+                fac * jnp.take(src, src_slots, axis=0), mode="drop")
+        return data
+
+
 def _add_union(dest, matrix_a, matrix_b, alpha, beta) -> None:
     """alpha*A + beta*B on the pattern union, installed into ``dest``
     (which may BE matrix_a — the in-place `add` — or a fresh matrix —
     `added`).  Accumulation order is fixed (A's term first)."""
-    new_keys = np.union1d(matrix_a.keys, matrix_b.keys)
-    rows = (new_keys // matrix_a.nblkcols).astype(np.int64)
-    cols = (new_keys % matrix_a.nblkcols).astype(np.int64)
-    from dbcsr_tpu.core.matrix import _bin_entries
+    with timed("add_union"):
+        new_keys = np.union1d(matrix_a.keys, matrix_b.keys)
+        rows = (new_keys // matrix_a.nblkcols).astype(np.int64)
+        cols = (new_keys % matrix_a.nblkcols).astype(np.int64)
+        from dbcsr_tpu.core.matrix import _bin_entries
 
-    nb, nsl, shapes = _bin_entries(
-        matrix_a.row_blk_sizes, matrix_a.col_blk_sizes, rows, cols
-    )
-    pos_a = np.searchsorted(new_keys, matrix_a.keys)
-    pos_b = np.searchsorted(new_keys, matrix_b.keys)
-    bins = []
-    for b_id, (bm, bn) in enumerate(shapes):
-        mask = nb == b_id
-        count = int(mask.sum())
-        cap = bucket_size(count)
-        data = mempool.zeros((cap, bm, bn), matrix_a.dtype)
-        for src, pos, fac in ((matrix_a, pos_a, alpha), (matrix_b, pos_b, beta)):
-            sel = nb[pos] == b_id  # src entries landing in this bin
-            if not sel.any():
-                continue
-            src_ent = np.nonzero(sel)[0]
-            src_bin = src.ent_bin[src_ent[0]]
-            dst_slots = nsl[pos[sel]]
-            src_slots = src.ent_slot[src_ent]
-            data = data.at[mempool.upload_index("add_dst", dst_slots)].add(
-                fac * jnp.take(src.bins[src_bin].data,
-                               mempool.upload_index("add_src", src_slots),
-                               axis=0)
-            )
-        bins.append(_Bin((bm, bn), data, count))
-    dest.set_structure_from_device(new_keys, bins, binning=(nb, nsl, shapes))
+        nb, nsl, shapes = _bin_entries(
+            matrix_a.row_blk_sizes, matrix_a.col_blk_sizes, rows, cols
+        )
+        pos_a = np.searchsorted(new_keys, matrix_a.keys)
+        pos_b = np.searchsorted(new_keys, matrix_b.keys)
+        bins = []
+        for b_id, (bm, bn) in enumerate(shapes):
+            mask = nb == b_id
+            count = int(mask.sum())
+            cap = bucket_size(count)
+            terms = []
+            for src, pos, fac in ((matrix_a, pos_a, alpha), (matrix_b, pos_b, beta)):
+                sel = nb[pos] == b_id  # src entries landing in this bin
+                if not sel.any():
+                    continue
+                src_ent = np.nonzero(sel)[0]
+                src_bin = src.ent_bin[src_ent[0]]
+                # bucketed lengths: a chain's patterns move every step, and
+                # a program per exact count would compile anew in each
+                pad = bucket_size(len(src_ent)) - len(src_ent)
+                dst_slots = np.concatenate(
+                    [nsl[pos[sel]], np.full(pad, cap, nsl.dtype)])
+                src_slots = np.concatenate(
+                    [src.ent_slot[src_ent], np.zeros(pad, src.ent_slot.dtype)])
+                terms.append((src.bins[src_bin].data,
+                              mempool.upload_index("add_src", src_slots),
+                              mempool.upload_index("add_dst", dst_slots), fac))
+            data = mempool.run_donated(
+                _add_union_bin, mempool.zeros((cap, bm, bn), matrix_a.dtype),
+                tuple(terms))
+            bins.append(_Bin((bm, bn), data, count))
+        dest.set_structure_from_device(new_keys, bins, binning=(nb, nsl, shapes))
 
 
 def add(
@@ -695,7 +725,8 @@ def dot(matrix_a: BlockSparseMatrix, matrix_b: BlockSparseMatrix) -> complex:
 def frobenius_norm(matrix: BlockSparseMatrix) -> float:
     """||A||_F (ref `dbcsr_frobenius_norm`)."""
     _require_valid(matrix)
-    norms = matrix.block_norms().astype(np.float64)
+    with timed("norm_fetch"):  # the fetch waits for the device
+        norms = matrix.block_norms().astype(np.float64)
     if matrix.matrix_type == NO_SYMMETRY:
         return float(np.sqrt((norms**2).sum()))
     rows, cols = matrix.entry_coords()
@@ -726,9 +757,10 @@ def gershgorin_norm(matrix: BlockSparseMatrix) -> float:
         mask = m.ent_bin == b_id
         if not mask.any():
             continue
-        partial = np.asarray(
-            jnp.sum(jnp.abs(jnp.take(b.data, jnp.asarray(m.ent_slot[mask]), axis=0)), axis=2)
-        ).astype(np.float64)
+        with timed("norm_fetch"):  # the fetch waits for the device
+            partial = np.asarray(
+                jnp.sum(jnp.abs(jnp.take(b.data, jnp.asarray(m.ent_slot[mask]), axis=0)), axis=2)
+            ).astype(np.float64)
         for e, r in enumerate(rows[mask]):
             o = row_off[r]
             row_sums[o : o + b.shape[0]] += partial[e]

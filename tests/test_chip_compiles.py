@@ -550,3 +550,41 @@ def test_mesh_shift_and_finish_of_northstar_2x2_compile(mesh_shapes):
     assert shift.memory_analysis().temp_size_in_bytes < 2 ** 28
     assert "collective-permute" not in finish.as_text()
     assert "all-reduce" not in finish.as_text()
+
+
+def test_chain_ops_of_h2o_ls_chain_compile_as_named_programs(one_chip):
+    """The union add of X_new - X at `h2o_ls_chain`'s sizes (PR 32: X
+    holds ~18 400 blocks of 23x23, H 17 000, the union in a bucket of
+    up to 24 576), the scale and the diagonal shift: one module each, under
+    the names `layers/chain_add_s.json` globs, the union add in place
+    in its zeroed bin."""
+    import jax
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.ops import operations as ops
+
+    f64 = jnp.float64
+    with jax.enable_x64():
+        bin_ = _shape(one_chip, (24576, 23, 23), f64)
+        src = _shape(one_chip, (20480, 23, 23), f64)
+        fac = _shape(one_chip, (), f64)
+
+        def term(n):
+            return (src, _shape(one_chip, (n,), jnp.int32),
+                    _shape(one_chip, (n,), jnp.int32), fac)
+
+        add = ops._add_union_bin.lower(bin_, (term(18248), term(19457)))
+        compiled = add.compile()
+        text = compiled.as_text()
+        assert "HloModule jit__add_union_bin" in text
+        assert "add_union" in text and "input_output_alias" in text
+        # 1.66 GB of temporaries for a bin of 104 MB as values: the
+        # gathered blocks and their products in tile-padded layout
+        # (23x23 in 24x128 tiles, 5.8 times the values), as the eager
+        # ops it replaced made them one by one; PERF.md section 7
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+        scale = ops._scale_bin.lower(bin_, fac).compile().as_text()
+        assert "HloModule jit__scale_bin" in scale
+        shift = ops._add_alpha_eye.lower(
+            bin_, _shape(one_chip, (435,), jnp.int32), fac).compile()
+        assert "HloModule jit__add_alpha_eye" in shift.as_text()

@@ -18,14 +18,17 @@ _TINY = dict(n=43, block=5, occupancy=0.4, seed=3)
 
 def test_single_device_legs_pass_tiny(capsys):
     out = chip_smoke.run_legs(**_TINY, mesh=False)
-    assert set(out) == {"f64", "f64_filtered", "f32"}
+    assert set(out) == {"f64", "f64_filtered", "f32", "sign_chain"}
     for leg, res in out.items():
         assert res["leg"] == leg and len(res["steady_s"]) == 2
         assert res["driver_launches"], leg  # names the driver that ran
     assert out["f64_filtered"]["checksum"] == pytest.approx(
         out["f64"]["checksum"], rel=1e-9)
     lines = capsys.readouterr().out.splitlines()
-    assert sum(line.startswith("CHECK ") for line in lines) == 3
+    assert sum(line.startswith("CHECK ") for line in lines) == 4
+    # three steps of the sign chain, held to the benchmark's NumPy chain
+    chain = out["sign_chain"]
+    assert chain["algorithm"] == "stack" and chain["flops"] > 0
 
 
 @pytest.mark.parametrize("leg", ["mesh4", "mesh4_filtered"])
