@@ -208,22 +208,24 @@ def _note_group_gather() -> None:
     ).inc(layout=GROUP_GATHER_LAYOUT)
 
 
-def _note_group_slots(tiles: "GroupTiles") -> None:
-    """Count what one planned `xla_group` span launches: the slots that
-    hold a stack entry against the slots of the chunks its loop runs
-    (fill = live / launched; the rest gathers the zero pad row)."""
+def _note_group_slots(tiles: "GroupTiles", driver: str = "xla_group") -> None:
+    """Count what one planned `xla_group` span, or one mesh plan's
+    grouped stacks (``driver`` "mesh"), launches: the slots that hold a
+    stack entry against the slots of the chunks its loops run (fill =
+    live / launched; the rest gathers the zero pad row)."""
     slots = _metrics.counter(
         "dbcsr_tpu_stack_slots_total",
-        "slots of the xla_group spans planned: 'live' hold a stack "
-        "entry, 'launched' are gathered and multiplied (the live chunks "
-        "of every width class, pad rows included)",
+        "slots of the xla_group spans and grouped mesh stacks planned: "
+        "'live' hold a stack entry, 'launched' are gathered and "
+        "multiplied (the live chunks of every width class, pad rows "
+        "included)",
     )
     slots.inc(tiles.entries, kind="live")
     slots.inc(tiles.slots_launched, kind="launched")
     from dbcsr_tpu.core import stats
 
     stats.record_group_tiles(tiles.widths, tiles.groups, tiles.entries,
-                             tiles.slots_launched)
+                             tiles.slots_launched, driver=driver)
 
 
 def _group_idx_shapes(plan) -> tuple:
@@ -248,6 +250,65 @@ def _take_rows(rows, ids):
     return rows.at[ids].get(mode="promise_in_bounds")
 
 
+def group_chunk_loop(c, a, b, live, tiles, alpha=None, prec=None):
+    """The grouped chunk loop, the one body behind `xla_group` on one
+    chip and the mesh engine's ticks (`parallel/sparse_dist.py`):
+    ``c[gc] += alpha * A-strip @ B-strip`` for every group the tiles
+    name, into the carried ``c`` (its own accumulate dtype's panel or
+    bin), and nothing else of ``c`` touched.
+
+    ``a`` (Na, m, k) and ``b`` (Nb, k, n) are block arrays whose rows
+    the tiles' ids name; every id lies inside them and the pad ids name
+    an all-zero block (`build_group_tiles`).  ``tiles`` holds one
+    ``(ga, gb, gc)`` triple per width class, widest first: ``ga``/``gb``
+    (nchunks, CH_w, w) gather ids, ``gc`` (nchunks, CH_w) C rows, an id
+    past ``c`` for a dead group (dropped).  ``live`` is the number of
+    chunks that hold a group, a device scalar: the arrays' extent is
+    bucketed (the program's shapes hold still while the pattern moves)
+    and the chunks past ``live`` are never read.  ``alpha`` None leaves
+    the products unscaled (the mesh scales its finished panel).
+
+    Once per call A and B become `_block_rows`; then ONE loop carries
+    ``c`` whatever the number of classes; a step gathers, multiplies
+    and adds chunk t of every class, widest first, under the
+    `stk_gather` / `stk_dot` / `stk_accum` scopes, the loop itself
+    under `stk_loop`.  `_stack_phases_xla_group` says why each form is
+    what it is."""
+    live = jnp.reshape(live, ())
+    _, m, n = c.shape
+    k = a.shape[2]
+    with device_scope("stk_gather"):
+        a_rows = _block_rows(a)
+        b_rows = _block_rows(b)
+    acc = _accum_dtype(c.dtype)
+
+    def body(t, c):
+        for ga, gb, gc in tiles:
+            _, ch, w = ga.shape
+            with device_scope("stk_gather"):
+                ia = jax.lax.dynamic_index_in_dim(ga, t, keepdims=False)
+                ib = jax.lax.dynamic_index_in_dim(gb, t, keepdims=False)
+                ic = jax.lax.dynamic_index_in_dim(gc, t, keepdims=False)
+                ablk = _take_rows(a_rows, ia.reshape(-1)).reshape(ch, w, m, k)
+                bblk = _take_rows(b_rows, ib.reshape(-1))
+                amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, w * k)
+                bmat = bblk.reshape(ch, w * k, n)
+                ragged = -(w * k) % 8
+                if ragged:  # zeros up to whole sublanes
+                    amat = jnp.pad(amat, ((0, 0), (0, 0), (0, ragged)))
+                    bmat = jnp.pad(bmat, ((0, 0), (0, ragged), (0, 0)))
+            with device_scope("stk_dot"):
+                prod = _batch_dot(amat, bmat, acc, prec)
+                if alpha is not None:
+                    prod = alpha.astype(acc) * prod
+                prod = prod.astype(c.dtype)
+            c = _accumulate_chunk(c, prod, ic)
+        return c
+
+    with device_scope("stk_loop"):
+        return jax.lax.fori_loop(0, live, body, c)
+
+
 def _stack_phases_xla_group(c_data, a_data, b_data, live, *tiles_alpha,
                             prec=None):
     """R-tiled ("k-merged") stack layout: entries sharing a C block are
@@ -265,15 +326,9 @@ def _stack_phases_xla_group(c_data, a_data, b_data, live, *tiles_alpha,
     PERF.md, PR 21).
 
     ``tiles_alpha`` is what `build_group_tiles` planned, flattened, and
-    alpha: one ``(ga, gb, gc)`` triple per width class, widest (R0)
-    first, ``ga``/``gb`` (nchunks, CH_w, w) gather ids padded with a
-    guaranteed-zero row id, ``gc`` (nchunks, CH_w) segment ids with
-    nseg for dead groups (dropped).  ``live`` is the number of chunks
-    that hold a group, a device scalar: the arrays' extent is bucketed
-    (the program's shapes hold still while the pattern moves) and the
-    chunks past ``live`` are never read.  ONE loop carries the bin
-    whatever the number of classes; a step gathers, multiplies and adds
-    chunk t of every class, widest first.  A C block's groups all lie
+    alpha; ``live`` the plan's live chunk count; the loop is
+    `group_chunk_loop`, which the mesh engine's ticks run too (PR 33).
+    A C block's groups all lie
     in one class in stack order, so its products are added in stack
     order, full groups first and the remainder of its run last
     (deterministic: the order is the plan's).  A strip whose depth w*k
@@ -293,37 +348,7 @@ def _stack_phases_xla_group(c_data, a_data, b_data, live, *tiles_alpha,
     """
     *flat, alpha = tiles_alpha
     tiles = [flat[i:i + 3] for i in range(0, len(flat), 3)]
-    live = jnp.reshape(live, ())
-    _, m, n = c_data.shape
-    k = a_data.shape[2]
-    with device_scope("stk_gather"):
-        a_rows = _block_rows(a_data)
-        b_rows = _block_rows(b_data)
-    acc = _accum_dtype(c_data.dtype)
-
-    def body(t, c):
-        for ga, gb, gc in tiles:
-            _, ch, w = ga.shape
-            with device_scope("stk_gather"):
-                ia = jax.lax.dynamic_index_in_dim(ga, t, keepdims=False)
-                ib = jax.lax.dynamic_index_in_dim(gb, t, keepdims=False)
-                ic = jax.lax.dynamic_index_in_dim(gc, t, keepdims=False)
-                ablk = _take_rows(a_rows, ia.reshape(-1)).reshape(ch, w, m, k)
-                bblk = _take_rows(b_rows, ib.reshape(-1))
-                amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, w * k)
-                bmat = bblk.reshape(ch, w * k, n)
-                ragged = -(w * k) % 8
-                if ragged:  # zeros up to whole sublanes (see docstring)
-                    amat = jnp.pad(amat, ((0, 0), (0, 0), (0, ragged)))
-                    bmat = jnp.pad(bmat, ((0, 0), (0, ragged), (0, 0)))
-            with device_scope("stk_dot"):
-                prod = _batch_dot(amat, bmat, acc, prec)
-                prod = (alpha.astype(acc) * prod).astype(c.dtype)
-            c = _accumulate_chunk(c, prod, ic)
-        return c
-
-    with device_scope("stk_loop"):
-        return jax.lax.fori_loop(0, live, body, c_data)
+    return group_chunk_loop(c_data, a_data, b_data, live, tiles, alpha, prec)
 
 
 _process_stack_xla_group = functools.partial(
@@ -350,23 +375,26 @@ def group_chunk_groups(r0: int, m: int, n: int, k: int, itemsize: int,
 
 
 class GroupTiles(NamedTuple):
-    """What `build_group_tiles` plans for one stack."""
+    """What `build_group_tiles` plans for one stack, or
+    `build_stacks_group_tiles` for several of one shape (then ``live``
+    is an array, one count a stack, and every tile array has the
+    stacks as its leading dimension)."""
 
-    live: int      # chunks that hold a group; past it: bucket slack
+    live: object   # chunks that hold a group; past it: bucket slack
     tiles: tuple   # per width class, widest first: (ga, gb, gc) host arrays
     groups: tuple  # per class: groups that hold an entry
     entries: int   # stack entries = slots that hold one
 
     @property
     def widths(self) -> tuple:
-        return tuple(ga.shape[2] for ga, _, _ in self.tiles)
+        return tuple(ga.shape[-1] for ga, _, _ in self.tiles)
 
     @property
     def slots_launched(self) -> int:
-        """Slots of the chunks the loop runs: what is gathered and
+        """Slots of the chunks the loops run: what is gathered and
         multiplied, live or pad row."""
-        return self.live * sum(ga.shape[1] * ga.shape[2]
-                               for ga, _, _ in self.tiles)
+        return int(np.sum(self.live)) * sum(ga.shape[-2] * ga.shape[-1]
+                                            for ga, _, _ in self.tiles)
 
     def flat(self) -> list:
         return [x for tile in self.tiles for x in tile]
@@ -408,37 +436,69 @@ def _group_widths(short_hist, other_slots: int, r0: int) -> list:
     return sorted(widths, reverse=True)
 
 
-def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
-                      c_pad: int, chunk_groups: int) -> GroupTiles:
-    """Host side of the grouped layout: tile each C segment's run of
-    entries into groups and the groups into the chunks of the body's
-    loop, so that what is launched is what holds entries.
+def _class_chunk_caps(counts, widths, r0: int, chunk_groups: int) -> list:
+    """``CH_w``, the groups one chunk holds of each width class, from
+    the groups the fullest stack has of each (``counts``).  It follows
+    the class's share of the slots in coarse steps and not its count,
+    so a pattern that grows keeps its shapes and takes more chunks
+    (``chunk_groups`` x r0 slots a chunk; a stack that fills no chunk
+    gets one of its own bucketed size)."""
+    slots = sum(cnt * w for cnt, w in zip(counts, widths))
+    if slots <= chunk_groups * r0:
+        # one chunk holds the stack: its size is the stack's, bucketed
+        return [bucket_size(cnt) for cnt in counts]
+    if tuple(widths) == (r0,):
+        return [chunk_groups]
+    step = max(1, 1 << max((chunk_groups // 16).bit_length() - 1, 0))
+    per_chunk = chunk_groups * r0 / slots
+    return [-(-int(np.ceil(cnt * per_chunk)) // step) * step
+            for cnt in counts]
+
+
+def build_stacks_group_tiles(stack_of, nstacks: int, c_idx, a_idx, b_idx,
+                             r0: int, a_pad: int, b_pad: int, c_pad: int,
+                             chunk_groups: int) -> GroupTiles:
+    """Host side of the grouped layout, for ``nstacks`` stacks that one
+    program runs (one stack on one chip; one a (device, tick) on a
+    mesh, where an SPMD program needs the same shapes everywhere):
+    tile each C segment's run of entries into groups and the groups
+    into the chunks of `group_chunk_loop`, so that what is launched is
+    what holds entries.  Entry e belongs to stack ``stack_of[e]``; the
+    entries come sorted by (stack, C segment).
 
     A run of more than ``r0`` entries (the tuned width: 6.3 GFLOP/s at
     r0 = 8 on a v5e, PR 21, on runs of mean 8) is cut into groups of r0,
     the last one filled up with zero-row ids.  A run of at most r0 is
     ONE group of the narrowest width class that holds it.  The classes
-    come from the stack's own run lengths (`_group_widths`): runs that
-    fill r0 give one class and the arrays this function always gave;
-    the north star's runs of mean 4.4 give three (8, 4, 2) and launch
-    0.65 of the slots.  All groups of a C block lie in one class, in
-    stack order.
+    come from the run lengths of all the stacks together
+    (`_group_widths`): runs that fill r0 give one class; the north
+    star's runs of mean 4.4 give three (8, 4, 2) and launch 0.65 of the
+    slots; half its k range a tick, as on the 2x2 grid, gives runs of
+    mean 2.5 and a fourth class, 1.  All groups of a C block lie in one
+    class, in stack order.
 
-    Each class holds its groups sorted by C block, cut into chunks of
-    ``CH_w`` groups; chunk t of every class is one step of the body's
-    loop.  ``CH_w`` follows the class's share of the slots in coarse
-    steps and not its count, so a pattern that grows keeps its shapes
-    and takes more chunks (``chunk_groups`` x r0 slots a chunk; a stack
-    that fills no chunk gets one of its own bucketed size).  Per class
-    the result holds (nchunks, CH_w, w) a/b gather arrays and
-    (nchunks, CH_w) segment ids, nchunks the bucketed count of chunks
-    and ``live`` the chunks that hold a group.  Every a/b id lies in
-    ``[0, a_pad]`` / ``[0, b_pad]``: the body's gathers promise the
-    compiler that and check nothing.  ``c_idx`` must be sorted
-    ascending; dead groups carry segment id ``c_pad`` (= nseg) after
-    the live ones, keeping ids sorted and dropped by the scatter-add."""
+    Each class holds a stack's groups sorted by C block, cut into
+    chunks of ``CH_w`` groups (`_class_chunk_caps`, from the fullest
+    stack's counts); chunk t of every class is one step of the body's
+    loop.  Per class the result holds (nstacks, nchunks, CH_w, w) a/b
+    gather arrays and (nstacks, nchunks, CH_w) segment ids, nchunks the
+    bucketed count of chunks the fullest stack takes, and ``live``
+    (nstacks,) the chunks of each stack that hold a group: 0 for a
+    stack with no entry, whose loop runs no step.  Every a/b id lies in
+    ``[0, a_pad]`` / ``[0, b_pad]`` where the entries' do: the body's
+    gathers promise the compiler that and check nothing.  Dead groups
+    carry segment id ``c_pad`` (= nseg) after the live ones, keeping
+    ids sorted and dropped by the scatter-add."""
     s = len(c_idx)
-    seg_starts = np.concatenate([[0], np.nonzero(np.diff(c_idx))[0] + 1])
+    if s == 0:  # no stack runs a step: one dead chunk of the widest class
+        def dead(pad, *shape):
+            return np.full((nstacks, 1, bucket_size(1)) + shape, pad, np.int32)
+        return GroupTiles(np.zeros(nstacks, np.int64),
+                          ((dead(a_pad, r0), dead(b_pad, r0), dead(c_pad)),),
+                          (0,), 0)
+    new_run = np.ones(s, bool)
+    new_run[1:] = (c_idx[1:] != c_idx[:-1]) | (stack_of[1:] != stack_of[:-1])
+    seg_starts = np.nonzero(new_run)[0]
     seg_len = np.diff(np.append(seg_starts, s))
     run_groups = -(-seg_len // r0)
     short = run_groups == 1
@@ -446,50 +506,62 @@ def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
                            int(run_groups[~short].sum()) * r0, r0)
     run_width = np.where(
         short, _narrowest_fit(widths, r0)[np.minimum(seg_len, r0)], r0)
-    # groups in stack order; a group's row among its class's groups
+    # groups in (stack, stack order); a group's row among its class's
     group_base = np.cumsum(run_groups) - run_groups
     width_of = np.repeat(run_width, run_groups)
     c_of = np.repeat(c_idx[seg_starts], run_groups)
+    stack_of_group = np.repeat(stack_of[seg_starts], run_groups)
     members = [np.nonzero(width_of == w)[0] for w in widths]
     widths, members = zip(*[(w, mem) for w, mem in zip(widths, members)
                             if len(mem)])  # r0 itself may hold nothing
+    per_stack = [np.bincount(stack_of_group[mem], minlength=nstacks)
+                 for mem in members]
     counts = [len(mem) for mem in members]
-    slots = sum(cnt * w for cnt, w in zip(counts, widths))
-    if slots <= chunk_groups * r0:
-        # one chunk holds the stack: its size is the stack's, bucketed
-        caps = [bucket_size(cnt) for cnt in counts]
-    elif widths == (r0,):
-        caps = [chunk_groups]
-    else:
-        step = max(1, 1 << max((chunk_groups // 16).bit_length() - 1, 0))
-        per_chunk = chunk_groups * r0 / slots
-        caps = [-(-int(np.ceil(cnt * per_chunk)) // step) * step
-                for cnt in counts]
-    live = max(-(-cnt // cap) for cnt, cap in zip(counts, caps))
-    nchunks = bucket_size(live, minimum=1)
+    caps = _class_chunk_caps([int(n.max()) for n in per_stack], widths, r0,
+                             chunk_groups)
+    live = np.max([-(-n // cap) for n, cap in zip(per_stack, caps)], axis=0)
+    nchunks = bucket_size(int(live.max()), minimum=1)
     # every class's rows in one buffer, so the ids are written once
-    rows = [nchunks * cap for cap in caps]
-    row_base = np.cumsum([0] + rows)
-    slot_base = np.cumsum([0] + [r * w for r, w in zip(rows, widths)])
+    rows = [nchunks * cap for cap in caps]  # of one stack
+    row_base = np.cumsum([0] + [nstacks * r for r in rows])
+    slot_base = np.cumsum([0] + [nstacks * r * w
+                                 for r, w in zip(rows, widths)])
     row_of = np.empty(len(width_of), np.int64)   # a group's row among all
     slot0_of = np.empty(len(width_of), np.int64)  # its first slot among all
-    for i, (w, mem) in enumerate(zip(widths, members)):
-        row_of[mem] = row_base[i] + np.arange(len(mem))
-        slot0_of[mem] = slot_base[i] + np.arange(len(mem)) * w
-    off_in_seg = np.arange(s) - np.repeat(seg_starts, seg_len)
-    gidx = np.repeat(group_base, seg_len) + off_in_seg // r0
-    dest = slot0_of[gidx] + off_in_seg % r0
+    for i, (w, mem, n) in enumerate(zip(widths, members, per_stack)):
+        stk = stack_of_group[mem]
+        row = stk * rows[i] + np.arange(len(mem)) - (np.cumsum(n) - n)[stk]
+        row_of[mem] = row_base[i] + row
+        slot0_of[mem] = slot_base[i] + row * w
+    # a run's groups are rows on end of one class and stack, so its
+    # entries fill consecutive slots from its first group's first
+    dest = np.arange(s) + np.repeat(slot0_of[group_base] - seg_starts,
+                                    seg_len)
     ga = np.full(slot_base[-1], a_pad, np.int32)
     gb = np.full(slot_base[-1], b_pad, np.int32)
     gc = np.full(row_base[-1], c_pad, np.int32)
     ga[dest] = a_idx
     gb[dest] = b_idx
     gc[row_of] = c_of
-    tiles = [(ga[slot_base[i]:slot_base[i + 1]].reshape(nchunks, cap, w),
-              gb[slot_base[i]:slot_base[i + 1]].reshape(nchunks, cap, w),
-              gc[row_base[i]:row_base[i + 1]].reshape(nchunks, cap))
-             for i, (w, cap) in enumerate(zip(widths, caps))]
+    tiles = [
+        (ga[slot_base[i]:slot_base[i + 1]].reshape(nstacks, nchunks, cap, w),
+         gb[slot_base[i]:slot_base[i + 1]].reshape(nstacks, nchunks, cap, w),
+         gc[row_base[i]:row_base[i + 1]].reshape(nstacks, nchunks, cap))
+        for i, (w, cap) in enumerate(zip(widths, caps))]
     return GroupTiles(live, tuple(tiles), tuple(counts), s)
+
+
+def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
+                      c_pad: int, chunk_groups: int) -> GroupTiles:
+    """`build_stacks_group_tiles` for ONE stack (``c_idx`` sorted
+    ascending): (nchunks, CH_w, w) / (nchunks, CH_w) arrays per class
+    and ``live`` a number."""
+    many = build_stacks_group_tiles(
+        np.zeros(len(c_idx), np.int32), 1, c_idx, a_idx, b_idx, r0,
+        a_pad, b_pad, c_pad, chunk_groups)
+    return GroupTiles(int(many.live[0]),
+                      tuple(tuple(x[0] for x in tile) for tile in many.tiles),
+                      many.groups, many.entries)
 
 
 def _stack_phases_xla(c_data, a_data, b_data, a_idx, b_idx, c_idx, alpha,

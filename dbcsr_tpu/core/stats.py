@@ -111,14 +111,15 @@ def record_driver(driver: str, flops: int, *, nbytes: int = 0,
 
 
 def record_group_tiles(widths, groups, slots_live: int,
-                       slots_launched: int) -> None:
-    """One planned `xla_group` span: its width classes with the groups
-    of each, and the slots it fills of those it launches."""
+                       slots_launched: int, driver: str = "xla_group") -> None:
+    """One planned `xla_group` span (or, ``driver`` "mesh", one mesh
+    plan's grouped stacks): its width classes with the groups of each,
+    and the slots it fills of those it launches."""
     from dbcsr_tpu.core.config import get_config
 
     if not get_config().keep_stats:
         return
-    agg = _driver_agg["xla_group"]
+    agg = _driver_agg[driver]
     agg.slots_live += slots_live
     agg.slots_launched += slots_launched
     for w, n in zip(widths, groups):

@@ -16,11 +16,13 @@ How the reference's machinery maps here:
 * hash-based C-index build + stack fill (`dbcsr_mm_csr.F:178`) -> the
   full symbolic product on host (vectorized / native engine), carved
   into one parameter stack per (device, tick), padded to a common
-  static length; padded entries point at C slot `cap_c` and are
-  dropped by the segment-sum.
-* per-thread multrec/stacks -> one gather + batched-matmul +
-  segment-sum per tick per device (the same kernel shape as
-  `dbcsr_tpu.acc.smm`).
+  static shape; padded entries point at C slot `cap_c` and are
+  dropped by the accumulation.
+* per-thread multrec/stacks -> per tick per device the stack programs
+  of `dbcsr_tpu.acc.smm`: for emulated dtypes (`_stack_r0`) its
+  grouped chunk loop on tiles planned by its rules, the very function
+  one chip's `xla_group` runs; else one gather + batched-matmul +
+  segment-sum.
 * 2.5D layers (`dbcsr_mm_3d.F`) -> the 'kl' mesh axis partitions the
   k block range; one `psum` over 'kl' completes C
   (ref `make_layers_3D_C_reduction`, `dbcsr_mm_3d.F:1037`).
@@ -127,20 +129,23 @@ def _prepare_operands(matrix_a, matrix_b, matrix_c):
 
 
 def _fill_stacks(group_id, st_a, st_b, st_c, nslots, cap_c, r0=0,
-                 pad_a=0, pad_b=0):
+                 pad_a=0, pad_b=0, chunk_groups=0):
     """Sort stack entries by (slot-group, C slot, A slot) and scatter
     into a (nslots, s_cap, 3) array whose padding rows target the
     dropped segment cap_c.  Shared by the ungrouped and grouped Cannon
-    assemblies (the host-side analog of `dbcsr_mm_accdrv.F:364-423`
-    stack sort/binning).
+    assemblies and the all-gather one (the host-side analog of
+    `dbcsr_mm_accdrv.F:364-423` stack sort/binning).
 
-    ``r0 > 0`` emits the R-tiled layout instead (the mesh sibling of
-    `acc/smm.py:_process_stack_xla_group`): each C slot's entries are
-    tiled into runs of r0 and packed as (nslots, G_cap, 2*r0+1) rows
-    ``[a_0..a_{r0-1}, b_0..b_{r0-1}, c]``; in-tile pads reference the
-    guaranteed-zero panel rows ``pad_a``/``pad_b`` (their product is 0
-    and MAY land in a live segment), dead tiles target segment cap_c.
-    """
+    ``r0 > 0`` plans the grouped layout instead, with the one-chip
+    engine's rules and code (`acc/smm.py:build_stacks_group_tiles`, one
+    stack a (device, tick)): width classes from the run lengths of all
+    the stacks together, a run of at most r0 one group of the narrowest
+    class that holds it, ``chunk_groups`` x r0 slots a chunk, every
+    class's chunk capacity and the chunk count from the fullest stack
+    so that one SPMD program serves every device and tick, and ``live``
+    chunks per stack.  In-group pads name the guaranteed-zero panel
+    rows ``pad_a``/``pad_b``, dead groups the dropped segment cap_c.
+    Returns the `GroupTiles`."""
     from dbcsr_tpu import native
 
     order = native.sort_order(group_id, nslots, st_c, st_a)
@@ -148,37 +153,11 @@ def _fill_stacks(group_id, st_a, st_b, st_c, nslots, cap_c, r0=0,
         group_id[order], st_a[order], st_b[order], st_c[order]
     )
     if r0:
-        n = len(group_id)
-        width = 2 * r0 + 1
-        if n == 0:
-            out = np.empty((nslots, 1, width), np.int32)
-            out[:, :, :r0] = pad_a
-            out[:, :, r0:2 * r0] = pad_b
-            out[:, :, 2 * r0] = cap_c
-            return out
-        same = (group_id[1:] == group_id[:-1]) & (st_c[1:] == st_c[:-1])
-        seg_id = np.concatenate([[0], np.cumsum(~same)])
-        seg_first = np.concatenate([[0], np.nonzero(~same)[0] + 1])
-        off = np.arange(n) - seg_first[seg_id]
-        new_tile = np.ones(n, bool)
-        new_tile[1:] = ~same | (off[1:] % r0 == 0)
-        tile_id = np.cumsum(new_tile) - 1
-        first_of_tile = np.nonzero(new_tile)[0]
-        tile_g = group_id[first_of_tile]
-        counts = np.bincount(tile_g, minlength=nslots)
-        g_cap = bucket_size(max(int(counts.max()), 1))
-        starts = np.concatenate([[0], np.cumsum(counts)])[:-1]
-        tile_pos = np.arange(len(first_of_tile)) - starts[tile_g]
-        out = np.empty((nslots, g_cap, width), np.int32)
-        out[:, :, :r0] = pad_a
-        out[:, :, r0:2 * r0] = pad_b
-        out[:, :, 2 * r0] = cap_c
-        sl = off % r0
-        pos_e = tile_pos[tile_id]
-        out[group_id, pos_e, sl] = st_a
-        out[group_id, pos_e, r0 + sl] = st_b
-        out[tile_g, tile_pos, 2 * r0] = st_c[first_of_tile]
-        return out
+        from dbcsr_tpu.acc.smm import build_stacks_group_tiles
+
+        return build_stacks_group_tiles(
+            group_id, nslots, st_c, st_a, st_b, r0, pad_a, pad_b, cap_c,
+            chunk_groups)
     counts = np.bincount(group_id, minlength=nslots)
     s_cap = bucket_size(max(int(counts.max()), 1) if len(counts) else 1)
     stacks = np.zeros((nslots, s_cap, 3), np.int32)
@@ -192,13 +171,52 @@ def _fill_stacks(group_id, st_a, st_b, st_c, nslots, cap_c, r0=0,
     return stacks
 
 
+def _upload_stacks(stacks, mesh, lead: tuple):
+    """The filled stacks of a plan on the mesh, every leaf under
+    P("kl","pr","pc") with ``lead`` = (kl, pr, pc, nticks) in front:
+    the flat (.., s_cap, 3) array, or for grouped stacks the pytree
+    ``(live, ((ga, gb, gc) per width class))`` whose per-tick slice
+    `acc/smm.py:group_chunk_loop` takes (``live`` one chunk count a
+    device and tick).  A grouped plan's live and launched slots are
+    counted here, once per built plan."""
+    if isinstance(stacks, np.ndarray):
+        host = stacks.reshape(lead + stacks.shape[1:])
+    else:
+        from dbcsr_tpu.acc.smm import _note_group_slots
+
+        _note_group_slots(stacks, driver="mesh")
+        host = (stacks.live.astype(np.int32).reshape(lead),
+                tuple(tuple(x.reshape(lead + x.shape[1:]) for x in tile)
+                      for tile in stacks.tiles))
+    return jax.device_put(host, NamedSharding(mesh, P("kl", "pr", "pc")))
+
+
+def _stacks_nbytes(stacks_dev) -> int:
+    return sum(int(x.nbytes) for x in jax.tree.leaves(stacks_dev))
+
+
+def _stack_chunk_groups(r0: int, bm: int, bn: int, bk: int, dtype) -> int:
+    """Groups of r0 a chunk of the grouped ticks holds: the one-chip
+    engine's `group_chunk_groups` (2 048 slots at 23^3 in f64)."""
+    from dbcsr_tpu.acc.smm import group_chunk_groups
+    from dbcsr_tpu.core.config import get_config
+
+    if not r0:
+        return 0
+    return group_chunk_groups(r0, bm, bn, bk, np.dtype(dtype).itemsize,
+                              get_config().mm_stack_size)
+
+
 def _stack_r0(dtype) -> int:
-    """R-tiling factor for the mesh stacks: group emulated dtypes
-    (f64/c128 — per-entry dots are MXU-starved under emulation, see
-    `acc/smm.py:_process_stack_xla_group`).  Auto mode applies this on
-    TPU only (f64 is native elsewhere; per-entry dots are fine there);
-    mm_driver='xla_group' forces it on any platform (how the CPU-mesh
-    tests cover the tiled layout)."""
+    """The widest group of the mesh stacks, and the one gate between
+    the grouped ticks (r0 > 0: `acc/smm.py`'s tiling rules and its
+    `group_chunk_loop`, the program `xla_group` runs on one chip) and
+    the flat ones (0: per-entry gathers and a segment sum): group
+    emulated dtypes (f64/c128 — per-entry dots are MXU-starved under
+    emulation, see `acc/smm.py:_stack_phases_xla_group`).  Auto mode
+    applies this on TPU only (f64 is native elsewhere; per-entry dots
+    are fine there); mm_driver='xla_group' forces it on any platform
+    (how the CPU-mesh tests cover the grouped layout)."""
     from dbcsr_tpu.acc.smm import emulated_dtype_on_tpu
     from dbcsr_tpu.core.config import get_config
 
@@ -213,10 +231,11 @@ def _stack_r0(dtype) -> int:
 _TICK_CHUNK_ENTRIES = 32768
 
 
-def _tick_chunks(s_cap: int, r0: int) -> tuple:
-    """(nchunk, rows_per_chunk) bounding per-tick gather/product temps
-    to ~`_TICK_CHUNK_ENTRIES` entry-equivalents (R-tiled rows count as
-    r0 entries each).  Small grids concentrate the whole product in ONE
+def _tick_chunks(s_cap: int) -> tuple:
+    """(nchunk, rows_per_chunk) of a FLAT tick (r0 = 0; a grouped
+    tick's chunks are the plan's, `acc/smm.py:group_chunk_groups`),
+    bounding per-tick gather/product temps to ~`_TICK_CHUNK_ENTRIES`
+    entries.  Small grids concentrate the whole product in ONE
     tick (a 1x1 grid: everything), and an unchunked tick materializes
     (E, bm, bn) gather/product temps — 3 x 3.5 GB f64 at the north
     star, which thrashes memory (measured: a 1x1x1 CPU-mesh rep ran 7x
@@ -224,7 +243,7 @@ def _tick_chunks(s_cap: int, r0: int) -> tuple:
     single-chip path chunks at mm_stack_size for exactly this reason).
     `bucket_size` capacities are {4..7}*2^k, so the power-of-two chunk
     count always divides s_cap exactly (no tail, no re-read)."""
-    target = max(1, _TICK_CHUNK_ENTRIES // max(r0, 1))
+    target = _TICK_CHUNK_ENTRIES
     nchunk = 1
     while s_cap // nchunk > target and s_cap % (nchunk * 2) == 0:
         nchunk *= 2
@@ -240,32 +259,15 @@ def _ring_perms(s: int) -> tuple:
             tuple(((i + 1) % s, i) for i in range(s)))
 
 
-def _stack_contrib(a, b, c, entries, *, r0, cap_c, acc_dtype):
-    """One stack chunk's contribution: gather → batched matmul →
-    sorted segment-sum.  ONE implementation shared by the fused
-    metronome body (`_cannon_tick_loop`) and the split per-tick
-    program (`_stack_mesh_tick`) so the two execution modes are
-    bitwise identical by construction.
-
-    The three phases carry the one-chip bodies' `device_scope` names
-    (`acc/smm.py`), so a device trace splits a mesh program's time the
-    way it splits `jit_fused_superstack`'s.  Every jitted program that
-    traces this body is named `_stack_*` for the reason given there: a
-    program whose scopes change needs a new name, or a compile cache
-    written before them answers with the scopeless executable."""
-    bm, bk, bn = a.shape[1], a.shape[2], b.shape[2]
+def _stack_contrib(a, b, c, entries, *, cap_c, acc_dtype):
+    """One FLAT stack chunk's contribution (r0 = 0, the dtypes a mesh
+    multiplies natively; no cell runs it): per-entry gather → batched
+    matmul → sorted segment-sum, under the one-chip bodies'
+    `device_scope` names (`acc/smm.py`)."""
     with device_scope("stk_gather"):
-        if r0:
-            ia = entries[:, :r0]
-            ib = entries[:, r0:2 * r0]
-            ic = entries[:, 2 * r0]
-            pa = jnp.take(a, ia.reshape(-1), axis=0).reshape(-1, r0, bm, bk)
-            pa = jnp.swapaxes(pa, 1, 2).reshape(-1, bm, r0 * bk)
-            pb = jnp.take(b, ib.reshape(-1), axis=0).reshape(-1, r0 * bk, bn)
-        else:
-            pa = jnp.take(a, entries[:, 0], axis=0)
-            pb = jnp.take(b, entries[:, 1], axis=0)
-            ic = entries[:, 2]
+        pa = jnp.take(a, entries[:, 0], axis=0)
+        pb = jnp.take(b, entries[:, 1], axis=0)
+        ic = entries[:, 2]
     with device_scope("stk_dot"):
         prod = jax.lax.dot_general(
             pa, pb, (((2,), (1,)), ((0,), (0,))),
@@ -279,32 +281,69 @@ def _stack_contrib(a, b, c, entries, *, r0, cap_c, acc_dtype):
         )
 
 
+def _local_stacks(st):
+    """A device's own part of the sharded stacks: every leaf without
+    its (kl, pr, pc) lead, so with the ticks in front."""
+    return jax.tree.map(lambda x: x.reshape(x.shape[3:]), st)
+
+
+def _stack_of_tick(st, t):
+    """Tick ``t``'s stack of a device's local stacks."""
+    return jax.tree.map(
+        lambda x: jax.lax.dynamic_index_in_dim(x, t, axis=0, keepdims=False),
+        st)
+
+
 def _tick_contrib_chunked(a, b, c, st_tick, *, r0, cap_c, acc_dtype):
-    """One tick's full contribution, run in `_tick_chunks` sub-chunks
-    (same chunk decomposition in both execution modes)."""
-    nchunk, rows = _tick_chunks(st_tick.shape[0], r0)
+    """One tick's full contribution into the device's C panel: ONE
+    implementation shared by the fused metronome body
+    (`_cannon_tick_loop`) and the split per-tick programs
+    (`_stack_mesh_tick`, `_stack_gather_tick`, `_stack_grouped_tick`),
+    so the two execution modes are bitwise identical by construction.
+
+    Grouped (r0 > 0): the one-chip engine's `acc/smm.py:group_chunk_loop`
+    on the local panels, whose last row is the all-zero block the
+    tiles' pad ids name: whole-block row gathers, one dot a width
+    class, the in-place sorted scatter-add, in one loop bounded by this
+    device's and tick's own ``live`` chunk count (a sharded scalar; no
+    collective sits inside the loop, so the count may differ by
+    device).  Flat (r0 = 0): `_stack_contrib` in `_tick_chunks`
+    sub-chunks.
+
+    Every jitted program that traces this body is named `_stack_*`: the
+    phases carry the one-chip bodies' `device_scope` names, a device
+    trace splits a mesh program's time the way it splits
+    `jit_fused_superstack`'s, and a program whose scopes change needs a
+    new name, or a compile cache written before them answers with the
+    scopeless executable (`acc/smm.py`)."""
+    if r0:
+        from dbcsr_tpu.acc.smm import group_chunk_loop
+
+        live, tiles = st_tick
+        return group_chunk_loop(c, a, b, live, tiles)
+    nchunk, rows = _tick_chunks(st_tick.shape[0])
     if nchunk > 1:
         st_t = st_tick.reshape(nchunk, rows, st_tick.shape[1])
         return jax.lax.fori_loop(
             0, nchunk,
-            lambda j, cc: _stack_contrib(a, b, cc, st_t[j], r0=r0,
-                                         cap_c=cap_c, acc_dtype=acc_dtype),
+            lambda j, cc: _stack_contrib(a, b, cc, st_t[j], cap_c=cap_c,
+                                         acc_dtype=acc_dtype),
             c,
         )
-    return _stack_contrib(a, b, c, st_tick, r0=r0, cap_c=cap_c,
-                          acc_dtype=acc_dtype)
+    return _stack_contrib(a, b, c, st_tick, cap_c=cap_c, acc_dtype=acc_dtype)
 
 
 def _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=0, nticks=None):
-    """The shared Cannon metronome: ticks of gather → batched matmul →
-    sorted segment-sum, ring-shifting A along 'pc' and B along 'pr'
-    (ref the grouped_k_index loop, `dbcsr_mm_cannon.F:1345`).
-    ``r0 > 0``: R-tiled stacks (k-merged dots, `_fill_stacks` layout).
+    """The shared Cannon metronome: ticks of `_tick_contrib_chunked`,
+    ring-shifting A along 'pc' and B along 'pr' between them, outside
+    the chunk loop (ref the grouped_k_index loop,
+    `dbcsr_mm_cannon.F:1345`).  ``st`` is the device's local stacks
+    (`_local_stacks`); ``r0 > 0``: grouped stacks (`_fill_stacks`).
     ``s == 0`` disables the ring shifts (the all-gather engine's chunk
     loop: operands already complete, ticks bound peak memory only);
     ``nticks`` overrides the tick count (defaults to s).  Each tick's
-    stack additionally runs in `_tick_chunks` sub-chunks so peak temp
-    memory stays bounded no matter how much product one tick carries."""
+    stack runs in chunks so peak temp memory stays bounded no matter
+    how much product one tick carries."""
     bm, bn = a.shape[1], b.shape[2]
     c = jnp.zeros((cap_c, bm, bn), acc_dtype)
     c = jax.lax.pcast(c, ("kl", "pr", "pc"), to="varying")
@@ -312,8 +351,8 @@ def _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=0, nticks=None):
 
     def tick(t, carry):
         a, b, c = carry
-        c = _tick_contrib_chunked(a, b, c, st[t], r0=r0, cap_c=cap_c,
-                                  acc_dtype=acc_dtype)
+        c = _tick_contrib_chunked(a, b, c, _stack_of_tick(st, t), r0=r0,
+                                  cap_c=cap_c, acc_dtype=acc_dtype)
         if s > 1:
             a = jax.lax.ppermute(a, ("pc",), shift_a)
             b = jax.lax.ppermute(b, ("pr",), shift_b)
@@ -327,14 +366,19 @@ def _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=0, nticks=None):
 def _record_mesh_dispatch(stacks_dev, r0: int) -> None:
     """Account one mesh launch through the fused-dispatch metrics
     (`acc.smm.record_dispatch`): the whole multiply — every tick's
-    `_tick_chunks` sub-chunk — rides a single SPMD program, i.e. the
-    mesh engine is natively on the fused path the single-chip
-    superstack engine reaches per C bin.  ``stacks_dev`` is the
-    (..., nticks, s_cap, width) device stack array."""
+    chunks — rides a single SPMD program, i.e. the mesh engine is
+    natively on the fused path the single-chip superstack engine
+    reaches per C bin.  ``stacks_dev`` is the plan's: the flat
+    (..., nticks, s_cap, 3) array, or the grouped pytree whose gather
+    ids are (..., nticks, nchunks, CH_w, w)."""
     from dbcsr_tpu.acc.smm import record_dispatch
 
-    nticks, s_cap = stacks_dev.shape[-3], stacks_dev.shape[-2]
-    nchunk, _ = _tick_chunks(s_cap, r0)
+    if r0:
+        ga = stacks_dev[1][0][0]
+        nticks, nchunk = ga.shape[3], ga.shape[4]
+    else:
+        nticks = stacks_dev.shape[-3]
+        nchunk, _ = _tick_chunks(stacks_dev.shape[-2])
     record_dispatch("fused", fused_spans=nticks * nchunk)
 
 
@@ -459,7 +503,7 @@ def _stack_mesh_run(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
     def body(a_p, b_p, st, c_in, alpha, beta_fac):
         a = a_p.reshape(a_p.shape[3:])  # (cap_a + xtr, bm, bk)
         b = b_p.reshape(b_p.shape[3:])
-        st = st.reshape(st.shape[3:])  # (nticks, s_cap, 3 or 2*r0+1)
+        st = _local_stacks(st)  # (nticks, ...): flat rows or group tiles
         c_in = c_in.reshape(c_in.shape[2:])  # (cap_c, bm, bn)
         fac = beta_fac.reshape(beta_fac.shape[2:])  # (cap_c,) or (cap_c,bm,bn)
         if fac.ndim == 1:
@@ -492,10 +536,11 @@ def _stack_mesh_run(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
 # --------------------------------------------------------------------------
 # Split per-tick programs: the double-buffered metronome
 # (parallel/overlap.py) dispatches these independently so the panel
-# ring shift feeding tick k+1 runs concurrently with tick k's gather +
-# batched matmul + segment-sum.  Per-tick op order (`_stack_contrib`,
-# `_tick_contrib_chunked`) is shared with the fused serial program, so
-# the two execution modes are bitwise identical.
+# ring shift feeding tick k+1 runs concurrently with tick k's chunk
+# loop.  The per-tick body (`_tick_contrib_chunked`: on the grouped
+# path `acc/smm.py:group_chunk_loop`, the one-chip engine's own loop,
+# on the tick's own tiles and live count) is shared with the fused
+# serial program, so the two execution modes are bitwise identical.
 # --------------------------------------------------------------------------
 
 
@@ -512,11 +557,10 @@ def _stack_mesh_tick(a_panels, b_panels, stacks, c_acc, t, *,
     def body(a_p, b_p, st, c_p, t):
         a = a_p.reshape(a_p.shape[3:])
         b = b_p.reshape(b_p.shape[3:])
-        st = st.reshape(st.shape[3:])    # (nticks, s_cap, w)
         c = c_p.reshape(c_p.shape[3:])   # (cap_c, bm, bn)
-        entries = jax.lax.dynamic_index_in_dim(st, t, axis=0, keepdims=False)
-        c = _tick_contrib_chunked(a, b, c, entries, r0=r0, cap_c=cap_c,
-                                  acc_dtype=acc_dtype)
+        c = _tick_contrib_chunked(
+            a, b, c, _stack_of_tick(_local_stacks(st), t), r0=r0,
+            cap_c=cap_c, acc_dtype=acc_dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
     fn = jax.shard_map(
@@ -661,7 +705,6 @@ def _stack_gather_tick(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
         b_r = b_r.reshape(b_r.shape[3:])
         a_c = a_c.reshape(a_c.shape[3:])  # (pc * seg_a, bm, bk)
         b_c = b_c.reshape(b_c.shape[3:])  # (pr * seg_b, bk, bn)
-        st = st.reshape(st.shape[3:])     # (nticks, s_cap, w)
         c = c_p.reshape(c_p.shape[3:])    # (cap_c, bm, bn)
         src_col = jax.lax.rem(jax.lax.axis_index("pc") + t,
                               jnp.int32(pc))
@@ -672,9 +715,9 @@ def _stack_gather_tick(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
                               jnp.int32(pr))
         b_c = jax.lax.dynamic_update_slice(
             b_c, b_r, (src_row * seg_b, zero, zero))
-        entries = jax.lax.dynamic_index_in_dim(st, t, axis=0, keepdims=False)
-        c = _tick_contrib_chunked(a_c, b_c, c, entries, r0=r0, cap_c=cap_c,
-                                  acc_dtype=acc_dtype)
+        c = _tick_contrib_chunked(
+            a_c, b_c, c, _stack_of_tick(_local_stacks(st), t), r0=r0,
+            cap_c=cap_c, acc_dtype=acc_dtype)
         return (a_c.reshape((1, 1, 1) + a_c.shape),
                 b_c.reshape((1, 1, 1) + b_c.shape),
                 c.reshape((1, 1, 1) + c.shape))
@@ -954,7 +997,7 @@ class _MeshPlan:
     acc_name: str
     true_flops: int
     n_cand: int
-    stacks_dev: object  # sharded (kl, s, s, s, cap, w) int32
+    stacks_dev: object  # `_upload_stacks`: flat array or grouped pytree
     a_asm: _BinAsm
     b_asm: _BinAsm
     cinit_asm: Optional[_BinAsm]  # None when C had no stored blocks
@@ -978,7 +1021,8 @@ class _MeshPlan:
         """Device bytes this plan pins: stacks, index maps, and the
         cached panels.  The panel keepalives are NOT counted — they
         alias the owning matrix's live bin data, not extra copies."""
-        n = int(self.stacks_dev.nbytes) + self.a_asm.nbytes() + self.b_asm.nbytes()
+        n = (_stacks_nbytes(self.stacks_dev) + self.a_asm.nbytes()
+             + self.b_asm.nbytes())
         if self.cinit_asm is not None:
             n += self.cinit_asm.nbytes()
         n += sum(int(x.nbytes) for x in self.collect_pos)
@@ -1091,7 +1135,8 @@ class _GroupedPlan:
     panel_cache: dict = dataclasses.field(default_factory=dict)
 
     def nbytes(self) -> int:
-        n = int(self.stacks_dev.nbytes) + self.a_asm.nbytes() + self.b_asm.nbytes()
+        n = (_stacks_nbytes(self.stacks_dev) + self.a_asm.nbytes()
+             + self.b_asm.nbytes())
         if self.cinit_asm is not None:
             n += self.cinit_asm.nbytes()
         n += sum(int(x.nbytes) for x in self.collect_pos)
@@ -1215,11 +1260,10 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
         stacks = _fill_stacks(
             group, st_a, st_b, c_slots[ent_c],
             kl * pr * pc * nticks, cap_c, r0=r0, pad_a=cap_a, pad_b=cap_b,
+            chunk_groups=_stack_chunk_groups(r0, bm, bn, bk, dtype),
         )
-    stacks = stacks.reshape(kl, pr, pc, nticks, -1, stacks.shape[-1])
     with timed("mesh_plan_upload"):
-        stacks_dev = jax.device_put(
-            stacks, NamedSharding(mesh, P("kl", "pr", "pc")))
+        stacks_dev = _upload_stacks(stacks, mesh, (kl, pr, pc, nticks))
 
     # ---- device-side panel assembly maps ----
     al, ai_, akc = a_panel // (pr * pc), (a_panel // pc) % pr, a_panel % pc
@@ -1324,7 +1368,8 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
     )
 
     upload_bytes = (
-        stacks.nbytes + a_asm.nbytes() + b_asm.nbytes() + inside_bytes
+        _stacks_nbytes(stacks_dev) + a_asm.nbytes() + b_asm.nbytes()
+        + inside_bytes
         + (cinit_asm.nbytes() if cinit_asm is not None else 0)
         + sum(int(x.nbytes) for x in collect_pos)
         + sum(int(x.nbytes) for x in collect_slots)
@@ -1688,7 +1733,7 @@ def _stack_grouped_run(a_panels, b_panels, stacks, c_init, alpha, beta,
     def body(a_p, b_p, st, c_in, alpha, beta):
         a = a_p.reshape(a_p.shape[3:])  # (cap_a, bm, bk)
         b = b_p.reshape(b_p.shape[2:])  # (cap_b, bk, bn), replicated on kl
-        st = st.reshape(st.shape[3:])  # (s, s_cap, 3) or (s, G_cap, 2*r0+1)
+        st = _local_stacks(st)  # (s, ...): flat rows or group tiles
         c_in = c_in.reshape(c_in.shape[3:])  # (cap_c, bm, bn)
         b = jax.lax.pcast(b, ("kl",), to="varying")
         c = _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=r0)
@@ -1763,11 +1808,10 @@ def _stack_grouped_tick(a_panels, b_panels, stacks, c_acc, t, *,
         a = a_p.reshape(a_p.shape[3:])
         b = b_p.reshape(b_p.shape[2:])
         b = jax.lax.pcast(b, ("kl",), to="varying")
-        st = st.reshape(st.shape[3:])    # (s, s_cap, w)
         c = c_p.reshape(c_p.shape[3:])   # (q*cap_c, bm, bn)
-        entries = jax.lax.dynamic_index_in_dim(st, t, axis=0, keepdims=False)
-        c = _tick_contrib_chunked(a, b, c, entries, r0=r0, cap_c=cap_c,
-                                  acc_dtype=acc_dtype)
+        c = _tick_contrib_chunked(
+            a, b, c, _stack_of_tick(_local_stacks(st), t), r0=r0,
+            cap_c=cap_c, acc_dtype=acc_dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
     fn = jax.shard_map(
@@ -1983,10 +2027,9 @@ def _build_grouped_plan(a, b, matrix_c, mesh, g, s, dtype, bm, bk, bn, r0,
     stacks = _fill_stacks(
         group_id, st_a, b_slots[b_ent], st_c,
         g * s * s * s, q * cap_c, r0=r0, pad_a=q * cap_a, pad_b=cap_b,
+        chunk_groups=_stack_chunk_groups(r0, bm, bn, bk, dtype),
     )
-    stacks = stacks.reshape(g, s, s, s, -1, stacks.shape[-1])
-
-    stacks_dev = jax.device_put(stacks, NamedSharding(mesh, P("kl", "pr", "pc")))
+    stacks_dev = _upload_stacks(stacks, mesh, (g, s, s, s))
 
     # ---- device-side panel assembly maps (skewed start positions) ----
     xtr = 1 if r0 else 0
@@ -2034,7 +2077,7 @@ def _build_grouped_plan(a, b, matrix_c, mesh, g, s, dtype, bm, bk, bn, r0,
         collect_counts.append(len(sel))
 
     upload_bytes = (
-        stacks.nbytes + a_asm.nbytes() + b_asm.nbytes()
+        _stacks_nbytes(stacks_dev) + a_asm.nbytes() + b_asm.nbytes()
         + (cinit_asm.nbytes() if cinit_asm is not None else 0)
         + sum(int(x.nbytes) for x in collect_pos)
         + sum(int(x.nbytes) for x in collect_slots)

@@ -366,6 +366,82 @@ def test_f64_group_body_gathers_whole_block_rows(ns_group_hlo, ns_plan):
         assert all(strip in text for strip in strips), strips
 
 
+def _op_counts(hlo_text):
+    """{opcode: instructions} over every computation of a module."""
+    ops = {}
+    for lines in _computations(hlo_text).values():
+        for ln in lines:
+            op = re.match(r"(?:ROOT )?%?[\w.\-]+ = .*? ([a-z][\w\-]*)\(", ln)
+            if op:
+                ops[op.group(1)] = ops.get(op.group(1), 0) + 1
+    return dict(sorted(ops.items()))
+
+
+def _small_group_program(one_chip):
+    """`_process_stack_xla_group` at a fixed small shape: three width
+    classes, one of a ragged depth."""
+    import jax
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import smm
+
+    def tile(ch, w):
+        return (_shape(one_chip, (3, ch, w), jnp.int32),) * 2 \
+            + (_shape(one_chip, (3, ch), jnp.int32),)
+
+    with jax.enable_x64(True):
+        return smm._process_stack_xla_group.lower(
+            _shape(one_chip, (64, 5, 4), jnp.float64),
+            _shape(one_chip, (9, 5, 3), jnp.float64),
+            _shape(one_chip, (9, 3, 4), jnp.float64),
+            _shape(one_chip, (1,), jnp.int32),
+            *tile(16, 8), *tile(24, 2), *tile(8, 1),
+            _shape(one_chip, (), jnp.float64),
+        ).compile()
+
+
+# optimised HLO of `_process_stack_xla_group` for a described v5e as
+# PR 32's tree compiled it (jax 0.9.0, libtpu 0.0.34), by opcode
+_GROUP_OPS_PR32 = {
+    "small": {
+        "abs": 24, "add": 298, "and": 96, "bitcast": 70, "bitcast-convert":
+        45, "broadcast": 204, "clamp": 6, "compare": 98, "constant": 318,
+        "convert": 2, "convolution": 9, "copy": 71, "custom-call": 39,
+        "dynamic-slice": 54, "dynamic-update-slice": 33, "fusion": 101,
+        "gather": 12, "get-tuple-element": 264, "is-finite": 56, "multiply":
+        102, "or": 24, "pad": 18, "parameter": 368, "remainder": 24,
+        "reshape": 57, "scatter": 3, "select": 137, "shift-left": 12,
+        "shift-right-arithmetic": 6, "shift-right-logical": 16, "sign": 36,
+        "slice": 36, "subtract": 259, "transpose": 33, "tuple": 50, "while":
+        16},
+    "north_star": {
+        "abs": 24, "add": 302, "and": 96, "bitcast": 79, "bitcast-convert":
+        45, "broadcast": 204, "clamp": 6, "compare": 98, "constant": 321,
+        "convert": 3, "convolution": 9, "copy": 71, "copy-done": 15,
+        "copy-start": 15, "custom-call": 42, "dynamic-slice": 54,
+        "dynamic-update-slice": 33, "fusion": 102, "gather": 12,
+        "get-tuple-element": 264, "is-finite": 56, "multiply": 102, "or": 24,
+        "pad": 18, "parameter": 378, "reduce": 4, "remainder": 24, "reshape":
+        55, "scatter": 3, "select": 137, "shift-left": 12,
+        "shift-right-arithmetic": 6, "shift-right-logical": 16, "sign": 36,
+        "slice": 36, "slice-done": 12, "slice-start": 12, "subtract": 259,
+        "transpose": 33, "tuple": 50, "while": 16},
+}
+
+
+@pytest.mark.parametrize("shape", ["small", "north_star"])
+def test_f64_group_program_is_op_for_op_what_it_was(one_chip, shape, request):
+    """PR 33 lifted the chunk loop into `group_chunk_loop` for the mesh
+    engine to share: what one chip compiles must not move, because
+    `northstar.scf_f64`'s `peak_hbm_gib` counts the executable's text
+    and `setup_s` every recompile."""
+    if shape == "small":
+        compiled = _small_group_program(one_chip)
+    else:
+        compiled, _, _ = request.getfixturevalue("ns_group_program")
+    assert _op_counts(compiled.as_text()) == _GROUP_OPS_PR32[shape]
+
+
 @pytest.mark.parametrize("body", ["xla", "xla_flat", "xla_group"])
 def test_stack_body_scatter_adds_into_its_carry(body):
     """What the guard above holds the compiler to, read off the jaxpr
@@ -426,15 +502,17 @@ _MESH_R0 = 8
 
 
 @pytest.fixture(scope="module")
-def northstar_2x2_caps():
-    """(cap_a, cap_b, cap_c, s_cap) of the deployment on the 2x2 grid,
-    from its pattern as `_build_mesh_plan` sizes them: blocks go to
-    devices cyclically, tick = the k parity that meets on a device, a
-    C block's candidates of one tick fill ceil(count / r0) group rows."""
+def northstar_2x2_plan():
+    """(cap_a, cap_b, cap_c, the grouped stacks) of the deployment on
+    the 2x2 grid, from its pattern as `_build_mesh_plan` bins it:
+    blocks go to devices cyclically, tick = the k parity that meets on
+    a device, slots count a panel's blocks in key order, and
+    `_fill_stacks` tiles the eight (device, tick) stacks."""
     import sys
 
     sys.path.insert(0, REPO)
     from benchmark import arithmetic
+    from dbcsr_tpu.parallel import sparse_dist as sd
     from dbcsr_tpu.utils.rounding import bucket_size
 
     with open(os.path.join(
@@ -446,24 +524,72 @@ def northstar_2x2_caps():
     pa = rng.random((nblk, nblk)) < cfg["occupancy"]["a"]
     pb = rng.random((nblk, nblk)) < cfg["occupancy"]["b"]
     assert (pa.sum(), pb.sum()) == (19115, 18989)  # the cell's operands
-    par = np.arange(nblk) % 2
-    panels = [(p, q) for p in (0, 1) for q in (0, 1)]
-    cap_a = bucket_size(max(int(pa[par == p][:, par == q].sum())
-                            for p, q in panels))
-    cap_b = bucket_size(max(int(pb[par == p][:, par == q].sum())
-                            for p, q in panels))
-    by_k = [pa[:, par == t].astype(np.int32) @ pb[par == t].astype(np.int32)
-            for t in (0, 1)]
-    reached = (by_k[0] + by_k[1]) > 0
-    cap_c = bucket_size(max(int(reached[par == p][:, par == q].sum())
-                            for p, q in panels))
-    rows = max(int((-(-cnt[par == p][:, par == q] // _MESH_R0)).sum())
-               for cnt in by_k for p, q in panels)
-    return cap_a, cap_b, cap_c, bucket_size(rows)
+    # candidates (i, k, j), k-major
+    a_of_k = [np.nonzero(pa[:, k])[0] for k in range(nblk)]
+    b_of_k = [np.nonzero(pb[k])[0] for k in range(nblk)]
+    i = np.concatenate([np.repeat(r, len(c)) for r, c in zip(a_of_k, b_of_k)])
+    j = np.concatenate([np.tile(c, len(r)) for r, c in zip(a_of_k, b_of_k)])
+    k = np.repeat(np.arange(nblk),
+                  [len(r) * len(c) for r, c in zip(a_of_k, b_of_k)])
+    reached = np.zeros((nblk, nblk), bool)
+    reached[i, j] = True
+
+    def panel_slots(there):
+        """(slot of every block in its (row, column parity) panel, the
+        fullest panel's count)."""
+        r, c = np.nonzero(there)
+        panel = (r % 2) * 2 + c % 2
+        slot = np.zeros(there.shape, np.int64)
+        slot[r, c] = sd._panel_slots(panel)
+        return slot, int(np.bincount(panel).max())
+
+    (a_slot, na), (b_slot, nb), (c_slot, nc) = map(panel_slots,
+                                                   (pa, pb, reached))
+    cap_a, cap_b, cap_c = map(bucket_size, (na, nb, nc))
+    tick = (k % 2 - i % 2 - j % 2) % 2
+    stack = ((i % 2) * 2 + j % 2) * 2 + tick
+    # the runs PERF.md counts: a C block's candidates of one tick
+    assert len(i) == 834386
+    assert len(np.unique((stack * nblk + i) * nblk + j)) == 337410
+    tiles = sd._fill_stacks(
+        stack, a_slot[i, k], b_slot[k, j], c_slot[i, j], 8, cap_c,
+        r0=_MESH_R0, pad_a=cap_a, pad_b=cap_b,
+        chunk_groups=sd._stack_chunk_groups(_MESH_R0, 23, 23, 23,
+                                            np.float64))
+    return cap_a, cap_b, cap_c, tiles
+
+
+def test_northstar_2x2_plan_launches_the_slots_that_hold_entries(
+        northstar_2x2_plan):
+    """Counts, not rates: until PR 33 every (device, tick) stack was
+    49 152 rows of 8, 3 145 728 slots on the grid (786 432 a device a
+    product) for these 834 386 candidates, fill 27%."""
+    cap_a, cap_b, cap_c, tiles = northstar_2x2_plan
+    # the panels the builder's chip run of PR 30 ran at
+    assert (cap_a, cap_b, cap_c) == (5120, 5120, 49152)
+    assert tiles.entries == 834386
+    # runs of mean 2.47: a tick sees half a block's k range
+    assert tiles.widths == (8, 4, 2, 1)
+    assert tiles.groups == (27109, 116776, 101798, 91859)
+    assert [ga.shape[1:] for ga, _, _ in tiles.tiles] == [
+        (64, 64, 8), (64, 256, 4), (64, 224, 2), (64, 192, 1)]
+    # 2 176 slots a chunk, 59-62 live chunks a (device, tick)
+    assert tiles.live.tolist() == [60, 59, 60, 60, 60, 60, 62, 61]
+    assert tiles.slots_launched == 482 * 2176 == 1048832
+    assert tiles.entries / tiles.slots_launched > 0.79
+    # a device's two ticks: 258 944-267 648 slots a product
+    by_device = tiles.live.reshape(4, 2).sum(axis=1) * 2176
+    assert by_device.tolist() == [258944, 261120, 261120, 267648]
+    # every id names a row of the panel it gathers from, the pad row
+    # included: the gathers promise that and check nothing
+    for ga, gb, gc in tiles.tiles:
+        assert 0 <= ga.min() and ga.max() == cap_a
+        assert 0 <= gb.min() and gb.max() == cap_b
+        assert 0 <= gc.min() and gc.max() == cap_c
 
 
 @pytest.fixture(scope="module")
-def mesh_shapes(v5e_2x2, northstar_2x2_caps):
+def mesh_shapes(v5e_2x2, northstar_2x2_plan):
     """The (1, 2, 2) mesh of the described chips and the engine's
     arguments on it, sharded as `_sparse_multiply_impl` places them."""
     import jax
@@ -472,9 +598,7 @@ def mesh_shapes(v5e_2x2, northstar_2x2_caps):
 
     from dbcsr_tpu.parallel.overlap import _HashableMesh
 
-    cap_a, cap_b, cap_c, s_cap = northstar_2x2_caps
-    # what the builder's chip run of PR 30 ran at
-    assert (cap_a, cap_b, cap_c, s_cap) == (5120, 5120, 49152, 49152)
+    cap_a, cap_b, cap_c, tiles = northstar_2x2_plan
     mesh = Mesh(np.array(v5e_2x2.devices).reshape(1, 2, 2),
                 ("kl", "pr", "pc"))
 
@@ -483,12 +607,15 @@ def mesh_shapes(v5e_2x2, northstar_2x2_caps):
             shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
 
     grid3 = ("kl", "pr", "pc")
+    lead = (1, 2, 2, 2)  # (kl, pr, pc, ticks), as `_upload_stacks` lays them
     return {
         "mref": _HashableMesh(mesh), "cap_c": cap_c,
+        "widths": tiles.widths,
         "a": arg((1, 2, 2, cap_a + 1, 23, 23), jnp.float64, *grid3),
         "b": arg((1, 2, 2, cap_b + 1, 23, 23), jnp.float64, *grid3),
-        "stacks": arg((1, 2, 2, 2, s_cap, 2 * _MESH_R0 + 1), jnp.int32,
-                      *grid3),
+        "stacks": (arg(lead, jnp.int32, *grid3),
+                   tuple(tuple(arg(lead + x.shape[1:], jnp.int32, *grid3)
+                               for x in tile) for tile in tiles.tiles)),
         "c_acc": arg((1, 2, 2, cap_c, 23, 23), jnp.float64, *grid3),
         "c_init": arg((2, 2, cap_c, 23, 23), jnp.float64, "pr", "pc"),
         "beta_fac": arg((2, 2, cap_c), jnp.float64, "pr", "pc"),
@@ -499,12 +626,17 @@ def mesh_shapes(v5e_2x2, northstar_2x2_caps):
 @pytest.mark.parametrize("program", ["tick", "run"])
 def test_mesh_stack_program_of_northstar_2x2_compiles(mesh_shapes, program):
     """The split per-tick program and the fused serial one at the
-    deployment's panels, emulated f64, r0 = 8: they compile, under the
-    names the benchmark's `jit__stack_*` globs read, with the three
-    phase scopes in their ops, and their temporaries (3.13 and 4.08 GiB
-    a device here in PR 30: the gathered strips and the whole-panel
-    arrays `segment_sum` makes per chunk) stay under a third of a
-    chip's 16 GB."""
+    deployment's panels and grouped stacks, emulated f64: they compile,
+    under the names the benchmark's `jit__stack_*` globs read, with the
+    phase scopes of `acc/smm.py:group_chunk_loop` in their ops, and in
+    its forms: ONE chunk `while` over the C panel, in it nothing
+    panel-shaped but the sorted scatter-adds of `_accumulate_chunk`, one
+    a width class (no `segment_sum`-style whole-panel add per chunk),
+    no bounds compare and NaN fill on the gathers.  Temporaries a
+    device: 2.26 GiB the tick (the arriving panel relaid to tiles and
+    split into its two f32 halves), 1.48 GiB the fused program (3.13 /
+    4.08 GiB in PR 30, with the gathered strips of 24 576 slots and the
+    whole-panel arrays `segment_sum` made per chunk)."""
     import jax
 
     from dbcsr_tpu.parallel import sparse_dist as sd
@@ -524,11 +656,39 @@ def test_mesh_stack_program_of_northstar_2x2_compiles(mesh_shapes, program):
     text = compiled.as_text()
     name = {"tick": "_stack_mesh_tick", "run": "_stack_mesh_run"}[program]
     assert f"HloModule jit_{name}" in text
-    for scope in ("stk_gather", "stk_dot", "stk_accum"):
+    for scope in ("stk_gather", "stk_dot", "stk_accum", "stk_loop"):
         assert f"/{scope}/" in text, scope
     # the ring shift rides in the fused program and not in a split tick
     assert ("collective-permute" in text) == (program == "run")
-    assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2 ** 30
+    comps = _computations(text)
+    panel = f"[{sh['cap_c']},23,23]"
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text))
+    chunk_loops = [b for b in bodies
+                   if any("stk_accum/scatter-add" in ln for ln in comps[b])]
+    assert len(chunk_loops) == 1, sorted(chunk_loops)
+    # the fused program's tick loop carries the panel around it
+    over_panel = [b for b in bodies if any(panel in ln for ln in comps[b])]
+    assert len(over_panel) == (1 if program == "tick" else 2), over_panel
+    body = comps[chunk_loops[0]]
+    made = [(ln, re.search(r" ([a-z][\w\-]*)\(", ln.split(" = ", 1)[1]))
+            for ln in body if " = " in ln]
+    makers = [ln for ln, op in made
+              if panel in ln.split(" = ", 1)[1][:op.start()]
+              and op.group(1) not in ("get-tuple-element", "parameter",
+                                      "tuple")]
+    assert len(makers) == len(sh["widths"]), [ln[:160] for ln in makers]
+    assert all("stk_accum/scatter-add" in ln for ln in makers), \
+        [ln[:400] for ln in makers]
+    reached = [body] + [comps[c] for ln in body
+                        for c in re.findall(r"calls=%?([\w.\-]+)", ln)]
+    scatters = [ln for lines in reached for ln in lines
+                if re.search(r" scatter\(", ln)]
+    assert scatters and all("indices_are_sorted=true" in ln
+                            for ln in scatters), scatters[:2]
+    assert not any("constant(nan)" in ln for lines in reached
+                   for ln in lines)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < {"tick": 2.5, "run": 1.7}[program] * 2 ** 30, temp
 
 
 def test_mesh_shift_and_finish_of_northstar_2x2_compile(mesh_shapes):

@@ -114,18 +114,63 @@ def test_mesh_square_bitwise_identity(mesh8):
     np.testing.assert_allclose(db, ref, rtol=1e-12, atol=1e-12)
 
 
-def test_mesh_square_r_tiled_bitwise_identity(mesh4):
-    # the R-tiled (xla_group) stack layout through the split per-tick
-    # program: same `_stack_contrib` path, grouped rows
+def _grouped_case(layout):
+    """(multiply(mode) -> dense C, the dense reference) of one layout
+    the grouped stacks (`mm_driver="xla_group"`: the tiling and the
+    chunk loop of `acc/smm.py`) run in."""
+    import jax
+    from jax.sharding import Mesh
+
+    if layout == "tas_grouped":
+        bs_tall, bs = [4] * 12, [4] * 5
+        rng = np.random.default_rng(27)
+        a = make_random_matrix("AT", bs_tall, bs, occupation=0.5, rng=rng)
+        b = make_random_matrix("B", bs, bs, occupation=0.6, rng=rng)
+        mesh = make_grid(8)
+
+        def multiply(mode):
+            set_config(cannon_overlap=mode)
+            clear_mesh_plans()
+            return to_dense(tas_grouped_multiply(2.0, a, b, 0.0, None, mesh))
+    else:
+        dtype = np.complex128 if layout == "cannon_2x2_c128" else np.float64
+        a = _rand("A", seed=11, dtype=dtype)
+        b = _rand("B", seed=12, dtype=dtype)
+        mesh = {
+            "cannon_2x2": lambda: make_grid(4),
+            "cannon_2x2_c128": lambda: make_grid(4),
+            # rectangular: the chunked all-gather, whose pad ids name
+            # rows of the concatenated operands
+            "allgather_2x3": lambda: make_grid(6),
+            # a LAYERED rectangular grid (kl=2, 1x2): the psum tail
+            "allgather_layered_1x2": lambda: Mesh(
+                np.asarray(jax.devices()[:4]).reshape(2, 1, 2),
+                axis_names=("kl", "pr", "pc")),
+        }[layout]()
+
+        def multiply(mode):
+            return _mesh_ab(mesh, mode, a, b)
+    return multiply, 2.0 * (to_dense(a) @ to_dense(b))
+
+
+@pytest.mark.parametrize("layout", [
+    "cannon_2x2", "cannon_2x2_c128", "allgather_2x3",
+    "allgather_layered_1x2", "tas_grouped"])
+def test_grouped_stacks_bitwise_identity_and_dense_reference(layout):
+    # the grouped (xla_group) stacks through the split per-tick
+    # programs and the fused one: one body (`_tick_contrib_chunked` ->
+    # `acc/smm.py:group_chunk_loop`), so bit for bit the same C, and
+    # the dense product
     prev = get_config().mm_driver
     set_config(mm_driver="xla_group")
     try:
-        a, b = _rand("A", seed=11), _rand("B", seed=12)
-        ser = _mesh_ab(mesh4, "serial", a, b)
-        db = _mesh_ab(mesh4, "double_buffer", a, b)
+        multiply, ref = _grouped_case(layout)
+        ser = multiply("serial")
+        db = multiply("double_buffer")
     finally:
         set_config(mm_driver=prev)
     assert (ser == db).all()
+    np.testing.assert_allclose(db, ref, rtol=1e-12, atol=1e-12)
 
 
 def test_mesh_allgather_route_identity(mesh6):
@@ -154,30 +199,6 @@ def test_mesh_allgather_beta_filtered_identity(mesh6):
     ser_f = _mesh_ab(mesh6, "serial", a, b, filter_eps=1e-3)
     db_f = _mesh_ab(mesh6, "double_buffer", a, b, filter_eps=1e-3)
     assert (ser_f == db_f).all()
-
-
-def test_mesh_allgather_layered_r_tiled_identity(mesh6):
-    # the R-tiled (xla_group) stack layout through the chunked gather
-    # (r0 pads reference guaranteed-zero concatenation rows in both
-    # execution modes), plus a LAYERED rectangular grid (kl=2, 1x2 —
-    # the psum tail shared with the fused program)
-    import jax
-    from jax.sharding import Mesh
-
-    prev = get_config().mm_driver
-    set_config(mm_driver="xla_group")
-    try:
-        a, b = _rand("A", seed=11), _rand("B", seed=12)
-        ser = _mesh_ab(mesh6, "serial", a, b)
-        db = _mesh_ab(mesh6, "double_buffer", a, b)
-        assert (ser == db).all()
-    finally:
-        set_config(mm_driver=prev)
-    mesh_l = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 1, 2),
-                  axis_names=("kl", "pr", "pc"))
-    ser_l = _mesh_ab(mesh_l, "serial", a, b)
-    db_l = _mesh_ab(mesh_l, "double_buffer", a, b)
-    assert (ser_l == db_l).all()
 
 
 def test_tas_route_identity(mesh8):

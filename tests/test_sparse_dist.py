@@ -854,30 +854,55 @@ def test_rect_comm_statistics(mesh6):
     assert "all_gather" in stats._comm and stats._comm["all_gather"].nbytes > 0
 
 
-def test_tick_chunks_bound_temp_memory():
-    """Per-tick sub-chunking (the 1x1-grid memory-thrash fix): chunk
-    counts divide the bucket capacity exactly and bound rows at the
-    entry-equivalent target."""
+@pytest.mark.parametrize("r0", [0, 8])
+def test_tick_chunks_bound_temp_memory(r0):
+    """Per-tick sub-chunking (the 1x1-grid memory-thrash fix).  Flat
+    ticks (r0 = 0): chunk counts divide the bucket capacity exactly
+    and bound rows at the entry target.  Grouped ticks (r0 = 8): the
+    chunk is the one-chip engine's (`group_chunk_groups`), so a chunk
+    of every plan launches at most its slots plus a step of rounding a
+    width class, however many entries a tick carries."""
+    from dbcsr_tpu.acc.smm import build_stacks_group_tiles, group_chunk_groups
     from dbcsr_tpu.parallel.sparse_dist import (
         _TICK_CHUNK_ENTRIES,
+        _stack_chunk_groups,
         _tick_chunks,
     )
     from dbcsr_tpu.utils.rounding import bucket_size
 
-    for n in (1, 16, 30000, 823000, 5_000_000):
-        cap = bucket_size(n)
-        for r0 in (0, 8):
-            nchunk, rows = _tick_chunks(cap, r0)
+    if r0 == 0:
+        assert _stack_chunk_groups(0, 23, 23, 23, np.float64) == 0
+        for n in (1, 16, 30000, 823000, 5_000_000):
+            cap = bucket_size(n)
+            nchunk, rows = _tick_chunks(cap)
             assert nchunk * rows == cap
-            target = max(1, _TICK_CHUNK_ENTRIES // max(r0, 1))
-            if cap > target:
+            if cap > _TICK_CHUNK_ENTRIES:
                 # bounded: a further halving would be possible only if
                 # it broke divisibility
-                assert rows <= target or cap % (nchunk * 2) != 0
+                assert rows <= _TICK_CHUNK_ENTRIES \
+                    or cap % (nchunk * 2) != 0
             else:
                 assert nchunk == 1
-    assert _tick_chunks(bucket_size(823000), 0)[1] <= 32768
-    assert _tick_chunks(bucket_size(823000), 8)[1] <= 4096
+        assert _tick_chunks(bucket_size(823000))[1] <= 32768
+        return
+    # 2 048 slots a chunk at 23^3 in f64, 1 024 in c128, what one chip runs
+    assert _stack_chunk_groups(8, 23, 23, 23, np.float64) == 256 \
+        == group_chunk_groups(8, 23, 23, 23, 8, 30000)
+    assert _stack_chunk_groups(8, 23, 23, 23, np.complex128) == 128
+    rng = np.random.default_rng(5)
+    for nruns in (3, 700, 40000):
+        # two stacks, the second a third as full, runs of 1..12
+        stack = np.repeat([0, 1], [nruns, nruns // 3])
+        runs = rng.integers(1, 13, len(stack))
+        seg = np.concatenate([np.arange(nruns), np.arange(nruns // 3)])
+        zeros = np.zeros(int(runs.sum()), np.int32)
+        tiles = build_stacks_group_tiles(
+            np.repeat(stack, runs), 2, np.repeat(seg, runs), zeros, zeros,
+            8, 9, 9, nruns, 256)
+        chunk_slots = sum(ga.shape[2] * ga.shape[3] for ga, _, _ in tiles.tiles)
+        assert chunk_slots <= 2048 + 16 * sum(tiles.widths)
+        assert tiles.live[0] >= tiles.live[1] >= 1
+        assert tiles.tiles[0][0].shape[1] >= tiles.live[0]
 
 
 # ---------------------------------------------------------------------------
@@ -899,20 +924,74 @@ _DECAYING = dict(sizes=[5] * 11 + [3], occ=0.6, decay=3.4, seed=73)
 # candidates pass the skip, so only the final pass can drop it
 _CANCELLING = dict(sizes=[5] * 7 + [3], occ=0.5, decay=0.0, seed=75,
                    cancel=dict(i=1, j=2, k=(3, 5), delta=1e-10))
+
+
+def _run_lengths_there(n):
+    """On device (0, 0) of the 2x2 grid (blocks go to devices
+    cyclically; tick = k's parity there) tick 0 holds runs of r0 + 1,
+    r0 and 1 candidates: C(0,0) meets all nine even k, C(0,2) eight of
+    them, C(0,4) one."""
+    a = np.zeros((n, n), bool)
+    b = np.zeros((n, n), bool)
+    a[0, :] = True
+    a[3, [1, 3]] = True
+    b[:, 0] = True
+    b[np.arange(0, 16, 2), 2] = True   # eight even k
+    b[1, 2] = b[0, 4] = True
+    b[[1, 3], 1] = True
+    return a, b
+
+
+def _idle_devices_there(n):
+    """A in even rows and even k only: the devices of grid row 1 get
+    no candidate at all, and on those of row 0 every candidate falls
+    into one of the two ticks."""
+    rng = np.random.default_rng(77)
+    a = rng.random((n, n)) < 0.7
+    b = rng.random((n, n)) < 0.7
+    a[1::2, :] = a[:, 1::2] = b[1::2, :] = False
+    return a, b
+
+
+def _unequal_live_there(n):
+    """Three of A's twelve odd rows hold blocks, all of its even ones:
+    at 128 slots a chunk the devices of grid row 0 run four times the
+    chunks a tick of those of row 1, on runs of the same lengths."""
+    rng = np.random.default_rng(79)
+    a = rng.random((n, n)) < 0.8
+    a[7::2, :] = False
+    b = rng.random((n, n)) < 0.5
+    return a, b
+
+
+# the grouped tiling's corners, on north-star values (PR 33): run
+# lengths around r0; devices and ticks with no candidate (`live` 0);
+# chunk counts that differ by device (`mm_stack_size` 128: 16 groups of
+# r0 a chunk)
+_RUN_LENGTHS = dict(sizes=[3] * 17 + [2], occ=0.0, decay=0.0, seed=81,
+                    there=_run_lengths_there)
+_IDLE_DEVICES = dict(sizes=[4] * 9 + [3], occ=0.0, decay=0.0, seed=83,
+                     there=_idle_devices_there)
+_UNEQUAL_LIVE = dict(sizes=[4] * 23 + [3], occ=0.0, decay=0.0, seed=85,
+                     there=_unequal_live_there, stack_size=128)
 _FILTERED_CASES = {"northstar_like": _NORTHSTAR_LIKE, "decaying": _DECAYING,
-                   "cancelling": _CANCELLING}
+                   "cancelling": _CANCELLING, "run_lengths": _RUN_LENGTHS,
+                   "idle_devices": _IDLE_DEVICES,
+                   "unequal_live": _UNEQUAL_LIVE}
 
 
-def _draw(sizes, occ, decay, rng):
+def _draw(sizes, occ, decay, rng, there=None):
     """Dense standard-normal blocks scaled by 10**(-decay * |i - j|),
-    and which blocks are there (the diagonal always)."""
+    and which blocks are there (``there`` if given; else the diagonal
+    always, the others at ``occ``)."""
     n = len(sizes)
     off = np.concatenate([[0], np.cumsum(sizes)])
     dense = np.zeros((off[-1], off[-1]))
     present = np.zeros((n, n), bool)
     for i in range(n):
         for j in range(n):
-            if i == j or rng.random() < occ:
+            if there[i, j] if there is not None else (
+                    i == j or rng.random() < occ):
                 present[i, j] = True
                 dense[off[i]:off[i + 1], off[j]:off[j + 1]] = (
                     rng.standard_normal((sizes[i], sizes[j]))
@@ -941,8 +1020,11 @@ def filtered_case(request):
     case = _FILTERED_CASES[request.param]
     sizes = case["sizes"]
     rng = np.random.default_rng(case["seed"])
-    a_dense, a_there, off = _draw(sizes, case["occ"], case["decay"], rng)
-    b_dense, b_there, _ = _draw(sizes, case["occ"], case["decay"], rng)
+    there = case["there"](len(sizes)) if "there" in case else (None, None)
+    a_dense, a_there, off = _draw(sizes, case["occ"], case["decay"], rng,
+                                  there[0])
+    b_dense, b_there, _ = _draw(sizes, case["occ"], case["decay"], rng,
+                                there[1])
     if "cancel" in case:
         # C[i, j] = Y X - Y X (1 - delta): column j of B holds X and
         # -X (1 - delta) and nothing else, row i of A holds Y twice
@@ -964,14 +1046,18 @@ def filtered_case(request):
     return request.param, a, b, a_dense, b_dense, off
 
 
-def _filtered_on_mesh(a, b, mesh, overlap="auto"):
+def _filtered_on_mesh(a, b, mesh, overlap="auto", case=None):
     """The product as the deployment runs it on a TPU: r0 = 8 grouped
-    stacks (forced here, where f64 is native), `filter_eps` 1e-7."""
+    stacks (forced here, where f64 is native), `filter_eps` 1e-7; a
+    case may shrink the chunk (`mm_stack_size`)."""
     from dbcsr_tpu import get_config, set_config
 
     cfg = get_config()
-    prev = dict(mm_driver=cfg.mm_driver, cannon_overlap=cfg.cannon_overlap)
-    set_config(mm_driver="xla_group", cannon_overlap=overlap)
+    prev = dict(mm_driver=cfg.mm_driver, cannon_overlap=cfg.cannon_overlap,
+                mm_stack_size=cfg.mm_stack_size)
+    set_config(mm_driver="xla_group", cannon_overlap=overlap,
+               mm_stack_size=_FILTERED_CASES.get(case, {}).get(
+                   "stack_size", cfg.mm_stack_size))
     try:
         return sparse_multiply_distributed(1.0, a, b, 0.0, None, mesh,
                                            filter_eps=_FILTER_EPS)
@@ -1012,17 +1098,19 @@ def test_filtered_product_on_2x2_matches_numpy_with_the_filter_by_definition(
     gap = (norms < _FILTER_EPS / 10) | (norms > _FILTER_EPS * 10)
     assert gap.all() and skipped.max() < _FILTER_EPS / 10
     reached = (norms > 0).sum()
-    if name == "northstar_like":
-        # every block the product reaches is kept, no candidate skipped
-        assert kept.sum() == reached > n and skipped.max() == 0.0
-    elif name == "decaying":
+    if name == "decaying":
         assert 0 < kept.sum() < reached and skipped.max() > 0.0
-    else:
+    elif name == "cancelling":
         # the one block that cancels, and nothing was skipped for it
         assert kept.sum() == reached - 1 and skipped.max() == 0.0
         assert 0 < norms[1, 2] < _FILTER_EPS / 10
+    else:
+        # north-star values: every block the product reaches is kept,
+        # no candidate skipped
+        assert kept.sum() == reached > 0 and skipped.max() == 0.0
+        assert name != "northstar_like" or reached > n
 
-    c = _filtered_on_mesh(a, b, mesh4)
+    c = _filtered_on_mesh(a, b, mesh4, case=name)
     rows, cols = c.entry_coords()
     got_kept = np.zeros((n, n), bool)
     got_kept[rows, cols] = True
@@ -1043,8 +1131,8 @@ def test_filtered_product_on_2x2_matches_numpy_with_the_filter_by_definition(
 
 def test_filtered_serial_and_double_buffered_ticks_are_bit_identical(
         mesh4, filtered_case):
-    _, a, b, *_ = filtered_case
-    by_mode = {mode: _filtered_on_mesh(a, b, mesh4, overlap=mode)
+    name, a, b, *_ = filtered_case
+    by_mode = {mode: _filtered_on_mesh(a, b, mesh4, overlap=mode, case=name)
                for mode in ("serial", "double_buffer")}
     serial, db = by_mode["serial"], by_mode["double_buffer"]
     np.testing.assert_array_equal(serial.keys, db.keys)
@@ -1059,7 +1147,7 @@ def test_second_filtered_product_rebuilds_the_plan_and_compiles_nothing(
 
     from dbcsr_tpu.core import timings
 
-    _, a, b, *_ = filtered_case
+    name, a, b, *_ = filtered_case
     compiles = []
 
     def listener(event, _secs, **_kw):
@@ -1070,11 +1158,11 @@ def test_second_filtered_product_rebuilds_the_plan_and_compiles_nothing(
         st = timings._stats.get("mesh_plan_build")
         return st.calls if st else 0
 
-    first = _filtered_on_mesh(a, b, mesh4)
+    first = _filtered_on_mesh(a, b, mesh4, case=name)
     mon.register_event_duration_secs_listener(listener)
     try:
         before, built = _plan_lookups(), builds()
-        second = _filtered_on_mesh(a, b, mesh4)
+        second = _filtered_on_mesh(a, b, mesh4, case=name)
         jax.block_until_ready([bn.data for bn in second.bins])
     finally:
         mon.unregister_event_duration_listener(listener)
@@ -1088,6 +1176,97 @@ def test_second_filtered_product_rebuilds_the_plan_and_compiles_nothing(
     np.testing.assert_array_equal(first.keys, second.keys)
     assert checksum(first) == checksum(second)
     assert np.array_equal(to_dense(first), to_dense(second))
+
+
+def test_filtered_plan_tiles_every_device_and_tick_by_its_own_runs(
+        mesh4, filtered_case, monkeypatch):
+    """What the mesh plan hands the tick programs (`_fill_stacks` at
+    r0 = 8, so `acc/smm.py:build_stacks_group_tiles`): one set of class
+    shapes for the grid, per (device, tick) its own candidates and its
+    own `live`, 0 where it has none; and the program's slot counters
+    say what was planned."""
+    from dbcsr_tpu.acc.smm import GroupTiles
+    from dbcsr_tpu.core import stats
+    from dbcsr_tpu.obs import metrics
+    from dbcsr_tpu.parallel import sparse_dist as sd
+
+    name, a, b, a_dense, b_dense, off = filtered_case
+    n = len(off) - 1
+    seen = []
+    fill = sd._fill_stacks
+
+    def spy(*args, **kw):
+        seen.append((fill(*args, **kw), args[5], kw))
+        return seen[-1][0]
+
+    def slots():
+        got = {lab["kind"]: v for lab, v in metrics.counter_items(
+            "dbcsr_tpu_stack_slots_total")}
+        return got.get("live", 0.0), got.get("launched", 0.0)
+
+    monkeypatch.setattr(sd, "_fill_stacks", spy)
+    live0, launched0 = slots()
+    rolled0 = stats.driver_rollup().get("mesh", {})
+    _filtered_on_mesh(a, b, mesh4, case=name)
+    (tiles, cap_c, kw), = seen
+    assert isinstance(tiles, GroupTiles) and kw["r0"] == 8
+    # 2 048 slots a chunk at 23^3, `mm_stack_size` slots at small blocks
+    assert kw["chunk_groups"] == {"northstar_like": 256,
+                                  "unequal_live": 16}.get(name, 3750)
+    # the candidates by (device, tick), from the patterns: blocks go to
+    # devices cyclically, tick = the alignment step at which k meets
+    na, nb = _block_norms(a_dense, off) > 0, _block_norms(b_dense, off) > 0
+    i, k, j = np.nonzero(na[:, :, None] & nb[None, :, :])
+    stack = ((i % 2) * 2 + j % 2) * 2 + (k % 2 - i % 2 - j % 2) % 2
+    want = np.bincount(stack, minlength=8)
+    # per stack: the ids that are no pad, over its live chunks
+    held = sum((ga != kw["pad_a"]).reshape(8, -1).sum(axis=1)
+               for ga, _, _ in tiles.tiles)
+    if name == "decaying":  # the norm skip left candidates out
+        assert (held <= want).all() and 0 < held.sum() < want.sum()
+    else:
+        np.testing.assert_array_equal(held, want)
+    assert tiles.entries == held.sum()
+    assert tiles.live.shape == (8,)
+    np.testing.assert_array_equal(tiles.live == 0, held == 0)
+    nchunks = tiles.tiles[0][0].shape[1]
+    assert 1 <= tiles.live.max() <= nchunks
+    for ga, gb, gc in tiles.tiles:
+        assert ga.shape[:2] == gb.shape[:2] == gc.shape[:2] == (8, nchunks)
+        assert 0 <= ga.min() and ga.max() <= kw["pad_a"]
+        assert 0 <= gb.min() and gb.max() <= kw["pad_b"]
+        for s in range(8):
+            # C rows ascend through a stack's chunks, dead groups last;
+            # nothing lives past its live count
+            assert (np.diff(gc[s].reshape(-1)) >= 0).all()
+            dead = gc[s, tiles.live[s]:]
+            assert (dead == cap_c).all()
+            assert (ga[s, tiles.live[s]:] == kw["pad_a"]).all()
+    if name == "run_lengths":
+        # device (0, 0), tick 0: the run of 9 is a group of 8 and one
+        # of 1 in the widest class, the run of 8 fills one, the run of
+        # 1 goes to the narrowest class there is
+        assert tiles.widths[0] == 8 and tiles.live[0] == tiles.live.max() == 1
+        ga, _, gc = tiles.tiles[0]
+        full = (ga[0, 0] != kw["pad_a"]).sum(axis=1)
+        assert full[:3].tolist() == [8, 1, 8] and not full[3:].any()
+        assert gc[0, 0, 0] == gc[0, 0, 1] != gc[0, 0, 2]
+        ga, _, _ = tiles.tiles[-1]
+        assert (ga[0, 0] != kw["pad_a"]).sum(axis=1).max() == 1
+    elif name == "idle_devices":
+        assert tiles.live.tolist() == [1, 0, 0, 1, 0, 0, 0, 0]
+    elif name == "unequal_live":
+        by_device = tiles.live.reshape(4, 2)
+        assert by_device[:2].min() > by_device[2:].max() >= 1
+    live1, launched1 = slots()
+    assert live1 - live0 == tiles.entries
+    assert launched1 - launched0 == tiles.slots_launched
+    rolled = stats.driver_rollup()["mesh"]
+    assert rolled["slots_live"] - rolled0.get("slots_live", 0) \
+        == tiles.entries
+    assert rolled["slots_launched"] - rolled0.get("slots_launched", 0) \
+        == tiles.slots_launched == int(tiles.live.sum()) * sum(
+            ga.shape[2] * ga.shape[3] for ga, _, _ in tiles.tiles)
 
 
 def test_unfiltered_mesh_plan_lookups_are_counted_miss_then_hit(mesh4):

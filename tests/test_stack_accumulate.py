@@ -382,6 +382,88 @@ def test_group_tiles_hold_every_entry_once_and_the_pad_row_else(
                                                       tiles.tiles))
 
 
+def _tiles_digest(shape):
+    """One hash over what `build_group_tiles` plans for ``shape`` at
+    five (r0, chunk_groups): counts, shapes and every id."""
+    import hashlib
+
+    h = hashlib.sha256()
+    for r0, chunk_groups in [(8, 16), (8, 256), (4, 7), (2, 64), (16, 5)]:
+        tiles, *_ = _tiled(shape, r0, chunk_groups)
+        h.update(repr((int(tiles.live), tiles.groups, tiles.entries,
+                       tiles.widths)).encode())
+        for x in tiles.flat():
+            h.update(repr((x.shape, str(x.dtype))).encode())
+            h.update(np.ascontiguousarray(x).tobytes())
+    return h.hexdigest()[:16]
+
+
+# `_tiles_digest` of PR 32's `build_group_tiles`
+_TILES_PR32 = {
+    "all_runs_1": "6f90203b77df1580",
+    "all_runs_r0": "83767fedec541ea0",
+    "mixed_with_empty_blocks": "eadbd8554f751f6a",
+    "north_star": "837a2cf6fd1177c1",
+    "one_block": "17603896802aa1e5",
+    "runs_past_2_r0": "d577668589b0ee13"
+}
+
+
+@pytest.mark.parametrize("shape", sorted(RUN_SHAPES))
+def test_one_stack_gets_bit_for_bit_the_arrays_it_got(shape):
+    """PR 33 made the tiling plan several stacks at once (a mesh's
+    devices and ticks): the one stack of one chip must come out as it
+    did, id for id, so that its programs and its plan cache hold."""
+    assert _tiles_digest(shape) == _TILES_PR32[shape]
+
+
+@pytest.mark.parametrize("shapes", [
+    ("north_star", "all_runs_1", None, "runs_past_2_r0"),
+    ("one_block", None, None),
+    (None, None),
+])
+def test_several_stacks_share_class_shapes_and_keep_their_own_entries(shapes):
+    """`build_stacks_group_tiles`, what a mesh plan calls for its
+    (device, tick) stacks: one set of classes and chunk capacities,
+    every stack's entries in its own rows and nowhere else, `live` its
+    own chunk count, 0 for a stack with no entry (None here)."""
+    r0, chunk_groups, na, nb, nseg = 8, 16, 40, 50, 4000
+    rng = np.random.default_rng(len(shapes))
+    per_stack = []
+    for shape in shapes:
+        runs = np.zeros(0, int) if shape is None else RUN_SHAPES[shape][0]
+        ci = np.repeat(np.arange(len(runs)), runs).astype(np.int32)
+        per_stack.append((ci, rng.integers(0, na, len(ci)).astype(np.int32),
+                          rng.integers(0, nb, len(ci)).astype(np.int32)))
+    stack_of = np.repeat(np.arange(len(shapes)),
+                         [len(ci) for ci, _, _ in per_stack])
+    ci, ai, bi = (np.concatenate(x) for x in zip(*per_stack))
+    tiles = smm.build_stacks_group_tiles(
+        stack_of, len(shapes), ci, ai, bi, r0, na, nb, nseg, chunk_groups)
+    assert tiles.entries == len(ci)
+    assert tiles.live.shape == (len(shapes),)
+    nchunks = tiles.tiles[0][0].shape[1]
+    assert nchunks == smm.bucket_size(int(tiles.live.max()), minimum=1) or (
+        len(ci) == 0 and nchunks == 1)
+    assert tiles.slots_launched == int(tiles.live.sum()) * sum(
+        ga.shape[2] * ga.shape[3] for ga, _, _ in tiles.tiles)
+    for s, (ci_s, ai_s, bi_s) in enumerate(per_stack):
+        got = []
+        for ga, gb, gc in tiles.tiles:
+            assert ga.shape[:2] == (len(shapes), nchunks)
+            there = ga[s] != na
+            assert not there[tiles.live[s]:].any()
+            assert (gc[s, tiles.live[s]:] == nseg).all()
+            rows = np.broadcast_to(gc[s][:, :, None], there.shape)
+            got += zip(rows[there], ga[s][there], gb[s][there])
+        assert sorted(got) == sorted(zip(ci_s, ai_s, bi_s))
+        assert (tiles.live[s] == 0) == (len(ci_s) == 0)
+        if len(ci_s):  # the least count that covers its fullest class
+            assert tiles.live[s] == max(
+                -(-int((gc[s] < nseg).sum()) // gc.shape[2])
+                for _, _, gc in tiles.tiles)
+
+
 @pytest.mark.parametrize("r0,chunk_groups", [(8, 16), (4, 7), (2, 64)])
 def test_full_runs_give_one_class_and_the_arrays_of_one_width(
         r0, chunk_groups):
