@@ -20,6 +20,10 @@ This is NOT a port.  Design mapping (reference concept -> here):
   no host threading needed.
 """
 
+import time as _time
+
+_t_import = _time.perf_counter()  # the span `import` starts here
+
 from dbcsr_tpu.core.kinds import (
     dbcsr_type_real_4,
     dbcsr_type_real_8,
@@ -229,3 +233,8 @@ __all__ = [
     "verify_matrix",
 ]
 
+# top to bottom of this file, as the timer table's span `import`
+# (`core.timings` did not exist when it began, so it is booked here)
+from dbcsr_tpu.core import timings as _timings
+
+_timings.book("import", _time.perf_counter() - _t_import)

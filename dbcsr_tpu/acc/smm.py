@@ -40,7 +40,7 @@ from dbcsr_tpu.acc import abft as _abft
 from dbcsr_tpu.core import mempool as _mempool
 from dbcsr_tpu.core.config import get_config
 from dbcsr_tpu.core.kinds import real_dtype_of
-from dbcsr_tpu.core.timings import device_scope
+from dbcsr_tpu.core.timings import device_scope, timed
 from dbcsr_tpu.obs import costmodel as _costmodel
 from dbcsr_tpu.obs import events as _events
 from dbcsr_tpu.obs import flight as _flight
@@ -796,12 +796,13 @@ def _ensure_pallas_validated(c_data, a_data, b_data, plan: StackPlan) -> None:
     if key in _validated_kernels:
         return
     ai, bi, ci = plan.val_idx
-    _validate_pallas_kernel(
-        c_data, a_data, b_data, ai, bi, ci,
-        None if plan.append_a_pad else plan.a_pad_row,
-        None if plan.append_b_pad else plan.b_pad_row,
-        plan.r_grp, variant="kmerge" if plan.kmerge else None,
-    )
+    with timed("kernel_validate"):
+        _validate_pallas_kernel(
+            c_data, a_data, b_data, ai, bi, ci,
+            None if plan.append_a_pad else plan.a_pad_row,
+            None if plan.append_b_pad else plan.b_pad_row,
+            plan.r_grp, variant="kmerge" if plan.kmerge else None,
+        )
     _validated_kernels.add(key)
 
 
@@ -1741,12 +1742,13 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
                 )
                 if key not in _validated_kernels:
                     ai, bi, ci = plan.val_idx
-                    _validate_pallas_kernel(
-                        c_data, a_data, b_data, ai, bi, ci,
-                        None if plan.append_a_pad else plan.a_pad_row,
-                        None if plan.append_b_pad else plan.b_pad_row,
-                        None, variant=cross_variant, pack=plan.pack,
-                    )
+                    with timed("kernel_validate"):
+                        _validate_pallas_kernel(
+                            c_data, a_data, b_data, ai, bi, ci,
+                            None if plan.append_a_pad else plan.a_pad_row,
+                            None if plan.append_b_pad else plan.b_pad_row,
+                            None, variant=cross_variant, pack=plan.pack,
+                        )
                     _validated_kernels.add(key)
             a_pad = _append_pad_row(a_data) if plan.append_a_pad else a_data
             b_pad = _append_pad_row(b_data) if plan.append_b_pad else b_data

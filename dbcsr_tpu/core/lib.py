@@ -11,13 +11,16 @@ there is no Fortran-style hard ordering requirement in Python.
 from __future__ import annotations
 
 import os
+import time
 
 import jax
 
 from dbcsr_tpu.core import stats
 from dbcsr_tpu.core import timings
+from dbcsr_tpu.obs import metrics
 
 _initialized = False
+_before_init_booked = False
 
 # the persistent compile cache's home when the environment names none:
 # a FIXED directory in the checkout (git-ignored) — the path is part of
@@ -92,12 +95,39 @@ def steady_host_allocator() -> bool:
     return all([bool(mallopt(param, value)) for param, value in _MALLOPT])
 
 
+def _seconds_since_process_start() -> float | None:
+    """Age of this process by the kernel's account (`/proc/self/stat`
+    field 22, clock ticks after boot, to 10 ms); None where there is no
+    such file."""
+    try:
+        with open("/proc/self/stat") as fh:
+            # the command name, field 2, may hold spaces and brackets
+            fields = fh.read().rpartition(")")[2].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
 def init_lib(enable_x64: bool = True) -> None:
-    global _initialized
+    """Once a process: 64-bit dtypes, and JAX's compile events booked
+    into the registry (`obs.metrics.listen_to_compiles`).  The first
+    call also books the region ``before_init`` in the timer table:
+    everything from the process's start to here (interpreter,
+    ``import jax``, the device client where the caller made one,
+    ``import dbcsr_tpu``, whose own span ``import`` lies inside it)."""
+    global _initialized, _before_init_booked
     if _initialized:
         return
-    if enable_x64:
-        jax.config.update("jax_enable_x64", True)
+    if not _before_init_booked:
+        _before_init_booked = True
+        age = _seconds_since_process_start()
+        if age is not None:
+            timings.book("before_init", age, children=("import",))
+    with timings.timed("init_lib"):
+        if enable_x64:
+            jax.config.update("jax_enable_x64", True)
+        metrics.listen_to_compiles()
     _initialized = True
 
 

@@ -35,6 +35,7 @@ from dbcsr_tpu.core import mempool
 from dbcsr_tpu.core.dist import Distribution
 from dbcsr_tpu.core.kinds import dtype_of, is_complex
 from dbcsr_tpu.core.lib import ensure_init
+from dbcsr_tpu.core.timings import booked
 from dbcsr_tpu.utils.rounding import bucket_size
 
 # matrix_type flags, ref dbcsr_type_no_symmetry/_symmetric/_antisymmetric/
@@ -428,6 +429,10 @@ class BlockSparseMatrix:
         if not self._work and not self._work_batches:
             self.valid = True
             return self
+        with booked("matrix_finalize"):
+            return self._merge_staged()
+
+    def _merge_staged(self) -> "BlockSparseMatrix":
         nbc = self.nblkcols
         if self._work:
             # single-put stagings become a leading replace batch (keys

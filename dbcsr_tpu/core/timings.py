@@ -110,6 +110,35 @@ def timed(name: str):
         timestop(name)
 
 
+def book(name: str, seconds: float, children=()) -> None:
+    """Book a region that no `timed` block can bracket, measured by the
+    caller: ``import`` (the package's own `__init__`, which runs before
+    this module exists) and ``before_init`` (from the process's start).
+    One call, ``seconds`` of total time; the totals already booked under
+    the names in ``children`` lay inside it and are taken off its self
+    time.  No open span, no tracer event, no profiler range."""
+    st = _stats.setdefault(name, _RoutineStat())
+    st.calls += 1
+    st.total += seconds
+    st.self_time += seconds - sum(
+        _stats[c].total for c in children if c in _stats)
+
+
+@contextlib.contextmanager
+def booked(name: str):
+    """`book` around a block: for a region that a thread other than the
+    engine's may run while spans are open (a client staging blocks beside
+    a multiply, the native library's first build).  `timed` keeps one
+    stack for the process and asserts its order, so it cannot bracket
+    such a block; this one touches no stack, and so is no ``phase`` of a
+    compile and takes nothing off an enclosing span's self time."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        book(name, time.perf_counter() - t0)
+
+
 def device_scope(name: str):
     """Name a phase INSIDE a device program: the device counterpart of
     `timed`.

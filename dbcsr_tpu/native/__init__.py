@@ -100,7 +100,14 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _TRIED:
             return _LIB
         _TRIED = True
-        so = _SO if _fresh() else _build()
+        if _fresh():
+            so = _SO
+        else:
+            # g++ on both sources: seconds, once per checkout and CPU
+            from dbcsr_tpu.core.timings import booked
+
+            with booked("native_build"):
+                so = _build()
         if so is None:
             return None
         try:

@@ -110,7 +110,10 @@ def counter_items(name: str) -> list:
     """Public enumeration of one counter's ``(labels_dict, value)``
     pairs — the supported way to read a labelled counter back out
     without binding to the registry's internal label-key encoding.
-    Empty when the counter never incremented."""
+    Empty when the counter never incremented.  The timer table's two
+    families (`_VIEWS`) are collected from `core.timings` here."""
+    if name in _VIEWS:
+        return [(dict(k), float(v)) for k, v in _VIEWS[name][1]()]
     with _lock:
         c = _counters.get(name)
         if c is None:
@@ -176,6 +179,132 @@ def jit_stats() -> dict:
             fn = dict(key).get("fn", "?")
             out.setdefault(fn, {"compiles": 0, "cache_hits": 0})[field] = v
     return out
+
+
+# ------------------------------------------------- JAX's own account
+# (`record_jit` above mirrors specialisation keys and guesses a compile;
+# `listen_to_compiles` books what JAX says it did)
+COMPILE_SECONDS = "dbcsr_tpu_compile_seconds_total"
+COMPILE_PROGRAMS = "dbcsr_tpu_compile_programs_total"
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_listening = False
+
+
+class _CompileThread(threading.local):
+    """Per thread: ``open`` holds, for each event that is still open,
+    the seconds of the closed events inside it (JAX traces a jitted
+    callee inside its caller's trace); ``retrieved`` says a cache
+    retrieval was reported inside the open backend event."""
+
+    def __init__(self):
+        self.open = []
+        self.retrieved = False
+
+
+_compile_thread = _CompileThread()
+
+
+def _on_compile_start(event, _start_time, **_kw) -> None:
+    if event in _COMPILE_STAGES:
+        _compile_thread.open.append(0.0)
+
+
+def _on_compile_duration(event, secs, fun_name="", **_kw) -> None:
+    if event == _CACHE_RETRIEVAL:
+        _compile_thread.retrieved = True
+        return
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    nest = _compile_thread.open
+    inside = nest.pop() if nest else 0.0
+    if nest:
+        nest[-1] += secs
+    if stage == "compile" and _compile_thread.retrieved:
+        stage, _compile_thread.retrieved = "cache_load", False
+    from dbcsr_tpu.core import timings
+
+    # tracing names the function `f`, lowering and the backend name its
+    # module `jit(f)`, the device trace `jit_f`: one name for all
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]
+    labels = {"stage": stage, "fn": fun_name,
+              "phase": timings._stack[-1][0] if timings._stack else ""}
+    counter(COMPILE_SECONDS,
+            "seconds JAX spent to trace, lower, compile or load from the "
+            "persistent cache, per jitted function and engine phase; the "
+            "stages are exclusive").inc(secs - inside, **labels)
+    if stage in ("compile", "cache_load"):
+        counter(COMPILE_PROGRAMS,
+                "programs JAX compiled or loaded from the persistent "
+                "cache, per jitted function and engine phase"
+                ).inc(**labels)
+
+
+def listen_to_compiles() -> None:
+    """Book JAX's compile events into the registry; `core.lib.init_lib`
+    calls this once a process and a second call installs nothing.
+
+    `dbcsr_tpu_compile_seconds_total{stage, fn, phase}` takes the
+    seconds, `dbcsr_tpu_compile_programs_total{stage, fn, phase}` one
+    bump per backend event.  ``stage`` is ``trace``, ``lower``,
+    ``compile`` or ``cache_load`` and the four are exclusive: a backend
+    event inside which JAX reported a cache retrieval is booked whole
+    as ``cache_load``, any other as ``compile``, and an event nested in
+    another (a jitted callee traced inside its caller) is taken off the
+    outer one, so the stages sum to what JAX spent and ``compile`` +
+    ``cache_load`` programs are its backend events.  ``fn`` is the
+    jitted function's name: the event's ``fun_name`` without the
+    ``jit(...)`` that lowering and the backend put around it (the
+    device trace's module is ``jit_<fn>``); ``phase`` is the innermost open
+    `core.timings.timed` span, ``""`` outside all of them.  The timer's
+    stack is one per process, so a compile on a second thread is booked
+    to whatever span the first has open: best effort across threads.
+
+    Nothing runs unless JAX traces, compiles or loads a program: a
+    steady product pays nothing for it."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    import jax.monitoring
+
+    jax.monitoring.register_scalar_listener(_on_compile_start)
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_compile_duration)
+
+
+# ------------------------------------------ the timer table, when read
+def _span_seconds() -> list:
+    from dbcsr_tpu.core import timings
+
+    return [(_label_key({"span": name, "kind": kind}), value)
+            for name, st in list(timings._stats.items())
+            for kind, value in (("self", st.self_time), ("total", st.total))]
+
+
+def _span_calls() -> list:
+    from dbcsr_tpu.core import timings
+
+    return [(_label_key({"span": name}), st.calls)
+            for name, st in list(timings._stats.items())]
+
+
+# counters that are views: collected from their source when read (by
+# `counter_items`, `snapshot`, `prometheus_text`), never bumped, so
+# `core.timings.timed` pays nothing for being readable
+_VIEWS = {
+    "dbcsr_tpu_span_seconds_total": (
+        "self and total host seconds per core.timings span", _span_seconds),
+    "dbcsr_tpu_span_calls_total": (
+        "completed calls per core.timings span", _span_calls),
+}
 
 
 def reset(include_stats: bool = True) -> None:
@@ -347,6 +476,9 @@ def snapshot() -> dict:
     if xc:
         snap["xla_cost"] = xc
     snap["counters"] = expand(_counters)
+    for name, (_, collect) in _VIEWS.items():
+        snap["counters"][name] = {json.dumps(dict(k)): v
+                                  for k, v in collect()}
     snap["gauges"] = expand(_gauges)
     snap["histograms"] = {
         name: {
@@ -411,6 +543,8 @@ def prometheus_text() -> str:
     # registry metrics
     for name, c in sorted(_counters.items()):
         emit(name, "counter", c.help or name, sorted(c.values.items()))
+    for name, (help, collect) in sorted(_VIEWS.items()):
+        emit(name, "counter", help, sorted(collect()))
     for name, g in sorted(_gauges.items()):
         emit(name, "gauge", g.help or name, sorted(g.values.items()))
     for name, h in sorted(_histograms.items()):
