@@ -48,16 +48,38 @@ from dbcsr_tpu.obs import metrics as _metrics
 from dbcsr_tpu.obs import tracer as _trace
 from dbcsr_tpu.resilience import breaker as _breaker
 from dbcsr_tpu.resilience import faults as _faults
-from dbcsr_tpu.utils.rounding import bucket_size
+from dbcsr_tpu.utils.rounding import bucket_size, ceil_div
 
 
 def emulated_dtype_on_tpu(dtype) -> bool:
     """True when ``dtype`` is software-EMULATED on the current device
-    (f64/c128 on TPU: split-f32/bf16 passes).  The single gate shared
-    by every driver decision that exists to counter the emulation
-    penalty (the xla_group default here and the mesh path's
+    (f64/c128 on TPU).  The single gate shared by every driver decision
+    that exists to counter the emulation penalty (the xla_group default
+    here, the form of its dot, `group_dot_form`, and the mesh path's
     `_stack_r0`).  Keys on `effective_platform` so the CPU suite can
-    assert the TPU branch (config.platform_override seam)."""
+    assert the TPU branch (config.platform_override seam).
+
+    What the emulation is: a v5e keeps an f64 as two f32 halves, and its
+    compiler expands an f64 ``dot_general`` where the dot stands, in
+    five stages: (1) `X64SplitHigh` / `X64SplitLow` of both operands;
+    (2) two `while` loops of 8 steps, one an operand, whose body
+    (`select_dynamic-update-slice_fusion`, about a hundred elementwise
+    ops) cuts every element into 8 f32 slices on an absolute grid of
+    8-bit exponent windows (`remainder` by a power of two from the
+    exponent, window = exponent >> 3, slice index = window & 7);
+    (3) a `while` of 4 steps that pairs neighbouring slices into four
+    more strips (a Karatsuba pairing), a `convert` of the slices to bf16
+    and a relayout `copy` of B's; (4) a `while` of 16 steps of three
+    bf16 `convolution`s each into f32, the only MXU work, accumulated by
+    order into eight f32 accumulators; (5) a `while` of 8 steps and two
+    fusions that fold the accumulators into the two halves,
+    `X64Combine`.  Stages 1-3 are elementwise in the operands: beside a
+    dense O(N^3) dot they are nothing, on the strips a stack chunk
+    gathers they were two thirds of the dot (PERF.md, PR 35).  To see
+    them: compile ``lax.dot_general`` of ``f64[256,23,184]`` by
+    ``f64[256,184,23]`` for a described v5e as `tests/
+    test_chip_compiles.py` does (`topologies.get_topology_desc("tpu",
+    "v5e:2x2")`, ``.lower(...).compile().as_text()``)."""
     from dbcsr_tpu.core.config import effective_platform
 
     return (
@@ -124,6 +146,141 @@ def _batch_dot(a, b, acc, prec):
     ah, al = _split_hi_lo(a, cdt)
     bh, bl = _split_hi_lo(b, cdt)
     return dot(ah, bh) + (dot(ah, bl) + dot(al, bh))
+
+
+# bf16 slices an emulated f64 is cut into: 8 windows of 8 bits hold the
+# 64 bits under an element's leading window, more than its two f32
+# halves carry
+SLICES = 8
+# the deepest strip whose slice-pair dots are exact in f32: a slice is
+# at most 2^7 of its grid's units, a product 2^14, 1 024 of them 2^24
+SLICED_MAX_DEPTH = 1024
+
+
+def group_dot_form(dtype, depth: int, prec=None) -> str:
+    """How the grouped chunk loop multiplies a group's strips of
+    ``depth`` = r0 * k: "sliced" (`_slice_blocks`, `_sliced_dot`) where
+    the dtype is real f64 that the device emulates, the plan executes
+    it as it is and the depth keeps the slice products exact;
+    "compiler" (`_batch_dot`) everywhere else: native dtypes, c128, the
+    demoted and compensated precisions.  Decided where a plan is made
+    and handed to the programs as a static argument, so that a
+    program's form is part of its cache key."""
+    if (prec is None and np.dtype(dtype) == np.float64
+            and depth <= SLICED_MAX_DEPTH and emulated_dtype_on_tpu(dtype)):
+        return "sliced"
+    return "compiler"
+
+
+def _f32_fixed(v, lead):
+    """f32 ``v`` as a signed int64 in units of 2^(8*lead - 56), bits
+    under the unit dropped; a subnormal reads 0."""
+    u = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    eb = ((u >> 23) & 0xFF).astype(jnp.int32)
+    sig = jnp.where(eb > 0, (u & 0x7FFFFF) | 0x800000, 0).astype(jnp.int64)
+    up = eb - 150 - (8 * lead - 56)  # the significand's unit over ours
+    mag = jnp.where(up >= 0, sig << jnp.clip(up, 0, 63),
+                    sig >> jnp.clip(-up, 0, 63))
+    return jnp.where(u >> 31 != 0, -mag, mag)
+
+
+def _bf16_slices(data):
+    """(N, r, c) f64 blocks cut into (N, SLICES, r, c) bf16 slices that
+    sum to the blocks.
+
+    The cut is the one the TPU compiler makes of the operands of an
+    emulated-f64 dot (`emulated_dtype_on_tpu`), made once per stored
+    block and not once per gathered slot.  An element is the two f32
+    halves the device keeps it in, ``hi = f32(x)`` and
+    ``lo = f32(x - hi)``.  The grid is absolute: window w holds the bits
+    that weigh 2^(8w) to 2^(8w+7).  An element whose hi is under
+    2^(8L+6) is written in the `SLICES` windows L, L-1, ... as signed
+    digits of -128 to 127, and the digit of window w is slice w mod
+    `SLICES`: a ring.  The digits are the bytes of the element as a
+    64-bit integer (`_f32_fixed`, under 2^62) plus 0x80 in every byte,
+    less 128 each: the one addition takes every carry.  So a slice is
+    an integer of 8 bits times a power of two, exactly a bf16; the
+    slices of an element sum to hi + lo exactly, since lo ends at most
+    53 bits under hi's leading one and the ring holds 55 or more; and
+    the elements of one slice index lie on one grid, or 2^64 apart.
+    Bits under 2^-120 (windows below -15, where bf16 runs out of
+    exponent) are dropped, as the device flushes a subnormal f32; from
+    2^126 on, where a product overflows the two halves anyway, the
+    slices are not finite."""
+    f32 = jnp.float32
+    hi = data.astype(f32)
+    lo = (data - hi.astype(data.dtype)).astype(f32)
+    # a pair the device did not leave normalised would let hi's and
+    # lo's bits overlap
+    top = hi + lo
+    lo = lo - (top - hi)
+    hi = top
+    eb = (jax.lax.bitcast_convert_type(hi, jnp.uint32) >> 23) & 0xFF
+    lead = (eb.astype(jnp.int32) - 125) >> 3
+    fixed = _f32_fixed(hi, lead) + _f32_fixed(lo, lead)
+    biased = fixed.astype(jnp.uint64) + jnp.uint64(0x8080808080808080)
+    slices = []
+    for i in range(SLICES):
+        below = (lead - i) & (SLICES - 1)  # windows under the leading one
+        byte = biased >> (8 * (SLICES - 1 - below)).astype(jnp.uint64)
+        digit = (byte & jnp.uint64(0xFF)).astype(jnp.int32) - 128
+        w = lead - below
+        unit = jax.lax.bitcast_convert_type((8 * w + 127) << 23, f32)
+        slices.append(jnp.where(w >= -15, digit.astype(f32) * unit, 0))
+    return jnp.stack(slices, axis=1).astype(jnp.bfloat16)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _slice_blocks(data, depth_axis: int):
+    """(N, r, c) f64 blocks as (N, kp, SLICES*o) bf16 blocks of their
+    `_bf16_slices`, depth-major: ``depth_axis`` (2 for A's (m, k), 1
+    for B's (k, n)) is the dimension a group's dot contracts, filled
+    with zero rows to ``kp``, whole bf16 tiles of 16 sublanes; the
+    other dimension o lies beside the slice index, for A filled with
+    zero rows to whole f32 tiles of 8 (the rows of the product's tiles,
+    which `_sliced_dot` adds up as whole tiles).  So the blocks a group
+    gathers, set on end, ARE its strip: (w, kp, ..) is (w*kp, ..)
+    without a relayout, which the (m, w*k) strip of the compiler's form
+    pays per chunk.  Jitted so that a process traces and lowers the cut
+    once per operand shape, not once per span of every fused program
+    (a chain compiles 22 of eight spans each)."""
+    n_blk, r, c = data.shape
+    sl = _bf16_slices(data)  # (N, SLICES, r, c)
+    if depth_axis == 2:  # A: (N, k, SLICES, m)
+        sl = sl.transpose(0, 3, 1, 2)
+        sl = jnp.pad(sl, ((0, 0),) * 3 + ((0, ceil_div(r, 8) * 8 - r),))
+    else:  # B: (N, k, SLICES, n)
+        sl = sl.transpose(0, 2, 1, 3)
+    k = sl.shape[1]
+    sl = sl.reshape(n_blk, k, -1)
+    return jnp.pad(sl, ((0, 0), (0, ceil_div(k, 16) * 16 - k), (0, 0)))
+
+
+def _halve(x, axis: int):
+    """``x`` summed along ``axis`` (a power of two long) as a tree of
+    halves: log2 roundings deep where a running sum has one a term."""
+    while x.shape[axis] > 1:
+        lower, upper = jnp.split(x, 2, axis=axis)
+        x = lower + upper
+    return jnp.squeeze(x, axis)
+
+
+def _sliced_dot(amat, bmat, m, n, acc):
+    """A group's product from its sliced strips, both depth-major
+    (`_slice_blocks`): ``amat`` (ch, depth, SLICES*mp) and ``bmat``
+    (ch, depth, SLICES*n) in bf16 give all SLICES^2 slice-pair
+    products as the (mp, n) tiles of ONE native dot.  Every tile is
+    exact in f32 (`SLICED_MAX_DEPTH`; where elements of a strip lie
+    2^64 apart the smaller falls under the larger's last bit).  The
+    tiles are summed in ``acc`` (f64), the only rounding, pair by pair
+    (`_halve`: the first level adds two f32 and is exact): first down
+    the rows, whole tiles of 8 sublanes, then what is left, an eighth,
+    along the lanes."""
+    tiles = jax.lax.dot_general(amat, bmat, (((1,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+    ch, smp, sn = tiles.shape
+    rows = _halve(tiles.reshape(ch, SLICES, smp // SLICES, sn).astype(acc), 1)
+    return _halve(rows.reshape(ch, -1, SLICES, n), 2)[:, :m]
 
 
 def _accumulate_chunk(c, prod, c_idx):
@@ -194,18 +351,37 @@ _process_stack_xla_flat = functools.partial(
     _stack_phases_xla_flat)
 
 
-# the layout `_stack_phases_xla_group` gathers A and B from
+# the layout `_stack_phases_group` gathers A and B from
 GROUP_GATHER_LAYOUT = "row"
 
 
-def _note_group_gather() -> None:
-    """Count one launched `xla_group` span by its gather layout."""
+def _note_group_span(dot_form: str) -> None:
+    """Count one launched `xla_group` span by its gather layout and by
+    the form of its dot."""
+    note_group_dot(dot_form)
     _metrics.counter(
         "dbcsr_tpu_stack_gather_total",
         "xla_group spans launched (per span or inside a fused launch), "
-        "by the layout their A/B gathers read: 'row' = whole blocks as "
-        "lane-dense (N, m*k) rows",
+        "by the layout their A/B gathers read: 'row' = whole blocks, "
+        "one a row of the array gathered from (lane-dense (N, m*k) rows; "
+        "with the sliced dot (N, kp, 8*m) blocks of bf16 slices)",
     ).inc(layout=GROUP_GATHER_LAYOUT)
+
+
+def note_group_dot(dot_form: str, driver: str = "xla_group") -> None:
+    """Count one launched grouped span (an `xla_group` span through
+    `_note_group_span`, or one product's grouped mesh stacks:
+    ``driver`` "mesh") by the form of its dot (`group_dot_form`)."""
+    _metrics.counter(
+        "dbcsr_tpu_stack_dot_total",
+        "grouped spans launched, by how the chunk loop multiplies a "
+        "group's strips: 'sliced' = bf16 slices cut once per stored "
+        "block and one native dot a width class (emulated f64), "
+        "'compiler' = the compiler's dot of the gathered strips",
+    ).inc(form=dot_form)
+    from dbcsr_tpu.core import stats
+
+    stats.record_group_dot(dot_form, driver=driver)
 
 
 def _note_group_slots(tiles: "GroupTiles", driver: str = "xla_group") -> None:
@@ -250,7 +426,8 @@ def _take_rows(rows, ids):
     return rows.at[ids].get(mode="promise_in_bounds")
 
 
-def group_chunk_loop(c, a, b, live, tiles, alpha=None, prec=None):
+def group_chunk_loop(c, a, b, live, tiles, alpha=None, prec=None,
+                     dot_form="compiler"):
     """The grouped chunk loop, the one body behind `xla_group` on one
     chip and the mesh engine's ticks (`parallel/sparse_dist.py`):
     ``c[gc] += alpha * A-strip @ B-strip`` for every group the tiles
@@ -267,38 +444,62 @@ def group_chunk_loop(c, a, b, live, tiles, alpha=None, prec=None):
     bucketed (the program's shapes hold still while the pattern moves)
     and the chunks past ``live`` are never read.  ``alpha`` None leaves
     the products unscaled (the mesh scales its finished panel).
+    ``dot_form`` is the plan's `group_dot_form`.
 
-    Once per call A and B become `_block_rows`; then ONE loop carries
-    ``c`` whatever the number of classes; a step gathers, multiplies
-    and adds chunk t of every class, widest first, under the
-    `stk_gather` / `stk_dot` / `stk_accum` scopes, the loop itself
-    under `stk_loop`.  `_stack_phases_xla_group` says why each form is
-    what it is."""
+    Once per call A and B become `_block_rows`, or with the sliced form
+    `_slice_blocks` (under `stk_dot/stk_split`: what the dot costs); then
+    ONE loop carries ``c`` whatever the number of classes; a step
+    gathers, multiplies and adds chunk t of every class, widest first,
+    under the `stk_gather` / `stk_dot` / `stk_accum` scopes, the loop
+    itself under `stk_loop`.  `_stack_phases_group` says why each
+    form is what it is."""
     live = jnp.reshape(live, ())
     _, m, n = c.shape
     k = a.shape[2]
-    with device_scope("stk_gather"):
-        a_rows = _block_rows(a)
-        b_rows = _block_rows(b)
     acc = _accum_dtype(c.dtype)
+    if dot_form == "sliced":
+        with device_scope("stk_dot"), device_scope("stk_split"):
+            a_rows = _slice_blocks(a, 2)
+            b_rows = _slice_blocks(b, 1)
+
+        def strips(ia, ib):
+            ch, w = ia.shape
+            ablk = _take_rows(a_rows, ia.reshape(-1))
+            bblk = _take_rows(b_rows, ib.reshape(-1))
+            return (ablk.reshape((ch, w * ablk.shape[1], -1)),
+                    bblk.reshape((ch, w * bblk.shape[1], -1)))
+
+        def dot(amat, bmat):
+            return _sliced_dot(amat, bmat, m, n, acc)
+    else:
+        with device_scope("stk_gather"):
+            a_rows = _block_rows(a)
+            b_rows = _block_rows(b)
+
+        def strips(ia, ib):
+            ch, w = ia.shape
+            ablk = _take_rows(a_rows, ia.reshape(-1)).reshape(ch, w, m, k)
+            bblk = _take_rows(b_rows, ib.reshape(-1))
+            amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, w * k)
+            bmat = bblk.reshape(ch, w * k, n)
+            ragged = -(w * k) % 8
+            if ragged:  # zeros up to whole sublanes
+                amat = jnp.pad(amat, ((0, 0), (0, 0), (0, ragged)))
+                bmat = jnp.pad(bmat, ((0, 0), (0, ragged), (0, 0)))
+            return amat, bmat
+
+        def dot(amat, bmat):
+            return _batch_dot(amat, bmat, acc, prec)
 
     def body(t, c):
         for ga, gb, gc in tiles:
-            _, ch, w = ga.shape
             with device_scope("stk_gather"):
                 ia = jax.lax.dynamic_index_in_dim(ga, t, keepdims=False)
                 ib = jax.lax.dynamic_index_in_dim(gb, t, keepdims=False)
                 ic = jax.lax.dynamic_index_in_dim(gc, t, keepdims=False)
-                ablk = _take_rows(a_rows, ia.reshape(-1)).reshape(ch, w, m, k)
-                bblk = _take_rows(b_rows, ib.reshape(-1))
-                amat = jnp.swapaxes(ablk, 1, 2).reshape(ch, m, w * k)
-                bmat = bblk.reshape(ch, w * k, n)
-                ragged = -(w * k) % 8
-                if ragged:  # zeros up to whole sublanes
-                    amat = jnp.pad(amat, ((0, 0), (0, 0), (0, ragged)))
-                    bmat = jnp.pad(bmat, ((0, 0), (0, ragged), (0, 0)))
+                amat, bmat = strips(ia, ib)
             with device_scope("stk_dot"):
-                prod = _batch_dot(amat, bmat, acc, prec)
+                prod = dot(amat, bmat)
                 if alpha is not None:
                     prod = alpha.astype(acc) * prod
                 prod = prod.astype(c.dtype)
@@ -309,8 +510,8 @@ def group_chunk_loop(c, a, b, live, tiles, alpha=None, prec=None):
         return jax.lax.fori_loop(0, live, body, c)
 
 
-def _stack_phases_xla_group(c_data, a_data, b_data, live, *tiles_alpha,
-                            prec=None):
+def _stack_phases_group(c_data, a_data, b_data, live, *tiles_alpha,
+                        prec=None, dot_form="compiler"):
     """R-tiled ("k-merged") stack layout: entries sharing a C block are
     tiled into groups; each group's A blocks concatenate along k into
     one (m, w*k) strip, its B blocks into (w*k, n), and the whole group
@@ -319,16 +520,22 @@ def _stack_phases_xla_group(c_data, a_data, b_data, live, *tiles_alpha,
 
     This is the f64 answer to the MXU-utilization problem the reference
     solves with kernel `grouping` (`smm_acc_dnt_*.h`: one thread block
-    processes `grouping` stack entries): on TPU, f64 is emulated in
-    split-f32/bf16 passes, so per-entry 23^3 dots run at 1.6 GFLOP/s;
-    groups of R0 = 8 measured 6.3 (the tuner at S=100000 on a v5e,
-    whose synthetic stack has runs of mean 8 entries a C block;
-    PERF.md, PR 21).
+    processes `grouping` stack entries): on TPU, f64 is emulated
+    (`emulated_dtype_on_tpu` lists the stages), and with the compiler's
+    dot per-entry 23^3 products ran at 1.6 GFLOP/s, groups of R0 = 8 at
+    6.3 (the tuner at S=100000 on a v5e, whose synthetic stack has runs
+    of mean 8 entries a C block; PERF.md, PR 21).  Since PR 35 a real
+    f64 span takes the sliced form (``dot_form``, `group_dot_form`):
+    the operands are cut into bf16 slices once per stored block and a
+    group is one native dot, where the compiler cut every gathered
+    strip anew, 58 times a block in the north star's product.
 
     ``tiles_alpha`` is what `build_group_tiles` planned, flattened, and
     alpha; ``live`` the plan's live chunk count; the loop is
     `group_chunk_loop`, which the mesh engine's ticks run too (PR 33).
-    A C block's groups all lie
+    What follows of strips, depths and block rows is the compiler's
+    form (every dtype but emulated real f64); the sliced form's are in
+    `_slice_blocks` and `_sliced_dot`.  A C block's groups all lie
     in one class in stack order, so its products are added in stack
     order, full groups first and the remainder of its run last
     (deterministic: the order is the plan's).  A strip whose depth w*k
@@ -348,12 +555,13 @@ def _stack_phases_xla_group(c_data, a_data, b_data, live, *tiles_alpha,
     """
     *flat, alpha = tiles_alpha
     tiles = [flat[i:i + 3] for i in range(0, len(flat), 3)]
-    return group_chunk_loop(c_data, a_data, b_data, live, tiles, alpha, prec)
+    return group_chunk_loop(c_data, a_data, b_data, live, tiles, alpha, prec,
+                            dot_form)
 
 
 _process_stack_xla_group = functools.partial(
-    jax.jit, donate_argnums=0, static_argnames=("prec",))(
-    _stack_phases_xla_group)
+    jax.jit, donate_argnums=0, static_argnames=("prec", "dot_form"))(
+    _stack_phases_group)
 
 
 # a narrower width class is opened only where it takes this share of
@@ -709,7 +917,7 @@ class StackPlan:
                  "b_pad_row", "append_a_pad", "append_b_pad", "val_idx",
                  "group_idx", "kmerge", "pack", "cross_launches",
                  "cross_vmem", "cross_src", "host_idx", "src_idx",
-                 "src_pads", "precision")
+                 "src_pads", "precision", "dot_form")
 
     def __init__(self):
         self.driver = "xla"
@@ -719,6 +927,7 @@ class StackPlan:
         self.r_grp = 1
         self.group_classes = ()  # xla_group: ((width, live groups), ...)
         self.group_launched = 0  # xla_group: slots its live chunks launch
+        self.dot_form = "compiler"  # xla_group: `group_dot_form`
         self.a_pad_row = None
         self.b_pad_row = None
         self.append_a_pad = False  # pallas/group: append a zero row at execute
@@ -967,6 +1176,8 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
         plan.group_launched = tiles.slots_launched
         _note_group_slots(tiles)
         plan.precision = prec
+        plan.dot_form = group_dot_form(c_data.dtype, r0 * a_data.shape[2],
+                                       prec)
         plan.a_pad_row = a_pad_row
         plan.b_pad_row = b_pad_row
         # the device index mirror (core.mempool): pattern-stable
@@ -1206,7 +1417,7 @@ def _record_stack_jit(plan: StackPlan, c_data, a_data, b_data):
         dev_entries = int(plan.xla_idx[0].size)
     elif drv == "xla_group":
         key = (c_data.shape, a_data.shape, b_data.shape, dt,
-               _group_idx_shapes(plan), plan.precision)
+               _group_idx_shapes(plan), plan.precision, plan.dot_form)
         fn = "_process_stack_xla_group"
         dev_entries = plan.group_launched
     elif drv == "pallas":
@@ -1239,7 +1450,8 @@ def _record_stack_jit(plan: StackPlan, c_data, a_data, b_data):
 
 
 def _capture_stack_xla_cost(fn_name, key, jit_fn, args, c_data, a_data,
-                            b_data, entries: int, prec=None) -> None:
+                            b_data, entries: int, prec=None,
+                            dot_form=None) -> None:
     """Opt-in XLA cost_analysis capture for a fresh stack-kernel
     specialization, with the analytic model of the DEVICE work (padded
     entries — XLA counts the masked pad rows too) stored alongside for
@@ -1254,9 +1466,13 @@ def _capture_stack_xla_cost(fn_name, key, jit_fn, args, c_data, a_data,
             m, n, k, entries, nseg=c_data.shape[0],
             itemsize=jnp.dtype(c_data.dtype).itemsize),
     }
+    kwargs = {}
+    if prec is not None:
+        kwargs["prec"] = prec
+    if dot_form is not None:  # the grouped program alone takes one
+        kwargs["dot_form"] = dot_form
     costmodel.capture_xla_cost(
-        fn_name, key, jit_fn, args, model=model,
-        kwargs=({"prec": prec} if prec is not None else None))
+        fn_name, key, jit_fn, args, model=model, kwargs=kwargs or None)
 
 
 # safety-ordered stack-driver chain (the reference's unsupported-kernel
@@ -1717,17 +1933,17 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
         if plan.append_b_pad:
             b_data = _append_pad_row(b_data)
         alpha_dev = jnp.asarray(alpha, dtype=c_data.dtype)
-        _note_group_gather()
+        _note_group_span(plan.dot_form)
         if want_xla_cost:
             _capture_stack_xla_cost(
                 jit_fn_name, jit_key, _process_stack_xla_group,
                 (c_data, a_data, b_data, *plan.group_idx, alpha_dev),
                 c_data, a_data, b_data, plan.group_launched,
-                prec=plan.precision,
+                prec=plan.precision, dot_form=plan.dot_form,
             )
         return _process_stack_xla_group(
             c_data, a_data, b_data, *plan.group_idx, alpha_dev,
-            prec=plan.precision,
+            prec=plan.precision, dot_form=plan.dot_form,
         )
     if plan.driver == "pallas_cross":
         from dbcsr_tpu.acc import pallas_smm
@@ -1980,7 +2196,7 @@ def prepare_superstack(plans) -> Optional[SuperstackPlan]:
             (len(p.group_idx) if p.driver == "xla_group"
              else 3 if p.driver in _XLA_FAMILY else 3 * len(p.launches)),
             bool(p.append_a_pad), bool(p.append_b_pad),
-            p.r_grp, bool(p.kmerge), p.precision,
+            p.r_grp, bool(p.kmerge), p.precision, p.dot_form,
         )
         for p in plans
     )
@@ -2008,8 +2224,8 @@ def _fused_fn(sig):
         from dbcsr_tpu.acc import pallas_smm
 
         pos = 0
-        for i, (driver, n_idx, ap_a, ap_b, r_grp, kmerge,
-                prec) in enumerate(spans_sig):
+        for i, (driver, n_idx, ap_a, ap_b, r_grp, kmerge, prec,
+                dot_form) in enumerate(spans_sig):
             a_data = flat[pos]
             b_data = flat[pos + 1]
             idx = flat[pos + 2: pos + 2 + n_idx]
@@ -2021,8 +2237,9 @@ def _fused_fn(sig):
                 if ap_b:
                     b_data = _append_pad_row(b_data)
                 if driver == "xla_group":
-                    c_data = _stack_phases_xla_group(
-                        c_data, a_data, b_data, *idx, alpha_dev, prec=prec)
+                    c_data = _stack_phases_group(
+                        c_data, a_data, b_data, *idx, alpha_dev, prec=prec,
+                        dot_form=dot_form)
                 elif driver == "pallas":
                     launches = [tuple(idx[3 * j: 3 * j + 3])
                                 for j in range(n_idx // 3)]
@@ -2037,6 +2254,12 @@ def _fused_fn(sig):
                                   prec=prec)
         return c_data
 
+    if any(span[-1] == "sliced" for span in spans_sig):
+        # the sliced spans' scopes are new (`stk_dot/stk_split`) and a
+        # program whose scopes change gets a new name (above); the
+        # programs of every other dtype are what they were and keep
+        # theirs, and with it their compile-cache entries
+        fused_superstack.__name__ = "fused_superstack_sliced"
     fn = jax.jit(fused_superstack, donate_argnums=0)
     _fused_fns[sig] = fn
     while len(_fused_fns) > _FUSED_FN_MAX:
@@ -2180,7 +2403,7 @@ def _dispatch_superstack(c_data, a_datas, b_datas, splan: SuperstackPlan,
             flat.extend(plan.xla_idx)
         elif plan.driver == "xla_group":
             flat.extend(plan.group_idx)
-            _note_group_gather()
+            _note_group_span(plan.dot_form)
         else:
             for lc in plan.launches:
                 flat.extend(lc)

@@ -269,6 +269,7 @@ def _tune_smm_x64(m, n, k, dtype_enum, stack_size, nrep, out, seed, jax, jnp,
         _process_stack_xla_group,
         build_group_tiles,
         group_chunk_groups,
+        group_dot_form,
     )
 
     a_padded = jnp.concatenate([a, jnp.zeros((1, m, k), dtype)])
@@ -284,10 +285,11 @@ def _tune_smm_x64(m, n, k, dtype_enum, stack_size, nrep, out, seed, jax, jnp,
                                            *tiles.flat())))
         fill = tiles.entries / tiles.slots_launched
 
-        def run_group(grp_args=grp_args):
+        def run_group(grp_args=grp_args, r0=r0):
             return _process_stack_xla_group(
                 jnp.zeros((nc, m, n), dtype), a_padded, b_padded, *grp_args,
                 jnp.asarray(1.0, dtype),
+                dot_form=group_dot_form(dtype, r0 * k),  # as a plan would
             )
 
         try:

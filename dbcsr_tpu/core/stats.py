@@ -53,6 +53,9 @@ class _DriverAgg:
     slots_live: int = 0
     slots_launched: int = 0
     groups_by_width: dict = dataclasses.field(default_factory=dict)
+    # grouped spans launched, by the form of their dot
+    # (`acc.smm.group_dot_form`)
+    dot_forms: dict = dataclasses.field(default_factory=dict)
 
 
 _by_mnk: dict = collections.defaultdict(_MnkStat)
@@ -126,6 +129,17 @@ def record_group_tiles(widths, groups, slots_live: int,
         agg.groups_by_width[w] = agg.groups_by_width.get(w, 0) + n
 
 
+def record_group_dot(dot_form: str, driver: str = "xla_group") -> None:
+    """One launched grouped span (``driver`` "mesh": one product's
+    grouped mesh stacks) by the form of its dot."""
+    from dbcsr_tpu.core.config import get_config
+
+    if not get_config().keep_stats:
+        return
+    forms = _driver_agg[driver].dot_forms
+    forms[dot_form] = forms.get(dot_form, 0) + 1
+
+
 def driver_rollup() -> dict:
     """Plain-dict view of the per-driver attribution aggregates."""
     out = {}
@@ -142,6 +156,8 @@ def driver_rollup() -> dict:
             out[d].update(slots_live=a.slots_live,
                           slots_launched=a.slots_launched,
                           groups_by_width=dict(a.groups_by_width))
+        if a.dot_forms:
+            out[d]["dot_forms"] = dict(a.dot_forms)
     return out
 
 

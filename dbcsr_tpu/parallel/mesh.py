@@ -8,7 +8,7 @@ Shape policy (`grid_shape`): square pr == pc grids run the skewed
 sparse Cannon; when the device count has no usable square factor (6,
 10, 14, ...) or an explicit layer count forces it (8 devices, layers=1),
 the grid goes RECTANGULAR pr != pc and the sparse engine switches to
-the all-gather algorithm (`sparse_dist._stack_mesh_run(gather=True)`) — the
+the all-gather algorithm (`sparse_dist._stack_run_mesh(gather=True)`) — the
 role the reference gives to image distributions over arbitrary
 nprows x npcols grids (`dbcsr_types.F:188-223`,
 `dbcsr_mm_dist_operations.F:58`).

@@ -213,7 +213,7 @@ def _stack_r0(dtype) -> int:
     `group_chunk_loop`, the program `xla_group` runs on one chip) and
     the flat ones (0: per-entry gathers and a segment sum): group
     emulated dtypes (f64/c128 — per-entry dots are MXU-starved under
-    emulation, see `acc/smm.py:_stack_phases_xla_group`).  Auto mode
+    emulation, see `acc/smm.py:_stack_phases_group`).  Auto mode
     applies this on TPU only (f64 is native elsewhere; per-entry dots
     are fine there); mm_driver='xla_group' forces it on any platform
     (how the CPU-mesh tests cover the grouped layout)."""
@@ -226,6 +226,22 @@ def _stack_r0(dtype) -> int:
     if driver != "auto":
         return 0
     return 8 if emulated_dtype_on_tpu(dtype) else 0
+
+
+def _stack_dot_form(r0: int, bk: int, dtype) -> str:
+    """The form of the grouped ticks' dot, `acc/smm.py:group_dot_form`
+    at the mesh stacks' depth (flat ticks, r0 = 0: the compiler's)."""
+    from dbcsr_tpu.acc.smm import group_dot_form
+
+    return group_dot_form(dtype, r0 * bk) if r0 else "compiler"
+
+
+def _note_mesh_dot(plan) -> None:
+    """Count one product's grouped mesh stacks by their dot's form."""
+    if plan.r0:
+        from dbcsr_tpu.acc.smm import note_group_dot
+
+        note_group_dot(plan.dot_form, driver="mesh")
 
 
 _TICK_CHUNK_ENTRIES = 32768
@@ -294,11 +310,12 @@ def _stack_of_tick(st, t):
         st)
 
 
-def _tick_contrib_chunked(a, b, c, st_tick, *, r0, cap_c, acc_dtype):
+def _tick_contrib_chunked(a, b, c, st_tick, *, r0, cap_c, acc_dtype,
+                          dot_form="compiler"):
     """One tick's full contribution into the device's C panel: ONE
     implementation shared by the fused metronome body
     (`_cannon_tick_loop`) and the split per-tick programs
-    (`_stack_mesh_tick`, `_stack_gather_tick`, `_stack_grouped_tick`),
+    (`_stack_tick_mesh`, `_stack_tick_gather`, `_stack_tick_grouped`),
     so the two execution modes are bitwise identical by construction.
 
     Grouped (r0 > 0): the one-chip engine's `acc/smm.py:group_chunk_loop`
@@ -320,7 +337,7 @@ def _tick_contrib_chunked(a, b, c, st_tick, *, r0, cap_c, acc_dtype):
         from dbcsr_tpu.acc.smm import group_chunk_loop
 
         live, tiles = st_tick
-        return group_chunk_loop(c, a, b, live, tiles)
+        return group_chunk_loop(c, a, b, live, tiles, dot_form=dot_form)
     nchunk, rows = _tick_chunks(st_tick.shape[0])
     if nchunk > 1:
         st_t = st_tick.reshape(nchunk, rows, st_tick.shape[1])
@@ -333,7 +350,8 @@ def _tick_contrib_chunked(a, b, c, st_tick, *, r0, cap_c, acc_dtype):
     return _stack_contrib(a, b, c, st_tick, cap_c=cap_c, acc_dtype=acc_dtype)
 
 
-def _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=0, nticks=None):
+def _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=0, nticks=None,
+                      dot_form="compiler"):
     """The shared Cannon metronome: ticks of `_tick_contrib_chunked`,
     ring-shifting A along 'pc' and B along 'pr' between them, outside
     the chunk loop (ref the grouped_k_index loop,
@@ -352,7 +370,8 @@ def _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=0, nticks=None):
     def tick(t, carry):
         a, b, c = carry
         c = _tick_contrib_chunked(a, b, c, _stack_of_tick(st, t), r0=r0,
-                                  cap_c=cap_c, acc_dtype=acc_dtype)
+                                  cap_c=cap_c, acc_dtype=acc_dtype,
+                                  dot_form=dot_form)
         if s > 1:
             a = jax.lax.ppermute(a, ("pc",), shift_a)
             b = jax.lax.ppermute(b, ("pr",), shift_b)
@@ -477,10 +496,11 @@ def _resolve_maps(a, b, matrix_c, pr: int, pc: int, kl: int):
 @functools.partial(
     jax.jit,
     static_argnames=("s", "nticks", "gather", "cap_c", "acc_name",
-                     "mesh_ref", "r0"),
+                     "mesh_ref", "r0", "dot_form"),
 )
-def _stack_mesh_run(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
-                    *, s, nticks, gather, cap_c, acc_name, mesh_ref, r0=0):
+def _stack_run_mesh(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
+                    *, s, nticks, gather, cap_c, acc_name, mesh_ref, r0=0,
+                    dot_form="compiler"):
     """The one mesh runner behind both sparse engines.
 
     ``gather=False``: square-grid skewed Cannon — s alignment ticks,
@@ -512,7 +532,8 @@ def _stack_mesh_run(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
             a = jax.lax.all_gather(a, "pc", axis=0, tiled=True)
             b = jax.lax.all_gather(b, "pr", axis=0, tiled=True)
         c = _cannon_tick_loop(a, b, st, 0 if gather else s, cap_c,
-                              acc_dtype, r0=r0, nticks=nticks)
+                              acc_dtype, r0=r0, nticks=nticks,
+                              dot_form=dot_form)
         c = jax.lax.psum(c, "kl")
         c = (alpha * c + fac * c_in.astype(acc_dtype)).astype(c_in.dtype)
         return c.reshape((1, 1) + c.shape)
@@ -545,10 +566,11 @@ def _stack_mesh_run(a_panels, b_panels, stacks, c_init, alpha, beta_fac,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cap_c", "acc_name", "mesh_ref", "r0"),
+    jax.jit,
+    static_argnames=("cap_c", "acc_name", "mesh_ref", "r0", "dot_form"),
 )
-def _stack_mesh_tick(a_panels, b_panels, stacks, c_acc, t, *,
-                     cap_c, acc_name, mesh_ref, r0=0):
+def _stack_tick_mesh(a_panels, b_panels, stacks, c_acc, t, *,
+                     cap_c, acc_name, mesh_ref, r0=0, dot_form="compiler"):
     """One Cannon tick's chunked contribution into the per-layer
     accumulator ``c_acc`` (global (kl, pr, pc, cap_c, bm, bn))."""
     mesh = mesh_ref.val
@@ -560,6 +582,7 @@ def _stack_mesh_tick(a_panels, b_panels, stacks, c_acc, t, *,
         c = c_p.reshape(c_p.shape[3:])   # (cap_c, bm, bn)
         c = _tick_contrib_chunked(
             a, b, c, _stack_of_tick(_local_stacks(st), t), r0=r0,
+            dot_form=dot_form,
             cap_c=cap_c, acc_dtype=acc_dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
@@ -686,11 +709,11 @@ def _gather_shift_program(a_panels, b_panels, *, pr, pc, mesh_ref):
 @functools.partial(
     jax.jit,
     static_argnames=("pr", "pc", "seg_a", "seg_b", "cap_c", "acc_name",
-                     "mesh_ref", "r0"),
+                     "mesh_ref", "r0", "dot_form"),
 )
-def _stack_gather_tick(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
+def _stack_tick_gather(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
                        pr, pc, seg_a, seg_b, cap_c, acc_name, mesh_ref,
-                       r0=0):
+                       r0=0, dot_form="compiler"):
     """One gather-pipeline tick: append the shard pair at ring distance
     ``t`` into the concatenations (A at column (j+t)%pc * seg_a, B at
     row (i+t)%pr * seg_b — the tiled-all_gather layout), then contract
@@ -717,6 +740,7 @@ def _stack_gather_tick(a_roll, b_roll, a_cat, b_cat, stacks, c_acc, t, *,
             b_c, b_r, (src_row * seg_b, zero, zero))
         c = _tick_contrib_chunked(
             a_c, b_c, c, _stack_of_tick(_local_stacks(st), t), r0=r0,
+            dot_form=dot_form,
             cap_c=cap_c, acc_dtype=acc_dtype)
         return (a_c.reshape((1, 1, 1) + a_c.shape),
                 b_c.reshape((1, 1, 1) + b_c.shape),
@@ -735,7 +759,7 @@ def _gather_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
                   alpha_dev, beta_fac, mode: str, measure: bool,
                   timings: list):
     """Host-driven chunked all-gather pipeline behind the rectangular-
-    grid route — bitwise identical to `_stack_mesh_run` with
+    grid route — bitwise identical to `_stack_run_mesh` with
     ``gather=True``.  The carried state is (a_cat, b_cat, c_acc): the
     incrementally built operand concatenations plus the accumulator."""
     from dbcsr_tpu.acc.smm import record_dispatch
@@ -759,11 +783,11 @@ def _gather_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
         return _gather_shift_program(aa, bb, pr=pr, pc=pc, mesh_ref=mref)
 
     def tick(aa, bb, carry, t):
-        return _stack_gather_tick(
+        return _stack_tick_gather(
             aa, bb, carry[0], carry[1], plan.stacks_dev, carry[2],
             jnp.asarray(t, jnp.int32), pr=pr, pc=pc, seg_a=seg_a,
             seg_b=seg_b, cap_c=plan.cap_c, acc_name=plan.acc_name,
-            mesh_ref=mref, r0=plan.r0,
+            mesh_ref=mref, r0=plan.r0, dot_form=plan.dot_form,
         )
 
     carry, shift_s, comp_s = _overlap.run_ticks(
@@ -786,7 +810,7 @@ def _mesh_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
                 timings: list):
     """Host-driven tick loop behind the double-buffered (and
     measured-serial) sparse mesh Cannon — bitwise identical to
-    `_stack_mesh_run` with ``gather=False``.  Appends the measured
+    `_stack_run_mesh` with ``gather=False``.  Appends the measured
     (shift_exposed_s, compute_s) split to ``timings`` — published by
     the caller only when the pipeline delivered the result
     (overlap.run_split_pipeline)."""
@@ -804,10 +828,10 @@ def _mesh_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
         return _mesh_shift_program(aa, bb, s=s, mesh_ref=mref)
 
     def tick(aa, bb, cc, t):
-        return _stack_mesh_tick(
+        return _stack_tick_mesh(
             aa, bb, plan.stacks_dev, cc, jnp.asarray(t, jnp.int32),
             cap_c=plan.cap_c, acc_name=plan.acc_name, mesh_ref=mref,
-            r0=plan.r0,
+            r0=plan.r0, dot_form=plan.dot_form,
         )
 
     c_acc, shift_s, comp_s = _overlap.run_ticks(
@@ -986,6 +1010,7 @@ class _MeshPlan:
     nticks: int  # Cannon: = s alignment steps; all-gather: chunk count
     kl: int
     r0: int
+    dot_form: str  # grouped stacks: `acc/smm.py:group_dot_form`
     xtr: int
     cap_a: int
     cap_b: int
@@ -1108,6 +1133,7 @@ class _GroupedPlan:
     g: int
     q: int
     r0: int
+    dot_form: str  # grouped stacks: `acc/smm.py:group_dot_form`
     xtr: int
     cap_a: int
     cap_b: int
@@ -1377,7 +1403,8 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
     acc_name = "float32" if np.dtype(dtype).name == "bfloat16" else np.dtype(dtype).name
     return _MeshPlan(
         s=pr, pc=pc, nticks=nticks,
-        kl=kl, r0=r0, xtr=xtr, cap_a=cap_a, cap_b=cap_b, cap_c=cap_c,
+        kl=kl, r0=r0, dot_form=_stack_dot_form(r0, bk, dtype), xtr=xtr,
+        cap_a=cap_a, cap_b=cap_b, cap_c=cap_c,
         bm=bm, bk=bk, bn=bn, dtype=np.dtype(dtype), acc_name=acc_name,
         true_flops=true_flops, n_cand=len(rows_t), stacks_dev=stacks_dev,
         a_asm=a_asm, b_asm=b_asm, cinit_asm=cinit_asm,
@@ -1461,7 +1488,7 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
             a.dist.fingerprint(), b.dist.fingerprint(),
             matrix_c.dist.fingerprint() if matrix_c is not None else None,
             np.dtype(dtype).name, retain_sparsity, limits, beta_window,
-            _HashableMesh(mesh), r0,
+            _HashableMesh(mesh), r0, _stack_dot_form(r0, bk, dtype),
         )
     plan = _mesh_plan_lookup(plan_key)
     if plan is None:
@@ -1536,15 +1563,17 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     mref = _HashableMesh(mesh)
 
     def serial_fn():
-        out = _stack_mesh_run(
+        out = _stack_run_mesh(
             a_panels, b_panels, plan.stacks_dev, c_init,
             alpha_dev, beta_fac,
             s=pr, nticks=plan.nticks, gather=not cannon, cap_c=cap_c,
             acc_name=plan.acc_name, mesh_ref=mref, r0=r0,
+            dot_form=plan.dot_form,
         )
         _record_mesh_dispatch(plan.stacks_dev, r0)
         return out
 
+    _note_mesh_dot(plan)
     measure = pipe_s > 1 and _overlap.measuring()
     with timed("mesh_ticks"):
         if _overlap.use_split_pipeline(mode, why, measure):
@@ -1717,10 +1746,12 @@ def _dense_multiply_mesh(alpha, a, b, beta, matrix_c, mesh, name, dtype,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("s", "cap_c", "acc_name", "mesh_ref", "r0"),
+    jax.jit,
+    static_argnames=("s", "cap_c", "acc_name", "mesh_ref", "r0", "dot_form"),
 )
-def _stack_grouped_run(a_panels, b_panels, stacks, c_init, alpha, beta,
-                       *, s, cap_c, acc_name, mesh_ref, r0=0):
+def _stack_run_grouped(a_panels, b_panels, stacks, c_init, alpha, beta,
+                       *, s, cap_c, acc_name, mesh_ref, r0=0,
+                       dot_form="compiler"):
     """nsplit independent Cannon multiplies, one per 'kl' group, in a
     single SPMD program.  The short matrix (B) arrives replicated over
     'kl' (spec without the axis) — the `dbcsr_tas_replicate` analog —
@@ -1736,7 +1767,8 @@ def _stack_grouped_run(a_panels, b_panels, stacks, c_init, alpha, beta,
         st = _local_stacks(st)  # (s, ...): flat rows or group tiles
         c_in = c_in.reshape(c_in.shape[3:])  # (cap_c, bm, bn)
         b = jax.lax.pcast(b, ("kl",), to="varying")
-        c = _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=r0)
+        c = _cannon_tick_loop(a, b, st, s, cap_c, acc_dtype, r0=r0,
+                              dot_form=dot_form)
         c = (alpha * c + beta * c_in.astype(acc_dtype)).astype(c_in.dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
@@ -1758,7 +1790,7 @@ def _stack_grouped_run(a_panels, b_panels, stacks, c_init, alpha, beta,
 
 # --------------------------------------------------------------------------
 # Grouped-TAS split per-tick programs: the per-group Cannons advance in
-# lockstep inside one fused program (`_stack_grouped_run`); staggering
+# lockstep inside one fused program (`_stack_run_grouped`); staggering
 # them through the double-buffer metronome dispatches the group
 # ensemble's tick-(t+1) ring shift before tick t's contraction is
 # consumed, so every group's shift overlaps every group's compute.  Op
@@ -1794,10 +1826,11 @@ def _grouped_shift_program(a_panels, b_panels, *, s, mesh_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cap_c", "acc_name", "mesh_ref", "r0"),
+    jax.jit,
+    static_argnames=("cap_c", "acc_name", "mesh_ref", "r0", "dot_form"),
 )
-def _stack_grouped_tick(a_panels, b_panels, stacks, c_acc, t, *,
-                        cap_c, acc_name, mesh_ref, r0=0):
+def _stack_tick_grouped(a_panels, b_panels, stacks, c_acc, t, *,
+                        cap_c, acc_name, mesh_ref, r0=0, dot_form="compiler"):
     """One grouped tick's chunked contribution into the per-group
     accumulator (global (kl, s, s, q*cap_c, bm, bn); ``cap_c`` here is
     the chunk-expanded q*cap_c capacity)."""
@@ -1811,6 +1844,7 @@ def _stack_grouped_tick(a_panels, b_panels, stacks, c_acc, t, *,
         c = c_p.reshape(c_p.shape[3:])   # (q*cap_c, bm, bn)
         c = _tick_contrib_chunked(
             a, b, c, _stack_of_tick(_local_stacks(st), t), r0=r0,
+            dot_form=dot_form,
             cap_c=cap_c, acc_dtype=acc_dtype)
         return c.reshape((1, 1, 1) + c.shape)
 
@@ -1860,7 +1894,7 @@ def _tas_ticks(plan: "_GroupedPlan", mesh, a_panels, b_panels, c_init,
                alpha_dev, beta_dev, mode: str, measure: bool,
                timings: list):
     """Host-driven staggered grouped-TAS metronome — bitwise identical
-    to `_stack_grouped_run` (shared per-tick op code, same tail)."""
+    to `_stack_run_grouped` (shared per-tick op code, same tail)."""
     from dbcsr_tpu.acc.smm import record_dispatch
 
     mref = _HashableMesh(mesh)
@@ -1875,10 +1909,10 @@ def _tas_ticks(plan: "_GroupedPlan", mesh, a_panels, b_panels, c_init,
         return _grouped_shift_program(aa, bb, s=s, mesh_ref=mref)
 
     def tick(aa, bb, cc, t):
-        return _stack_grouped_tick(
+        return _stack_tick_grouped(
             aa, bb, plan.stacks_dev, cc, jnp.asarray(t, jnp.int32),
             cap_c=q * plan.cap_c, acc_name=plan.acc_name, mesh_ref=mref,
-            r0=plan.r0,
+            r0=plan.r0, dot_form=plan.dot_form,
         )
 
     c_acc, shift_s, comp_s = _overlap.run_ticks(
@@ -2084,7 +2118,8 @@ def _build_grouped_plan(a, b, matrix_c, mesh, g, s, dtype, bm, bk, bn, r0,
     )
     acc_name = "float32" if np.dtype(dtype).name == "bfloat16" else np.dtype(dtype).name
     return _GroupedPlan(
-        s=s, g=g, q=q, r0=r0, xtr=xtr, cap_a=cap_a, cap_b=cap_b, cap_c=cap_c,
+        s=s, g=g, q=q, r0=r0, dot_form=_stack_dot_form(r0, bk, dtype),
+        xtr=xtr, cap_a=cap_a, cap_b=cap_b, cap_c=cap_c,
         bm=bm, bk=bk, bn=bn, dtype=np.dtype(dtype), acc_name=acc_name,
         true_flops=true_flops, n_cand=len(rows_t),
         ngroups=int(row_group.max()) + 1 if len(row_group) else 0,
@@ -2119,6 +2154,7 @@ def _tas_grouped_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
             "tas", a.pattern_fingerprint(), b.pattern_fingerprint(),
             matrix_c.pattern_fingerprint() if matrix_c is not None else None,
             np.dtype(dtype).name, nsplit, _HashableMesh(mesh), r0,
+            _stack_dot_form(r0, bk, dtype),
         )
     plan = _mesh_plan_lookup(plan_key)
     if plan is None:
@@ -2167,15 +2203,16 @@ def _tas_grouped_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     mref = _HashableMesh(mesh)
 
     def serial_fn():
-        out = _stack_grouped_run(
+        out = _stack_run_grouped(
             a_panels, b_panels, plan.stacks_dev, c_init,
             alpha_dev, beta_dev,
             s=s, cap_c=q * cap_c, acc_name=plan.acc_name,
-            mesh_ref=mref, r0=r0,
+            mesh_ref=mref, r0=r0, dot_form=plan.dot_form,
         )
         _record_mesh_dispatch(plan.stacks_dev, r0)
         return out
 
+    _note_mesh_dot(plan)
     measure = s > 1 and _overlap.measuring()
     if _overlap.use_split_pipeline(mode, why, measure):
         c_out = _overlap.run_split_pipeline(

@@ -113,7 +113,7 @@ def _run_fused(drivers, c, spans, alpha):
     each; a grouped span appends its pad rows inside the program."""
     sig = ("xla", False, tuple(
         (d, 4 if d == "xla_group" else 3, d == "xla_group",
-         d == "xla_group", 1, False, None)
+         d == "xla_group", 1, False, None, "compiler")
         for d in drivers))
     flat = [jnp.asarray(x) for a, b, idx in spans for x in (a, b, *idx)]
     return smm._fused_fn(sig)(
@@ -627,7 +627,7 @@ def test_fused_classed_span_equals_the_span_alone_bitwise():
                                    runs=np.ones(1, int))
     idx = _device_tiles(tiles)
     sig = ("xla", False,
-           (("xla_group", len(idx), True, True, 8, False, None),))
+           (("xla_group", len(idx), True, True, 8, False, None, "compiler"),))
     fused = smm._fused_fn(sig)(jnp.array(c), jnp.asarray(0.75),
                                jnp.asarray(a), jnp.asarray(b), *idx)
     alone = smm._process_stack_xla_group(

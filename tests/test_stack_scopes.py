@@ -48,8 +48,8 @@ def _lower_span(fn, idx):
 
 def _lower_fused():
     # span 0: xla_group with both pad rows appended; span 1: xla, k = 3
-    sig = ("xla", False, (("xla_group", 7, True, True, 1, False, None),
-                          ("xla", 3, False, False, 1, False, None)))
+    sig = ("xla", False, (("xla_group", 7, True, True, 1, False, None, "compiler"),
+                          ("xla", 3, False, False, 1, False, None, "compiler")))
     c, a0, b0 = _operands(5, 5, 5)
     _, a1, b1 = _operands(5, 5, 3)
     return smm._fused_fn(sig).lower(
@@ -58,7 +58,7 @@ def _lower_fused():
 
 
 def _lower_fused_pallas():
-    sig = ("pallas", True, (("pallas", 3, True, False, 1, False, None),))
+    sig = ("pallas", True, (("pallas", 3, True, False, 1, False, None, "compiler"),))
     c, a, b = _operands(8, 8, 8)
     launch = (jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int32),
               jnp.zeros(4, jnp.int32))
@@ -75,7 +75,7 @@ CASES = {
                  "jit__stack_phases_xla_flat", PHASES),
     "xla_group": (lambda: _lower_span(smm._process_stack_xla_group,
                                       _group_idx()),
-                  "jit__stack_phases_xla_group", PHASES),
+                  "jit__stack_phases_group", PHASES),
     "fused": (_lower_fused, "jit_fused_superstack",
               PHASES | {"stk_pad", "span0.xla_group.5x5x5",
                         "span1.xla.5x5x3"}),
