@@ -23,12 +23,17 @@ every chip of the host and starts no child that touches JAX:
                 the result of the last, the union add, the pool; checked
                 against the benchmark's NumPy chain (flops, blocks,
                 checksum)
+  sign_chain_mesh4  the same three steps on the 2x2 grid
+                (`sign_iteration(..., mesh=make_grid(4))`): every product
+                the sparse mesh engine's, on bins its collect left on
+                every device; skipped, loudly, with fewer than four
 
 Each leg runs one first call (set-up: compile + staging) and two fenced
 repeats, requires bit-identical checksums across them, and is checked
 against plain NumPy on sampled block rows (f64, f32), against the f64
-leg's checksum (f64_filtered, mesh4, mesh4_filtered) or against the NumPy
-chain (sign_chain).  The engine's failover code is
+leg's checksum (f64_filtered, mesh4, mesh4_filtered), against the NumPy
+chain (sign_chain) or against the sign_chain leg (sign_chain_mesh4: flops,
+blocks, checksum).  The engine's failover code is
 safety code; the smoke FAILS when any of it fires.
 
 It sets no platform: without a TPU it exits non-zero before any work.
@@ -345,15 +350,14 @@ def _sign_chain_recipe():
     return cfg, ref
 
 
-def leg_sign_chain(*, n, block, occupancy, seed):
-    """Three steps of the sign chain on the cell's H at 2 000 x 2 000
-    (smaller where the caller's ``n`` is), as many stored neighbours a
-    molecule as the configuration has.  ``occupancy`` is the product
-    legs'; H's comes from the configuration."""
+def _sign_chain_operand(n, block, seed):
+    """(H as the benchmark's blocks, H staged, steps, filter_eps, the
+    benchmark's chain module): the cell's H at 2 000 x 2 000 (smaller
+    where the caller's ``n`` is), as many stored neighbours a molecule
+    as the configuration has."""
     import numpy as np
 
     import dbcsr_tpu as dt
-    from dbcsr_tpu.models.sign import sign_iteration
 
     cfg, ref = _sign_chain_recipe()
     sizes = np.asarray(_block_sizes(min(n, SIGN_CHAIN["n"]), block))
@@ -368,8 +372,16 @@ def leg_sign_chain(*, n, block, occupancy, seed):
                     "float64")
     for rows, cols, data in h.by_shape():
         mat.put_blocks(rows, cols, data)
-    mat = mat.finalize()
-    steps, eps = SIGN_CHAIN["steps"], float(cfg["filter_eps"])
+    return (h, mat.finalize(), SIGN_CHAIN["steps"],
+            float(cfg["filter_eps"]), ref)
+
+
+def leg_sign_chain(*, n, block, occupancy, seed):
+    """Three steps of the sign chain on the cell's H.  ``occupancy`` is
+    the product legs'; H's comes from the configuration."""
+    from dbcsr_tpu.models.sign import sign_iteration
+
+    h, mat, steps, eps, ref = _sign_chain_operand(n, block, seed)
 
     def run():
         x, history = sign_iteration(mat, steps=steps, filter_eps=eps,
@@ -399,8 +411,44 @@ def leg_sign_chain(*, n, block, occupancy, seed):
     return res
 
 
+def leg_sign_chain_mesh4(*, n, block, occupancy, seed, reference):
+    """The `sign_chain` leg's three steps on the 2x2 grid: every product
+    through the sparse mesh engine on what the last one's collect left.
+    Must return the one-chip chain's flops and blocks, and its checksum
+    to `CHECKSUM_RTOL`."""
+    import jax
+
+    from dbcsr_tpu.models.sign import sign_iteration
+    from dbcsr_tpu.parallel import make_grid
+
+    ndev = len(jax.devices())
+    if ndev < 4:
+        print(f"LEG sign_chain_mesh4 skipped: {ndev} device(s)", flush=True)
+        return None
+    mesh = make_grid(4)
+    _, mat, steps, eps, _ = _sign_chain_operand(n, block, seed)
+
+    def run():
+        x, history = sign_iteration(mat, steps=steps, filter_eps=eps,
+                                    tol=0.0, mesh=mesh)
+        assert len(history) == steps
+        return x, x._last_flops
+
+    res, _ = _timed_repeats("sign_chain_mesh4", run)
+    res["grid"] = dict(mesh.shape)
+    _report("LEG", res)
+    if (res["flops"], res["nblks"]) != (reference["flops"],
+                                        reference["nblks"]):
+        raise SmokeFailure(
+            f"sign_chain_mesh4: flops {res['flops']}, blocks "
+            f"{res['nblks']}; the sign_chain leg {reference['flops']}, "
+            f"{reference['nblks']}")
+    _report("CHECK", _check_against("sign_chain_mesh4", res, reference))
+    return res
+
+
 LEGS = ("f64", "f64_filtered", "f32", "mesh4", "mesh4_filtered",
-        "sign_chain")
+        "sign_chain", "sign_chain_mesh4")
 
 
 def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
@@ -425,7 +473,8 @@ def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
         if leg not in legs:
             return
         if "reference" in kw and kw["reference"] is None:
-            failures.append(f"{leg}: not run, the f64 leg gave no reference")
+            failures.append(f"{leg}: not run, the leg it is checked "
+                            "against gave no reference")
             return
         try:
             out[leg] = fn(**size, **kw)
@@ -450,6 +499,9 @@ def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
                 attempt("mesh4_filtered", leg_mesh4_filtered,
                         reference=out.get("f64"))
             attempt("sign_chain", leg_sign_chain)
+            if mesh:
+                attempt("sign_chain_mesh4", leg_sign_chain_mesh4,
+                        reference=out.get("sign_chain"))
     finally:
         dt.set_config(incremental=prev_inc)
     here = os.path.dirname(os.path.abspath(__file__))

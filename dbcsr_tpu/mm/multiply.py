@@ -374,19 +374,26 @@ def _multiply_body(a, b, c, alpha, beta, retain_sparsity, filter_eps,
             with timed("multiply_filter_norms"):
                 norms = c.block_norms()
             compress(c, norms.astype(np.float64) ** 2 >= float(filter_eps) ** 2)
-            _flight.note("filtered_blocks", nblks_pre - c.nblks)
-            _flight.note("kept_blocks", c.nblks)
-            fates = _metrics.counter(
-                "dbcsr_tpu_filter_blocks_total",
-                "C blocks of filtered products by what the norm filter "
-                "made of them")
-            fates.inc(c.nblks, fate="kept")
-            fates.inc(nblks_pre - c.nblks, fate="dropped")
+            note_filter_fates(nblks_pre, c.nblks)
 
     mflops = 2 * c.nfullrows * c.nfullcols * a.nfullcols
     stats.record_multiply(mflops)
     stats.sample_memory()
     return int(flops)
+
+
+def note_filter_fates(nblks_pre: int, nblks: int) -> None:
+    """Say what the norm filter made of a product's C blocks: on the
+    open flight record and in `dbcsr_tpu_filter_blocks_total{fate}`
+    (shared by the single-chip and mesh engines)."""
+    _flight.note("filtered_blocks", nblks_pre - nblks)
+    _flight.note("kept_blocks", nblks)
+    fates = _metrics.counter(
+        "dbcsr_tpu_filter_blocks_total",
+        "C blocks of filtered products by what the norm filter "
+        "made of them")
+    fates.inc(nblks, fate="kept")
+    fates.inc(nblks_pre - nblks, fate="dropped")
 
 
 def mask_in_sorted(cand_keys: np.ndarray, sorted_keys: np.ndarray) -> np.ndarray:

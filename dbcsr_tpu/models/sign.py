@@ -31,25 +31,38 @@ from dbcsr_tpu.ops.operations import (
 )
 
 
+def _product(alpha, a: BlockSparseMatrix, b: BlockSparseMatrix, name: str,
+             filter_eps: Optional[float], mesh):
+    """alpha * A @ B into a fresh matrix, filtered at ``filter_eps``:
+    on the one-chip engine, or with a ``mesh`` on the sparse mesh engine
+    (`parallel/sparse_dist.py`).  Returns (C, true flops)."""
+    if mesh is not None:
+        from dbcsr_tpu.parallel.sparse_dist import sparse_multiply_distributed
+
+        c = sparse_multiply_distributed(alpha, a, b, 0.0, None, mesh,
+                                        name=name, filter_eps=filter_eps)
+        return c, c._last_flops
+    c = BlockSparseMatrix(name, a.row_blk_sizes, b.col_blk_sizes, a.dtype,
+                          a.dist)
+    return c, multiply("N", "N", alpha, a, b, 0.0, c, filter_eps=filter_eps)
+
+
 def sign_step(
-    x: BlockSparseMatrix, filter_eps: Optional[float] = None
+    x: BlockSparseMatrix, filter_eps: Optional[float] = None, mesh=None
 ) -> BlockSparseMatrix:
-    """One Newton–Schulz step: X' = X (3I - X²) / 2.
+    """One Newton–Schulz step: X' = X (3I - X²) / 2; with a ``mesh``
+    (`parallel.make_grid`) both products run on the process grid.
 
     Chain-scoped (core.mempool): X² is retired to the memory pool once
     the step's second multiply consumed it, so an iteration loop keeps
     reusing the same device buffers."""
     with mempool.chain() as ch:
-        x2 = BlockSparseMatrix("X2", x.row_blk_sizes, x.col_blk_sizes,
-                               x.dtype, x.dist)
-        flops = multiply("N", "N", 1.0, x, x, 0.0, x2, filter_eps=filter_eps)
+        x2, flops = _product(1.0, x, x, "X2", filter_eps, mesh)
         # T = 3I - X²  (in place on X²'s storage)
         scale(x2, -1.0)
         add_on_diag(x2, 3.0)
-        out = BlockSparseMatrix("X'", x.row_blk_sizes, x.col_blk_sizes,
-                                x.dtype, x.dist)
-        flops += multiply("N", "N", 0.5, x, x2, 0.0, out,
-                          filter_eps=filter_eps)
+        out, flops2 = _product(0.5, x, x2, "X'", filter_eps, mesh)
+        flops += flops2
         ch.retire(x2)
         ch.detach(out)
     out._last_flops = int(flops)  # true flops of the step's two products
@@ -61,11 +74,15 @@ def sign_iteration(
     steps: int = 20,
     filter_eps: Optional[float] = None,
     tol: float = 1e-10,
+    mesh=None,
 ):
     """sign(A) by Newton–Schulz; returns (X, convergence_history).
 
     A is Gershgorin-scaled so the iteration contracts; convergence is
     measured as ||X_k - X_{k-1}||_F and iteration stops below ``tol``.
+    With a ``mesh`` every product of the chain runs on the process
+    grid (`parallel.sparse_multiply_distributed`); the rest of the
+    chain is the same code on what the grid's collect left.
     """
     from dbcsr_tpu.core.matrix import NO_SYMMETRY
     from dbcsr_tpu.ops.transformations import desymmetrize
@@ -94,7 +111,7 @@ def sign_iteration(
             with timed("sign_step"):
                 reuse0 = _incremental.stats_snapshot()
                 snap = ch.snapshot(x) if guard else None
-                x_new = sign_step(x, filter_eps=filter_eps)
+                x_new = sign_step(x, filter_eps=filter_eps, mesh=mesh)
                 flops += x_new._last_flops
                 # out-of-place diff: no copy, so neither iterate is ever
                 # marked shared and both keep donating to the pool
@@ -117,7 +134,7 @@ def sign_iteration(
                         seen = {}
 
                         def _build(x=x):
-                            xn = sign_step(x, filter_eps=filter_eps)
+                            xn = sign_step(x, filter_eps=filter_eps, mesh=mesh)
                             return xn, added(xn, x, 1.0, -1.0, name="diff")
 
                         def _validate(cand, limit=limit):

@@ -48,6 +48,7 @@ from dbcsr_tpu.core.matrix import NO_SYMMETRY, BlockSparseMatrix
 from dbcsr_tpu.core.timings import device_scope, timed
 from dbcsr_tpu.obs import costmodel as _costmodel
 from dbcsr_tpu.obs import events as _events
+from dbcsr_tpu.obs import flight as _flight
 from dbcsr_tpu.obs import metrics as _metrics
 from dbcsr_tpu.ops.transformations import desymmetrize
 from dbcsr_tpu.parallel import overlap as _overlap
@@ -193,6 +194,27 @@ def _upload_stacks(stacks, mesh, lead: tuple):
 
 def _stacks_nbytes(stacks_dev) -> int:
     return sum(int(x.nbytes) for x in jax.tree.leaves(stacks_dev))
+
+
+def _note_program(program: str, fn: str, *arrays, **static) -> None:
+    """Report one launch of a mesh product's jitted ``fn`` to the jit
+    mirror (`obs/metrics.record_jit`), keyed by what keys its jit cache
+    (the shapes and dtypes of ``arrays``, any pytrees, and the
+    ``static`` arguments), and count in
+    `dbcsr_tpu_mesh_programs_total{program}` a shape it has not run
+    before: what a product whose pattern moved compiles, and what
+    bucketing the capacities is judged by."""
+    key = (tuple((x.shape, str(x.dtype)) for x in jax.tree.leaves(arrays)),
+           tuple(sorted(static.items())))
+    if _metrics.record_jit("parallel.sparse_dist." + fn, key):
+        _metrics.counter(
+            "dbcsr_tpu_mesh_programs_total",
+            "distinct shapes of the sparse mesh engine's programs "
+            "(assembly, cut = panels out of a buffer every device "
+            "holds, tick, shift, finish, run = the fused serial "
+            "program, collect) that products have run: each is a "
+            "compile or a load from the persistent cache",
+        ).inc(program=program)
 
 
 def _stack_chunk_groups(r0: int, bm: int, bn: int, bk: int, dtype) -> int:
@@ -834,6 +856,11 @@ def _mesh_ticks(plan: "_MeshPlan", mesh, a_panels, b_panels, c_init,
             r0=plan.r0, dot_form=plan.dot_form,
         )
 
+    _note_program("tick", "_stack_tick_mesh", a_panels, b_panels,
+                  plan.stacks_dev, cap_c=plan.cap_c, acc=plan.acc_name,
+                  r0=plan.r0, dot_form=plan.dot_form)
+    _note_program("shift", "_mesh_shift_program", a_panels, b_panels)
+    _note_program("finish", "_mesh_finish_program", c_acc)
     c_acc, shift_s, comp_s = _overlap.run_ticks(
         plan.nticks, a_panels, b_panels, c_acc, shift, tick,
         mode=mode, engine="mesh", measure=measure,
@@ -994,6 +1021,9 @@ def _make_bin_asm(m: BlockSparseMatrix, flat: np.ndarray, nflat: int,
 
 def _run_bin_asm(asm: _BinAsm, m: BlockSparseMatrix, dtype) -> object:
     datas = tuple(m.bins[b].data for b in asm.bin_ids)
+    _note_program("assembly", "_assemble_flat", datas, asm.flat_pos,
+                  nflat=asm.nflat, bm=asm.bm, bn=asm.bn,
+                  dtype=np.dtype(dtype).name)
     return _assemble_flat(
         datas, asm.flat_pos, asm.src_slots,
         nflat=asm.nflat, bm=asm.bm, bn=asm.bn, dtype_name=np.dtype(dtype).name,
@@ -1104,6 +1134,14 @@ def _mesh_plan_insert(key, plan: _MeshPlan) -> None:
     _mesh_cache_evict()
 
 
+@functools.lru_cache(maxsize=64)
+def _panel_cut_program(mesh_ref: _HashableMesh, panel_shape: tuple, spec):
+    """Cut a flat panel buffer that every device of the mesh holds
+    whole into the sharded panels, each device keeping its own rows."""
+    return jax.jit(lambda flat: flat.reshape(panel_shape),
+                   out_shardings=NamedSharding(mesh_ref.val, spec))
+
+
 def _cached_panels(plan: _MeshPlan, which: str, m: BlockSparseMatrix,
                    mesh, panel_shape, spec) -> object:
     """Sharded panels for one operand, rebuilt on device only when the
@@ -1114,9 +1152,18 @@ def _cached_panels(plan: _MeshPlan, which: str, m: BlockSparseMatrix,
         return hit[1]
     asm = {"a": plan.a_asm, "b": plan.b_asm}[which]
     flat = _run_bin_asm(asm, m, plan.dtype)
-    panels = jax.device_put(
-        flat.reshape(panel_shape), NamedSharding(mesh, spec)
-    )
+    sharding = NamedSharding(mesh, spec)
+    if flat.sharding.device_set == sharding.device_set:
+        # the operand is a mesh product's result, whose bins the
+        # collect left whole on every device, and so is the buffer
+        # assembled from them: `jax.device_put` would fetch it to the
+        # host and upload its slices (0.48 s a panel at 87 MB, the chips
+        # idle: 13.4 of a sign chain's 17.8 s on the 2x2 grid, PR 37)
+        _note_program("cut", "_panel_cut_program", flat)
+        panels = _panel_cut_program(
+            _HashableMesh(mesh), tuple(panel_shape), spec)(flat)
+    else:  # staged on one device: sliced there, copied device to device
+        panels = jax.device_put(flat.reshape(panel_shape), sharding)
     plan.panel_cache[which] = (ids, panels, [bb.data for bb in m.bins])
     # panels are the big rows in the byte budget and land AFTER the
     # plan's insert — re-check the cap every time one is stored
@@ -1563,6 +1610,10 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     mref = _HashableMesh(mesh)
 
     def serial_fn():
+        _note_program("run", "_stack_run_mesh", a_panels, b_panels,
+                      plan.stacks_dev, beta_fac, nticks=plan.nticks,
+                      cap_c=cap_c, acc=plan.acc_name, r0=r0,
+                      dot_form=plan.dot_form)
         out = _stack_run_mesh(
             a_panels, b_panels, plan.stacks_dev, c_init,
             alpha_dev, beta_fac,
@@ -1600,35 +1651,25 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
         dist=plan.out_dist,
     )
     with timed("mesh_collect"):
-        if len(plan.c_keys):
-            bin_datas = _collect_bins(
-                c_out.reshape(pr * pc * cap_c, bm, bn),
-                plan.collect_pos, plan.collect_slots,
-                caps=plan.collect_caps, shapes=plan.collect_shapes,
-            )
-            bins = [
-                _mk_bin(shape, data, count)
-                for shape, data, count in zip(
-                    plan.collect_shapes, bin_datas, plan.collect_counts
-                )
-            ]
-        else:
-            bins = []
+        bins = _collected_bins(plan, c_out.reshape(pr * pc * cap_c, bm, bn))
         out.set_structure_from_device(plan.c_keys, bins,
                                       binning=plan.c_binning)
     if filter_eps is not None and not retain_sparsity:
         # final ||C|| >= eps pass (ref multrec_filtering,
         # dbcsr_mm_multrec.F:694-748) — shared criterion with the
         # single-chip engine so filtered patterns agree exactly
+        from dbcsr_tpu.mm.multiply import note_filter_fates
         from dbcsr_tpu.ops.operations import filter_matrix
 
         with timed("mesh_filter"):
+            nblks_pre = out.nblks
             # the norms need C: on an async device this call is the
             # wait for the ticks and the collect, and gets a span of
             # its own so that mesh_filter's self time is the host's work
             with timed("mesh_filter_norms"):
                 norms = out.block_norms()
             filter_matrix(out, filter_eps, norms=norms)
+            note_filter_fates(nblks_pre, out.nblks)
 
     stats.record_stack(
         bm, bn, bk, plan.n_cand, driver="mesh",
@@ -1667,13 +1708,30 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
         )
     out._last_flops = plan.true_flops  # true flop count of this product
     out._mm_algorithm = "stack"
+    # on the product's flight record, as `mm.multiply` notes them
+    _flight.note("flops", plan.true_flops)
+    _flight.note("algorithm", "stack")
     return out
 
 
-def _mk_bin(shape, data, count):
+def _collected_bins(plan, c_flat) -> list:
+    """The plan's C bins carved on device from the flat C panel buffer
+    (`_collect_bins`); no bin where the product bore no block."""
     from dbcsr_tpu.core.matrix import _Bin
 
-    return _Bin((int(shape[0]), int(shape[1])), data, int(count))
+    if not len(plan.c_keys):
+        return []
+    _note_program("collect", "_collect_bins", c_flat, plan.collect_pos,
+                  caps=plan.collect_caps, shapes=plan.collect_shapes)
+    bin_datas = _collect_bins(
+        c_flat, plan.collect_pos, plan.collect_slots,
+        caps=plan.collect_caps, shapes=plan.collect_shapes,
+    )
+    return [
+        _Bin((int(shape[0]), int(shape[1])), data, int(count))
+        for shape, data, count in zip(
+            plan.collect_shapes, bin_datas, plan.collect_counts)
+    ]
 
 
 def _dense_multiply_mesh(alpha, a, b, beta, matrix_c, mesh, name, dtype,
@@ -2231,20 +2289,7 @@ def _tas_grouped_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
         a.row_blk_sizes, b.col_blk_sizes, dtype,
         dist=matrix_c.dist if matrix_c is not None else None,
     )
-    if len(plan.c_keys):
-        bin_datas = _collect_bins(
-            c_out.reshape(g * s * s * q * cap_c, bm, bn),
-            plan.collect_pos, plan.collect_slots,
-            caps=plan.collect_caps, shapes=plan.collect_shapes,
-        )
-        bins = [
-            _mk_bin(shape, data, count)
-            for shape, data, count in zip(
-                plan.collect_shapes, bin_datas, plan.collect_counts
-            )
-        ]
-    else:
-        bins = []
+    bins = _collected_bins(plan, c_out.reshape(g * s * s * q * cap_c, bm, bn))
     out.set_structure_from_device(plan.c_keys, bins, binning=plan.c_binning)
     out._tas_ngroups = plan.ngroups
     if filter_eps is not None:

@@ -806,3 +806,137 @@ def test_chain_ops_of_h2o_ls_chain_compile_as_named_programs(one_chip):
         shift = ops._add_alpha_eye.lower(
             bin_, _shape(one_chip, (435,), jnp.int32), fac).compile()
         assert "HloModule jit__add_alpha_eye" in shift.as_text()
+
+
+# ---------------------------------------------------------------------------
+# The sign chain on the 2x2 grid (`h2o_ls_chain_2x2.sign_f64`, PR 37):
+# every product has a pattern of its own, so the mesh programs run at
+# several shapes a chain.  The two ends of the reference chain at full
+# size (seed 1; `_build_mesh_plan` on the NumPy chain's operands, host
+# only, PR 37): the largest product is X.T of the third step (466 300
+# candidates, C born with 62 587 blocks), the smallest X.T of the last
+# (20 383 candidates, T = 3I - X^2 all but diagonal: 2 048-bucket
+# bins).  Seven of the fourteen products share the largest's panels and
+# all but its chunk count (40 for 48).
+# ---------------------------------------------------------------------------
+
+_CHAIN_PRODUCTS = {
+    # cap_a, cap_b, cap_c, (chunks, ((groups a chunk, width), ...)),
+    # bucketed (18,18) / (18,23) / (23,18) / (23,23) bins of A, B and C
+    "largest": (5120, 5120, 16384, (48, ((192, 8), (80, 4), (160, 2))),
+                (16, 48, 48, 20480), (16, 48, 48, 20480),
+                (16, 160, 160, 65536)),
+    "smallest": (5120, 640, 5120, (3, ((16, 8), (1952, 1))),
+                 (16, 48, 48, 20480), (16, 16, 16, 2048),
+                 (16, 56, 48, 20480)),
+}
+_CHAIN_BIN_SHAPES = ((18, 18), (18, 23), (23, 18), (23, 23))
+# temporaries a device, GiB, measured when compiled here for the
+# described v5e:2x2 (PR 37), with a fifth of room: program -> product
+_CHAIN_TEMP_GIB = {
+    "tick": {"largest": 0.9, "smallest": 1.15},
+    "finish": {"largest": 0.08, "smallest": 0.01},
+    "collect": {"largest": 3.6, "smallest": 1.15},
+    "assembly": {"largest": 1.15, "smallest": 0.1},
+}
+
+
+@pytest.fixture(scope="module")
+def chain_mesh(v5e_2x2):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(v5e_2x2.devices).reshape(1, 2, 2),
+                ("kl", "pr", "pc"))
+
+
+@pytest.mark.parametrize("product", ["largest", "smallest"])
+@pytest.mark.parametrize("program", ["tick", "finish", "collect",
+                                     "assembly"])
+def test_mesh_programs_of_the_sign_chain_compile(chain_mesh, program,
+                                                 product):
+    """The grid's programs at the two ends of `h2o_ls_chain_2x2`'s
+    chain: the tick on the product's own class tiles, the finish on its
+    C panel, the collect from the sharded panel buffer into bins that
+    every device holds whole (its gather ends in an all-reduce: where X
+    lives between two products), the assembly of B's panels from such
+    replicated bins and the cut of the assembled buffer into the
+    sharded panels.  Temporaries a device (GiB, compiled here, PR 37;
+    largest / smallest): tick 0.751 / 0.936 (the smallest product's
+    class of width 1 holds 1 952 groups a chunk), finish 0.065 / 0,
+    collect 3.001 / 0.938 (nine times the bins' 0.35 / 0.11 GB as
+    values: the gathered blocks in tile-padded layout on every device),
+    assembly 0.938 / 0.077."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dbcsr_tpu.parallel import sparse_dist as sd
+    from dbcsr_tpu.parallel.overlap import _HashableMesh
+
+    cap_a, cap_b, cap_c, (nchunk, classes), a_bins, b_bins, c_bins = \
+        _CHAIN_PRODUCTS[product]
+    grid3 = ("kl", "pr", "pc")
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(chain_mesh, P(*spec)))
+
+    f64, i32 = jnp.float64, jnp.int32
+    mref = _HashableMesh(chain_mesh)
+    with jax.enable_x64(True):
+        if program == "tick":
+            lead = (1, 2, 2, 2)
+            stacks = (arg(lead, i32, *grid3), tuple(
+                (arg(lead + (nchunk, ch, w), i32, *grid3),
+                 arg(lead + (nchunk, ch, w), i32, *grid3),
+                 arg(lead + (nchunk, ch), i32, *grid3))
+                for ch, w in classes))
+            compiled = sd._stack_tick_mesh.lower(
+                arg((1, 2, 2, cap_a + 1, 23, 23), f64, *grid3),
+                arg((1, 2, 2, cap_b + 1, 23, 23), f64, *grid3), stacks,
+                arg((1, 2, 2, cap_c, 23, 23), f64, *grid3), arg((), i32),
+                cap_c=cap_c, acc_name="float64", mesh_ref=mref,
+                r0=_MESH_R0, dot_form="sliced").compile()
+            text = compiled.as_text()
+            assert "HloModule jit__stack_tick_mesh" in text
+            for scope in ("stk_gather", "stk_dot", "stk_accum"):
+                assert f"/{scope}/" in text, scope
+        elif program == "finish":
+            compiled = sd._mesh_finish_program.lower(
+                arg((1, 2, 2, cap_c, 23, 23), f64, *grid3),
+                arg((2, 2, cap_c, 23, 23), f64, "pr", "pc"), arg((), f64),
+                arg((2, 2, cap_c), f64, "pr", "pc"),
+                acc_name="float64", mesh_ref=mref).compile()
+            assert "all-reduce" not in compiled.as_text()
+        elif program == "collect":
+            compiled = sd._collect_bins.lower(
+                arg((4 * cap_c, 23, 23), f64, ("pr", "pc")),
+                tuple(arg((n,), i32) for n in c_bins),
+                tuple(arg((n,), i32) for n in c_bins),
+                caps=c_bins, shapes=_CHAIN_BIN_SHAPES).compile()
+            text = compiled.as_text()
+            assert "HloModule jit__collect_bins" in text
+            # the bins leave the program whole on every device
+            assert "all-reduce" in text or "all-gather" in text
+            assert all(len(s.device_set) == 4 and s.is_fully_replicated
+                       for s in compiled.output_shardings)
+        else:
+            compiled = sd._assemble_flat.lower(
+                tuple(arg((n,) + shape, f64)
+                      for n, shape in zip(b_bins, _CHAIN_BIN_SHAPES)),
+                tuple(arg((n,), i32) for n in b_bins),
+                tuple(arg((n,), i32) for n in b_bins),
+                nflat=4 * (cap_b + 1), bm=23, bn=23,
+                dtype_name="float64").compile()
+            assert "HloModule jit__assemble_flat" in compiled.as_text()
+            # every device holds the assembled buffer whole and cuts its
+            # own panel out of it: a slice, no collective
+            shape = (1, 2, 2, cap_b + 1, 23, 23)
+            cut = sd._panel_cut_program(mref, shape, P(*grid3)).lower(
+                arg((4 * (cap_b + 1), 23, 23), f64)).compile()
+            assert not re.search(r"all-gather|all-reduce|collective-permute"
+                                 r"|all-to-all", cut.as_text())
+            assert cut.output_shardings.spec == P(*grid3)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"CHAIN_TEMP {program} {product} {temp / 2 ** 30:.3f} GiB")
+    assert temp < _CHAIN_TEMP_GIB[program][product] * 2 ** 30, temp

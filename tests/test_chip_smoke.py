@@ -31,7 +31,8 @@ def test_single_device_legs_pass_tiny(capsys):
     assert chain["algorithm"] == "stack" and chain["flops"] > 0
 
 
-@pytest.mark.parametrize("leg", ["mesh4", "mesh4_filtered"])
+@pytest.mark.parametrize("leg", ["mesh4", "mesh4_filtered",
+                                 "sign_chain_mesh4"])
 def test_mesh_leg_is_never_silently_absent(capsys, monkeypatch, leg):
     monkeypatch.setattr(jax, "devices", lambda *a: [object()] * 2)
     fn = getattr(chip_smoke, f"leg_{leg}")
@@ -56,6 +57,27 @@ def test_filtered_mesh_leg_runs_the_sparse_engine_in_both_modes(capsys):
         by_mode["double_buffer"]["checksum"]
     lines = capsys.readouterr().out.splitlines()
     assert sum(line.startswith("CHECK ") for line in lines) == 3
+
+
+def test_sign_chain_leg_on_the_grid_matches_the_one_chip_leg(capsys):
+    """`sign_chain_mesh4` on four of conftest's virtual devices, beside
+    the `sign_chain` leg it is checked against: the same flops and
+    blocks, the checksum to 1e-9."""
+    out = chip_smoke.run_legs(
+        **_TINY, legs=("sign_chain", "sign_chain_mesh4"))
+    assert set(out) == {"sign_chain", "sign_chain_mesh4"}
+    one, grid = out["sign_chain"], out["sign_chain_mesh4"]
+    assert grid["algorithm"] == "stack" and grid["grid"]["pr"] == 2
+    assert (grid["flops"], grid["nblks"]) == (one["flops"], one["nblks"])
+    assert grid["checksum"] == pytest.approx(one["checksum"], rel=1e-9)
+    lines = capsys.readouterr().out.splitlines()
+    assert sum(line.startswith("CHECK ") for line in lines) == 2
+    assert any("checksum_vs_sign_chain" in line for line in lines)
+
+
+def test_sign_chain_leg_on_the_grid_needs_its_reference():
+    with pytest.raises(chip_smoke.SmokeFailure, match="gave no reference"):
+        chip_smoke.run_legs(**_TINY, legs=("sign_chain_mesh4",))
 
 
 def test_main_rejects_an_unknown_leg(capsys):
