@@ -56,6 +56,10 @@ class _DriverAgg:
     # grouped spans launched, by the form of their dot
     # (`acc.smm.group_dot_form`)
     dot_forms: dict = dataclasses.field(default_factory=dict)
+    # the mesh collect (`parallel.sparse_dist._collect_bins`): blocks of
+    # C, and piece slots all-gathered over the grid (pads included)
+    collect_live: int = 0
+    collect_shipped: int = 0
 
 
 _by_mnk: dict = collections.defaultdict(_MnkStat)
@@ -140,6 +144,18 @@ def record_group_dot(dot_form: str, driver: str = "xla_group") -> None:
     forms[dot_form] = forms.get(dot_form, 0) + 1
 
 
+def record_collect_slots(live: int, shipped: int) -> None:
+    """One mesh product's collect: the blocks of C it carved and the
+    piece slots it all-gathered for them (bucket pads included)."""
+    from dbcsr_tpu.core.config import get_config
+
+    if not get_config().keep_stats:
+        return
+    agg = _driver_agg["mesh"]
+    agg.collect_live += live
+    agg.collect_shipped += shipped
+
+
 def driver_rollup() -> dict:
     """Plain-dict view of the per-driver attribution aggregates."""
     out = {}
@@ -158,6 +174,9 @@ def driver_rollup() -> dict:
                           groups_by_width=dict(a.groups_by_width))
         if a.dot_forms:
             out[d]["dot_forms"] = dict(a.dot_forms)
+        if a.collect_shipped:
+            out[d].update(collect_live=a.collect_live,
+                          collect_shipped=a.collect_shipped)
     return out
 
 

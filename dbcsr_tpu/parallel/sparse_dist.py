@@ -95,13 +95,18 @@ def _dense_blocks_host(matrix: BlockSparseMatrix, bm: int, bn: int) -> np.ndarra
 
 def _panel_slots(panel_ids: np.ndarray) -> np.ndarray:
     """Slot of each entry within its panel (entries pre-sorted by key
-    within equal panel_ids groups)."""
-    order = np.argsort(panel_ids, kind="stable")
-    sorted_ids = panel_ids[order]
-    starts = np.searchsorted(sorted_ids, sorted_ids)
-    slots_sorted = np.arange(len(panel_ids)) - starts
-    slots = np.empty(len(panel_ids), np.int64)
-    slots[order] = slots_sorted
+    within equal panel_ids groups).  Panels are few: ids that fit 16
+    bits are sorted as such (NumPy's stable sort of them is a radix
+    sort), and a panel's start comes from the counts."""
+    n = len(panel_ids)
+    if not n:
+        return np.empty(0, np.int64)
+    counts = np.bincount(panel_ids)
+    ids = panel_ids.astype(np.int16) if len(counts) <= 2 ** 15 else panel_ids
+    order = np.argsort(ids, kind="stable")
+    slots = np.empty(n, np.int64)
+    slots[order] = np.arange(n) - np.repeat(np.cumsum(counts) - counts,
+                                            counts)
     return slots
 
 
@@ -960,19 +965,93 @@ def _assemble_flat(bin_datas, flat_pos, src_slots, *, nflat, bm, bn, dtype_name)
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("caps", "shapes"))
-def _collect_bins(c_flat, gather_pos, bin_slots, *, caps, shapes):
-    """Carve the flat C panel buffer into per-shape bins on device (the
+@functools.partial(jax.jit, static_argnames=("shapes", "mesh_ref"))
+def _collect_bins(c_panels, own_rows, slot_rows, *, shapes, mesh_ref):
+    """Carve the C panels into per-shape bins that every device holds
+    whole, slots in `_bin_entries` order and pad slots zero (the
     collect half of `dbcsr_merge_all`, `dbcsr_work_operations.F:1393`,
-    without the host round-trip `_adopt_panels` pays).  Padded index
-    rows carry an out-of-range bin slot and are dropped."""
-    outs = []
-    for fp, sl, cap, (bmb, bnb) in zip(gather_pos, bin_slots, caps, shapes):
-        blk = jnp.take(c_flat, fp, axis=0)[:, :bmb, :bnb]
-        outs.append(
-            jnp.zeros((cap, bmb, bnb), c_flat.dtype).at[sl].set(blk, mode="drop")
-        )
-    return tuple(outs)
+    without the host round-trip `_adopt_panels` pays).  Maps from
+    `_collect_maps`.  ``c_panels`` is (grid..., cap, bm, bn), one panel
+    a device of the mesh axes its leading dims are sharded over (the
+    last two or all three of 'kl', 'pr', 'pc'); three steps a bin:
+
+    1. every device takes the rows of the blocks it owns out of its OWN
+       panel, seen as (cap, bm*bn) rows (`acc/smm.py:_block_rows`: a
+       gather of 3-D blocks fetches every element on its own), by
+       ``own_rows`` (grid..., L): local panel slots, the pads past the
+       panel, which read as zero rows;
+    2. the pieces are all-gathered over those axes: a block crosses ICI
+       once, and nothing else does but the bucket pads;
+    3. one more row gather puts them in slot order by ``slot_rows``
+       (cap_bin,): the row of the gathered pieces that holds bin slot
+       i, for a pad slot a row that is a pad."""
+    from dbcsr_tpu.acc.smm import _block_rows, _take_rows
+
+    lead = c_panels.ndim - 3
+    axes = ("kl", "pr", "pc")[-lead:]
+    bm, bn = c_panels.shape[-2:]
+
+    def body(c_p, own, slot):
+        rows = _block_rows(c_p.reshape(c_p.shape[lead:]))
+        outs = []
+        for ids, perm, (bmb, bnb) in zip(own, slot, shapes):
+            piece = rows.at[ids.reshape(-1)].get(mode="fill", fill_value=0)
+            if (bmb, bnb) != (bm, bn):
+                piece = piece.reshape(-1, bm, bn)[:, :bmb, :bnb]
+                piece = piece.reshape(-1, bmb * bnb)
+            pieces = jax.lax.all_gather(piece, axes, tiled=True)
+            outs.append(_take_rows(pieces, perm).reshape(-1, bmb, bnb))
+        return tuple(outs)
+
+    fn = jax.shard_map(
+        body,
+        mesh=mesh_ref.val,
+        in_specs=(P(*axes), P(*axes), P()),
+        out_specs=P(),
+        # an all-gather's result is the same on every device it spans,
+        # which the replication check does not infer (public
+        # `lax.all_gather` types its result as varying)
+        check_vma=False,
+    )
+    return fn(c_panels, own_rows, slot_rows)
+
+
+def _collect_maps(mesh, axes: tuple, nb, nsl, nbins: int, c_dev, c_local,
+                  cap_local: int) -> tuple:
+    """`_collect_bins`' index maps for C blocks in key order: ``nb`` /
+    ``nsl`` their bin and in-bin slot (`_bin_entries`), ``c_dev`` the
+    device that owns each (row-major over the mesh ``axes`` C is
+    sharded over), ``c_local`` its slot in that device's panel of
+    ``cap_local`` rows.  Returns (own_rows, slot_rows, counts, shipped):
+    per bin the (grid..., L) local slots a device takes and the (cap,)
+    rows of the gathered pieces in slot order, both on the mesh; the
+    bins' block counts; and the piece slots all-gathered in all.  Every
+    length is bucketed so that patterns of like counts share a program;
+    L leaves every device a pad row, which reads zero and which the pad
+    slots name."""
+    grid = tuple(mesh.shape[ax] for ax in axes)
+    ndev = int(np.prod(grid))
+    own_rows, slot_rows, counts = [], [], []
+    for b_id in range(nbins):
+        sel = np.nonzero(nb == b_id)[0]
+        dev = c_dev[sel]
+        per_dev = np.bincount(dev, minlength=ndev)
+        length = bucket_size(int(per_dev.max()) + 1)
+        pos = _panel_slots(dev)  # key order within a device's piece
+        own = np.full((ndev, length), cap_local, np.int32)
+        own[dev, pos] = c_local[sel]
+        perm = np.full(bucket_size(len(sel)), length - 1, np.int32)
+        perm[nsl[sel]] = dev * length + pos
+        own_rows.append(own.reshape(grid + (length,)))
+        slot_rows.append(perm)
+        counts.append(len(sel))
+    shipped = sum(int(x.size) for x in own_rows)
+    with timed("mesh_plan_upload"):
+        own_rows = jax.device_put(tuple(own_rows),
+                                  NamedSharding(mesh, P(*axes)))
+        slot_rows = jax.device_put(tuple(slot_rows),
+                                   NamedSharding(mesh, P()))
+    return own_rows, slot_rows, tuple(counts), shipped
 
 
 @dataclasses.dataclass
@@ -1061,11 +1140,11 @@ class _MeshPlan:
     inside_dev: object  # (s, s, cap_c) bool device array, or None
     c_keys: np.ndarray
     c_binning: tuple  # (_bin_entries result) for c_keys
-    collect_pos: tuple  # per-out-bin jnp gather positions into flat C
-    collect_slots: tuple  # per-out-bin jnp in-bin slots
-    collect_caps: tuple
+    collect_own: tuple  # `_collect_maps`: per out-bin, a device's rows
+    collect_perm: tuple  # per out-bin, the gathered rows in slot order
     collect_counts: tuple
     collect_shapes: tuple
+    collect_shipped: int  # piece slots all-gathered, bucket pads included
     out_dist: object
     upload_bytes: int
     # (bin-data ids, sharded panels, keepalive) per operand; the ids are
@@ -1080,8 +1159,8 @@ class _MeshPlan:
              + self.b_asm.nbytes())
         if self.cinit_asm is not None:
             n += self.cinit_asm.nbytes()
-        n += sum(int(x.nbytes) for x in self.collect_pos)
-        n += sum(int(x.nbytes) for x in self.collect_slots)
+        n += sum(int(x.nbytes) for x in self.collect_own)
+        n += sum(int(x.nbytes) for x in self.collect_perm)
         if self.inside_dev is not None:
             n += int(self.inside_dev.nbytes)
         for _, panels, _ in self.panel_cache.values():
@@ -1199,11 +1278,11 @@ class _GroupedPlan:
     cinit_asm: Optional[_BinAsm]
     c_keys: np.ndarray
     c_binning: tuple
-    collect_pos: tuple
-    collect_slots: tuple
-    collect_caps: tuple
+    collect_own: tuple
+    collect_perm: tuple
     collect_counts: tuple
     collect_shapes: tuple
+    collect_shipped: int
     upload_bytes: int
     panel_cache: dict = dataclasses.field(default_factory=dict)
 
@@ -1212,8 +1291,8 @@ class _GroupedPlan:
              + self.b_asm.nbytes())
         if self.cinit_asm is not None:
             n += self.cinit_asm.nbytes()
-        n += sum(int(x.nbytes) for x in self.collect_pos)
-        n += sum(int(x.nbytes) for x in self.collect_slots)
+        n += sum(int(x.nbytes) for x in self.collect_own)
+        n += sum(int(x.nbytes) for x in self.collect_perm)
         for _, panels, _ in self.panel_cache.values():
             n += int(panels.nbytes)
         return n
@@ -1409,24 +1488,9 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
     from dbcsr_tpu.core.matrix import _bin_entries
 
     nb, nsl, shapes = _bin_entries(a.row_blk_sizes, b.col_blk_sizes, c_rows, c_cols)
-    collect_pos, collect_slots, collect_caps, collect_counts = [], [], [], []
-    c_flat_pos = c_panel * cap_c + c_slots
-    for b_id in range(len(shapes)):
-        sel = np.nonzero(nb == b_id)[0]
-        cap = bucket_size(len(sel))
-        # padded index rows: gather position 0 (any), bin slot cap
-        # (out of range -> dropped by the mode="drop" scatter)
-        fp = np.zeros(cap, np.int32)
-        fp[: len(sel)] = c_flat_pos[sel]
-        sl = np.full(cap, cap, np.int32)
-        sl[: len(sel)] = nsl[sel]
-        collect_pos.append(fp)
-        collect_slots.append(sl)
-        collect_caps.append(cap)
-        collect_counts.append(len(sel))
-    with timed("mesh_plan_upload"):
-        collect_pos = [jnp.asarray(fp) for fp in collect_pos]
-        collect_slots = [jnp.asarray(sl) for sl in collect_slots]
+    collect_own, collect_perm, collect_counts, collect_shipped = \
+        _collect_maps(mesh, ("pr", "pc"), nb, nsl, len(shapes),
+                      c_panel, c_slots, cap_c)
 
     from dbcsr_tpu.core.dist import Distribution, ProcessGrid
 
@@ -1444,8 +1508,8 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
         _stacks_nbytes(stacks_dev) + a_asm.nbytes() + b_asm.nbytes()
         + inside_bytes
         + (cinit_asm.nbytes() if cinit_asm is not None else 0)
-        + sum(int(x.nbytes) for x in collect_pos)
-        + sum(int(x.nbytes) for x in collect_slots)
+        + sum(int(x.nbytes) for x in collect_own)
+        + sum(int(x.nbytes) for x in collect_perm)
     )
     acc_name = "float32" if np.dtype(dtype).name == "bfloat16" else np.dtype(dtype).name
     return _MeshPlan(
@@ -1458,9 +1522,9 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
         has_window=has_window, inside_all=bool(inside.all()),
         inside_dev=inside_dev, c_keys=c_keys,
         c_binning=(nb, nsl, shapes),
-        collect_pos=tuple(collect_pos), collect_slots=tuple(collect_slots),
-        collect_caps=tuple(collect_caps), collect_counts=tuple(collect_counts),
-        collect_shapes=tuple(shapes), out_dist=out_dist,
+        collect_own=collect_own, collect_perm=collect_perm,
+        collect_counts=collect_counts, collect_shapes=tuple(shapes),
+        collect_shipped=collect_shipped, out_dist=out_dist,
         upload_bytes=int(upload_bytes),
     )
 
@@ -1651,7 +1715,7 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
         dist=plan.out_dist,
     )
     with timed("mesh_collect"):
-        bins = _collected_bins(plan, c_out.reshape(pr * pc * cap_c, bm, bn))
+        bins = _collected_bins(plan, mref, c_out)
         out.set_structure_from_device(plan.c_keys, bins,
                                       binning=plan.c_binning)
     if filter_eps is not None and not retain_sparsity:
@@ -1714,19 +1778,30 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     return out
 
 
-def _collected_bins(plan, c_flat) -> list:
-    """The plan's C bins carved on device from the flat C panel buffer
-    (`_collect_bins`); no bin where the product bore no block."""
+def _collected_bins(plan, mref: _HashableMesh, c_panels) -> list:
+    """The plan's C bins carved on device from the C panels
+    (`_collect_bins`), whole on every device; no bin where the product
+    bore no block."""
+    from dbcsr_tpu.core import stats
     from dbcsr_tpu.core.matrix import _Bin
 
     if not len(plan.c_keys):
         return []
-    _note_program("collect", "_collect_bins", c_flat, plan.collect_pos,
-                  caps=plan.collect_caps, shapes=plan.collect_shapes)
+    _note_program("collect", "_collect_bins", c_panels, plan.collect_own,
+                  plan.collect_perm, shapes=plan.collect_shapes)
     bin_datas = _collect_bins(
-        c_flat, plan.collect_pos, plan.collect_slots,
-        caps=plan.collect_caps, shapes=plan.collect_shapes,
+        c_panels, plan.collect_own, plan.collect_perm,
+        shapes=plan.collect_shapes, mesh_ref=mref,
     )
+    slots = _metrics.counter(
+        "dbcsr_tpu_mesh_collect_slots_total",
+        "block slots of the mesh collect (`_collect_bins`), a product: "
+        "'live' are C's blocks, 'shipped' the piece slots all-gathered "
+        "over the grid (bucket pads included)",
+    )
+    slots.inc(len(plan.c_keys), kind="live")
+    slots.inc(plan.collect_shipped, kind="shipped")
+    stats.record_collect_slots(len(plan.c_keys), plan.collect_shipped)
     return [
         _Bin((int(shape[0]), int(shape[1])), data, int(count))
         for shape, data, count in zip(
@@ -2152,27 +2227,17 @@ def _build_grouped_plan(a, b, matrix_c, mesh, g, s, dtype, bm, bk, bn, r0,
 
     nb, nsl, shapes = _bin_entries(a.row_blk_sizes, b.col_blk_sizes,
                                    c_rows, c_cols)
-    c_flat_pos = (
-        (row_kl[c_rows] * s + rdist_in[c_rows]) * s + cdist[c_cols]
-    ) * (q * cap_c) + row_ch[c_rows] * cap_c + c_slots
-    collect_pos, collect_slots, collect_caps, collect_counts = [], [], [], []
-    for b_id in range(len(shapes)):
-        sel = np.nonzero(nb == b_id)[0]
-        cap = bucket_size(len(sel))
-        fp = np.zeros(cap, np.int32)
-        fp[: len(sel)] = c_flat_pos[sel]
-        sl = np.full(cap, cap, np.int32)
-        sl[: len(sel)] = nsl[sel]
-        collect_pos.append(jnp.asarray(fp))
-        collect_slots.append(jnp.asarray(sl))
-        collect_caps.append(cap)
-        collect_counts.append(len(sel))
+    collect_own, collect_perm, collect_counts, collect_shipped = \
+        _collect_maps(
+            mesh, ("kl", "pr", "pc"), nb, nsl, len(shapes),
+            (row_kl[c_rows] * s + rdist_in[c_rows]) * s + cdist[c_cols],
+            row_ch[c_rows] * cap_c + c_slots, q * cap_c)
 
     upload_bytes = (
         _stacks_nbytes(stacks_dev) + a_asm.nbytes() + b_asm.nbytes()
         + (cinit_asm.nbytes() if cinit_asm is not None else 0)
-        + sum(int(x.nbytes) for x in collect_pos)
-        + sum(int(x.nbytes) for x in collect_slots)
+        + sum(int(x.nbytes) for x in collect_own)
+        + sum(int(x.nbytes) for x in collect_perm)
     )
     acc_name = "float32" if np.dtype(dtype).name == "bfloat16" else np.dtype(dtype).name
     return _GroupedPlan(
@@ -2183,9 +2248,9 @@ def _build_grouped_plan(a, b, matrix_c, mesh, g, s, dtype, bm, bk, bn, r0,
         ngroups=int(row_group.max()) + 1 if len(row_group) else 0,
         stacks_dev=stacks_dev, a_asm=a_asm, b_asm=b_asm, cinit_asm=cinit_asm,
         c_keys=c_keys, c_binning=(nb, nsl, shapes),
-        collect_pos=tuple(collect_pos), collect_slots=tuple(collect_slots),
-        collect_caps=tuple(collect_caps), collect_counts=tuple(collect_counts),
-        collect_shapes=tuple(shapes), upload_bytes=int(upload_bytes),
+        collect_own=collect_own, collect_perm=collect_perm,
+        collect_counts=collect_counts, collect_shapes=tuple(shapes),
+        collect_shipped=collect_shipped, upload_bytes=int(upload_bytes),
     )
 
 
@@ -2289,7 +2354,7 @@ def _tas_grouped_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
         a.row_blk_sizes, b.col_blk_sizes, dtype,
         dist=matrix_c.dist if matrix_c is not None else None,
     )
-    bins = _collected_bins(plan, c_out.reshape(g * s * s * q * cap_c, bm, bn))
+    bins = _collected_bins(plan, mref, c_out)
     out.set_structure_from_device(plan.c_keys, bins, binning=plan.c_binning)
     out._tas_ngroups = plan.ngroups
     if filter_eps is not None:

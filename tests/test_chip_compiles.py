@@ -770,6 +770,62 @@ def test_mesh_shift_and_finish_of_northstar_2x2_compile(mesh_shapes):
     assert "all-reduce" not in finish.as_text()
 
 
+def _assert_collects_from_own_panels(compiled) -> None:
+    """The forms of `_collect_bins` (PR 38) in a program compiled for
+    the described 2x2 grid: under the name `layers/mesh_collect_s.json`
+    globs; every device reads its own panel and the pieces cross the
+    grid once, in an all-gather, with no all-reduce of zero-filled bins
+    (until PR 38 the gather ran over the sharded buffer by global
+    positions and the partitioner all-reduced every bin whole); every
+    gather moves whole block rows of a 2-D operand (a gather of 3-D
+    blocks fetches each element on its own: `acc/smm.py:_block_rows`);
+    the bins leave whole on all four devices."""
+    text = compiled.as_text()
+    assert "HloModule jit__collect_bins" in text
+    assert "all-gather" in text
+    assert "all-reduce" not in text
+    gathers = [ln for ln in text.splitlines() if re.search(r" gather\(", ln)]
+    assert gathers
+    for ln in gathers:
+        assert re.search(r"= [a-z]\d+\[\d+,\d+\]\{1,0", ln), ln[:200]
+        assert re.search(r"slice_sizes=\{1,\d+\}", ln), ln[:400]
+    assert all(len(s.device_set) == 4 and s.is_fully_replicated
+               for s in compiled.output_shardings)
+
+
+def test_mesh_collect_of_northstar_2x2_compiles(mesh_shapes):
+    """The collect at `northstar_2x2_filtered`'s shapes: C is full, 435^2
+    blocks, 47 089 of 23 x 23 a device in a panel of `cap_c` 49 152,
+    the ragged last row and column in bins of their own.  Temporaries a
+    device 1.33 GiB (1 428 934 656 B compiled here, PR 38: the panel's
+    and the bin's two f32 halves as rows); the program it replaced
+    asked for 9.00 GiB (the gathered blocks a 24 x 128 tile each)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dbcsr_tpu.parallel import sparse_dist as sd
+
+    sh = mesh_shapes
+    mesh = sh["mref"].val
+    assert sh["cap_c"] == 49152
+
+    def arg(shape, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.int32, sharding=NamedSharding(mesh, P(*spec)))
+
+    with jax.enable_x64(True):
+        compiled = sd._collect_bins.lower(
+            sh["c_init"],
+            tuple(arg((2, 2, n), "pr", "pc") for n in (16, 224, 224, 49152)),
+            tuple(arg((n,)) for n in (16, 448, 448, 196608)),
+            shapes=((18, 18), (18, 23), (23, 18), (23, 23)),
+            mesh_ref=sh["mref"]).compile()
+    _assert_collects_from_own_panels(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * 2 ** 30, temp
+
+
 def test_chain_ops_of_h2o_ls_chain_compile_as_named_programs(one_chip):
     """The union add of X_new - X at `h2o_ls_chain`'s sizes (PR 32: X
     holds ~18 400 blocks of 23x23, H 17 000, the union in a bucket of
@@ -822,13 +878,15 @@ def test_chain_ops_of_h2o_ls_chain_compile_as_named_programs(one_chip):
 
 _CHAIN_PRODUCTS = {
     # cap_a, cap_b, cap_c, (chunks, ((groups a chunk, width), ...)),
-    # bucketed (18,18) / (18,23) / (23,18) / (23,23) bins of A, B and C
+    # bucketed (18,18) / (18,23) / (23,18) / (23,23) bins of A, B and C,
+    # and of C's bins the piece a device ships to the collect (PR 38:
+    # a quarter of the blocks and a pad row, in the bucket above)
     "largest": (5120, 5120, 16384, (48, ((192, 8), (80, 4), (160, 2))),
                 (16, 48, 48, 20480), (16, 48, 48, 20480),
-                (16, 160, 160, 65536)),
+                (16, 160, 160, 65536), (16, 96, 96, 16384)),
     "smallest": (5120, 640, 5120, (3, ((16, 8), (1952, 1))),
                  (16, 48, 48, 20480), (16, 16, 16, 2048),
-                 (16, 56, 48, 20480)),
+                 (16, 56, 48, 20480), (16, 32, 28, 5120)),
 }
 _CHAIN_BIN_SHAPES = ((18, 18), (18, 23), (23, 18), (23, 23))
 # temporaries a device, GiB, measured when compiled here for the
@@ -836,7 +894,7 @@ _CHAIN_BIN_SHAPES = ((18, 18), (18, 23), (23, 18), (23, 23))
 _CHAIN_TEMP_GIB = {
     "tick": {"largest": 0.9, "smallest": 1.15},
     "finish": {"largest": 0.08, "smallest": 0.01},
-    "collect": {"largest": 3.6, "smallest": 1.15},
+    "collect": {"largest": 0.54, "smallest": 0.06},  # PR 38
     "assembly": {"largest": 1.15, "smallest": 0.1},
 }
 
@@ -856,15 +914,15 @@ def test_mesh_programs_of_the_sign_chain_compile(chain_mesh, program,
                                                  product):
     """The grid's programs at the two ends of `h2o_ls_chain_2x2`'s
     chain: the tick on the product's own class tiles, the finish on its
-    C panel, the collect from the sharded panel buffer into bins that
-    every device holds whole (its gather ends in an all-reduce: where X
-    lives between two products), the assembly of B's panels from such
+    C panel, the collect from every device's own panel into bins that
+    every device holds whole (the pieces all-gathered: where X lives
+    between two products), the assembly of B's panels from such
     replicated bins and the cut of the assembled buffer into the
     sharded panels.  Temporaries a device (GiB, compiled here, PR 37;
     largest / smallest): tick 0.751 / 0.936 (the smallest product's
     class of width 1 holds 1 952 groups a chunk), finish 0.065 / 0,
-    collect 3.001 / 0.938 (nine times the bins' 0.35 / 0.11 GB as
-    values: the gathered blocks in tile-padded layout on every device),
+    collect 0.444 / 0.043 (PR 38: the panel and the bins as rows; 3.001
+    / 0.938 until then, the gathered blocks a 24 x 128 tile each),
     assembly 0.938 / 0.077."""
     import jax
     import jax.numpy as jnp
@@ -873,8 +931,8 @@ def test_mesh_programs_of_the_sign_chain_compile(chain_mesh, program,
     from dbcsr_tpu.parallel import sparse_dist as sd
     from dbcsr_tpu.parallel.overlap import _HashableMesh
 
-    cap_a, cap_b, cap_c, (nchunk, classes), a_bins, b_bins, c_bins = \
-        _CHAIN_PRODUCTS[product]
+    (cap_a, cap_b, cap_c, (nchunk, classes), a_bins, b_bins, c_bins,
+     c_pieces) = _CHAIN_PRODUCTS[product]
     grid3 = ("kl", "pr", "pc")
 
     def arg(shape, dtype, *spec):
@@ -910,16 +968,11 @@ def test_mesh_programs_of_the_sign_chain_compile(chain_mesh, program,
             assert "all-reduce" not in compiled.as_text()
         elif program == "collect":
             compiled = sd._collect_bins.lower(
-                arg((4 * cap_c, 23, 23), f64, ("pr", "pc")),
+                arg((2, 2, cap_c, 23, 23), f64, "pr", "pc"),
+                tuple(arg((2, 2, n), i32, "pr", "pc") for n in c_pieces),
                 tuple(arg((n,), i32) for n in c_bins),
-                tuple(arg((n,), i32) for n in c_bins),
-                caps=c_bins, shapes=_CHAIN_BIN_SHAPES).compile()
-            text = compiled.as_text()
-            assert "HloModule jit__collect_bins" in text
-            # the bins leave the program whole on every device
-            assert "all-reduce" in text or "all-gather" in text
-            assert all(len(s.device_set) == 4 and s.is_fully_replicated
-                       for s in compiled.output_shardings)
+                shapes=_CHAIN_BIN_SHAPES, mesh_ref=mref).compile()
+            _assert_collects_from_own_panels(compiled)
         else:
             compiled = sd._assemble_flat.lower(
                 tuple(arg((n,) + shape, f64)
