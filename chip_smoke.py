@@ -11,6 +11,12 @@ every chip of the host and starts no child that touches JAX:
                 stack engine with on-device norm filtering); no block of
                 these operands is below 1e-7, so C must equal f64's
   f32           unfiltered f32 product (whatever the format planner picks)
+  f64_filtered_mixed  a filtered f64 product on atom blocks {5,13,23}
+                cycled on m, n and k at occupancy 0.05 (the deployment of
+                `mixed10k_filtered`, the same 10 000 rows): 54 (m,n,k)
+                triples in 15 C bins through the stack engine on whatever
+                driver `prepare_stack` gives each; held to NumPy on a row
+                of every block size
   mesh4         the f64 product on the 2x2 grid `make_grid(4)` builds,
                 serial and double-buffered Cannon; skipped, loudly, with
                 fewer than four devices
@@ -30,7 +36,8 @@ every chip of the host and starts no child that touches JAX:
 
 Each leg runs one first call (set-up: compile + staging) and two fenced
 repeats, requires bit-identical checksums across them, and is checked
-against plain NumPy on sampled block rows (f64, f32), against the f64
+against plain NumPy on sampled block rows (f64, f32, f64_filtered_mixed:
+there a row of every block size), against the f64
 leg's checksum (f64_filtered, mesh4, mesh4_filtered), against the NumPy
 chain (sign_chain) or against the sign_chain leg (sign_chain_mesh4: flops,
 blocks, checksum).  The engine's failover code is
@@ -56,6 +63,7 @@ import time
 import warnings
 
 NORTH_STAR = {"n": 10000, "block": 23, "occupancy": 0.1}
+MIXED = {"blocks": (5, 13, 23), "occupancy": 0.05}
 SIGN_CHAIN = {"n": 2000, "steps": 3}
 FILTER_EPS = 1e-7
 N_SAMPLE_ROWS = 4
@@ -67,15 +75,17 @@ class SmokeFailure(AssertionError):
     failover path."""
 
 
-def _block_sizes(n: int, block: int):
+def _block_sizes(n: int, block):
     from dbcsr_tpu.perf.driver import expand_block_sizes
 
-    return expand_block_sizes(n, [(1, block)])
+    blocks = (block,) if isinstance(block, int) else block
+    return expand_block_sizes(n, [(1, b) for b in blocks])
 
 
-def make_operands(dtype, n: int, block: int, occupancy: float, seed: int):
+def make_operands(dtype, n: int, block, occupancy: float, seed: int):
     """A and B from ``seed`` (the same seed gives the same pattern and,
-    up to the dtype's rounding, the same values)."""
+    up to the dtype's rounding, the same values).  ``block`` is one
+    size, or several cycled on every dimension."""
     import numpy as np
 
     import dbcsr_tpu as dt
@@ -193,30 +203,36 @@ def _report(tag: str, row: dict) -> None:
     print(f"{tag} " + json.dumps(row, default=str), flush=True)
 
 
-def _sample_rows(nblk: int, seed: int):
-    """A few block rows: the first, the (ragged) last, and random ones."""
+def _sample_rows(row_sizes, seed: int):
+    """A few block rows: the first, the (ragged) last, one of every
+    other block size, and random ones."""
     import numpy as np
 
+    nblk = len(row_sizes)
     rng = np.random.default_rng(seed + 1)
     picks = {0, nblk - 1}
+    for size in sorted(set(row_sizes.tolist())
+                       - {int(row_sizes[r]) for r in picks}):
+        picks.add(int(rng.choice(np.nonzero(row_sizes == size)[0])))
     while len(picks) < min(N_SAMPLE_ROWS, nblk):
         picks.add(int(rng.integers(0, nblk)))
     return sorted(picks)
 
 
-def _check_rows(leg: str, a, b, c, block: int, seed: int) -> dict:
+def _check_rows(leg: str, a, b, c, seed: int) -> dict:
     """C against plain NumPy on sampled block rows:
     ``to_dense(A)[rows] @ to_dense(B)`` on the host in f64, tolerance
-    from `obs.costmodel.kernel_validation_tolerance` for a k=block dot
-    accumulated over every block column of A."""
+    from `obs.costmodel.kernel_validation_tolerance` for a dot as deep
+    as A's largest block, accumulated over every block column of A."""
     import numpy as np
 
     import dbcsr_tpu as dt
     from dbcsr_tpu.obs import costmodel
 
     off = a.row_blk_offsets
+    block_rows = _sample_rows(a.row_blk_sizes, seed)
     rows = np.concatenate([
-        np.arange(off[r], off[r + 1]) for r in _sample_rows(a.nblkrows, seed)
+        np.arange(off[r], off[r + 1]) for r in block_rows
     ])
     ref = (dt.to_dense(a)[rows].astype(np.float64)
            @ dt.to_dense(b).astype(np.float64))
@@ -227,12 +243,14 @@ def _check_rows(leg: str, a, b, c, block: int, seed: int) -> dict:
     err = float(np.max(np.abs(got.astype(np.float64) - ref))
                 / max(float(np.max(np.abs(ref))), 1.0))
     tol = costmodel.kernel_validation_tolerance(
-        np.dtype(c.dtype).name, block, a.nblkcols)
+        np.dtype(c.dtype).name, int(a.col_blk_sizes.max()), a.nblkcols)
     if not err <= tol:
         raise SmokeFailure(
             f"{leg}: relative error {err:.3e} > {tol:.3e} vs NumPy on "
             f"{len(rows)} sampled rows")
     return {"leg": leg, "check": "numpy_rows", "rows": int(len(rows)),
+            "row_block_sizes": sorted({int(a.row_blk_sizes[r])
+                                       for r in block_rows}),
             "rel_err": err, "tol": tol}
 
 
@@ -265,7 +283,7 @@ def _single_chip_leg(leg, dtype, *, n, block, occupancy, seed,
     res, c = _timed_repeats(leg, run)
     _report("LEG", res)  # out before a check can fail
     if check_rows:
-        _report("CHECK", _check_rows(leg, a, b, c, block, seed))
+        _report("CHECK", _check_rows(leg, a, b, c, seed))
     if reference is not None:
         _report("CHECK", _check_against(leg, res, reference))
     return res  # only scalars outlive the leg: C leaves HBM with it
@@ -283,6 +301,15 @@ def leg_f64_filtered(*, reference, **size):
 
 def leg_f32(**size):
     return _single_chip_leg("f32", "float32", **size)
+
+
+def leg_f64_filtered_mixed(*, n, block, occupancy, seed):
+    """The filtered f64 product on atom blocks (`MIXED`, at the caller's
+    ``n``; its ``block`` and ``occupancy`` are the uniform legs').  The
+    tolerance is a dot of the largest block's depth."""
+    return _single_chip_leg(
+        "f64_filtered_mixed", "float64", n=n, block=MIXED["blocks"],
+        occupancy=MIXED["occupancy"], seed=seed, filter_eps=FILTER_EPS)
 
 
 def leg_mesh4(*, n, block, occupancy, seed, reference, filter_eps=None):
@@ -447,8 +474,8 @@ def leg_sign_chain_mesh4(*, n, block, occupancy, seed, reference):
     return res
 
 
-LEGS = ("f64", "f64_filtered", "f32", "mesh4", "mesh4_filtered",
-        "sign_chain", "sign_chain_mesh4")
+LEGS = ("f64", "f64_filtered", "f32", "f64_filtered_mixed", "mesh4",
+        "mesh4_filtered", "sign_chain", "sign_chain_mesh4")
 
 
 def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
@@ -494,6 +521,7 @@ def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
             attempt("f64_filtered", leg_f64_filtered,
                     reference=out.get("f64"))
             attempt("f32", leg_f32)
+            attempt("f64_filtered_mixed", leg_f64_filtered_mixed)
             if mesh:
                 attempt("mesh4", leg_mesh4, reference=out.get("f64"))
                 attempt("mesh4_filtered", leg_mesh4_filtered,

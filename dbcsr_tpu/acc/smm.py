@@ -355,10 +355,15 @@ _process_stack_xla_flat = functools.partial(
 GROUP_GATHER_LAYOUT = "row"
 
 
-def _note_group_span(dot_form: str) -> None:
+def _mnk_label(a_data, b_data) -> str:
+    """A span's "mxnxk", as `obs.metrics` spells it in `by_mnk`."""
+    return f"{a_data.shape[1]}x{b_data.shape[2]}x{a_data.shape[2]}"
+
+
+def _note_group_span(dot_form: str, mnk: str) -> None:
     """Count one launched `xla_group` span by its gather layout and by
     the form of its dot."""
-    note_group_dot(dot_form)
+    note_group_dot(dot_form, mnk)
     _metrics.counter(
         "dbcsr_tpu_stack_gather_total",
         "xla_group spans launched (per span or inside a fused launch), "
@@ -368,17 +373,20 @@ def _note_group_span(dot_form: str) -> None:
     ).inc(layout=GROUP_GATHER_LAYOUT)
 
 
-def note_group_dot(dot_form: str, driver: str = "xla_group") -> None:
+def note_group_dot(dot_form: str, mnk: str,
+                   driver: str = "xla_group") -> None:
     """Count one launched grouped span (an `xla_group` span through
     `_note_group_span`, or one product's grouped mesh stacks:
-    ``driver`` "mesh") by the form of its dot (`group_dot_form`)."""
+    ``driver`` "mesh") by the form of its dot (`group_dot_form`) and
+    its block shape ``mnk`` ("5x13x23")."""
     _metrics.counter(
         "dbcsr_tpu_stack_dot_total",
-        "grouped spans launched, by how the chunk loop multiplies a "
-        "group's strips: 'sliced' = bf16 slices cut once per stored "
-        "block and one native dot a width class (emulated f64), "
-        "'compiler' = the compiler's dot of the gathered strips",
-    ).inc(form=dot_form)
+        "grouped spans launched, by their (m,n,k) and by how the chunk "
+        "loop multiplies a group's strips: 'sliced' = bf16 slices cut "
+        "once per stored block and one native dot a width class "
+        "(emulated f64), 'compiler' = the compiler's dot of the "
+        "gathered strips",
+    ).inc(form=dot_form, mnk=mnk)
     from dbcsr_tpu.core import stats
 
     stats.record_group_dot(dot_form, driver=driver)
@@ -917,11 +925,12 @@ class StackPlan:
                  "b_pad_row", "append_a_pad", "append_b_pad", "val_idx",
                  "group_idx", "kmerge", "pack", "cross_launches",
                  "cross_vmem", "cross_src", "host_idx", "src_idx",
-                 "src_pads", "precision", "dot_form")
+                 "src_pads", "precision", "dot_form", "entries")
 
     def __init__(self):
         self.driver = "xla"
         self.nseg = 0
+        self.entries = 0         # true stack entries (the stack's length)
         self.xla_idx = None      # (ai, bi, ci) device (nchunks, chunk)
         self.launches = None     # pallas: [(ai_flat, bi_flat, ci) device]
         self.r_grp = 1
@@ -1079,6 +1088,7 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
     def _host_plan():
         plan = StackPlan()
         plan.nseg = c_data.shape[0]
+        plan.entries = S
         plan.driver = "host"
         plan.a_pad_row = a_pad_row
         plan.b_pad_row = b_pad_row
@@ -1140,6 +1150,7 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
             precision=f"{prec[0]}{'+comp' if prec[1] else ''}")
     plan = StackPlan()
     plan.nseg = c_data.shape[0]
+    plan.entries = S
     # R-tiled grouped layout (see _process_stack_xla_group): the default
     # for emulated-f64 dtypes on TPU, where the per-entry dot is
     # MXU-starved; elsewhere f64 is native and per-entry is fine (same
@@ -1397,6 +1408,35 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
     return plan
 
 
+def _note_launched_entries(plan: StackPlan, mnk: str,
+                           dev_entries: int) -> None:
+    """Count one launched span (on its own or inside a fused launch)
+    under its driver and block shape: the slots the device works
+    through (chunk/group/bucket padding included) against the true
+    entries among them — the pad-overhead attribution the roofline
+    needs when achieved GFLOP/s (true flops) undershoots the device's
+    busy rate.  Counted at the launch, so a plan-cache hit counts like
+    the product that made the plan."""
+    _metrics.counter(
+        "dbcsr_tpu_device_entries_total",
+        "stack entries actually launched per driver and (m,n,k), "
+        "padding included",
+    ).inc(dev_entries, driver=plan.driver, mnk=mnk)
+    entries = _metrics.counter(
+        "dbcsr_tpu_stack_entries_total",
+        "slots of the launched spans per driver and (m,n,k): 'live' "
+        "hold a true stack entry, 'pad' are the padding launched with "
+        "them (live + pad = dbcsr_tpu_device_entries_total)",
+    )
+    entries.inc(plan.entries, driver=plan.driver, mnk=mnk, kind="live")
+    entries.inc(dev_entries - plan.entries, driver=plan.driver, mnk=mnk,
+                kind="pad")
+    from dbcsr_tpu.core import stats
+
+    stats.record_launched_entries(plan.driver, mnk, plan.entries,
+                                  dev_entries)
+
+
 def _record_stack_jit(plan: StackPlan, c_data, a_data, b_data):
     """Mirror the XLA jit cache for the stack kernels (the reference's
     per-(m,n,k) NVRTC kernel cache, `libsmm_acc.cpp:89-224`): each
@@ -1438,14 +1478,7 @@ def _record_stack_jit(plan: StackPlan, c_data, a_data, b_data):
             plan.cross_launches)
     else:  # host driver: no device compilation to account
         return False, None, None
-    # device-work entries (incl. chunk/group/bucket padding) vs the
-    # true entries in core.stats.by_mnk: the pad-overhead attribution
-    # the roofline needs when achieved GFLOP/s (true flops) undershoots
-    # the device's busy rate
-    _metrics.counter(
-        "dbcsr_tpu_device_entries_total",
-        "stack entries actually launched per driver, padding included",
-    ).inc(dev_entries, driver=drv)
+    _note_launched_entries(plan, _mnk_label(a_data, b_data), dev_entries)
     return _metrics.record_jit(f"acc.smm.{fn}", key), f"acc.smm.{fn}", key
 
 
@@ -1933,7 +1966,7 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
         if plan.append_b_pad:
             b_data = _append_pad_row(b_data)
         alpha_dev = jnp.asarray(alpha, dtype=c_data.dtype)
-        _note_group_span(plan.dot_form)
+        _note_group_span(plan.dot_form, _mnk_label(a_data, b_data))
         if want_xla_cost:
             _capture_stack_xla_cost(
                 jit_fn_name, jit_key, _process_stack_xla_group,
@@ -2313,7 +2346,7 @@ def _record_superstack_jit(splan: SuperstackPlan, c_data, a_datas,
 
     dt = str(jnp.dtype(c_data.dtype))
     idx_shapes = []
-    for plan in splan.plans:
+    for plan, a_d, b_d in zip(splan.plans, a_datas, b_datas):
         if plan.driver in ("xla", "xla_flat"):
             idx_shapes.append(plan.xla_idx[0].shape)
             dev_entries = int(plan.xla_idx[0].size)
@@ -2324,10 +2357,7 @@ def _record_superstack_jit(splan: SuperstackPlan, c_data, a_datas,
             idx_shapes.append(tuple(lc[0].shape for lc in plan.launches))
             dev_entries = pallas_smm.launch_entries(plan.launches,
                                                     plan.r_grp)
-        _metrics.counter(
-            "dbcsr_tpu_device_entries_total",
-            "stack entries actually launched per driver, padding included",
-        ).inc(dev_entries, driver=plan.driver)
+        _note_launched_entries(plan, _mnk_label(a_d, b_d), dev_entries)
     key = (splan.sig, c_data.shape, dt,
            tuple(a.shape for a in a_datas),
            tuple(b.shape for b in b_datas), tuple(idx_shapes))
@@ -2403,7 +2433,7 @@ def _dispatch_superstack(c_data, a_datas, b_datas, splan: SuperstackPlan,
             flat.extend(plan.xla_idx)
         elif plan.driver == "xla_group":
             flat.extend(plan.group_idx)
-            _note_group_span(plan.dot_form)
+            _note_group_span(plan.dot_form, _mnk_label(a_d, b_d))
         else:
             for lc in plan.launches:
                 flat.extend(lc)

@@ -56,6 +56,9 @@ class _DriverAgg:
     # grouped spans launched, by the form of their dot
     # (`acc.smm.group_dot_form`)
     dot_forms: dict = dataclasses.field(default_factory=dict)
+    # launched spans by block shape, "mxnxk" -> [true entries, slots
+    # launched with their padding] (`acc.smm._note_launched_entries`)
+    entries_by_mnk: dict = dataclasses.field(default_factory=dict)
     # the mesh collect (`parallel.sparse_dist._collect_bins`): blocks of
     # C, and piece slots all-gathered over the grid (pads included)
     collect_live: int = 0
@@ -144,6 +147,19 @@ def record_group_dot(dot_form: str, driver: str = "xla_group") -> None:
     forms[dot_form] = forms.get(dot_form, 0) + 1
 
 
+def record_launched_entries(driver: str, mnk: str, live: int,
+                            launched: int) -> None:
+    """One launched span of ``driver`` at block shape ``mnk``
+    ("5x13x23"): its true entries and the slots launched for them."""
+    from dbcsr_tpu.core.config import get_config
+
+    if not get_config().keep_stats:
+        return
+    cell = _driver_agg[driver].entries_by_mnk.setdefault(mnk, [0, 0])
+    cell[0] += live
+    cell[1] += launched
+
+
 def record_collect_slots(live: int, shipped: int) -> None:
     """One mesh product's collect: the blocks of C it carved and the
     piece slots it all-gathered for them (bucket pads included)."""
@@ -174,6 +190,10 @@ def driver_rollup() -> dict:
                           groups_by_width=dict(a.groups_by_width))
         if a.dot_forms:
             out[d]["dot_forms"] = dict(a.dot_forms)
+        if a.entries_by_mnk:
+            out[d]["entries_by_mnk"] = {
+                mnk: {"live": live, "launched": launched}
+                for mnk, (live, launched) in a.entries_by_mnk.items()}
         if a.collect_shipped:
             out[d].update(collect_live=a.collect_live,
                           collect_shipped=a.collect_shipped)

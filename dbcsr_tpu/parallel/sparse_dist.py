@@ -268,7 +268,8 @@ def _note_mesh_dot(plan) -> None:
     if plan.r0:
         from dbcsr_tpu.acc.smm import note_group_dot
 
-        note_group_dot(plan.dot_form, driver="mesh")
+        note_group_dot(plan.dot_form, f"{plan.bm}x{plan.bn}x{plan.bk}",
+                       driver="mesh")
 
 
 _TICK_CHUNK_ENTRIES = 32768

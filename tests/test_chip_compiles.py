@@ -1,6 +1,7 @@
 """Compile-only guards: the Pallas stack kernels at the real shapes of
 the `mixed10k` deployment, the emulated-f64 XLA stack body at the
-north star's and the sparse mesh engine's programs at
+north star's, the largest fused f64 program of `mixed10k_filtered`,
+and the sparse mesh engine's programs at
 `northstar_2x2_filtered`'s panels, lowered and compiled for a DESCRIBED
 TPU v5e (no chip attached, nothing runs).  Interpret mode cannot see what this sees: the
 chip's 1 MiB of scalar memory, which a crosspack launch's prefetched
@@ -200,6 +201,113 @@ def test_base_kernel_launch_of_mixed10k_compiles(one_chip, mixed10k):
             r_grp=r_grp, interpret=False, kmerge=True,
         ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------- mixed10k_filtered.scf_f64 (PR 39)
+@pytest.fixture(scope="module")
+def mixed_f64_bin(mixed10k):
+    """The (23,23) C bin of `mixed10k_filtered.scf_f64`, its largest:
+    the spans k = 5, 13, 23 and the ragged 19 as `prepare_stack` plans
+    them for f64 on a v5e (`xla_group`, r0 8: the parameter table's
+    23^3 f64 row is the donor of all four), from the cell's pattern
+    (ids zero: only the shapes are used).  (bin capacity, [(k, tiles,
+    A's capacity, B's capacity)])."""
+    from dbcsr_tpu.acc import smm
+    from dbcsr_tpu.utils.rounding import bucket_size
+
+    sizes, pa, pb = mixed10k
+    reach = np.zeros(((sizes["m"] == 23).sum(), (sizes["n"] == 23).sum()),
+                     bool)
+    runs = {}
+    for k in np.unique(sizes["k"]):
+        a_mk = pa[sizes["m"] == 23][:, sizes["k"] == k]
+        b_kn = pb[sizes["k"] == k][:, sizes["n"] == 23]
+        counts = a_mk.astype(np.int32) @ b_kn.astype(np.int32)
+        reach |= counts > 0
+        runs[int(k)] = (counts[counts > 0], int(a_mk.sum()), int(b_kn.sum()))
+    nseg = bucket_size(int(reach.sum()))
+    spans = []
+    for k, (run, na, nb) in sorted(runs.items()):
+        c_idx = np.repeat(np.arange(len(run)), run).astype(np.int32)
+        zeros = np.zeros(len(c_idx), np.int32)
+        tiles = smm.build_group_tiles(
+            c_idx, zeros, zeros, 8, na, nb, nseg,
+            smm.group_chunk_groups(8, 23, 23, k, 8, 30000))
+        spans.append((k, tiles, bucket_size(na), bucket_size(nb)))
+    return nseg, spans
+
+
+def test_mixed_f64_plan_is_runs_of_one_in_four_classes(mixed_f64_bin):
+    """Counts, not rates: a C block of one span collects 1.4 products
+    here (4.4 at the north star), so class 1 carries the product, and
+    the chunk grows as the block shrinks (`GROUP_CHUNK_BYTES`)."""
+    nseg, spans = mixed_f64_bin
+    assert nseg == 57344 and [k for k, *_ in spans] == [5, 13, 19, 23]
+    for k, tiles, _, _ in spans:
+        if k == 19:  # the ragged block column: 120 entries, one class
+            assert tiles.widths == (1,) and tiles.entries == 120
+            continue
+        assert tiles.widths == (8, 4, 2, 1)
+        assert 35_000 < tiles.entries < 36_100
+        assert tiles.groups[3] > tiles.entries / 2 > 10 * tiles.groups[0]
+        assert 0.85 < tiles.entries / tiles.slots_launched < 0.89
+    slots_a_chunk = {k: sum(ga.shape[1] * ga.shape[2]
+                            for ga, _, _ in tiles.tiles)
+                     for k, tiles, _, _ in spans}
+    assert slots_a_chunk[5] > 2 * slots_a_chunk[13] > 3 * slots_a_chunk[23]
+    assert 2048 <= slots_a_chunk[23] < 2300  # the north star's 2 048
+
+
+def test_largest_fused_f64_program_of_mixed10k_compiles_sliced(
+        one_chip, mixed_f64_bin):
+    """`jit_fused_superstack_sliced` of that bin compiles for the v5e:
+    one `while` a span carries the bin, and inside each the dots are
+    native bf16 convolutions of slices cut once per stored block, one a
+    width class, at strip depths of 5, 13, 19 and 23 a slot: no f64 dot
+    that the compiler would expand per gathered strip."""
+    import jax
+    import jax.numpy as jnp
+
+    from dbcsr_tpu.acc import smm
+
+    nseg, spans = mixed_f64_bin
+    sig, flat = [], []
+    for k, tiles, cap_a, cap_b in spans:
+        idx = tiles.flat()
+        # a pad row past the stored blocks is there: nothing appended
+        sig.append(("xla_group", 1 + len(idx), False, False, 8, False, None,
+                    "sliced"))
+        flat += [_shape(one_chip, (cap_a, 23, k), jnp.float64),
+                 _shape(one_chip, (cap_b, k, 23), jnp.float64),
+                 _shape(one_chip, (1,), jnp.int32)]
+        flat += [_shape(one_chip, x.shape, jnp.int32) for x in idx]
+    with jax.enable_x64(True):
+        compiled = smm._fused_fn(("xla", False, tuple(sig))).lower(
+            _shape(one_chip, (nseg, 23, 23), jnp.float64),
+            _shape(one_chip, (), jnp.float64), *flat).compile()
+    mem = compiled.memory_analysis()
+    print(f"mixed10k_filtered (23,23) bin: temp_size_in_bytes "
+          f"{mem.temp_size_in_bytes}, generated_code_size_in_bytes "
+          f"{mem.generated_code_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 4 * 2 ** 30  # 3.18 GiB, PR 39
+    text = compiled.as_text()
+    assert "fused_superstack_sliced" in text
+    comps = _computations(text)
+    bin_shape = f"[{nseg},23,23]"
+    loops = [name for name in set(re.findall(
+        r"\bwhile\(.*?body=%?([\w.\-]+)", text))
+        if any(bin_shape in ln for ln in comps[name])]
+    assert len(loops) == len(spans), sorted(loops)
+    classes = []
+    for name in loops:
+        convs = [ln for lines in _reached(comps, comps[name]) for ln in lines
+                 if re.search(r" convolution\(", ln)]
+        classes.append(len(convs))
+        _assert_split_once_not_per_slot(comps, comps[name], len(convs))
+    assert sorted(classes) == sorted(len(t.widths) for _, t, _, _ in spans)
+    # and no dot or convolution of the program takes f32 or f64 operands
+    assert text.count(" convolution(") == sum(classes)
+    assert not re.search(r" dot\(", text)
 
 
 # `northstar.scf_f64`'s 23^3 span: the C bin, the A and B bins with

@@ -16,19 +16,52 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _TINY = dict(n=43, block=5, occupancy=0.4, seed=3)
 
 
-def test_single_device_legs_pass_tiny(capsys):
+@pytest.fixture
+def dense_mixed(monkeypatch):
+    """At a few blocks the mixed leg's occupancy of 0.05 stores nothing."""
+    monkeypatch.setitem(chip_smoke.MIXED, "occupancy", 0.6)
+
+
+def test_single_device_legs_pass_tiny(capsys, dense_mixed):
     out = chip_smoke.run_legs(**_TINY, mesh=False)
-    assert set(out) == {"f64", "f64_filtered", "f32", "sign_chain"}
+    assert set(out) == {"f64", "f64_filtered", "f32", "f64_filtered_mixed",
+                        "sign_chain"}
     for leg, res in out.items():
         assert res["leg"] == leg and len(res["steady_s"]) == 2
         assert res["driver_launches"], leg  # names the driver that ran
     assert out["f64_filtered"]["checksum"] == pytest.approx(
         out["f64"]["checksum"], rel=1e-9)
     lines = capsys.readouterr().out.splitlines()
-    assert sum(line.startswith("CHECK ") for line in lines) == 4
+    assert sum(line.startswith("CHECK ") for line in lines) == 5
     # three steps of the sign chain, held to the benchmark's NumPy chain
     chain = out["sign_chain"]
     assert chain["algorithm"] == "stack" and chain["flops"] > 0
+
+
+def test_mixed_leg_is_held_to_numpy_on_a_row_of_every_block_size(
+        capsys, dense_mixed):
+    """`f64_filtered_mixed` at 120 rows: blocks {5, 13, 23} cycled and a
+    ragged 20, filtered f64 through the stack engine, every row-block
+    size among the rows NumPy checks, no failover."""
+    import json
+
+    out = chip_smoke.run_legs(**dict(_TINY, n=120),
+                              legs=("f64_filtered_mixed",))
+    res = out["f64_filtered_mixed"]
+    assert res["algorithm"] == "stack" and res["driver_launches"]
+    assert res["flops"] > 0 and len(res["steady_s"]) == 2
+    lines = capsys.readouterr().out.splitlines()
+    check, = (json.loads(ln.split(" ", 1)[1]) for ln in lines
+              if ln.startswith("CHECK "))
+    assert check["check"] == "numpy_rows"
+    assert check["row_block_sizes"] == [5, 13, 20, 23]
+    assert check["rel_err"] <= check["tol"] < 1e-13
+    sizes = chip_smoke._block_sizes(120, chip_smoke.MIXED["blocks"])
+    assert check["rows"] >= 5 + 13 + 20 + 23 and sizes.sum() == 120
+    # the uniform legs sample as before: first, last, random picks
+    rows = chip_smoke._sample_rows(chip_smoke._block_sizes(43, 5), 3)
+    assert rows[0] == 0 and rows[-1] == 8
+    assert len(rows) == chip_smoke.N_SAMPLE_ROWS
 
 
 @pytest.mark.parametrize("leg", ["mesh4", "mesh4_filtered",

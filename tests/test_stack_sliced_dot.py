@@ -315,8 +315,9 @@ def _counts():
     from dbcsr_tpu.core import stats
     from dbcsr_tpu.obs import metrics
 
-    forms = {lab["form"]: v for lab, v in metrics.counter_items(
-        "dbcsr_tpu_stack_dot_total")}
+    forms = {}
+    for lab, v in metrics.counter_items("dbcsr_tpu_stack_dot_total"):
+        forms[lab["form"]] = forms.get(lab["form"], 0) + v  # over mnk
     spans = sum(v for _, v in metrics.counter_items(
         "dbcsr_tpu_stack_gather_total"))
     rolled = stats.driver_rollup().get("xla_group", {}).get("dot_forms", {})
