@@ -1113,7 +1113,8 @@ def _run_bin_asm(asm: _BinAsm, m: BlockSparseMatrix, dtype) -> object:
 @dataclasses.dataclass
 class _MeshPlan:
     """Everything about a mesh multiply that only depends on the
-    operands' patterns, distributions, dtype and product options."""
+    operands' patterns, distributions, dtype and product options, and,
+    for a filtered product, on its surviving candidates."""
 
     s: int       # 'pr' extent (== pc on Cannon grids)
     pc: int
@@ -1170,16 +1171,18 @@ class _MeshPlan:
 
 
 _mesh_plan_cache: "_OrderedDict[tuple, _MeshPlan]" = _OrderedDict()
-_MESH_PLAN_MAX = 8
+# a sign chain cycles through 14 plans: as many as `mm.multiply`'s
+# one-chip `_PLAN_CACHE_MAX`, so that a second chain finds them all
+_MESH_PLAN_MAX = 16
 _MESH_PLAN_MAX_BYTES = 512 * 1024 * 1024
 
 
 def _mesh_plan_lookup(plan_key):
     """The cached plan under ``plan_key`` or None, counted by outcome:
     ``hit`` or ``miss`` of `_mesh_plan_cache`, or ``uncacheable`` where
-    the key is None: a filtered product, whose candidates follow the
-    operands' values (the norm skip), so its plan is rebuilt every
-    time."""
+    the key is None: a filtered product of the grouped TAS engine,
+    whose plan is rebuilt every time (`_sparse_multiply_impl` keys a
+    filtered plan by its surviving candidates)."""
     plan = None
     if plan_key is not None:
         plan = _mesh_plan_cache.get(plan_key)
@@ -1188,8 +1191,10 @@ def _mesh_plan_lookup(plan_key):
     _metrics.counter(
         "dbcsr_tpu_mesh_plan_total",
         "mesh plan lookups of the distributed sparse engines, by "
-        "outcome: hit / miss of the pattern-keyed plan cache, or "
-        "uncacheable (a filtered product: rebuilt every product)",
+        "outcome: hit / miss of the plan cache (keyed by pattern, and "
+        "for a filtered product by its surviving candidates too), or "
+        "uncacheable (a filtered grouped-TAS product: rebuilt every "
+        "product)",
     ).inc(cache="uncacheable" if plan_key is None
           else "miss" if plan is None else "hit")
     return plan
@@ -1201,12 +1206,23 @@ def clear_mesh_plans() -> None:
 
 
 def _mesh_cache_evict() -> None:
-    while len(_mesh_plan_cache) > _MESH_PLAN_MAX or (
-        len(_mesh_plan_cache) > 1
-        and sum(p.nbytes() for p in _mesh_plan_cache.values())
-        > _MESH_PLAN_MAX_BYTES
-    ):
+    """Hold the cache to `_MESH_PLAN_MAX` plans and, but for the most
+    recent plan, to `_MESH_PLAN_MAX_BYTES`: over the bytes the least
+    recently used plans first give up their cached panels and the
+    operands those keep alive (one assembly rebuilds them, and a chain
+    whose operands are new every product pays it anyway), and only then
+    do whole plans go."""
+    while len(_mesh_plan_cache) > _MESH_PLAN_MAX:
         _mesh_plan_cache.popitem(last=False)
+    nbytes = sum(p.nbytes() for p in _mesh_plan_cache.values())
+    for plan in list(_mesh_plan_cache.values())[:-1]:
+        if nbytes <= _MESH_PLAN_MAX_BYTES:
+            return
+        nbytes -= sum(int(panels.nbytes)
+                      for _, panels, _ in plan.panel_cache.values())
+        plan.panel_cache.clear()
+    while len(_mesh_plan_cache) > 1 and nbytes > _MESH_PLAN_MAX_BYTES:
+        nbytes -= _mesh_plan_cache.popitem(last=False)[1].nbytes()
 
 
 def _mesh_plan_insert(key, plan: _MeshPlan) -> None:
@@ -1299,18 +1315,13 @@ class _GroupedPlan:
         return n
 
 
-def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
-                     limits, retain_sparsity, filter_eps,
-                     beta_window=None) -> _MeshPlan:
-    """The host-side half of a mesh multiply: symbolic product, device
-    and tick assignment, stack fill, panel/collect index maps — all of
-    it pattern-determined and device-uploaded exactly once.
-
-    Square grids (pr == pc) get the skewed Cannon layout; rectangular
-    grids get the all-gather layout (stack entries index the
-    'pc'-gathered A / 'pr'-gathered B concatenations, no skew, ticks =
-    balanced chunks instead of alignment steps)."""
-    from dbcsr_tpu.mm.multiply import _candidates
+def _mesh_candidates(a, b, matrix_c, dtype, limits, retain_sparsity,
+                     filter_eps) -> tuple:
+    """The candidates a mesh product keeps, ``(rows, cols, a_ent,
+    b_ent)``: `mm.multiply._candidates` (under ``filter_eps`` the norm
+    skip, which reads the operands' values), less those outside C's
+    pattern under ``retain_sparsity``."""
+    from dbcsr_tpu.mm.multiply import _candidates, mask_in_sorted
 
     shell_c = matrix_c if matrix_c is not None else BlockSparseMatrix(
         f"{a.name}*{b.name}", a.row_blk_sizes, b.col_blk_sizes, dtype
@@ -1319,14 +1330,30 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
         rows_t, cols_t, a_ent, b_ent = _candidates(
             a, b, shell_c, filter_eps, *limits
         )
-    old_keys = matrix_c.keys if matrix_c is not None else np.empty(0, np.int64)
     if retain_sparsity:
-        from dbcsr_tpu.mm.multiply import mask_in_sorted
-
-        ok = mask_in_sorted(rows_t * shell_c.nblkcols + cols_t, old_keys)
+        ok = mask_in_sorted(rows_t * shell_c.nblkcols + cols_t,
+                            shell_c.keys)
         rows_t, cols_t, a_ent, b_ent = (
             rows_t[ok], cols_t[ok], a_ent[ok], b_ent[ok]
         )
+    return rows_t, cols_t, a_ent, b_ent
+
+
+def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
+                     limits, retain_sparsity, cands,
+                     beta_window=None) -> _MeshPlan:
+    """The host-side half of a mesh multiply on the candidates it keeps
+    (`_mesh_candidates`): device and tick assignment, stack fill,
+    panel/collect index maps — all of it determined by the patterns and
+    the candidates, and device-uploaded exactly once.
+
+    Square grids (pr == pc) get the skewed Cannon layout; rectangular
+    grids get the all-gather layout (stack entries index the
+    'pc'-gathered A / 'pr'-gathered B concatenations, no skew, ticks =
+    balanced chunks instead of alignment steps)."""
+    rows_t, cols_t, a_ent, b_ent = cands
+    nbc = b.nblkcols  # C's block columns
+    old_keys = matrix_c.keys if matrix_c is not None else np.empty(0, np.int64)
     k_of_a = (a.keys % a.nblkcols).astype(np.int64)
     k_t = k_of_a[a_ent]
     true_flops = int(
@@ -1362,15 +1389,15 @@ def _build_mesh_plan(a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
     if retain_sparsity:
         c_keys = old_keys
     else:
-        prod_keys = np.unique(rows_t * shell_c.nblkcols + cols_t)
+        prod_keys = np.unique(rows_t * nbc + cols_t)
         c_keys = np.union1d(old_keys, prod_keys)
-    c_rows = (c_keys // shell_c.nblkcols).astype(np.int64)
-    c_cols = (c_keys % shell_c.nblkcols).astype(np.int64)
+    c_rows = (c_keys // nbc).astype(np.int64)
+    c_cols = (c_keys % nbc).astype(np.int64)
     c_panel = rdist[c_rows] * pc + cdist[c_cols]
     c_slots = _panel_slots(c_panel)
     cap_c = bucket_size(max(int(np.bincount(c_panel, minlength=pr * pc).max()), 1) if len(c_keys) else 1)
 
-    ent_c = np.searchsorted(c_keys, rows_t * shell_c.nblkcols + cols_t)
+    ent_c = np.searchsorted(c_keys, rows_t * nbc + cols_t)
     xtr = 1 if r0 else 0
     if cannon:
         # Cannon: the tick is the alignment step at which A's k column
@@ -1589,11 +1616,13 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
     r0 = _stack_r0(dtype)
     from dbcsr_tpu.core import stats
 
-    # ---- plan lookup (pattern-keyed; filtered products depend on
-    # VALUES via the norm skip, so they rebuild every time — the
-    # single-chip `_plan_cache` convention) ----
-    plan_key = None
-    if filter_eps is None:
+    # ---- plan lookup: the patterns and product options key a plan.
+    # A filtered product's candidates also follow the operands' values
+    # (the norm skip), so they are found every product and their
+    # digest joins the key, as the single-chip `_plan_cache` keys them
+    # (`mm.multiply.multiply`): with A's and B's patterns in the key,
+    # the (a_ent, b_ent) pairs name the survivors whole ----
+    with timed("mesh_plan_build"):
         plan_key = (
             a.pattern_fingerprint(), b.pattern_fingerprint(),
             matrix_c.pattern_fingerprint() if matrix_c is not None else None,
@@ -1602,18 +1631,29 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
             np.dtype(dtype).name, retain_sparsity, limits, beta_window,
             _HashableMesh(mesh), r0, _stack_dot_form(r0, bk, dtype),
         )
-    plan = _mesh_plan_lookup(plan_key)
-    if plan is None:
-        with timed("mesh_plan_build"):
+        cands = None
+        if filter_eps is not None:
+            from dbcsr_tpu.core import digests
+
+            cands = _mesh_candidates(a, b, matrix_c, dtype, limits,
+                                     retain_sparsity, filter_eps)
+            plan_key += ("filtered", float(filter_eps),
+                         digests.index_digest(cands[2], cands[3]))
+        plan = _mesh_plan_lookup(plan_key)
+        if plan is None:
+            if cands is None:
+                cands = _mesh_candidates(a, b, matrix_c, dtype, limits,
+                                         retain_sparsity, None)
             plan = _build_mesh_plan(
                 a, b, matrix_c, mesh, pr, pc, kl, dtype, bm, bk, bn, r0,
-                limits, retain_sparsity, filter_eps, beta_window,
+                limits, retain_sparsity, cands, beta_window,
             )
-        if plan_key is not None:
             _mesh_plan_insert(plan_key, plan)
-        # the plan build is the ONLY host->device traffic of a mesh
-        # multiply now; plan-cache hits upload nothing
-        stats.record_comm("host2dev", 1, plan.upload_bytes)
+            # the plan build is the ONLY host->device traffic of a mesh
+            # multiply now; plan-cache hits upload nothing
+            stats.record_comm("host2dev", 1, plan.upload_bytes)
+        else:
+            _flight.note("plan_cache", "hit")
     cap_a, cap_b, cap_c = plan.cap_a, plan.cap_b, plan.cap_c
     xtr = plan.xtr
 

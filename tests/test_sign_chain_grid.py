@@ -163,6 +163,51 @@ def test_chain_crosses_bucket_boundaries_and_counts_its_programs(
     assert dt.checksum(again) == dt.checksum(x)
 
 
+@pytest.mark.parametrize("driver", ["xla_group"], indirect=True)
+def test_second_chain_finds_every_plan_and_drops_panels_before_plans(
+        mesh4, driver, monkeypatch):
+    """More survivor sets than the cache held until PR 40 (8): a second
+    chain on the same H reaches the same survivors product by product,
+    so it finds every plan and builds none, and its X is the first
+    chain's bit for bit.  Under a byte budget that holds the plans but
+    not all their panels, the least recently used plans give up their
+    panels and no plan goes."""
+    from dbcsr_tpu.parallel import sparse_dist as sd
+
+    def lookups() -> dict:
+        return _counted("dbcsr_tpu_mesh_plan_total")
+
+    def panel_bytes(plan) -> int:
+        return sum(int(p.nbytes) for _, p, _ in plan.panel_cache.values())
+
+    h, mat = _hamiltonian(40, 6)
+    sd.clear_mesh_plans()
+    start = lookups()
+    x, history = sign_iteration(mat, steps=MAX_STEPS, filter_eps=EPS,
+                                tol=TOL, mesh=mesh4)
+    first = {k: v - start.get(k, 0) for k, v in lookups().items()}
+    products = 2 * len(history)
+    plans = list(sd._mesh_plan_cache.values())
+    assert first.get("uncacheable", 0) == 0
+    assert first["miss"] == len(plans) > 8
+    assert first["miss"] + first.get("hit", 0) == products
+    own = sum(p.nbytes() - panel_bytes(p) for p in plans)
+    budget = own + 3 * max(panel_bytes(p) for p in plans) // 2
+    assert sum(p.nbytes() for p in plans) > budget
+    monkeypatch.setattr(sd, "_MESH_PLAN_MAX_BYTES", budget)
+    middle = lookups()
+    again, _ = sign_iteration(mat, steps=MAX_STEPS, filter_eps=EPS,
+                              tol=TOL, mesh=mesh4)
+    second = {k: v - middle.get(k, 0) for k, v in lookups().items()}
+    assert {k: v for k, v in second.items() if v} == {"hit": products}
+    cached = list(sd._mesh_plan_cache.values())
+    assert sorted(map(id, cached)) == sorted(map(id, plans))
+    assert sum(p.nbytes() for p in cached) <= budget
+    assert not cached[0].panel_cache and cached[-1].panel_cache
+    assert dt.checksum(again) == dt.checksum(x)
+    assert np.array_equal(dt.to_dense(again), dt.to_dense(x))
+
+
 def test_mesh_products_say_what_they_did_on_their_flight_records(
         mesh4, driver):
     """One record a product, with the flops and the surviving blocks:

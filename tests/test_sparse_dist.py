@@ -1140,12 +1140,27 @@ def test_filtered_serial_and_double_buffered_ticks_are_bit_identical(
     assert np.array_equal(to_dense(serial), to_dense(db))
 
 
-def test_second_filtered_product_rebuilds_the_plan_and_compiles_nothing(
-        mesh4, filtered_case):
+def _spy_plan_builds(monkeypatch) -> list:
+    """Every `_build_mesh_plan` run from now on, one entry each."""
+    from dbcsr_tpu.parallel import sparse_dist as sd
+
+    runs, build = [], sd._build_mesh_plan
+
+    def spy(*args, **kw):
+        runs.append(1)
+        return build(*args, **kw)
+
+    monkeypatch.setattr(sd, "_build_mesh_plan", spy)
+    return runs
+
+
+def test_second_filtered_product_hits_the_plan_and_compiles_nothing(
+        mesh4, filtered_case, monkeypatch):
     import jax
     import jax.monitoring as mon
 
     from dbcsr_tpu.core import timings
+    from dbcsr_tpu.obs import flight
 
     name, a, b, *_ = filtered_case
     compiles = []
@@ -1154,28 +1169,127 @@ def test_second_filtered_product_rebuilds_the_plan_and_compiles_nothing(
         if event == "/jax/core/compile/backend_compile_duration":
             compiles.append(event)
 
-    def builds():
+    def spans():
         st = timings._stats.get("mesh_plan_build")
         return st.calls if st else 0
 
     first = _filtered_on_mesh(a, b, mesh4, case=name)
+    runs = _spy_plan_builds(monkeypatch)
     mon.register_event_duration_secs_listener(listener)
     try:
-        before, built = _plan_lookups(), builds()
+        before, opened = _plan_lookups(), spans()
         second = _filtered_on_mesh(a, b, mesh4, case=name)
         jax.block_until_ready([bn.data for bn in second.bins])
     finally:
         mon.unregister_event_duration_listener(listener)
     after = _plan_lookups()
-    # a filtered plan follows the operands' values, so it is never
-    # cached: the second product pays the whole host plan again
+    # the same operands reach the same survivors: the plan keyed by the
+    # patterns and their digest is found, and only the key is paid
     assert {k: after[k] - before[k] for k in after} == \
-        {"hit": 0, "miss": 0, "uncacheable": 1}
-    assert builds() == built + 1
+        {"hit": 1, "miss": 0, "uncacheable": 0}
+    assert spans() == opened + 1 and runs == []
+    assert flight.records()[-1]["plan_cache"] == "hit"
     assert compiles == []
     np.testing.assert_array_equal(first.keys, second.keys)
     assert checksum(first) == checksum(second)
     assert np.array_equal(to_dense(first), to_dense(second))
+
+
+def test_filtered_product_of_new_values_misses_and_keeps_the_filter(
+        mesh4, monkeypatch):
+    """The same patterns, values that decay faster: the norm skip keeps
+    other survivors, so the digest differs, the plan is built anew, and
+    the product is NumPy's under the filter's definition (blocks under
+    eps dropped, skipped candidates summing under eps a block)."""
+    sizes = [4] * 10
+    rng = np.random.default_rng(91)
+    a_dense, there_a, off = _draw(sizes, 0.5, 1.0, rng)
+    b_dense, there_b, _ = _draw(sizes, 0.5, 1.0, rng)
+    a = _stage("A", a_dense, there_a, sizes, off)
+    b = _stage("B", b_dense, there_b, sizes, off)
+    a2_dense = a_dense * 1e-3
+    a2 = _stage("A", a2_dense, there_a, sizes, off)
+    assert a2.pattern_fingerprint() == a.pattern_fingerprint()
+    _filtered_on_mesh(a, b, mesh4)
+    runs = _spy_plan_builds(monkeypatch)
+    before = _plan_lookups()
+    c = _filtered_on_mesh(a2, b, mesh4)
+    after = _plan_lookups()
+    assert {k: after[k] - before[k] for k in after} == \
+        {"hit": 0, "miss": 1, "uncacheable": 0}
+    assert runs == [1]
+    want = a2_dense @ b_dense
+    na, nb = _block_norms(a2_dense, off), _block_norms(b_dense, off)
+    pair = na[:, :, None] * nb[None, :, :]
+    row_eps = _FILTER_EPS / np.maximum(1, (na > 0).sum(axis=1))
+    skipped = np.where((pair > 0) & (pair < row_eps[:, None, None]),
+                       pair, 0.0).sum(axis=1)
+    # other survivors than the first product's
+    first_pair = _block_norms(a_dense, off)[:, :, None] * nb[None, :, :]
+    first_eps = _FILTER_EPS / np.maximum(1, (na > 0).sum(axis=1))
+    assert ((first_pair < first_eps[:, None, None])
+            != (pair < row_eps[:, None, None])).any()
+    got = to_dense(c)
+    rows, cols = c.entry_coords()
+    norms = _block_norms(want, off)
+    # f64 rounding of a k = 40 dot, a block of 16 elements
+    slack = 1e-13 * np.abs(want).max() * 4
+    for i, j in zip(rows, cols):
+        blk = (slice(off[i], off[i + 1]), slice(off[j], off[j + 1]))
+        assert np.linalg.norm(got[blk] - want[blk]) <= skipped[i, j] + slack
+        assert norms[i, j] >= _FILTER_EPS - skipped[i, j] - slack
+    kept = np.zeros((10, 10), bool)
+    kept[rows, cols] = True
+    assert 0 < kept.sum() < (norms > 0).sum()
+    for i, j in zip(*np.nonzero(~kept)):
+        assert norms[i, j] < _FILTER_EPS + skipped[i, j] + slack
+        assert not got[off[i]:off[i + 1], off[j]:off[j + 1]].any()
+
+
+def test_a_hit_shares_no_mutable_state_with_its_result(mesh4, monkeypatch):
+    """The filter drops blocks from a product whose plan was cached;
+    the plan's C keys, binning and maps are what they were, and a third
+    product from the same plan is the second bit for bit."""
+    from dbcsr_tpu.parallel import sparse_dist as sd
+
+    sizes = [4] * 12
+    rng = np.random.default_rng(93)
+    a_dense, there_a, off = _draw(sizes, 0.5, 1.5, rng)
+    b_dense, there_b, _ = _draw(sizes, 0.5, 1.5, rng)
+    a = _stage("A", a_dense, there_a, sizes, off)
+    b = _stage("B", b_dense, there_b, sizes, off)
+    sd.clear_mesh_plans()
+    first = _filtered_on_mesh(a, b, mesh4)
+    (plan,) = sd._mesh_plan_cache.values()
+    # the filter dropped blocks of C: the plan holds more than the result
+    assert first.nblks < len(plan.c_keys)
+    held = {
+        "c_keys": plan.c_keys.copy(),
+        "c_binning": tuple(np.array(x, copy=True) for x in plan.c_binning[:2]),
+        "collect": [np.asarray(x).copy() for x in plan.collect_own
+                    + plan.collect_perm],
+        "a_asm": [np.asarray(x).copy() for x in plan.a_asm.flat_pos
+                  + plan.a_asm.src_slots],
+    }
+    runs = _spy_plan_builds(monkeypatch)
+    second = _filtered_on_mesh(a, b, mesh4)
+    third = _filtered_on_mesh(a, b, mesh4)
+    assert runs == []
+    assert [id(p) for p in sd._mesh_plan_cache.values()] == [id(plan)]
+    assert second.nblks == first.nblks < len(plan.c_keys)
+    np.testing.assert_array_equal(plan.c_keys, held["c_keys"])
+    for got, want in zip(plan.c_binning[:2], held["c_binning"]):
+        np.testing.assert_array_equal(got, want)
+    for got, want in zip(plan.collect_own + plan.collect_perm,
+                         held["collect"]):
+        np.testing.assert_array_equal(np.asarray(got), want)
+    for got, want in zip(plan.a_asm.flat_pos + plan.a_asm.src_slots,
+                         held["a_asm"]):
+        np.testing.assert_array_equal(np.asarray(got), want)
+    for c in (second, third):
+        np.testing.assert_array_equal(c.keys, first.keys)
+        assert checksum(c) == checksum(first)
+        assert np.array_equal(to_dense(c), to_dense(first))
 
 
 def test_filtered_plan_tiles_every_device_and_tick_by_its_own_runs(
@@ -1205,6 +1319,7 @@ def test_filtered_plan_tiles_every_device_and_tick_by_its_own_runs(
         return got.get("live", 0.0), got.get("launched", 0.0)
 
     monkeypatch.setattr(sd, "_fill_stacks", spy)
+    sd.clear_mesh_plans()  # the case's plan may be cached: build it here
     live0, launched0 = slots()
     rolled0 = stats.driver_rollup().get("mesh", {})
     _filtered_on_mesh(a, b, mesh4, case=name)
