@@ -155,6 +155,33 @@ SLICES = 8
 # the deepest strip whose slice-pair dots are exact in f32: a slice is
 # at most 2^7 of its grid's units, a product 2^14, 1 024 of them 2^24
 SLICED_MAX_DEPTH = 1024
+# the fewest groups a chunk of one width class holds where its tiles are
+# folded into weight classes (`_fold_classes`): the fold reads them with
+# the group index along lanes, which is where the compiler puts the
+# tiles of such a chunk for the f64 sums too (for a v5e: chunks of 256
+# groups and more); a smaller chunk's f64 sums read the dot's own
+# layout, and a fold that relaid its tiles out cost the north star's
+# filtered product 5% on a v5e where it saved nothing
+SLICED_FOLD_MIN_GROUPS = 256
+
+
+def sliced_depth(k: int) -> int:
+    """The depth a stored block of inner dimension ``k`` brings to a
+    sliced strip (`_slice_blocks`): k filled to whole bf16 tiles of 16
+    sublanes."""
+    return ceil_div(k, 16) * 16
+
+
+def sliced_fold(depth: int, groups: int) -> int:
+    """Slice-pair tiles of one weight class that `_sliced_dot` sums in
+    f32 for a chunk of ``groups`` strips of ``depth``: the largest of 8,
+    4, 2, 1 whose f tiles hold at most `SLICED_MAX_DEPTH` products of
+    2^14 an element, the bound one tile is exact under; 1 (no fold) in
+    a chunk of fewer than `SLICED_FOLD_MIN_GROUPS` groups."""
+    f = SLICES if groups >= SLICED_FOLD_MIN_GROUPS else 1
+    while f > 1 and f * depth > SLICED_MAX_DEPTH:
+        f //= 2
+    return f
 
 
 def group_dot_form(dtype, depth: int, prec=None) -> str:
@@ -253,7 +280,7 @@ def _slice_blocks(data, depth_axis: int):
         sl = sl.transpose(0, 2, 1, 3)
     k = sl.shape[1]
     sl = sl.reshape(n_blk, k, -1)
-    return jnp.pad(sl, ((0, 0), (0, ceil_div(k, 16) * 16 - k), (0, 0)))
+    return jnp.pad(sl, ((0, 0), (0, sliced_depth(k) - k), (0, 0)))
 
 
 def _halve(x, axis: int):
@@ -265,22 +292,74 @@ def _halve(x, axis: int):
     return jnp.squeeze(x, axis)
 
 
+def _two_sum(a, b):
+    """Two (sum, error) pairs of f32 added: the sums in f32, the
+    rounding of that add kept exactly (Knuth's TwoSum) and carried
+    with both errors."""
+    (s1, e1), (s2, e2) = a, b
+    s = s1 + s2
+    z = s - s1
+    return s, (e1 + e2) + ((s1 - (s - z)) + (s2 - z))
+
+
+def _fold_classes(tiles, n, f):
+    """The (ch, SLICES*mp, SLICES*n) slice-pair tiles of `_sliced_dot`
+    as partial class tiles in f32: (ch, SLICES//f, mp, SLICES, n), and
+    (ch, mp, n) the rounding errors of the folds (at f = 1 the tiles as
+    they are, (ch, SLICES, mp, SLICES*n), and no error term).
+
+    Slice i holds the windows w = i (mod 8) of every element
+    (`_bf16_slices`), so tile (i, j) lies on the grid 2^(8s) with
+    s = i + j (mod 8): class c is the tiles (i, (c - i) mod 8).  Entry
+    [b, :, c] sums the class-c tiles of i = b*f .. b*f + f-1, f*depth
+    products of 2^14 a grid: exact where they lie on one grid, while
+    `sliced_fold` allows f.  But a class holds, for one pair of
+    elements, the product of their leading windows beside products
+    2^64 under it, whose bits an f32 sum drops (up to 2^-43 of the
+    pair's product, where a single tile holds one product a pair): so
+    every add of the fold keeps its rounding (`_two_sum`, one variadic
+    reduce), and the errors, each under 2^-40 of its sum, come back
+    summed in f32.  The class map is a roll of each A slice's row of
+    tiles by i tiles along j, read from j doubled (on a v5e a diagonal
+    read through one (i, j) reshape relays the doubled block out twice
+    and takes twice the time)."""
+    ch, smp, sn = tiles.shape
+    mp = smp // SLICES
+    if f == 1:  # no two tiles meet in f32: (i, j) order, no error term
+        return tiles.reshape(ch, SLICES, mp, sn), None
+    t = tiles.reshape(ch, SLICES, mp, SLICES, n)
+    t = jnp.concatenate([t, t], axis=3)
+    t = jnp.concatenate([t[:, i:i + 1, :, SLICES - i:2 * SLICES - i]
+                         for i in range(SLICES)], axis=1)
+    t = t.reshape(ch, SLICES // f, f, mp, SLICES, n)
+    zero = jnp.float32(0)
+    hi, lo = jax.lax.reduce((t, jnp.zeros_like(t)), (zero, zero), _two_sum,
+                            (2,))
+    return hi, lo.sum(axis=(1, 3))
+
+
 def _sliced_dot(amat, bmat, m, n, acc):
     """A group's product from its sliced strips, both depth-major
     (`_slice_blocks`): ``amat`` (ch, depth, SLICES*mp) and ``bmat``
     (ch, depth, SLICES*n) in bf16 give all SLICES^2 slice-pair
     products as the (mp, n) tiles of ONE native dot.  Every tile is
     exact in f32 (`SLICED_MAX_DEPTH`; where elements of a strip lie
-    2^64 apart the smaller falls under the larger's last bit).  The
-    tiles are summed in ``acc`` (f64), the only rounding, pair by pair
-    (`_halve`: the first level adds two f32 and is exact): first down
-    the rows, whole tiles of 8 sublanes, then what is left, an eighth,
-    along the lanes."""
+    2^64 apart the smaller falls under the larger's last bit), and f
+    tiles of one weight class are summed in f32 with their roundings
+    kept (`_fold_classes`, f = `sliced_fold` of the strip's depth and
+    the chunk's groups: 8 up to depth 128).  The 64/f partial class
+    tiles are summed in ``acc`` (f64) pair by pair (`_halve`): first
+    the partials of a class, then the classes (at f = 1 the A slices,
+    then the B slices); the folds' errors are added last."""
     tiles = jax.lax.dot_general(amat, bmat, (((1,), (1,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-    ch, smp, sn = tiles.shape
-    rows = _halve(tiles.reshape(ch, SLICES, smp // SLICES, sn).astype(acc), 1)
-    return _halve(rows.reshape(ch, -1, SLICES, n), 2)[:, :m]
+    hi, lo = _fold_classes(tiles, n, sliced_fold(amat.shape[1],
+                                                  amat.shape[0]))
+    rows = _halve(hi.astype(acc), 1)
+    out = _halve(rows.reshape(rows.shape[0], -1, SLICES, n), 2)
+    if lo is not None:
+        out = out + lo.astype(acc)
+    return out[:, :m]
 
 
 def _accumulate_chunk(c, prod, c_idx):
@@ -360,10 +439,11 @@ def _mnk_label(a_data, b_data) -> str:
     return f"{a_data.shape[1]}x{b_data.shape[2]}x{a_data.shape[2]}"
 
 
-def _note_group_span(dot_form: str, mnk: str) -> None:
-    """Count one launched `xla_group` span by its gather layout and by
-    the form of its dot."""
-    note_group_dot(dot_form, mnk)
+def _note_group_span(plan, a_data, b_data) -> None:
+    """Count one launched `xla_group` span by its gather layout, by the
+    form of its dot and, sliced, by its classes' folds."""
+    note_group_dot(plan.dot_form, _mnk_label(a_data, b_data),
+                   [s[1:] for s in _group_idx_shapes(plan)], a_data.shape[2])
     _metrics.counter(
         "dbcsr_tpu_stack_gather_total",
         "xla_group spans launched (per span or inside a fused launch), "
@@ -373,12 +453,15 @@ def _note_group_span(dot_form: str, mnk: str) -> None:
     ).inc(layout=GROUP_GATHER_LAYOUT)
 
 
-def note_group_dot(dot_form: str, mnk: str,
+def note_group_dot(dot_form: str, mnk: str, classes, k: int,
                    driver: str = "xla_group") -> None:
     """Count one launched grouped span (an `xla_group` span through
     `_note_group_span`, or one product's grouped mesh stacks:
     ``driver`` "mesh") by the form of its dot (`group_dot_form`) and
-    its block shape ``mnk`` ("5x13x23")."""
+    its block shape ``mnk`` ("5x13x23"); a sliced one's width classes,
+    ``classes`` = ((groups a chunk, width), ...), also by the tiles a
+    weight class folds in f32 (`sliced_fold` of the strip's depth,
+    w * `sliced_depth` of ``k``, and the chunk's groups)."""
     _metrics.counter(
         "dbcsr_tpu_stack_dot_total",
         "grouped spans launched, by their (m,n,k) and by how the chunk "
@@ -387,6 +470,16 @@ def note_group_dot(dot_form: str, mnk: str,
         "(emulated f64), 'compiler' = the compiler's dot of the "
         "gathered strips",
     ).inc(form=dot_form, mnk=mnk)
+    if dot_form == "sliced":
+        folds = _metrics.counter(
+            "dbcsr_tpu_sliced_fold_total",
+            "width classes of the sliced grouped spans launched (per "
+            "span or inside a fused launch; once a product for a mesh "
+            "plan's grouped stacks), by the slice-pair tiles of one "
+            "weight class their dot sums in f32: 8, 4, 2 or 1",
+        )
+        for groups, w in classes:
+            folds.inc(fold=str(sliced_fold(w * sliced_depth(k), groups)))
     from dbcsr_tpu.core import stats
 
     stats.record_group_dot(dot_form, driver=driver)
@@ -1966,7 +2059,7 @@ def _execute_plan(c_data, a_data, b_data, plan: Optional[StackPlan], alpha=1.0,
         if plan.append_b_pad:
             b_data = _append_pad_row(b_data)
         alpha_dev = jnp.asarray(alpha, dtype=c_data.dtype)
-        _note_group_span(plan.dot_form, _mnk_label(a_data, b_data))
+        _note_group_span(plan, a_data, b_data)
         if want_xla_cost:
             _capture_stack_xla_cost(
                 jit_fn_name, jit_key, _process_stack_xla_group,
@@ -2433,7 +2526,7 @@ def _dispatch_superstack(c_data, a_datas, b_datas, splan: SuperstackPlan,
             flat.extend(plan.xla_idx)
         elif plan.driver == "xla_group":
             flat.extend(plan.group_idx)
-            _note_group_span(plan.dot_form, _mnk_label(a_d, b_d))
+            _note_group_span(plan, a_d, b_d)
         else:
             for lc in plan.launches:
                 flat.extend(lc)

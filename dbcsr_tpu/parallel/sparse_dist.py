@@ -264,12 +264,14 @@ def _stack_dot_form(r0: int, bk: int, dtype) -> str:
 
 
 def _note_mesh_dot(plan) -> None:
-    """Count one product's grouped mesh stacks by their dot's form."""
+    """Count one product's grouped mesh stacks by their dot's form
+    and, sliced, their width classes by their folds."""
     if plan.r0:
         from dbcsr_tpu.acc.smm import note_group_dot
 
+        classes = [tile[0].shape[-2:] for tile in plan.stacks_dev[1]]
         note_group_dot(plan.dot_form, f"{plan.bm}x{plan.bn}x{plan.bk}",
-                       driver="mesh")
+                       classes, plan.bk, driver="mesh")
 
 
 _TICK_CHUNK_ENTRIES = 32768
