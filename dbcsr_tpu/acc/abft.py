@@ -539,33 +539,6 @@ def check_dense_canvas(cd, ad, bd, c_old, alpha, beta, *, dtype,
                   (cd.shape[0], n, k), site="dense")
 
 
-def check_dense_canvas_batched(pd, ad, bd, *, dtype,
-                               driver: str = "composite") -> None:
-    """Batched sibling of `check_dense_canvas` for the composite panel
-    path: the raw batched product ``pd[g]`` must equal ``ad[g] @ bd[g]``
-    for EVERY panel g, checked through the same rank-1 probe identity
-    per panel and reduced to a single worst-panel error — ONE host sync
-    for the whole batch, so the check never serializes the panels the
-    composite format exists to fuse."""
-    acc = _acc_dtype(dtype)
-    n = int(pd.shape[2])
-    k = int(ad.shape[2])
-    _record_check(driver)
-    v = probe_vector(n, dtype)
-    lhs = jnp.einsum("gmn,n->gm", pd.astype(acc), v)
-    rhs = jnp.einsum("gmk,gk->gm", ad.astype(acc),
-                     jnp.einsum("gkn,n->gk", bd.astype(acc), v))
-    err_d = jnp.max(jnp.abs(lhs - rhs))
-    scale_d = jnp.maximum(jnp.max(jnp.abs(lhs)), jnp.max(jnp.abs(rhs)))
-    es = np.asarray(jnp.stack([err_d, scale_d]))
-    err, scale = float(es[0]), float(es[1])
-    tol = _costmodel.abft_tolerance(str(jnp.dtype(dtype)), k, 4)
-    if not np.isfinite(err) or err > tol * max(scale, 1e-30):
-        _mismatch(driver, err / max(scale, 1e-30), tol, scale,
-                  (int(pd.shape[0]), int(pd.shape[1]), n, k),
-                  site="dense")
-
-
 # ------------------------------------------- distributed tick probes
 
 def tree_probe_device(tree):

@@ -30,22 +30,12 @@ class Config:
     #      DBCSR_TPU_MM_FORMAT; ref MM_DENSE + the decision at
     #      dbcsr_mm.F:593-617) ----
     # per-product execution format: "auto" (the planner picks between
-    # the BCSR shape-bucketed stack path, the whole-panel padded dense
-    # GEMM, and the block-diagonal composite panel from the pattern
-    # fingerprint's occupancy, the live roofline, and learned per-device
-    # crossover rows in the tune params table), or a forced
-    # "stack"/"dense"/"composite" (A/B legs and the safe engine; a
-    # forced format the product or the engine cannot run — e.g.
-    # composite with no independent row panels, or on a mesh — falls
-    # back to stack, counted under reason="ineligible")
+    # the BCSR shape-bucketed stack path and the whole-panel padded
+    # dense GEMM from the operands' occupancy and dtype), or a forced
+    # "stack"/"dense" (A/B legs and the safe engine; a forced dense the
+    # engine cannot run — a mesh that is not square — falls back to
+    # stack with reason="ineligible")
     mm_format: str = "auto"
-    # composite panel packing limits (mm/multiply.py:composite_panels):
-    # most row-panels one batched GEMM may carry, and the largest
-    # fraction of the k-dimension a panel's k-support may span while
-    # still counting as "narrow" (above it the batched GEMM does the
-    # same flops as whole-panel dense and the batching is pure overhead)
-    composite_max_panels: int = 64
-    composite_ksup: float = 0.75
     # use the fused pallas SMM kernel when available (ref: libsmm_acc JIT
     # kernels vs cuBLAS loop)
     use_pallas: bool = True
@@ -166,14 +156,10 @@ class Config:
         if self.mm_driver not in ("auto", "xla", "xla_group", "pallas",
                                   "pallas_cross", "host"):
             raise ValueError(f"unknown mm_driver {self.mm_driver!r}")
-        if self.mm_format not in ("auto", "stack", "dense", "composite"):
+        if self.mm_format not in ("auto", "stack", "dense"):
             raise ValueError(
-                f"mm_format must be 'auto'/'stack'/'dense'/'composite', "
+                f"mm_format must be 'auto'/'stack'/'dense', "
                 f"got {self.mm_format!r}")
-        if self.composite_max_panels < 2:
-            raise ValueError("composite_max_panels must be >= 2")
-        if not 0.0 < self.composite_ksup <= 1.0:
-            raise ValueError("composite_ksup must be in (0, 1]")
         if self.superstack not in ("auto", "fused", "per_span"):
             raise ValueError(
                 f"superstack must be 'auto'/'fused'/'per_span', "

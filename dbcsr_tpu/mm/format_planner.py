@@ -1,79 +1,59 @@
-"""Storage-format planner: stack, dense or composite, once per product.
+"""Storage-format planner: stack or dense, once per product.
 
 This module is the ONLY place that decides how a product executes.
 Both engines — `mm.multiply` on one chip and
 `parallel.sparse_dist.sparse_multiply_distributed` on a mesh — ask
-`choose`, and tell it what they can execute (``executors``,
-``chunked_canvas``); the rules are never re-derived anywhere else.
-The three executions of the identical product:
+`choose`, and say in one word whether they can run a dense canvas
+(``dense``: always on one chip, on a square grid on the mesh); the
+rules are never re-derived anywhere else.  The two executions of the
+identical product:
 
-* ``stack``     — the shape-bucketed BCSR stack engine (the default);
-* ``dense``     — one padded dense GEMM on whole-matrix canvases
+* ``stack`` — the shape-bucketed BCSR stack engine (the default);
+* ``dense`` — one padded dense GEMM on whole-matrix canvases
   (`mm.multiply._dense_multiply`; strip-chunked beyond the canvas cap
-  on one chip, the dense Cannon on a square mesh);
-* ``composite`` — the block-diagonal composite panel: C's block-rows
-  are greedily grouped into row-panels with narrow k-support, packed
-  into ONE batched padded GEMM (`_composite_multiply`, one chip only).
+  on one chip, the dense Cannon on a square mesh).
 
-The funnel, first hit wins:
+The funnel, first hit wins, decides only from what the product shows:
 
-0. the structural gate (`_stack_only`): a filtered, pattern-locked,
+1. the structural gate (`_stack_only`): a filtered, pattern-locked,
    limited or symmetric-C product, or one under ``mm_driver="pallas"``,
-   or a caller with no canvas executor, runs on the stack engine
-   (``reason="structural"``);
-1. the ``format_plan`` fault site (an injected fault degrades the plan
+   runs on the stack engine (``reason="structural"``);
+2. the ``format_plan`` fault site (an injected fault degrades the plan
    to stack, ``reason="fault"`` — never cached);
 
-   from here on the plan is cached by pattern fingerprints + config +
-   what the caller can execute + params generation (a tuner
-   promotion/demotion bumps the generation, so learned crossovers
-   retire cached plans immediately);
-2. ``mm_format`` forced (``reason="forced"``; a force the caller cannot
-   execute falls back to stack, ``reason="ineligible"``);
-3. a learned params-table row carrying ``format``/``format_occ``
-   columns for this block cell: above the learned occupancy crossover
-   the row's format wins (``reason="tuned"``) — this is where the
-   autotuner (`dbcsr_tpu.tune`) overrides the rules per device;
+   from here on the plan is cached by pattern fingerprints + dtype +
+   config + platform + what the caller can execute;
+3. ``mm_format`` forced (``reason="forced"``; a dense force the caller
+   cannot execute falls back to stack, ``reason="ineligible"``); a
+   caller with no canvas executor stops here on the stack engine
+   (``reason="structural"``);
 4. the dense rules (`_dense_rule`, ``reason="heuristic"``): both
    operands at or above `DENSE_OCC_THRESHOLD` occupancy (the
    reference's gate, `dbcsr_mm.F:593-617`), or — for a dtype the TPU
    only emulates — dense flops under `DENSE_FLOP_RATIO` times the true
-   flops with a C that would fill anyway.  Every benchmark cell is
-   routed by this step;
-5. on an MXU (`effective_platform() == "tpu"`), the
-   `obs.costmodel.format_costs` occupancy-parameterized curves: the
-   cheapest modeled format among those the caller can execute
-   (``reason="model"``); guarded by the same >= 0.5 candidate-fill
-   rule so a structurally sparse C is never silently densified;
-6. stack (``reason="default"``; a non-uniform blocking, which steps 3
-   and 5 cannot price, reports ``reason="structural"``).
+   flops with a C that would fill anyway;
+5. stack (``reason="default"``; a non-uniform blocking reports
+   ``reason="structural"``).
 
 Every one-chip decision lands on ``dbcsr_tpu_format_decision_total{
 format, reason}`` and in the product's trace span/flight record
 (`note_decision`; the mesh engine's call waits for a `benchmark` PR,
-see its call site); every EXECUTED one-chip product reports back
-through `note_outcome`, which
-keeps a bounded regret ring (model-predicted vs measured GFLOP/s) that
-the timeseries collector samples and `tune.miner.mine_format` mines
-for re-trial when the planner's choice underperforms its own model.
+see its call site).
 
-Import-light: numpy only at import; jax, config, params, costmodel and
-`mm.multiply` are reached lazily (multiply imports THIS module lazily
-too, so there is no cycle).  Of `mm.multiply` the planner asks only
-facts and feasibility: `_true_product_flops`, `_uniformly_blocked`,
-`composite_panels`, `dense_canvas_feasible`.
+Import-light: numpy only at import; jax, config and `mm.multiply` are
+reached lazily (multiply imports THIS module lazily too, so there is
+no cycle).  Of `mm.multiply` the planner asks only facts and
+feasibility: `_true_product_flops`, `_uniformly_blocked`,
+`dense_canvas_feasible`.
 """
 
 from __future__ import annotations
 
 import collections
 import threading
-import time
 from typing import Optional
 
 import numpy as np
-
-FORMATS = ("stack", "dense", "composite")
 
 # both operands at or above this occupancy go dense on any platform
 # (ref MM_DENSE's gate, `dbcsr_mm.F:593-617`)
@@ -89,30 +69,21 @@ DENSE_FLOP_RATIO = 250.0
 _lock = threading.Lock()
 _plan_cache: "collections.OrderedDict" = collections.OrderedDict()
 _PLAN_CACHE_MAX = 256
-_regret: "collections.deque" = collections.deque(maxlen=256)
-# measured/predicted below this ratio marks the decision a regret the
-# format miner re-trials (mirrors the tuner's roofline floor idea)
-_REGRET_FLOOR = 0.5
 
 
 class Plan:
     """One product's format decision plus the evidence it rode on."""
 
-    __slots__ = ("fmt", "reason", "panels", "predicted", "cell", "occ",
-                 "grid", "why")
+    __slots__ = ("fmt", "reason", "cell", "occ", "why")
 
-    def __init__(self, fmt: str, reason: str, panels=None,
-                 predicted: Optional[dict] = None,
+    def __init__(self, fmt: str, reason: str,
                  cell: Optional[tuple] = None, occ: Optional[float] = None,
-                 grid: Optional[tuple] = None, why: Optional[str] = None):
+                 why: Optional[str] = None):
         self.fmt = fmt
         self.reason = reason
         self.why = why            # which dense rule fired (flight dense_why)
-        self.panels = panels
-        self.predicted = predicted
         self.cell = cell          # (bm, bn, bk, dtype) — uniform products
         self.occ = occ            # pair occupancy: entries/(nbr*nbc*nbk)
-        self.grid = grid          # (nbr, nbc, nbk)
 
     def __repr__(self):
         return f"Plan({self.fmt}, reason={self.reason}, occ={self.occ})"
@@ -134,44 +105,19 @@ def _cache_put(key, value, cache=_plan_cache, cap=_PLAN_CACHE_MAX) -> None:
 
 
 def reset() -> None:
-    """Drop cached plans and regret history (tests, config flips)."""
+    """Drop cached plans and the last choice per cell (tests, config
+    flips)."""
     with _lock:
         _plan_cache.clear()
-        _regret.clear()
         _last_choice.clear()
 
 
-def _tuned_row(bm: int, bn: int, bk: int, dtype: str) -> Optional[dict]:
-    """The params-table row for this block cell IF it carries learned
-    format columns (promoted by `tune.store`, adopted from fleet peers,
-    or hand-written).  Falls back to the nearest same-device-kind
-    format-carrying row (`tune.predictor.format_prior`) so one trialed
-    cell informs its shape neighborhood; None otherwise."""
-    try:
-        from dbcsr_tpu.acc import params as params_mod
-
-        row = params_mod.lookup(bm, bn, bk, dtype)
-    except Exception:
-        return None
-    if row and row.get("format") in FORMATS:
-        return row
-    try:
-        from dbcsr_tpu.tune.predictor import format_prior
-
-        row = format_prior(bm, bn, bk, dtype)
-    except Exception:
-        return None
-    if row and row.get("format") in FORMATS:
-        return row
-    return None
-
-
 def _stack_only(c, cfg, filter_eps, retain_sparsity, no_limits) -> bool:
-    """THE structural gate, shared by every non-stack format and both
-    engines: the canvas executors write C's full pattern in one piece,
-    so a filtered, pattern-locked, limited or symmetric-C product can
-    only run on the stack engine — as can one whose stack driver was
-    pinned to Pallas (``mm_driver="pallas"``: kernel A/B legs)."""
+    """THE structural gate, shared by both engines: the canvas
+    executors write C's full pattern in one piece, so a filtered,
+    pattern-locked, limited or symmetric-C product can only run on the
+    stack engine — as can one whose stack driver was pinned to Pallas
+    (``mm_driver="pallas"``: kernel A/B legs)."""
     from dbcsr_tpu.core.matrix import NO_SYMMETRY
 
     return (filter_eps is not None or retain_sparsity or not no_limits
@@ -179,20 +125,18 @@ def _stack_only(c, cfg, filter_eps, retain_sparsity, no_limits) -> bool:
 
 
 def choose(a, b, c, *, filter_eps, retain_sparsity, no_limits,
-           executors=("dense", "composite"), chunked_canvas=True) -> Plan:
+           dense: bool, chunked_canvas: bool = True) -> Plan:
     """Resolve the product's execution format (see the module funnel).
-    ``executors`` are the non-stack formats the caller can run and
+    ``dense`` says whether the caller can run a dense canvas and
     ``chunked_canvas`` whether its dense executor survives the canvas
-    cap by strips: all that differs between the one-chip engine (the
-    defaults) and the mesh engine (the dense Cannon on a square grid,
-    no strips, no composite).  Cheap on repeat: cached by pattern
-    fingerprints + config + params generation + device kind."""
+    cap by strips: all that differs between the one-chip engine and the
+    mesh engine (the dense Cannon on a square grid, no strips).  Cheap
+    on repeat: cached by pattern fingerprints + config + device kind."""
     from dbcsr_tpu.core.config import effective_platform, get_config
     from dbcsr_tpu.resilience import faults as _faults
 
     cfg = get_config()
-    if not executors or _stack_only(c, cfg, filter_eps, retain_sparsity,
-                                    no_limits):
+    if _stack_only(c, cfg, filter_eps, retain_sparsity, no_limits):
         return Plan("stack", "structural")
     # fault boundary: an injected plan fault degrades to stack for THIS
     # product only (never cached — the fault is transient)
@@ -202,97 +146,51 @@ def choose(a, b, c, *, filter_eps, retain_sparsity, no_limits,
         except BaseException:
             return Plan("stack", "fault")
 
-    from dbcsr_tpu.acc import params as params_mod
-
     key = (
         a.pattern_fingerprint(), b.pattern_fingerprint(),
         c.pattern_fingerprint(), str(np.dtype(c.dtype)),
-        (cfg.mm_format, cfg.mm_driver,
-         cfg.composite_max_panels, cfg.composite_ksup,
-         effective_platform(), tuple(executors), bool(chunked_canvas)),
-        params_mod.generation(),
+        (cfg.mm_format, cfg.mm_driver, effective_platform(),
+         bool(dense), bool(chunked_canvas)),
     )
     plan = _cache_get(key)
     if plan is not None:
         return plan
-    plan = _choose_uncached(a, b, c, cfg, executors, chunked_canvas)
+    plan = _choose_uncached(a, b, c, cfg, dense, chunked_canvas)
     _cache_put(key, plan)
     return plan
 
 
-def _choose_uncached(a, b, c, cfg, executors, chunked_canvas) -> Plan:
-    from dbcsr_tpu.core.config import effective_platform
+def _choose_uncached(a, b, c, cfg, dense, chunked_canvas) -> Plan:
     from dbcsr_tpu.mm import multiply as _mm
-    from dbcsr_tpu.obs import costmodel as _costmodel
 
     uniform = all(_mm._uniformly_blocked(m) for m in (a, b, c))
-    cell = occ = grid = predicted = None
-    entries = 0
-    panels = None
+    cell = occ = None
     if uniform:
         bm = int(c.row_blk_sizes[0])
         bn = int(c.col_blk_sizes[0])
         bk = int(a.col_blk_sizes[0])
-        nbr, nbc, nbk = a.nblkrows, c.nblkcols, a.nblkcols
         cell = (bm, bn, bk, str(np.dtype(c.dtype)))
-        grid = (nbr, nbc, nbk)
         entries = max(
             int(round(_mm._true_product_flops(a, b) / (2.0 * bm * bn * bk))),
             0)
-        occ = entries / float(max(nbr * nbc * nbk, 1))
-        if "composite" in executors:
-            panels = _mm.composite_panels(a, b, c)
-        predicted = _costmodel.format_costs(
-            nbr=nbr, nbc=nbc, nbk=nbk, bm=bm, bn=bn, bk=bk,
-            entries=entries,
-            panels=(panels.G, panels.mp, panels.kp) if panels else None,
-            dtype=str(np.dtype(c.dtype)),
-            itemsize=np.dtype(c.dtype).itemsize)
-
-    def _feasible(fmt: str) -> bool:
-        if fmt == "stack":
-            return True
-        if fmt not in executors:
-            return False
-        if fmt == "composite":
-            return panels is not None
-        return True  # dense: forced past the cap it runs chunked or whole
+        occ = entries / float(max(a.nblkrows * c.nblkcols * a.nblkcols, 1))
 
     def _plan(fmt, reason, why=None):
-        return Plan(fmt, reason, panels=panels, predicted=predicted,
-                    cell=cell, occ=occ, grid=grid, why=why)
+        return Plan(fmt, reason, cell=cell, occ=occ, why=why)
 
-    # 2. explicit force
-    if cfg.mm_format != "auto":
-        if _feasible(cfg.mm_format):
-            return _plan(cfg.mm_format, "forced")
-        return _plan("stack", "ineligible")
-    # 3. learned per-device crossover (the tune axis)
-    if cell is not None:
-        row = _tuned_row(*cell)
-        if row is not None:
-            fmt = str(row["format"])
-            crossover = float(row.get("format_occ", 0.0))
-            if occ is not None and occ >= crossover and _feasible(fmt):
-                return _plan(fmt, "tuned")
-            return _plan("stack", "tuned")
+    # 3. explicit force; a caller with no canvas executor stops here
+    if cfg.mm_format == "stack":
+        return _plan("stack", "forced")
+    if cfg.mm_format == "dense":
+        return _plan("dense", "forced") if dense \
+            else _plan("stack", "ineligible")
+    if not dense:
+        return _plan("stack", "structural")
     # 4. the dense rules: occupancy, then the emulated-dtype flop ratio
-    why = _dense_rule(a, b, c, cfg, chunked_canvas) \
-        if _feasible("dense") else None
+    why = _dense_rule(a, b, c, cfg, chunked_canvas)
     if why is not None:
         return _plan("dense", "heuristic", why=why)
-    # 5. MXU cost curves (never densify a structurally sparse C)
-    if (uniform and predicted is not None
-            and effective_platform() == "tpu"
-            and _candidate_fill(a, b) >= 0.5):
-        best, best_s = "stack", predicted["stack"]["seconds"]
-        for fmt in ("dense", "composite"):
-            leg = predicted.get(fmt)
-            if leg is not None and _feasible(fmt) \
-                    and leg["seconds"] < best_s:
-                best, best_s = fmt, leg["seconds"]
-        if best != "stack":
-            return _plan(best, "model")
+    # 5. stack
     return _plan("stack", "default" if uniform else "structural")
 
 
@@ -421,61 +319,3 @@ def _note_choice_change(plan: Plan) -> None:
         "cell": key, "format": plan.fmt, "reason": plan.reason,
         "prev": f"{prev[0]}:{prev[1]}",
     })
-
-
-def note_outcome(plan: Plan, seconds: float, flops: float) -> None:
-    """Close the loop on one executed product: measured rate vs the
-    model's prediction for the chosen format.  Feeds the regret ring
-    (timeseries collector + `tune.miner.mine_format`)."""
-    if plan.predicted is None or plan.cell is None or seconds <= 0:
-        return
-    leg = plan.predicted.get(plan.fmt)
-    if not leg or not leg.get("gflops"):
-        return
-    measured = flops / seconds / 1e9
-    predicted = float(leg["gflops"])
-    rec = {
-        "format": plan.fmt,
-        "reason": plan.reason,
-        "cell": plan.cell,
-        "grid": plan.grid,
-        "occ": plan.occ,
-        "predicted_gflops": round(predicted, 4),
-        "measured_gflops": round(measured, 4),
-        "ratio": round(measured / predicted, 6) if predicted else 0.0,
-        "predicted_alternatives": {
-            f: round(v["gflops"], 4)
-            for f, v in plan.predicted.items() if v},
-        "t_unix": time.time(),
-    }
-    with _lock:
-        _regret.append(rec)
-
-
-def regret_records(limit: Optional[int] = None) -> list:
-    """Recent outcome records, oldest first (the miner's substrate)."""
-    with _lock:
-        recs = list(_regret)
-    return recs if limit is None else recs[-limit:]
-
-
-def regret_gauges() -> list:
-    """Latest measured/predicted ratio per format — the timeseries
-    collector's points (`dbcsr_tpu_format_regret`); a ratio far below
-    1.0 means the planner's model overpromised for that format."""
-    latest: dict = {}
-    with _lock:
-        for rec in _regret:
-            latest[rec["format"]] = rec["ratio"]
-    return [({"format": f}, r) for f, r in sorted(latest.items())]
-
-
-def mis_crossovers(floor: float = _REGRET_FLOOR) -> list:
-    """Cells whose chosen format underperformed the model by more than
-    ``floor`` on their latest sighting — the doctor hint's evidence and
-    the format miner's candidate source."""
-    latest: dict = {}
-    with _lock:
-        for rec in _regret:
-            latest[(rec["cell"], rec["format"])] = rec
-    return [r for r in latest.values() if r["ratio"] < floor]

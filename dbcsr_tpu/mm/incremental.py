@@ -132,12 +132,12 @@ def _drop(key) -> None:
 
 
 def note_format_executed(a, b) -> None:
-    """A canvas-path (dense/composite) execution just restructured C
-    for these operands: cached delta entries keyed to them can never be
-    reused again under a stack plan built for the SAME product state
-    (the format planner may flip back on the next generation bump), so
-    drop them eagerly instead of waiting for the epoch check to churn
-    through stale entries."""
+    """A dense canvas-path execution just restructured C for these
+    operands: cached delta entries keyed to them can never be reused
+    again under a stack plan built for the SAME product state (the
+    format planner may flip back after a config change), so drop them
+    eagerly instead of waiting for the epoch check to churn through
+    stale entries."""
     stale = [k for k, ent in _cache.items()
              if ent.a() is a or ent.b() is b]
     for k in stale:

@@ -229,33 +229,27 @@ def _multiply_body(a, b, c, alpha, beta, retain_sparsity, filter_eps,
                    last_k, beta_window, no_limits) -> int:
     """The format-planned engine body of `multiply` (split out so the
     flight recorder brackets every exit path exactly once).  The
-    storage format — stack, dense, or composite — is resolved by
+    storage format — stack or dense — is resolved by
     `mm.format_planner.choose`, the one decider both engines ask."""
     from dbcsr_tpu.mm import format_planner as _fmt
 
     plan = _fmt.choose(a, b, c, filter_eps=filter_eps,
                        retain_sparsity=retain_sparsity,
-                       no_limits=no_limits)
+                       no_limits=no_limits, dense=True)
     _fmt.note_decision(plan)
-    if plan.fmt in ("dense", "composite"):
+    if plan.fmt == "dense":
         with timed("multiply_dense"):
             c._mm_algorithm = plan.fmt
-            # canvas-path failover: the dense/composite MXU routes and
-            # the stack path compute the identical product, so a canvas
-            # failure (injected or real — compile gap, OOM, corrupted
-            # canvas) degrades to the stack engine instead of killing
-            # the multiply.  Only safe while C is still untouched: the
-            # canvas paths restructure C last, and the held-identity
+            # canvas-path failover: the dense MXU route and the stack
+            # path compute the identical product, so a canvas failure
+            # (injected or real — compile gap, OOM, corrupted canvas)
+            # degrades to the stack engine instead of killing the
+            # multiply.  Only safe while C is still untouched: the
+            # canvas path restructures C last, and the held-identity
             # check proves no restructuring happened.
             held = [b_.data for b_ in c.bins]
-            t0 = time.perf_counter()
             try:
-                if plan.fmt == "composite" and plan.panels is not None:
-                    flops = _composite_multiply(a, b, c, alpha, beta,
-                                                plan.panels)
-                else:
-                    flops = _dense_multiply(a, b, c, alpha, beta)
-                _fmt.note_outcome(plan, time.perf_counter() - t0, flops)
+                flops = _dense_multiply(a, b, c, alpha, beta)
                 # a canvas-path restructure makes any delta-cache entry
                 # for these operands unreachable garbage: drop eagerly
                 from dbcsr_tpu.mm import incremental as _inc
@@ -265,7 +259,7 @@ def _multiply_body(a, b, c, alpha, beta, retain_sparsity, filter_eps,
             except Exception as exc:
                 if [id(b_.data) for b_ in c.bins] != [id(d) for d in held]:
                     raise  # C already restructured: unrecoverable here
-                _note_dense_fallback(exc, driver=plan.fmt)
+                _note_dense_fallback(exc)
     c._mm_algorithm = "stack"
 
     with timed("multiply_index"):
@@ -483,20 +477,20 @@ def dense_canvas_feasible(a, b, c, *, chunked: bool) -> bool:
         chunked and _dense_strips(a, b, c) is not None)
 
 
-def _note_dense_fallback(exc: BaseException, driver: str = "dense") -> None:
-    """Record a canvas-path (dense/composite) → stack failover, the
-    mm-layer sibling of `acc.smm`'s stack-driver chain — emitted
-    through the same smm helpers so the counter/trace/flight schema
-    stays single-sourced."""
+def _note_dense_fallback(exc: BaseException) -> None:
+    """Record a dense canvas-path → stack failover, the mm-layer
+    sibling of `acc.smm`'s stack-driver chain — emitted through the
+    same smm helpers so the counter/trace/flight schema stays
+    single-sourced."""
     from dbcsr_tpu.acc import smm as _smm
 
     kind = _smm._classify_failure(exc)
-    _smm._record_driver_failure(driver, kind, exc, ())
-    _smm._record_fallback(driver, "stack", ())
+    _smm._record_driver_failure("dense", kind, exc, ())
+    _smm._record_fallback("dense", "stack", ())
     if kind == "sdc":
         # C was untouched (held-identity check) and the stack engine
         # recomputes the product: the detected canvas SDC is healed
-        _abft.record_recovery(driver)
+        _abft.record_recovery("dense")
     _flight.note("dense_fallback", f"{type(exc).__name__}: {exc}"[:200])
 
 
@@ -944,288 +938,6 @@ def _dense_multiply_chunked(a, b, c, alpha, beta, strips) -> int:
         dtype=str(np.dtype(c.dtype)),
     )
     stats.record_multiply(2 * nbr * bm * nbc * bn * nbk * bk)
-    return _true_product_flops(a, b)
-
-
-# ------------------------------------------------- composite format
-
-class _PanelPack:
-    """Host-side plan for the composite format: a greedy contiguous
-    partition of A's block-rows into ``G`` row-panels, each padded to
-    ``mp`` block-rows and carrying its own COMPACTED k-support of at
-    most ``kp`` block-cols — so one batched panel GEMM multiplies all
-    panels at once against per-panel-duplicated B row-strips.  This is
-    the serve coalescer's batching trick applied inside one product:
-    banded/block-diagonal patterns that would pad a whole-matrix dense
-    canvas mostly with zeros keep near-dense MXU shapes per panel."""
-
-    __slots__ = ("G", "mp", "kp", "row_panel", "row_local", "kmap")
-
-    def __init__(self, G, mp, kp, row_panel, row_local, kmap):
-        self.G = int(G)     # panel count (batch dim)
-        self.mp = int(mp)   # block-rows per panel (padded)
-        self.kp = int(kp)   # k-support block-cols per panel (padded)
-        self.row_panel = row_panel  # (nbr,) block-row -> panel id
-        self.row_local = row_local  # (nbr,) block-row -> row in panel
-        self.kmap = kmap    # (G, nbk) global k -> panel-local k or -1
-
-
-_panel_cache = None  # created lazily; pattern+limits-keyed LRU
-
-
-def composite_panels(a, b, c):
-    """The composite-format plan for this product, or None when the
-    pattern offers no compression over whole-panel dense (then dense or
-    stack win anyway).  Memoized by pattern fingerprints + packing
-    limits: repeated same-pattern multiplies plan once."""
-    import collections
-
-    from dbcsr_tpu.core.config import get_config
-
-    global _panel_cache
-    cfg = get_config()
-    if a.nblks == 0 or b.nblks == 0:
-        return None
-    if not all(_uniformly_blocked(m) for m in (a, b, c)):
-        return None
-    nbr, nbk = a.nblkrows, a.nblkcols
-    if nbr < 2 or float(nbr) * nbk > 5e7:
-        return None
-    key = (a.pattern_fingerprint(), b.pattern_fingerprint(),
-           int(cfg.composite_max_panels), float(cfg.composite_ksup))
-    if _panel_cache is None:
-        _panel_cache = collections.OrderedDict()
-    if key in _panel_cache:
-        _panel_cache.move_to_end(key)
-        return _panel_cache[key]
-    pack = _build_panels(a, b, c, cfg)
-    _panel_cache[key] = pack
-    while len(_panel_cache) > 64:
-        _panel_cache.popitem(last=False)
-    return pack
-
-
-def _greedy_panel_partition(support, limit, max_panels):
-    """One greedy pass: walk block-rows in order, closing a panel when
-    its k-support union would exceed ``limit``; then merge the adjacent
-    pair with the smallest combined support until at most
-    ``max_panels`` remain.  Returns (bounds, sups)."""
-    nbr = support.shape[0]
-    bounds, sups = [], []
-    cur, start = support[0].copy(), 0
-    for r in range(1, nbr):
-        new = cur | support[r]
-        if int(new.sum()) > limit:
-            bounds.append((start, r))
-            sups.append(cur)
-            start, cur = r, support[r].copy()
-        else:
-            cur = new
-    bounds.append((start, nbr))
-    sups.append(cur)
-    while len(bounds) > max_panels:
-        unions = [int((sups[i] | sups[i + 1]).sum())
-                  for i in range(len(sups) - 1)]
-        i = int(np.argmin(unions))
-        bounds[i] = (bounds[i][0], bounds[i + 1][1])
-        sups[i] = sups[i] | sups[i + 1]
-        del bounds[i + 1], sups[i + 1]
-    return bounds, sups
-
-
-def _build_panels(a, b, c, cfg):
-    """Greedy contiguous panelization (see `_PanelPack`): sweep a few
-    candidate k-support close-limits under ``composite_ksup * nbk``
-    (`_greedy_panel_partition` per limit) and keep the partition with
-    the smallest padded volume.  Returns None when batching cannot
-    beat a single canvas (no k compression, padding blowup, B
-    duplication blowup, or a canvas over the cap)."""
-    nbr, nbk, nbc = a.nblkrows, a.nblkcols, b.nblkcols
-    bm = int(c.row_blk_sizes[0])
-    bn = int(c.col_blk_sizes[0])
-    bk = int(a.col_blk_sizes[0])
-    ar, ac = a.entry_coords()
-    support = np.zeros((nbr, nbk), bool)
-    support[ar, ac] = True
-    ksup_limit = max(1, int(cfg.composite_ksup * nbk))
-    # padding is what kills compression (panels pad to the WIDEST
-    # support), so sweep a few candidate close-limits under the knob's
-    # ceiling and keep the partition with the smallest padded volume
-    best = None
-    cap = _DENSE_MAX_CANVAS
-    n_el = nbc * bn
-    for lim in sorted({ksup_limit, max(1, nbk // 2), max(1, nbk // 4),
-                       max(1, nbk // 8)}):
-        if lim > ksup_limit:
-            continue
-        bounds, sups = _greedy_panel_partition(
-            support, lim, cfg.composite_max_panels)
-        G = len(bounds)
-        if G < 2:
-            continue
-        mp = max(r1 - r0 for r0, r1 in bounds)
-        kp = max(int(s.sum()) for s in sups)
-        # feasibility gates apply PER candidate partition: a tighter
-        # close-limit can have the smallest padded volume yet blow the
-        # B-duplication bound (many small panels re-scatter many
-        # overlapping supports) while a coarser partition passes
-        if kp >= nbk:
-            continue  # no k compression: plain dense dominates
-        # row padding + support padding must still shrink the A volume
-        if float(G) * mp * kp >= 0.9 * float(nbr) * nbk:
-            continue
-        # every panel re-scatters its k-support rows of B: bound the
-        # blowup (sum of panel unions = how many B block-rows upload)
-        if sum(int(s.sum()) for s in sups) > 3 * nbk:
-            continue
-        if (G * mp * bm * kp * bk > cap or G * kp * bk * n_el > cap
-                or G * mp * bm * n_el > cap):
-            continue
-        if best is None or G * mp * kp < best[0]:
-            best = (G * mp * kp, bounds, sups, G, mp, kp)
-    if best is None:
-        return None
-    _, bounds, sups, G, mp, kp = best
-    row_panel = np.empty(nbr, np.int64)
-    row_local = np.empty(nbr, np.int64)
-    kmap = np.full((G, nbk), -1, np.int64)
-    for g, (r0, r1) in enumerate(bounds):
-        row_panel[r0:r1] = g
-        row_local[r0:r1] = np.arange(r1 - r0)
-        supp_idx = np.nonzero(sups[g])[0]
-        kmap[g, supp_idx] = np.arange(len(supp_idx))
-    return _PanelPack(G, mp, kp, row_panel, row_local, kmap)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("G", "m_el", "k_el", "n_el", "bm", "bn", "bk"),
-)
-def _composite_dot(a_data, a_ro, a_co, b_data, dup_idx, b_ro, b_co,
-                   *, G, m_el, k_el, n_el, bm, bn, bk):
-    """Scatter the A panels and the per-panel-duplicated B row-strips
-    onto flat canvases, then ONE batched panel GEMM over the G groups.
-    Returns (ad, bd, pd) so the ABFT batched probe can verify the raw
-    product against the very canvases that produced it."""
-    ad = _scatter_bin_to_canvas(
-        jnp.zeros((G * m_el, k_el), a_data.dtype), a_data, a_ro, a_co,
-        bm=bm, bn=bk,
-    ).reshape(G, m_el, k_el)
-    bd = _scatter_bin_to_canvas(
-        jnp.zeros((G * k_el, n_el), b_data.dtype), b_data[dup_idx],
-        b_ro, b_co, bm=bk, bn=bn,
-    ).reshape(G, k_el, n_el)
-    pd = jax.lax.dot_general(
-        ad, bd, (((2,), (1,)), ((0,), (0,))),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=a_data.dtype,
-    )
-    return ad, bd, pd
-
-
-@functools.partial(
-    jax.jit, static_argnames=("G", "mp", "nbc", "bm", "bn"),
-)
-def _composite_to_blocks(pd, map_idx, c_blocks, c_keys, alpha, beta,
-                         *, G, mp, nbc, bm, bn):
-    """Carve the batched product canvas into C's FULL row-major block
-    pattern (panel-major layout carve, then a block-granular take back
-    into row-major key order) and merge beta*old like the dense path."""
-    carved = (pd.reshape(G, mp, bm, nbc, bn)
-              .transpose(0, 1, 3, 2, 4)
-              .reshape(G * mp * nbc, bm, bn))
-    out = alpha * jnp.take(carved, map_idx, axis=0)
-    return out.at[c_keys].add(beta * c_blocks.astype(out.dtype),
-                              mode="drop")
-
-
-def _composite_multiply(a, b, c, alpha, beta, pack: _PanelPack) -> int:
-    """Composite-format execution: one batched panel GEMM over the
-    `_PanelPack` partition, bitwise-identical per block to the dense
-    canvas product (same HIGHEST-precision dot over the same operand
-    values; the panels only remove all-zero padding).  Shares the
-    ``dense`` fault/corruption site with the other canvas paths."""
-    if _faults.active():
-        _faults.maybe_inject("dense")
-    t_start = time.perf_counter()
-    bm = int(c.row_blk_sizes[0])
-    bn = int(c.col_blk_sizes[0])
-    bk = int(a.col_blk_sizes[0])
-    nbr, nbc, nbk = a.nblkrows, c.nblkcols, a.nblkcols
-    G, mp, kp = pack.G, pack.mp, pack.kp
-    _metrics.record_jit(
-        "mm.multiply._composite_dot",
-        (G, mp, kp, nbc, bm, bn, bk, str(np.dtype(c.dtype))),
-    )
-    ar, ac = a.entry_coords()
-    br_, bc_ = b.entry_coords()
-    g_e = pack.row_panel[ar]
-    a_ro = (g_e * mp + pack.row_local[ar]) * bm
-    a_co = pack.kmap[g_e, ac] * bk  # always >= 0: support is the union
-    # B duplication: panel g re-scatters the B rows in its k-support at
-    # panel-local row offsets (the only data the composite format pays
-    # twice; `_build_panels` bounds the blowup)
-    dup_sel, b_ro, b_co = [], [], []
-    for g in range(G):
-        kl = pack.kmap[g, br_]
-        sel = np.nonzero(kl >= 0)[0]
-        dup_sel.append(sel)
-        b_ro.append((g * kp + kl[sel]) * bk)
-        b_co.append(bc_[sel] * bn)
-    dup_sel = np.concatenate(dup_sel)
-    b_ro = np.concatenate(b_ro)
-    b_co = np.concatenate(b_co)
-    a_data = (a.bins[0].data[: a.nblks] if a.nblks
-              else jnp.zeros((0, bm, bk), c.dtype))
-    b_data = (b.bins[0].data[: b.nblks] if b.nblks
-              else jnp.zeros((0, bk, bn), c.dtype))
-    c_blocks = (c.bins[0].data[: c.nblks] if c.nblks
-                else jnp.zeros((0, bm, bn), c.dtype))
-    up = mempool.upload_index
-    ad, bd, pd = _composite_dot(
-        a_data, up("composite_aro", a_ro), up("composite_aco", a_co),
-        b_data, up("composite_dup", dup_sel.astype(np.int64)),
-        up("composite_bro", b_ro), up("composite_bco", b_co),
-        G=G, m_el=mp * bm, k_el=kp * bk, n_el=nbc * bn,
-        bm=bm, bn=bn, bk=bk,
-    )
-    pd = _dense_guard(pd)
-    if _abft.enabled():
-        _abft.check_dense_canvas_batched(pd, ad, bd, dtype=c.dtype)
-    del ad, bd
-    # full-pattern key -> panel-major carved row (every block-row lives
-    # in exactly one panel, so the map is total)
-    keys_full = np.arange(nbr * nbc, dtype=np.int64)
-    rows_full = keys_full // nbc
-    map_idx = ((pack.row_panel[rows_full] * mp
-                + pack.row_local[rows_full]) * nbc + keys_full % nbc)
-    dt_name = str(np.dtype(c.dtype))
-    alpha_dev = _dense_const(("scalar", complex(alpha), dt_name),
-                             lambda: jnp.asarray(alpha, dtype=c.dtype))
-    beta_dev = _dense_const(("scalar", complex(beta), dt_name),
-                            lambda: jnp.asarray(beta, dtype=c.dtype))
-    keys32 = c.keys.astype(np.int32)
-    c_keys_dev = _dense_const(("ckeys", nbr, nbc, keys32.tobytes()),
-                              lambda: jnp.asarray(keys32))
-    out = _composite_to_blocks(
-        pd, up("composite_map", map_idx), c_blocks, c_keys_dev,
-        alpha_dev, beta_dev, G=G, mp=mp, nbc=nbc, bm=bm, bn=bn,
-    )
-    cap = bucket_size(len(keys_full))
-    if cap > len(keys_full):
-        out = jnp.concatenate(
-            [out, jnp.zeros((cap - len(keys_full), bm, bn), out.dtype)])
-    c.set_structure_from_device(
-        keys_full, [_Bin((bm, bn), out, len(keys_full))])
-    itemsize = np.dtype(c.dtype).itemsize
-    nbytes = itemsize * G * (mp * bm * kp * bk + kp * bk * nbc * bn
-                             + 2 * mp * bm * nbc * bn)
-    stats.record_stack(
-        bm, bn, bk, G * mp * nbc * kp, driver="composite",
-        seconds=time.perf_counter() - t_start, nbytes=nbytes,
-        dtype=dt_name,
-    )
-    stats.record_multiply(2 * G * (mp * bm) * (nbc * bn) * (kp * bk))
     return _true_product_flops(a, b)
 
 

@@ -88,77 +88,6 @@ def intensity(flops: float, nbytes: float) -> float:
     return float(flops) / float(nbytes) if nbytes else 0.0
 
 
-# ------------------------------------------- storage-format cost curves
-
-# Modeled efficiency of each execution format relative to its own
-# roofline attainable.  The stack engine's per-entry gathers revisit
-# tile-padded rows and its scatter read-modify-writes C segments, so it
-# lands far below attainable (on a v5e the 23^3 f32 Pallas kernel does
-# 149 GFLOP/s and the f64 grouped path 6.3 — PERF.md, PR 21); one big
-# padded GEMM runs nearer peak.  These constants are the
-# model's PRIOR — the planner's decision is overridden per device by
-# learned `format`/`format_occ` rows in the tune params table, so a
-# wrong prior costs one mis-crossover window, not the fleet's steady
-# state.
-_FORMAT_EFF = {"stack": 0.10, "dense": 0.70, "composite": 0.55}
-# fixed per-launch dispatch overhead charged to every format leg
-_DISPATCH_S = 5e-5
-
-
-def format_costs(*, nbr: int, nbc: int, nbk: int,
-                 bm: int, bn: int, bk: int, entries: int,
-                 nseg: int | None = None, dispatches: int = 1,
-                 panels=None, dtype: str = "float64",
-                 itemsize: int = 8, kind: str | None = None) -> dict:
-    """Occupancy-parameterized cost curves of one product under each
-    storage format: modeled seconds and GFLOP/s for the BCSR stack
-    path, the whole-panel padded dense GEMM, and (when ``panels``
-    describes a feasible packing) the block-diagonal composite panel.
-
-    ``entries`` is the product's TRUE (A-block, B-block) pair count —
-    the stack path's work scales with it (occupancy), the dense panel's
-    work is the full ``(nbr*bm, nbk*bk) x (nbk*bk, nbc*bn)`` canvas
-    regardless.  ``panels`` is the ``(groups, panel_rows, panel_kblocks)``
-    summary of `mm.multiply.composite_panels`; None marks composite
-    structurally ineligible.  Each leg models ``t = max(flops/peak,
-    bytes/bw) / efficiency + dispatch`` against the live `peaks_for`
-    roofline (env peak overrides apply, so tests pin the crossover
-    deterministically).  Stdlib-only, like everything in this module.
-    """
-    kind = kind or device_kind()
-    peak = peak_gflops(kind, dtype) * 1e9
-    bw = peaks_for(kind)["gbs"] * 1e9
-
-    def _leg(fmt: str, flops: float, nbytes: float, n_disp: int) -> dict:
-        eff = _FORMAT_EFF[fmt]
-        t_min = max(flops / peak if peak else 0.0,
-                    nbytes / bw if bw else 0.0)
-        secs = t_min / eff + n_disp * _DISPATCH_S
-        return {"flops": int(flops), "bytes": int(nbytes),
-                "seconds": secs,
-                "gflops": flops / secs / 1e9 if secs > 0 else 0.0}
-
-    entries = max(int(entries), 1)
-    true_flops = 2.0 * bm * bn * bk * entries
-    dense = dense_cost(nbr * bm, nbc * bn, nbk * bk, itemsize=itemsize)
-    out = {
-        "stack": _leg("stack", true_flops,
-                      stack_bytes(bm, bn, bk, entries,
-                                  nseg=nseg, itemsize=itemsize),
-                      max(int(dispatches), 1)),
-        "dense": _leg("dense", dense["flops"], dense["bytes"], 1),
-        "composite": None,
-    }
-    if panels is not None:
-        groups, mp, kp = (int(panels[0]), int(panels[1]), int(panels[2]))
-        n_el = nbc * bn
-        c_flops = 2.0 * groups * (mp * bm) * n_el * (kp * bk)
-        c_bytes = itemsize * groups * (
-            mp * bm * kp * bk + kp * bk * n_el + 2 * mp * bm * n_el)
-        out["composite"] = _leg("composite", c_flops, c_bytes, 1)
-    return out
-
-
 # machine epsilon of the ACCUMULATION dtype each engine dtype uses
 # (bf16 accumulates in f32, acc/smm._accum_dtype) — stdlib-only so the
 # tolerance stays computable without jax/numpy imported

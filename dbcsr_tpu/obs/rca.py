@@ -1,8 +1,8 @@
 """Automated root-cause attribution: the change ledger + causal ranker.
 
 With PRs 15–19 the engine *changes itself* continuously — autotuner
-promotions rewrite the params table, the format planner learns
-crossovers, precision schedules demote cells, breakers quarantine
+promotions rewrite the params table, the format planner changes a
+route, precision schedules demote cells, breakers quarantine
 drivers, the serve fleet fails workers over and rolls them.  When a
 change-point fires (`obs/changepoint.py`: "this series stepped to a
 worse level at time T"), the question a human used to answer by

@@ -506,13 +506,8 @@ def _collect_tune() -> list:
 
 
 def _collect_format() -> list:
-    """Storage-format planner plane (mm.format_planner): the
-    decision counter by (format, reason), the fleet-sync counter, and
-    per-format planner REGRET (latest measured/predicted GFLOP/s
-    ratio) — the series `tune.miner.mine_format` and `doctor --trend`
-    line mis-crossovers up against."""
-    import sys
-
+    """Storage-format planner plane (mm.format_planner): the decision
+    counter by (format, reason) and the fleet-sync counter."""
     pts: list = []
     from dbcsr_tpu.obs import metrics
 
@@ -520,15 +515,6 @@ def _collect_format() -> list:
                  "dbcsr_tpu_tune_fleet_total"):
         for labels, v in metrics.counter_items(name):
             pts.append((name, labels, v, COUNTER))
-    fp = sys.modules.get("dbcsr_tpu.mm.format_planner")
-    if fp is not None:  # an un-imported planner has no regrets
-        try:
-            # regret_gauges() yields (labels_dict, ratio) rows
-            for labels, ratio in fp.regret_gauges():
-                pts.append(("dbcsr_tpu_format_regret", dict(labels),
-                            ratio, GAUGE))
-        except Exception:
-            pass
     return pts
 
 

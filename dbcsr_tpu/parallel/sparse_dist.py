@@ -1605,7 +1605,7 @@ def _sparse_multiply_impl(alpha, matrix_a, matrix_b, beta, matrix_c, mesh, name,
             dtype),
         filter_eps=filter_eps, retain_sparsity=retain_sparsity,
         no_limits=all(x is None for x in limits),
-        executors=("dense",) if cannon else (), chunked_canvas=False,
+        dense=cannon, chunked_canvas=False,
     )
     # (not `_fmt.note_decision`-ed yet: the benchmark's harness test
     # pins that a mesh window moves no decision counter, and only a

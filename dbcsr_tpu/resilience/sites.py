@@ -41,9 +41,8 @@ SITES = {
         "corruptible": False, "chaos": True, "dynamic": False,
     },
     "dense": {
-        "boundary": "the canvas paths in `mm.multiply` (whole-panel "
-                    "dense AND the batched composite panels share this "
-                    "site: one failover, one corruption hook)",
+        "boundary": "the whole-panel dense canvas path in "
+                    "`mm.multiply` (one failover, one corruption hook)",
         "corruptible": True, "chaos": True, "dynamic": False,
     },
     "format_plan": {

@@ -203,60 +203,6 @@ def _mine_captures(paths) -> List[Dict]:
     return out
 
 
-def mine_format(limit: Optional[int] = None) -> List[Dict]:
-    """Format-axis candidates: block cells whose PLANNED storage
-    format underperformed the planner's own cost model
-    (`mm.format_planner.mis_crossovers` — measured/predicted below the
-    regret floor on the latest sighting).  Same ranking currency as
-    kernel cells (wasted FLOP-seconds); the schema adds ``format``/
-    ``occ``/``grid`` fields `tune.trials.run_format_trial` consumes.
-    Never instantiates the planner: an un-imported planner has no
-    regrets to mine."""
-    import sys
-
-    fp = sys.modules.get("dbcsr_tpu.mm.format_planner")
-    if fp is None:
-        return []
-    out: List[Dict] = []
-    stack_size = _production_stack_size()
-    for rec in fp.mis_crossovers():
-        cell = rec.get("cell")
-        if not cell:
-            continue
-        bm, bn, bk, dtype = cell
-        observed = float(rec.get("measured_gflops") or 0.0)
-        target = float(rec.get("predicted_gflops") or 0.0)
-        if observed <= 0 or target <= observed:
-            continue
-        grid = tuple(rec.get("grid") or (1, 1, 1))
-        occ = float(rec.get("occ") or 0.0)
-        flops = 2.0 * bm * bn * bk * occ * grid[0] * grid[1] * grid[2]
-        out.append({
-            "m": int(bm), "n": int(bn), "k": int(bk),
-            "dtype": str(dtype), "driver": "format",
-            "stack_size": stack_size,
-            "format": rec.get("format"), "occ": occ,
-            "grid": [int(g) for g in grid],
-            "observed_gflops": round(observed, 4),
-            "target_gflops": round(target, 4),
-            "wasted_flop_seconds": _wasted(flops, observed, target),
-            "flops": flops,
-            "source": "format_planner",
-            "reason": (f"format {rec.get('format')} measured/predicted "
-                       f"{rec.get('ratio')}"),
-        })
-    best: Dict[tuple, Dict] = {}
-    for c in out:
-        key = (c["m"], c["n"], c["k"], c["dtype"])
-        cur = best.get(key)
-        if cur is None or c["wasted_flop_seconds"] > \
-                cur["wasted_flop_seconds"]:
-            best[key] = c
-    ranked = sorted(best.values(),
-                    key=lambda c: -c["wasted_flop_seconds"])
-    return ranked[:max_cells() if limit is None else limit]
-
-
 def mine(limit: Optional[int] = None, query=None,
          capture_paths=()) -> List[Dict]:
     """The ranked candidate-cell queue, most wasted FLOP-seconds first.

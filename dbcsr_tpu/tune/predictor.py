@@ -125,47 +125,6 @@ def transfer_predict(m: int, n: int, k: int, dtype,
     return out
 
 
-def format_prior(bm: int, bn: int, bk: int, dtype,
-                 kind: Optional[str] = None) -> Optional[Dict]:
-    """Nearest SAME-device-kind tuned row carrying learned storage-
-    format columns — the format planner's donor fallback when the
-    exact block cell was never format-trialed.  Same-dtype rows only,
-    within the `_MAX_FLOP_RATIO` shape window; returns a copy tagged
-    ``format_from`` (the donor's (m, n, k)) or None.  Cross-device
-    format transfer is deliberately NOT offered: a crossover is a
-    property of one chip's dense/stack balance, not of the shape."""
-    import numpy as np
-
-    from dbcsr_tpu.acc import params as params_mod
-
-    kind = kind or params_mod.device_kind()
-    want_dtype = np.dtype(dtype).name
-    target = math.log(max(float(bm) * bn * bk, 1.0))
-    max_d = math.log(_MAX_FLOP_RATIO)
-    best, best_d = None, None
-    try:
-        rows = params_mod._load(kind).values()
-    except Exception:
-        return None
-    for e in rows:
-        try:
-            if e.get("dtype") != want_dtype or not e.get("format"):
-                continue
-            d = abs(math.log(max(float(e["m"]) * e["n"] * e["k"], 1.0))
-                    - target)
-        except (KeyError, TypeError, ValueError):
-            continue
-        if d > max_d:
-            continue
-        if best_d is None or d < best_d:
-            best, best_d = e, d
-    if best is None:
-        return None
-    out = dict(best)
-    out["format_from"] = [int(best["m"]), int(best["n"]), int(best["k"])]
-    return out
-
-
 # ------------------------------------------------------------- learned
 
 def _features(m: int, n: int, k: int, dtype, stack_size: int) -> list:

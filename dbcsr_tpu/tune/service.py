@@ -22,14 +22,11 @@ One `cycle()` is the whole closed loop, synchronous and deterministic
    ``DBCSR_TPU_TUNE_MARGIN`` (default 5%).  The promotion bumps the
    params generation, retiring every stale plan.
 
-Two side channels ride each cycle: `store.peer_sync` adopts
+A side channel rides each cycle: `store.peer_sync` adopts
 same-device-kind peers' promotions over the fleet tier
 (``DBCSR_TPU_FLEET_PEERS``) so one worker's trial pays for the whole
-fleet, and an IDLE cycle (empty kernel queue) spends itself on the
-FORMAT axis instead — `miner.mine_format` ranks the storage-format
-planner's mis-crossovers, `trials.run_format_trial` A/Bs the formats
-off the hot path, and the winning format columns merge into the
-incumbent params row (`docs/performance.md` § storage formats).
+fleet.  An IDLE cycle (empty kernel queue) just returns: the storage
+format is the planner's rule (`mm.format_planner`), not a tuned row.
 
 Lifecycle: `maybe_start_from_env()` starts the background thread when
 ``DBCSR_TPU_TUNE=1`` (the serve engine calls it at start and
@@ -170,10 +167,7 @@ class TuneService:
             cells = miner.mine()
         self._note(queue_depth=len(cells))
         if not cells:
-            # no kernel cell wastes FLOP-seconds: spend the idle cycle
-            # on the FORMAT axis (planner regrets mined off the live
-            # mis-crossover ring; same trial guards, merge-promotion)
-            return self._format_cycle(out)
+            return out  # no kernel cell wastes FLOP-seconds: idle
         cell = cells[0]
         out["cell"] = {k: cell.get(k)
                        for k in ("m", "n", "k", "dtype", "stack_size",
@@ -273,91 +267,6 @@ class TuneService:
                               "wasted_flop_seconds", "reason",
                               "source")}},
             stack_size=int(cell.get("stack_size", trial.stack_size)),
-            kind=self.kind)
-
-    def _format_cycle(self, out: Dict) -> Dict:
-        """Idle-cycle format-axis pass: trial the worst planner
-        mis-crossover and merge the winning format columns into the
-        incumbent params row.  A non-OK trial promotes nothing."""
-        cells = miner.mine_format()
-        if not cells:
-            return out
-        cell = cells[0]
-        out["cell"] = {k: cell.get(k)
-                       for k in ("m", "n", "k", "dtype", "format", "occ",
-                                 "wasted_flop_seconds", "reason")}
-        with self._state_lock:
-            self.stats["trials"] += 1
-        trial = trials.run_format_trial(cell, seed=self.seed)
-        if not trial.ok:
-            with self._state_lock:
-                self.stats["trial_failure_streak"] += 1
-            out["outcome"] = f"trial_{trial.outcome}"
-            out["error"] = trial.error
-            return out
-        self._note(trial_failure_streak=0)
-        promoted = self._maybe_promote_format(cell, trial)
-        if promoted is not None:
-            with self._state_lock:
-                self.stats["promotions"] += 1
-            out["promoted"] = {
-                "format": promoted["entry"].get("format"),
-                "format_occ": promoted["entry"].get("format_occ"),
-                "generation": promoted["generation"],
-            }
-            out["outcome"] = "promoted"
-        elif out["outcome"] != "demoted":
-            out["outcome"] = "held"
-        return out
-
-    def _maybe_promote_format(self, cell: Dict, trial):
-        """Merge the trial's format columns into the incumbent kernel
-        row (or start a fresh row when none exists) — the kernel
-        engine's driver/grouping fields are never displaced.  The bar:
-        the winning format must beat the planner's measured rate for
-        the cell by the promotion margin, and re-pinning the format
-        the planner already chose is churn, not progress."""
-        import numpy as np
-
-        entry = trial.entry
-        if not entry or not entry.get("format"):
-            return None
-        if entry["format"] == cell.get("format"):
-            return None  # the trial agreed with the regretted plan
-        bar = cell.get("observed_gflops")
-        if isinstance(bar, (int, float)) and bar > 0 and \
-                entry.get("format_gflops", 0.0) <= bar * (1.0 + self.margin):
-            return None
-        from dbcsr_tpu.acc import params as params_mod
-
-        m, n, k = int(cell["m"]), int(cell["n"]), int(cell["k"])
-        dtype = np.dtype(cell.get("dtype", "float64")).name
-        incumbent = params_mod.lookup(
-            m, n, k, dtype, stack_size=cell.get("stack_size")) or {}
-        row = dict(incumbent)
-        row.update({
-            "m": m, "n": n, "k": k, "dtype": dtype,
-            "stack_size": int(cell.get("stack_size")
-                              or incumbent.get("stack_size") or 0),
-            "env": incumbent.get("env", "cpu"),
-            "format": entry["format"],
-            "format_occ": entry["format_occ"],
-            "format_gflops": entry["format_gflops"],
-        })
-        if entry.get("format_driver"):
-            row["format_driver"] = entry["format_driver"]
-        else:
-            row.pop("format_driver", None)
-        return store.promote(
-            row,
-            trial={"axis": "format",
-                   "elapsed_s": round(trial.elapsed_s, 3),
-                   "candidates": trial.candidates,
-                   "mined": {kk: cell.get(kk) for kk in
-                             ("format", "occ", "grid", "observed_gflops",
-                              "target_gflops", "wasted_flop_seconds",
-                              "reason", "source")}},
-            stack_size=int(cell.get("stack_size", 0)),
             kind=self.kind)
 
     # ------------------------------------------------------- background

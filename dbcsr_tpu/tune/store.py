@@ -365,8 +365,7 @@ def peer_sync(kind: Optional[str] = None, peers=None) -> List[list]:
             incumbent = _lookup_exact(m, n, k, dtype, s, kind)
             if incumbent and incumbent.get("tuned_by") and \
                     float(incumbent.get("gflops") or 0.0) >= \
-                    float(entry.get("gflops") or 0.0) and \
-                    incumbent.get("format") == entry.get("format"):
+                    float(entry.get("gflops") or 0.0):
                 continue  # local evidence already as good: no churn
             promote(dict(entry, adopted_from=peer),
                     trial={"adopted_from": peer,
@@ -404,10 +403,7 @@ def check_regressions(kind: Optional[str] = None,
     for rec in live_promotions(kind):
         frac0 = rec.get("roofline_at_promotion")
         entry = rec.get("entry") or {}
-        # a format-axis promotion executes under the canvas driver it
-        # promoted (dense/composite), not the row's kernel driver — the
-        # judge must watch the roofline cell that row actually produces
-        driver = entry.get("format_driver") or entry.get("driver")
+        driver = entry.get("driver")
         if not frac0 or not driver:
             continue
         try:

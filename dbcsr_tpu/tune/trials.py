@@ -221,150 +221,6 @@ def run_trial(cell: Dict, seed: int = 7, out=None,
                        budget_hit=budget_hit[0])
 
 
-def run_format_trial(cell: Dict, seed: int = 7,
-                     deadline_s: Optional[float] = None,
-                     reps: Optional[int] = None) -> TrialResult:
-    """A/B the storage formats for one mined format cell, OFF the hot
-    path: build a synthetic product at the cell's (block shape, grid,
-    occupancy), execute it once per forced format
-    (``set_config(mm_format=…)`` — the same seam the planner's forced
-    step reads), and return the fastest format as the trial entry.
-
-    The entry carries FORMAT COLUMNS ONLY (``format``/``format_occ``/
-    ``format_driver``/``format_gflops``): the service merges them into
-    the incumbent kernel params row, never displacing the stack
-    engine's driver fields.  Shares `run_trial`'s guard envelope: the
-    ``tune_trial`` watchdog channel and fault site, the wall budget
-    between format legs, the pool chain scope."""
-    import numpy as np
-
-    from dbcsr_tpu.resilience import faults
-    from dbcsr_tpu.resilience.watchdog import Watchdog
-
-    m, n, k = int(cell["m"]), int(cell["n"]), int(cell["k"])
-    dtype = cell.get("dtype", "float64")
-    mnk = f"{m}x{n}x{k}"
-    wall_budget = budget_s() if deadline_s is None else deadline_s
-    # trial grids stay small: the crossover is a property of (occupancy,
-    # block shape), not of the full production grid size
-    grid = [max(2, min(int(g), 16)) for g in (cell.get("grid")
-                                              or (8, 8, 8))]
-    occ = min(max(float(cell.get("occ") or 0.9), 0.05), 1.0)
-    rep_n = nrep() if reps is None else reps
-    candidates: List[Dict] = []
-    entry_box: list = [None]
-    fault_abort = [False]
-    budget_hit = [False]
-
-    def _sweep(_deadline: float):
-        if faults.active():
-            try:
-                faults.maybe_inject("tune_trial", mnk=mnk,
-                                    dtype=str(dtype))
-            except BaseException:
-                fault_abort[0] = True
-                raise
-        from dbcsr_tpu import create, make_random_matrix, multiply
-        from dbcsr_tpu.core.config import get_config, set_config
-        from dbcsr_tpu.mm import format_planner as fp
-
-        nbr, nbc, nbk = grid
-        rng = np.random.default_rng(seed)
-        # the cell's occ is the PLANNER's unit: product-triple density
-        # entries/(nbr*nbc*nbk).  Two random patterns at fill f meet in
-        # ~f^2 of the triples, so build the synthetic pair at sqrt(occ)
-        # to reproduce the mined product's density.
-        fill = min(1.0, max(occ, 1e-4) ** 0.5)
-        a = make_random_matrix("tune_fmt_a", [m] * nbr, [k] * nbk,
-                               dtype=dtype, occupation=fill, rng=rng)
-        b = make_random_matrix("tune_fmt_b", [k] * nbk, [n] * nbc,
-                               dtype=dtype, occupation=fill, rng=rng)
-        deadline = time.monotonic() + wall_budget
-        cfg0 = get_config()
-        prev_fmt, prev_inc = cfg0.mm_format, cfg0.incremental
-        try:
-            from dbcsr_tpu.core import mempool
-
-            chain = mempool.chain
-        except ImportError:
-            import contextlib
-
-            chain = contextlib.nullcontext
-        try:
-            # the delta-aware incremental plane would splice repeated
-            # identical products and time the SPLICE, not the format
-            set_config(incremental="full")
-            with chain():
-                for fmt in ("stack", "dense", "composite"):
-                    set_config(mm_format=fmt)
-                    fp.reset()  # forced plans must not reuse cached autos
-                    best = None
-                    executed = "stack"
-                    for _ in range(max(rep_n, 1)):
-                        c = create("tune_fmt_c", [m] * nbr, [n] * nbc,
-                                   dtype=dtype)
-                        t0 = time.perf_counter()
-                        multiply("N", "N", 1.0, a, b, 0.0, c)
-                        dt = time.perf_counter() - t0
-                        executed = getattr(c, "_mm_algorithm", "stack")
-                        best = dt if best is None or dt < best else best
-                    if executed == fmt and best and best > 0:
-                        flops = 2.0 * (nbr * m) * (nbc * n) * (nbk * k)
-                        candidates.append({
-                            "format": fmt,
-                            "seconds": round(best, 6),
-                            "gflops": round(flops / best / 1e9, 4),
-                        })
-                    # an infeasible force fell back: not a candidate
-                    if time.monotonic() > deadline:
-                        budget_hit[0] = True
-                        break
-        finally:
-            set_config(mm_format=prev_fmt, incremental=prev_inc)
-            fp.reset()
-        if candidates:
-            win = max(candidates, key=lambda c_: c_["gflops"])
-            entry = {
-                "m": m, "n": n, "k": k, "dtype": str(dtype),
-                "format": win["format"],
-                # the crossover: at or above the occupancy the win was
-                # measured at, use the winning format (a stack win pins
-                # stack everywhere — occ 0.0 always applies)
-                "format_occ": (0.0 if win["format"] == "stack"
-                               else round(occ, 4)),
-                "format_gflops": win["gflops"],
-            }
-            if win["format"] in ("dense", "composite"):
-                entry["format_driver"] = win["format"]
-            entry_box[0] = entry
-        return entry_box[0]
-
-    wd = Watchdog("tune_trial", deadline_s=wall_budget)
-    res = wd.guard(_sweep)
-    if res.outcome == "WEDGED":
-        outcome = WEDGED
-    elif res.error is not None:
-        outcome = FAULTED if fault_abort[0] else FAILED
-    else:
-        outcome = OK
-    _count_trial(outcome)
-    try:
-        from dbcsr_tpu.obs import events as _events
-
-        _events.publish("tune_trial", {
-            "mnk": mnk, "dtype": str(dtype), "outcome": outcome,
-            "axis": "format", "candidates": len(candidates),
-            "budget_hit": budget_hit[0],
-            "elapsed_s": round(res.elapsed_s, 3), "error": res.error,
-        })
-    except Exception:
-        pass
-    return TrialResult(outcome, cell, entry_box[0], list(candidates),
-                       res.elapsed_s, res.error,
-                       int(cell.get("stack_size", 0)),
-                       budget_hit=budget_hit[0])
-
-
 def _breaker_open(driver: str, m: int, n: int, k: int, dtype) -> bool:
     """Whether the live breaker board holds an OPEN breaker for this
     (driver, shape).  Never CREATES a board; shape matching is by the

@@ -1,14 +1,12 @@
-"""The adaptive storage-format planner (`mm.format_planner`) and its
-learning loop.
+"""The storage-format planner (`mm.format_planner`).
 
-Pinned here: the occupancy ladder resolves to the expected format
-through each funnel step (forced, learned crossover, heuristic,
-default); every format computes the BITWISE-identical product for
-integer-valued operands; a tuning promotion's generation bump retires
-cached plans and a demotion restores the stack default; chaos
-block-flips under each format are detected and healed bitwise; ABFT
-runs live on the composite panel path; canvas-exceeding wide-N
-products still go dense via n-chunking; and format promotions travel
+Pinned here: the planner routes each cell's product family to the
+format the benchmark shows (structural gate, dense rules, stack
+default, the mesh's canvas check); every format computes the
+BITWISE-identical product for integer-valued operands; an injected
+plan fault degrades once and is never cached; chaos block-flips under
+each format are detected and healed bitwise; canvas-exceeding wide-N
+products still go dense via n-chunking; and tuned kernel rows travel
 the fleet tier (same device kind only).  All tier-1, CPU-only.
 """
 
@@ -28,7 +26,7 @@ from dbcsr_tpu.mm import multiply as mm_mod
 from dbcsr_tpu.obs import health, metrics
 from dbcsr_tpu.ops.test_methods import to_dense
 from dbcsr_tpu.resilience import breaker, faults
-from dbcsr_tpu.tune import store, trials
+from dbcsr_tpu.tune import store
 from dbcsr_tpu.tune import service as tune_service
 
 
@@ -39,9 +37,8 @@ def _clean_slate(tmp_path, monkeypatch):
     monkeypatch.setenv("DBCSR_TPU_PARAMS_DIR", str(tmp_path))
     params_mod.invalidate()
     cfg0 = {f: getattr(get_config(), f)
-            for f in ("abft", "mm_driver", "mm_format",
-                      "composite_max_panels", "composite_ksup",
-                      "incremental")}
+            for f in ("abft", "mm_driver", "mm_format", "incremental",
+                      "platform_override")}
     faults.clear()
     breaker.reset_board()
     metrics.reset()
@@ -100,15 +97,7 @@ def _dense_of(c):
 
 def _choose(a, b, c):
     return fp.choose(a, b, c, filter_eps=None, retain_sparsity=False,
-                     no_limits=True)
-
-
-def _dense_rules_off(monkeypatch):
-    """No product passes either dense rule (the planner's constants are
-    patched, as `_DENSE_MAX_CANVAS` is; neither is in the plan key)."""
-    monkeypatch.setattr(fp, "DENSE_OCC_THRESHOLD", 2.0)
-    monkeypatch.setattr(fp, "DENSE_FLOP_RATIO", 0)
-    fp.reset()
+                     no_limits=True, dense=True)
 
 
 def _ctr(name, **labels):
@@ -122,13 +111,13 @@ def _ctr(name, **labels):
 # ------------------------------------------------- the format ladder
 
 def test_every_format_bitwise_identical():
-    """Forced stack/dense/composite all compute the same C, bit for
-    bit, and report what they executed — format choice is performance
-    only, never numerics."""
+    """Forced stack/dense compute the same C, bit for bit, and report
+    what they executed — format choice is performance only, never
+    numerics."""
     a, b, bs = _pair(nblk=8, bsize=4, band=1, seed=3)
     ref = None
     executed = {}
-    for fmt in ("stack", "dense", "composite"):
+    for fmt in ("stack", "dense"):
         c = _run(fmt, a, b, bs)
         executed[fmt] = c._mm_algorithm
         d = _dense_of(c)
@@ -137,13 +126,10 @@ def test_every_format_bitwise_identical():
         assert (d == ref).all(), f"{fmt} diverged bitwise"
     assert executed["stack"] == "stack"
     assert executed["dense"] == "dense"
-    # banded pattern: the composite pack is feasible and actually runs
-    assert executed["composite"] == "composite"
 
 
 def test_occupancy_ladder_heuristic_and_default():
-    """No learned rows: a near-full product goes dense through the
-    occupancy rule, a sparse one stays on the stack path,
+    """A near-full product goes dense through the occupancy rule, a sparse one stays on the stack path,
     and both land on the decision counter."""
     set_config(mm_format="auto")
     full_a, full_b, bs = _pair(nblk=6, bsize=4, fill=1.0, seed=1)
@@ -162,72 +148,79 @@ def test_occupancy_ladder_heuristic_and_default():
                 format="dense", reason="heuristic") >= 1
 
 
-def test_occupancy_ladder_learned_crossover(monkeypatch):
-    """A promoted format row steers the planner by triple-occupancy:
-    above the learned crossover the row's format wins, below it the
-    stack default holds (reason='tuned' both ways)."""
-    params_mod.save_entry({"m": 4, "n": 4, "k": 4, "dtype": "float64",
-                           "stack_size": 0, "format": "dense",
-                           "format_occ": 0.5, "format_gflops": 9.9,
-                           "tuned_by": "test"})
-    set_config(mm_format="auto")
-    _dense_rules_off(monkeypatch)  # isolate the row
-    lo_a, lo_b, bs = _pair(nblk=6, bsize=4, fill=0.4, seed=4)
-    plan = _choose(lo_a, lo_b, dt.create("fC", bs, bs))
-    assert (plan.fmt, plan.reason) == ("stack", "tuned")
-    assert plan.occ < 0.5
-
-    hi_a, hi_b, bs = _pair(nblk=6, bsize=4, fill=1.0, seed=5)
-    plan = _choose(hi_a, hi_b, dt.create("fC", bs, bs))
-    assert (plan.fmt, plan.reason) == ("dense", "tuned")
-    assert plan.occ >= 0.5
+# the north star's block grid (10 000 / 23 blocks a side): the planner
+# decides from block counts and occupancy, so 4-blocks on this grid
+# take the cell's own route at a CPU-sized canvas
+_NS_NBLK = 10000 // 23
 
 
-def test_forced_infeasible_falls_back_to_stack():
-    """composite forced on a pattern with no panel compression runs
-    stack under reason='ineligible' — never an error."""
-    a, b, bs = _pair(nblk=4, bsize=4, fill=1.0, seed=6)
-    assert mm_mod.composite_panels(a, b, dt.create("fC", bs, bs)) is None
-    set_config(mm_format="composite")
-    plan = _choose(a, b, dt.create("fC", bs, bs))
-    assert (plan.fmt, plan.reason) == ("stack", "ineligible")
+def _random_pair(nblk, occ, dtype, sizes=(4,), seed=0):
+    rng = np.random.default_rng(seed)
+    bs = [sizes[i % len(sizes)] for i in range(nblk)]
+    a = dt.make_random_matrix("rA", bs, bs, dtype=dtype, occupation=occ,
+                              rng=rng)
+    b = dt.make_random_matrix("rB", bs, bs, dtype=dtype, occupation=occ,
+                              rng=rng)
+    return a, b, dt.create("rC", bs, bs, dtype=dtype)
 
 
-# --------------------------------------- plan cache vs the generation
+@pytest.mark.parametrize("case,want", [
+    # *.scf_f64 / *.sign_f64: the filter holds the product on the stack
+    ("filtered_f64", ("stack", "structural")),
+    # northstar.plain_f64: dense flops 100x the true flops
+    ("northstar_f64", ("dense", "heuristic")),
+    # northstar.plain_f32: a native dtype below the occupancy gate
+    ("northstar_f32", ("stack", "default")),
+    # mixed10k.plain_f32: blocks {5,13,23}
+    ("mixed_f32", ("stack", "structural")),
+    # both operands over DENSE_OCC_THRESHOLD
+    ("full_f32", ("dense", "heuristic")),
+    # the north star's f64 product on a 1x4 grid: no dense Cannon
+    ("mesh_1x4", ("stack", "structural")),
+])
+def test_planner_routes_like_the_cells(case, want):
+    """Each cell's product family takes the route the benchmark's
+    ``dense_route_share`` shows, on a TPU as the planner sees it."""
+    set_config(platform_override="tpu", mm_format="auto")
+    fp.reset()
+    eps = None
+    dense = True
+    if case == "filtered_f64":
+        a, b, c = _random_pair(_NS_NBLK, 0.1, np.float64)
+        eps = 1e-7
+    elif case in ("northstar_f64", "mesh_1x4"):
+        a, b, c = _random_pair(_NS_NBLK, 0.1, np.float64)
+    elif case == "northstar_f32":
+        a, b, c = _random_pair(_NS_NBLK, 0.1, np.float32)
+    elif case == "mixed_f32":
+        a, b, c = _random_pair(60, 0.05, np.float32, sizes=(5, 13, 23))
+    else:
+        a, b, c = _random_pair(8, 1.0, np.float32)
+    if case == "mesh_1x4":
+        import jax
+        from jax.sharding import Mesh
 
-def test_promotion_generation_bump_retires_cached_plans(monkeypatch):
-    a, b, bs = _pair(nblk=6, bsize=4, fill=1.0, seed=7)
-    set_config(mm_format="auto")
-    _dense_rules_off(monkeypatch)
-    c = dt.create("fC", bs, bs)
-    p1 = _choose(a, b, c)
-    assert (p1.fmt, p1.reason) == ("stack", "default")
-    assert _choose(a, b, c) is p1  # cached: same plan object
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 1, 4),
+                    ("kl", "pr", "pc"))
+        # what `sparse_multiply_distributed` can execute on this grid
+        dense = mesh.shape["pr"] == mesh.shape["pc"]
 
-    store.promote({"m": 4, "n": 4, "k": 4, "dtype": "float64",
-                   "stack_size": 0, "format": "dense",
-                   "format_occ": 0.2, "format_gflops": 9.9,
-                   "driver": "dense", "gflops": 9.9})
-    p2 = _choose(a, b, c)
-    assert p2 is not p1  # the generation bump retired the cached plan
-    assert (p2.fmt, p2.reason) == ("dense", "tuned")
+    def plan():
+        return fp.choose(a, b, c, filter_eps=eps, retain_sparsity=False,
+                         no_limits=True, dense=dense,
+                         chunked_canvas=case != "mesh_1x4")
 
-
-def test_demotion_on_regression_restores_stack(monkeypatch):
-    a, b, bs = _pair(nblk=6, bsize=4, fill=1.0, seed=8)
-    set_config(mm_format="auto")
-    _dense_rules_off(monkeypatch)
-    c = dt.create("fC", bs, bs)
-    store.promote({"m": 4, "n": 4, "k": 4, "dtype": "float64",
-                   "stack_size": 0, "format": "dense",
-                   "format_occ": 0.2, "format_gflops": 9.9,
-                   "driver": "dense", "gflops": 9.9})
-    assert _choose(a, b, c).fmt == "dense"
-    assert store.demote(4, 4, 4, "float64", 0, reason="regression")
-    plan = _choose(a, b, c)
-    assert (plan.fmt, plan.reason) == ("stack", "default")
-    assert _ctr("dbcsr_tpu_tune_demotions_total", reason="regression") \
-        >= 1
+    got = plan()
+    assert (got.fmt, got.reason) == want
+    if case == "northstar_f64":
+        assert got.why == "cost-model:emulated-dtype"
+    elif case == "full_f32":
+        assert got.why == f"occupancy>={fp.DENSE_OCC_THRESHOLD}"
+    elif case == "mesh_1x4":
+        # a dense force the grid cannot run falls back, named as such
+        set_config(mm_format="dense")
+        forced = plan()
+        assert (forced.fmt, forced.reason) == ("stack", "ineligible")
 
 
 # --------------------------------------------------- faults and ABFT
@@ -249,7 +242,6 @@ def test_format_plan_fault_degrades_to_stack_once():
 @pytest.mark.parametrize("fmt,site", [
     ("stack", "execute_stack"),
     ("dense", "dense"),
-    ("composite", "dense"),  # canvas paths share the dense site
 ])
 def test_chaos_flip_under_each_format_heals_bitwise(fmt, site):
     """A seed-deterministic finite block-flip injected under each
@@ -269,18 +261,6 @@ def test_chaos_flip_under_each_format_heals_bitwise(fmt, site):
     assert (_dense_of(c) == clean).all()
     assert _ctr("dbcsr_tpu_abft_mismatches_total") >= 1
     assert _ctr("dbcsr_tpu_abft_recoveries_total") >= 1
-
-
-def test_abft_live_on_composite_clean_run():
-    """ABFT probes the batched composite panels on a healthy run:
-    no mismatch, no fallback, the composite format actually executes."""
-    a, b, bs = _pair(nblk=8, bsize=4, band=1, seed=11)
-    set_config(abft="verify", mm_format="composite")
-    fp.reset()
-    c = dt.create("fC", bs, bs)
-    dt.multiply("N", "N", 1.0, a, b, 0.0, c)
-    assert c._mm_algorithm == "composite"
-    assert _ctr("dbcsr_tpu_abft_mismatches_total") == 0
 
 
 # ------------------------------------------------- wide-N n-chunking
@@ -324,49 +304,6 @@ def test_wide_n_product_goes_dense_via_n_chunking(monkeypatch):
     assert (_dense_of(c) == _dense_of(ref)).all()
 
 
-# ------------------------------------- the trial → promotion closing
-
-def test_format_trial_promotes_learned_crossover(monkeypatch):
-    """The off-hot-path format trial A/Bs the formats on a synthetic
-    grid and the service merge-promotes the winner's format columns —
-    the planner then serves them (reason='tuned')."""
-    monkeypatch.setenv("DBCSR_TPU_TUNE_NREP", "1")
-    cell = {"m": 8, "n": 8, "k": 8, "dtype": "float64",
-            "driver": "format", "stack_size": 0, "format": "stack",
-            "occ": 0.95, "grid": [8, 8, 8],
-            "observed_gflops": 1e-4, "target_gflops": 1.0,
-            "wasted_flop_seconds": 1.0, "source": "test",
-            "reason": "test"}
-    trial = trials.run_format_trial(cell, seed=3, reps=2)
-    assert trial.ok and trial.entry is not None
-    assert trial.entry["format"] in fp.FORMATS
-    cands = {c["format"]: c for c in trial.candidates}
-    assert {"stack", "dense"} <= set(cands)
-    assert all(c["gflops"] > 0 for c in trial.candidates)
-
-    svc = tune_service.TuneService(interval_s=3600)
-    if trial.entry["format"] == "stack":
-        # under suite-wide CPU load the tiny trial grid's timing can
-        # let stack win — the promotion contract is then a HOLD:
-        # re-pinning the regretted format is churn, not progress
-        assert svc._maybe_promote_format(cell, trial) is None
-    # promotion path, decoupled from the timing race: a dense win
-    # carries exactly the format columns the trial emits
-    win = trials.TrialResult(
-        trials.OK, cell,
-        {"m": 8, "n": 8, "k": 8, "dtype": "float64",
-         "format": "dense", "format_occ": 0.95,
-         "format_driver": "dense",
-         "format_gflops": cands["dense"]["gflops"]},
-        trial.candidates, trial.elapsed_s, None, 0)
-    rec = svc._maybe_promote_format(cell, win)
-    assert rec is not None
-    row = params_mod.lookup(8, 8, 8, "float64")
-    assert row["format"] == "dense"
-    assert 0.0 < float(row["format_occ"]) <= 0.95
-    assert float(row["format_gflops"]) > 0
-
-
 # ----------------------------------------------------- fleet sharing
 
 class _PeerState:
@@ -396,13 +333,15 @@ def peer_url():
     srv.server_close()
 
 
+def _kernel_row(**extra):
+    """A tuned kernel row: the stack engine's driver/grouping columns."""
+    return {"m": 4, "n": 4, "k": 4, "dtype": "float64", "stack_size": 0,
+            "driver": "xla_group", "grouping": 8, **extra}
+
+
 def _peer_row():
     return {"key": [4, 4, 4, "float64", 0],
-            "entry": {"m": 4, "n": 4, "k": 4, "dtype": "float64",
-                      "stack_size": 0, "driver": "xla", "gflops": 5.0,
-                      "format": "dense", "format_occ": 0.3,
-                      "format_gflops": 5.0, "format_driver": "dense",
-                      "tuned_by": "dbcsr_tpu.tune"},
+            "entry": _kernel_row(gflops=5.0, tuned_by="dbcsr_tpu.tune"),
             "generation": 3, "t_unix": 0.0}
 
 
@@ -412,7 +351,7 @@ def test_fleet_adopts_same_kind_format_promotion(peer_url):
     adopted = store.peer_sync(kind=kind, peers=[peer_url])
     assert adopted == [[4, 4, 4, "float64", 0]]
     row = params_mod.lookup(4, 4, 4, "float64")
-    assert row["format"] == "dense"
+    assert (row["driver"], row["grouping"]) == ("xla_group", 8)
     assert row["adopted_from"] == peer_url
     assert _ctr("dbcsr_tpu_tune_fleet_total", event="adopted") == 1
     # adopted rows never re-export: no promotion echo around the fleet
@@ -422,7 +361,7 @@ def test_fleet_adopts_same_kind_format_promotion(peer_url):
 
 
 def test_fleet_skips_other_device_kind(peer_url):
-    """Another chip's crossover does not transfer: a kind-mismatched
+    """Another chip's tuned row does not transfer: a kind-mismatched
     payload is counted and dropped without touching the table."""
     _PeerState.payload = {"kind": "definitely_not_this_kind",
                           "rows": [_peer_row()]}
@@ -434,10 +373,7 @@ def test_fleet_skips_other_device_kind(peer_url):
 def test_promotions_route_serves_origin_rows():
     from dbcsr_tpu.obs import server
 
-    store.promote({"m": 4, "n": 4, "k": 4, "dtype": "float64",
-                   "stack_size": 0, "format": "dense",
-                   "format_occ": 0.2, "format_gflops": 9.9,
-                   "driver": "dense", "gflops": 9.9})
+    store.promote(_kernel_row(gflops=9.9))
     kind = params_mod.device_kind()
     server.start(port=0)
     try:
@@ -449,7 +385,8 @@ def test_promotions_route_serves_origin_rows():
         server.stop()
     assert payload["kind"] == kind
     assert len(payload["rows"]) == 1
-    assert payload["rows"][0]["entry"]["format"] == "dense"
+    entry = payload["rows"][0]["entry"]
+    assert (entry["driver"], entry["grouping"]) == ("xla_group", 8)
 
 
 # ------------------------------------------------------------- knobs
@@ -458,6 +395,6 @@ def test_format_knob_validation():
     with pytest.raises(ValueError):
         set_config(mm_format="bogus")
     with pytest.raises(ValueError):
-        set_config(composite_max_panels=1)
+        set_config(mm_format="composite")
     set_config(mm_format="dense")
     assert get_config().mm_format == "dense"

@@ -180,7 +180,8 @@ def _plan(a, b, c, filter_eps=None, retain_sparsity=False):
     from dbcsr_tpu.mm import format_planner as fp
 
     return fp.choose(a, b, c, filter_eps=filter_eps,
-                     retain_sparsity=retain_sparsity, no_limits=True)
+                     retain_sparsity=retain_sparsity, no_limits=True,
+                     dense=True)
 
 
 def test_dense_cost_model_routes_f64_on_fake_tpu(fake_tpu):

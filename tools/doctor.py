@@ -154,14 +154,6 @@ HINTS = {
         "burning cycles — check the trial watchdog channel "
         "(tune_trial) and the last_error in the tune health component",
         "docs/autotuning.md#runbook-failing-trials"),
-    "format_mis_crossover": (
-        "the storage-format planner's chosen format keeps measuring "
-        "well below its own cost-model prediction (regret < 0.5x): "
-        "the dense/stack crossover for that block cell is mis-placed "
-        "on this device — the tuner mines these automatically "
-        "(mine_format) and will trial/promote a learned crossover; "
-        "force DBCSR_TPU_MM_FORMAT only as a stopgap",
-        "docs/performance.md#storage-format-planner"),
     "tenant_hotspot": (
         "one tenant dominates the attributed device time; check its "
         "request mix and quotas (and `tools/usage_report.py` for the "
@@ -201,7 +193,6 @@ TREND_METRICS = (
     "dbcsr_tpu_precision_promotions_total",
     "dbcsr_tpu_tune_promotions_total",
     "dbcsr_tpu_params_generation",
-    "dbcsr_tpu_format_regret",
     "dbcsr_tpu_serve_queue_depth",
     "dbcsr_tpu_serve_latency_p95_ms",
     "dbcsr_tpu_serve_shed_total",
@@ -618,30 +609,13 @@ def analyze(health: dict | None, prom: dict, events: list,
             detail=f"{failed} non-OK trial(s): " + ", ".join(
                 f"{o}={n}" for o, n in sorted(tr.items()))))
 
-    # storage-format planner plane: decision counters by (format,
-    # reason) and the per-format regret gauges (latest measured/
-    # predicted ratio) — a format persistently under half its own
-    # prediction is a mis-placed crossover
-    fmtp: dict = {}
+    # storage-format planner plane: decision counters by (format, reason)
     decisions = collections.Counter()
     for labels, v in prom.get("dbcsr_tpu_format_decision_total", []):
         decisions[f"{labels.get('format', '?')}/"
                   f"{labels.get('reason', '?')}"] += int(v)
     if decisions:
-        fmtp["decisions"] = dict(decisions)
-    regret = {}
-    for labels, v in prom.get("dbcsr_tpu_format_regret", []):
-        regret[labels.get("format", "?")] = float(v)
-    if regret:
-        fmtp["regret"] = regret
-    if fmtp:
-        report["format_planner"] = fmtp
-    bad = {f: r for f, r in regret.items() if r < 0.5}
-    if bad:
-        report["hints"].append(_hint(
-            "format_mis_crossover", detail=", ".join(
-                f"{f} at {r:.2f}x predicted"
-                for f, r in sorted(bad.items()))))
+        report["format_planner"] = {"decisions": dict(decisions)}
 
     # SLO burn: the live verdict's slo component first, else slo_burn
     # bus events (the telemetry history plane, obs/slo.py)
@@ -1008,10 +982,6 @@ def render(report: dict, out=print) -> None:
         if fpn.get("decisions"):
             parts.append("decisions[" + ", ".join(
                 f"{k}={v}" for k, v in sorted(fpn["decisions"].items()))
-                + "]")
-        if fpn.get("regret"):
-            parts.append("regret[" + ", ".join(
-                f"{f}={r:g}x" for f, r in sorted(fpn["regret"].items()))
                 + "]")
         out(" format planner: " + (", ".join(parts) or "idle"))
     if report.get("slo_burning"):

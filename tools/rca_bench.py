@@ -67,7 +67,7 @@ os.environ.setdefault("DBCSR_TPU_PROFILE_EPOCH_N", "8")
 
 
 def _build_pair(nblk: int, bsize: int, occ: float, seed: int):
-    """A, B at one block size/occupancy (format_bench's recipe)."""
+    """A, B at one block size/occupancy: a random block pattern."""
     import numpy as np
 
     import dbcsr_tpu as dt
