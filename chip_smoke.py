@@ -33,6 +33,15 @@ every chip of the host and starts no child that touches JAX:
                 (`sign_iteration(..., mesh=make_grid(4))`): every product
                 the sparse mesh engine's, on bins its collect left on
                 every device; skipped, loudly, with fewer than four
+  tensor_3c     one batch of cubic-scaling RPA's chi(i tau) on a few waters
+                (`benchmark/generators/rpa_chi.py`, the deployment of
+                `rpa_h2o32`): two rank-3 x matrix contractions
+                and one rank-3 x rank-3 contraction through
+                `dbcsr_tpu.tensor` (restrict, remap, one TAS group each,
+                f64 stacks forced, every span sliced where the device
+                emulates f64, the deferred filter); checked
+                against the generator's NumPy batch (flops, chi's
+                pattern, every block)
 
 Each leg runs one first call (set-up: compile + staging) and two fenced
 repeats, requires bit-identical checksums across them, and is checked
@@ -65,6 +74,7 @@ import warnings
 NORTH_STAR = {"n": 10000, "block": 23, "occupancy": 0.1}
 MIXED = {"blocks": (5, 13, 23), "occupancy": 0.05}
 SIGN_CHAIN = {"n": 2000, "steps": 3}
+TENSOR_3C = {"molecules": 4, "batches": 2}
 FILTER_EPS = 1e-7
 N_SAMPLE_ROWS = 4
 CHECKSUM_RTOL = 1e-9  # filtered / mesh legs vs the f64 leg
@@ -474,8 +484,102 @@ def leg_sign_chain_mesh4(*, n, block, occupancy, seed, reference):
     return res
 
 
+def _rpa():
+    """`benchmark/generators/rpa_chi.py` (the box, the tensors, the batch
+    through `dbcsr_tpu.tensor` and its plain NumPy reference) and the
+    configuration `rpa_h2o32`'s recipe, loaded by path."""
+    import importlib.util
+    import json
+
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark")
+    spec = importlib.util.spec_from_file_location(
+        "_smoke_rpa_chi", os.path.join(here, "generators", "rpa_chi.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with open(os.path.join(here, "configs", "rpa_h2o32.json")) as fh:
+        return mod, json.load(fh)
+
+
+_TENSOR_COUNTERS = ("dbcsr_tpu_tensor_remap_blocks_total",
+                    "dbcsr_tpu_tensor_remap_bytes_total",
+                    "dbcsr_tpu_tas_groups_total",
+                    "dbcsr_tpu_tensor_batches_total",
+                    "dbcsr_tpu_stack_dot_total")
+
+
+def _tensor_counters() -> dict:
+    from dbcsr_tpu.obs import metrics
+
+    return {name: {",".join(f"{k}={v}" for k, v in sorted(lab.items())): val
+                   for lab, val in metrics.counter_items(name)}
+            for name in _TENSOR_COUNTERS}
+
+
+def leg_tensor_3c(*, n, block, occupancy, seed):
+    """Batch 0 of `rpa_h2o32`'s chi(i tau) at `TENSOR_3C`'s
+    molecules (2 where the caller's ``n`` is a few blocks): M^occ,
+    M^virt and chi through `dbcsr_tpu.tensor.contract` inside
+    `batched_contraction`, so `tas_multiply` and the f64 stack engine;
+    held to the generator's NumPy batch (flops, chi's pattern, every
+    block of chi).  A box of a few waters stores nearly every block, so
+    the format planner would send each product to the dense route: the
+    leg forces the stack engine (`mm_format="stack"`), whose tall (728
+    rows) and deep (k = 169) spans are what a large box runs.  Where
+    the device emulates f64 every span must take the sliced dot
+    (`dbcsr_tpu_stack_dot_total{form="compiler"}` stands still).
+    ``block`` and ``occupancy`` are the product legs'.  Prints the
+    tensor counters that moved."""
+    import numpy as np
+
+    import dbcsr_tpu as dt
+    from dbcsr_tpu.acc.smm import emulated_dtype_on_tpu
+
+    molecules = TENSOR_3C["molecules"] if n >= 2000 else 2
+    rpa, cfg = _rpa()
+    dep = rpa.Deployment(dict(cfg["recipe"], batches=TENSOR_3C["batches"]),
+                         molecules, cfg["pattern_seed"], seed)
+    _report("OPERANDS", dict(dep.describe(), leg="tensor_3c"))
+    last = {}
+
+    def run():
+        last["chi"], flops = dep.run_batch(0)
+        return last["chi"].matrix, flops
+
+    prev = dt.get_config().mm_format
+    dt.set_config(mm_format="stack")
+    try:
+        counters0 = _tensor_counters()
+        res, _ = _timed_repeats("tensor_3c", run)
+        counters1 = _tensor_counters()
+    finally:
+        dt.set_config(mm_format=prev)
+    res["counters"] = {
+        name: {lab: v - counters0[name].get(lab, 0.0)
+               for lab, v in by.items() if v != counters0[name].get(lab)}
+        for name, by in counters1.items()}
+    _report("LEG", res)
+    ref = dep.reference(0)
+    want = sum(info["flops"] for info in ref.infos)
+    check = dep.check(0, last["chi"], ref=ref)
+    compiler_form = sum(
+        v for lab, v in res["counters"]["dbcsr_tpu_stack_dot_total"].items()
+        if "form=compiler" in lab)
+    if emulated_dtype_on_tpu(np.float64) and compiler_form:
+        raise SmokeFailure(
+            f"tensor_3c: {compiler_form} spans took the compiler's f64 "
+            "dot, not the sliced one")
+    if res["flops"] != want or not check["ok"]:
+        raise SmokeFailure(
+            f"tensor_3c: flops {res['flops']} (NumPy batch {want}), "
+            f"check {check}")
+    _report("CHECK", dict(check, leg="tensor_3c", check="numpy_batch",
+                          flops=want))
+    return res
+
+
 LEGS = ("f64", "f64_filtered", "f32", "f64_filtered_mixed", "mesh4",
-        "mesh4_filtered", "sign_chain", "sign_chain_mesh4")
+        "mesh4_filtered", "sign_chain", "sign_chain_mesh4", "tensor_3c")
 
 
 def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
@@ -530,6 +634,7 @@ def run_legs(*, n, block, occupancy, seed, mesh=True, legs=LEGS) -> dict:
             if mesh:
                 attempt("sign_chain_mesh4", leg_sign_chain_mesh4,
                         reference=out.get("sign_chain"))
+            attempt("tensor_3c", leg_tensor_3c)
     finally:
         dt.set_config(incremental=prev_inc)
     here = os.path.dirname(os.path.abspath(__file__))

@@ -48,7 +48,8 @@ from dbcsr_tpu.obs import metrics as _metrics
 from dbcsr_tpu.obs import tracer as _trace
 from dbcsr_tpu.resilience import breaker as _breaker
 from dbcsr_tpu.resilience import faults as _faults
-from dbcsr_tpu.utils.rounding import bucket_size, ceil_div
+from dbcsr_tpu.utils.rounding import (bucket_pow2, bucket_pow4, bucket_size,
+                                      ceil_div)
 
 
 def emulated_dtype_on_tpu(dtype) -> bool:
@@ -197,6 +198,21 @@ def group_dot_form(dtype, depth: int, prec=None) -> str:
             and depth <= SLICED_MAX_DEPTH and emulated_dtype_on_tpu(dtype)):
         return "sliced"
     return "compiler"
+
+
+def sliced_width(r0: int, k: int, dtype, prec=None) -> int:
+    """The widest group of a plan whose blocks are ``k`` deep: ``r0``,
+    halved while ``r0 * k`` passes `SLICED_MAX_DEPTH` where the dtype
+    takes the sliced form (`group_dot_form` at depth ``k``).  Left as it
+    is, such a plan would hand every class of the span to the compiler's
+    form for its widest group's sake; a span of k = 169 keeps the sliced
+    form at width 4.  No block of k * 8 <= 1 024 (every 23-block) is
+    touched."""
+    if group_dot_form(dtype, k, prec) != "sliced":
+        return r0
+    while r0 > 1 and r0 * k > SLICED_MAX_DEPTH:
+        r0 //= 2
+    return r0
 
 
 def _f32_fixed(v, lead):
@@ -745,17 +761,18 @@ def _group_widths(short_hist, other_slots: int, r0: int) -> list:
     return sorted(widths, reverse=True)
 
 
-def _class_chunk_caps(counts, widths, r0: int, chunk_groups: int) -> list:
+def _class_chunk_caps(counts, widths, r0: int, chunk_groups: int,
+                      round_up=bucket_size) -> list:
     """``CH_w``, the groups one chunk holds of each width class, from
     the groups the fullest stack has of each (``counts``).  It follows
     the class's share of the slots in coarse steps and not its count,
     so a pattern that grows keeps its shapes and takes more chunks
     (``chunk_groups`` x r0 slots a chunk; a stack that fills no chunk
-    gets one of its own bucketed size)."""
+    gets one of its own size, rounded up by ``round_up``)."""
     slots = sum(cnt * w for cnt, w in zip(counts, widths))
     if slots <= chunk_groups * r0:
         # one chunk holds the stack: its size is the stack's, bucketed
-        return [bucket_size(cnt) for cnt in counts]
+        return [round_up(cnt) for cnt in counts]
     if tuple(widths) == (r0,):
         return [chunk_groups]
     step = max(1, 1 << max((chunk_groups // 16).bit_length() - 1, 0))
@@ -766,7 +783,8 @@ def _class_chunk_caps(counts, widths, r0: int, chunk_groups: int) -> list:
 
 def build_stacks_group_tiles(stack_of, nstacks: int, c_idx, a_idx, b_idx,
                              r0: int, a_pad: int, b_pad: int, c_pad: int,
-                             chunk_groups: int) -> GroupTiles:
+                             chunk_groups: int,
+                             moving: bool = False) -> GroupTiles:
     """Host side of the grouped layout, for ``nstacks`` stacks that one
     program runs (one stack on one chip; one a (device, tick) on a
     mesh, where an SPMD program needs the same shapes everywhere):
@@ -797,7 +815,13 @@ def build_stacks_group_tiles(stack_of, nstacks: int, c_idx, a_idx, b_idx,
     ``[0, a_pad]`` / ``[0, b_pad]`` where the entries' do: the body's
     gathers promise the compiler that and check nothing.  Dead groups
     carry segment id ``c_pad`` (= nseg) after the live ones, keeping
-    ids sorted and dropped by the scatter-add."""
+    ids sorted and dropped by the scatter-add.
+
+    ``moving``: the stacks' counts move from call to call by more than
+    the buckets' 25% (a tensor's batches, `prepare_stack`), and the
+    shapes must not follow them: one width class, ``r0``, a chunk of
+    one of a few sizes (powers of two) and nchunks a power of four
+    (the chunks past ``live`` cost index memory only, never a step)."""
     s = len(c_idx)
     if s == 0:  # no stack runs a step: one dead chunk of the widest class
         def dead(pad, *shape):
@@ -811,8 +835,9 @@ def build_stacks_group_tiles(stack_of, nstacks: int, c_idx, a_idx, b_idx,
     seg_len = np.diff(np.append(seg_starts, s))
     run_groups = -(-seg_len // r0)
     short = run_groups == 1
-    widths = _group_widths(np.bincount(seg_len[short], minlength=r0 + 1),
-                           int(run_groups[~short].sum()) * r0, r0)
+    widths = [r0] if moving else _group_widths(
+        np.bincount(seg_len[short], minlength=r0 + 1),
+        int(run_groups[~short].sum()) * r0, r0)
     run_width = np.where(
         short, _narrowest_fit(widths, r0)[np.minimum(seg_len, r0)], r0)
     # groups in (stack, stack order); a group's row among its class's
@@ -827,9 +852,11 @@ def build_stacks_group_tiles(stack_of, nstacks: int, c_idx, a_idx, b_idx,
                  for mem in members]
     counts = [len(mem) for mem in members]
     caps = _class_chunk_caps([int(n.max()) for n in per_stack], widths, r0,
-                             chunk_groups)
+                             chunk_groups,
+                             bucket_pow2 if moving else bucket_size)
     live = np.max([-(-n // cap) for n, cap in zip(per_stack, caps)], axis=0)
-    nchunks = bucket_size(int(live.max()), minimum=1)
+    nchunks = (bucket_pow4 if moving else bucket_size)(int(live.max()),
+                                                       minimum=1)
     # every class's rows in one buffer, so the ids are written once
     rows = [nchunks * cap for cap in caps]  # of one stack
     row_base = np.cumsum([0] + [nstacks * r for r in rows])
@@ -861,13 +888,15 @@ def build_stacks_group_tiles(stack_of, nstacks: int, c_idx, a_idx, b_idx,
 
 
 def build_group_tiles(c_idx, a_idx, b_idx, r0: int, a_pad: int, b_pad: int,
-                      c_pad: int, chunk_groups: int) -> GroupTiles:
+                      c_pad: int, chunk_groups: int,
+                      moving: bool = False) -> GroupTiles:
     """`build_stacks_group_tiles` for ONE stack (``c_idx`` sorted
     ascending): (nchunks, CH_w, w) / (nchunks, CH_w) arrays per class
-    and ``live`` a number."""
+    and ``live`` a number.  ``moving``: as `build_stacks_group_tiles`
+    takes it."""
     many = build_stacks_group_tiles(
         np.zeros(len(c_idx), np.int32), 1, c_idx, a_idx, b_idx, r0,
-        a_pad, b_pad, c_pad, chunk_groups)
+        a_pad, b_pad, c_pad, chunk_groups, moving=moving)
     return GroupTiles(int(many.live[0]),
                       tuple(tuple(x[0] for x in tile) for tile in many.tiles),
                       many.groups, many.entries)
@@ -1118,7 +1147,8 @@ def _ensure_pallas_validated(c_data, a_data, b_data, plan: StackPlan) -> None:
 
 
 def prepare_stack(c_data, a_data, b_data, a_idx, b_idx, c_idx,
-                  a_pad_row=None, b_pad_row=None) -> Optional[StackPlan]:
+                  a_pad_row=None, b_pad_row=None,
+                  moving: bool = False) -> Optional[StackPlan]:
     """Host side of stack processing: driver selection (tuned table +
     prediction), grouping/chunking/padding, and upload of the int32
     index arrays.  Returns None for an empty stack.
@@ -1128,13 +1158,18 @@ def prepare_stack(c_data, a_data, b_data, a_idx, b_idx, c_idx,
     for a different driver without the engine re-deriving the stack.
     A planning failure (injected, or a real host-side grouping bug)
     re-plans once on the safe XLA path instead of killing the
-    multiply."""
+    multiply.  ``moving``: C's pattern moves from call to call (its
+    `moving_pattern`, a tensor's batches), so a grouped plan takes
+    shapes that do not follow the stack's counts (one width class,
+    chunk sizes in powers of two, chunk counts in powers of four:
+    `build_stacks_group_tiles`), and a batch reuses the last one's
+    programs."""
     try:
         if _faults.active():
             _faults.maybe_inject("prepare_stack")
         plan = _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx,
                                    c_idx, a_pad_row=a_pad_row,
-                                   b_pad_row=b_pad_row)
+                                   b_pad_row=b_pad_row, moving=moving)
     except Exception as exc:  # noqa: BLE001 — classified + recorded
         shape_key = _stack_shape_key(c_data, a_data, b_data)
         _record_driver_failure("prepare", _classify_failure(exc), exc,
@@ -1157,7 +1192,8 @@ def prepare_stack(c_data, a_data, b_data, a_idx, b_idx, c_idx,
 
 def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
                         a_pad_row=None, b_pad_row=None,
-                        cfg=None) -> Optional[StackPlan]:
+                        cfg=None, moving: bool = False
+                        ) -> Optional[StackPlan]:
     """Driver selection + plan construction.  ``cfg`` overrides the
     live config — the failover path passes a copy with ``mm_driver``
     forced so one rebuild targets one specific chain driver."""
@@ -1260,7 +1296,8 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
         )
     )
     if want_group:
-        r0 = int(tuned.get("r0", 8)) if tuned else 8
+        r0 = sliced_width(int(tuned.get("r0", 8)) if tuned else 8,
+                          a_data.shape[2], c_data.dtype, prec)
         if a_pad_row is None:
             plan.append_a_pad = True
             a_pad_row = a_data.shape[0]
@@ -1273,6 +1310,7 @@ def _prepare_stack_impl(c_data, a_data, b_data, a_idx, b_idx, c_idx,
         tiles = build_group_tiles(
             np.asarray(c_idx), np.asarray(a_idx), np.asarray(b_idx),
             r0, a_pad_row, b_pad_row, plan.nseg, chunk_groups,
+            moving=moving,
         )
         plan.driver = "xla_group"
         plan.r_grp = r0  # metadata: the widest R-tile grouping used

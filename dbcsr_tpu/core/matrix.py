@@ -36,7 +36,7 @@ from dbcsr_tpu.core.dist import Distribution
 from dbcsr_tpu.core.kinds import dtype_of, is_complex
 from dbcsr_tpu.core.lib import ensure_init
 from dbcsr_tpu.core.timings import booked
-from dbcsr_tpu.utils.rounding import bucket_size
+from dbcsr_tpu.utils.rounding import bucket_pow2, bucket_size
 
 # matrix_type flags, ref dbcsr_type_no_symmetry/_symmetric/_antisymmetric/
 # _hermitian in src/core/dbcsr_types.F
@@ -95,6 +95,18 @@ def _scatter_staged(dst, blocks, slots, add: bool):
 
 class BlockSparseMatrix:
     """A distributed block-compressed sparse row matrix."""
+
+    # a matrix whose pattern moves from call to call (a tensor's, cut
+    # into batches and refilled batch after batch:
+    # `tensor.types.BlockSparseTensor`) takes bins in powers of two, and
+    # the stacks that fill it take shapes that do not follow its counts
+    # (`acc.smm.prepare_stack`), so that one batch's programs serve the
+    # next
+    moving_pattern = False
+
+    def bin_capacity(self, n: int) -> int:
+        """The capacity of a bin of ``n`` blocks."""
+        return bucket_pow2(n) if self.moving_pattern else bucket_size(n)
 
     def __init__(
         self,
@@ -380,6 +392,11 @@ class BlockSparseMatrix:
         `dbcsr_tensor_reshape.F:67,288`).  The batch merges at
         `finalize` via the same device gather/scatter as host batches.
 
+        ``blocks`` may hold more rows than there are coordinates (a
+        bucketed batch, so that the program that made it and the merge
+        that takes it are keyed by the bucket): row i is block i of the
+        coordinates, the rows past them are never read.
+
         Caller contract: (row, col) pairs are unique within the batch
         (jnp scatter with duplicates is undefined-order), and the
         matrix has no symmetry (device blocks are not host-foldable).
@@ -390,7 +407,7 @@ class BlockSparseMatrix:
             )
         rows = np.ascontiguousarray(rows, np.int64)
         cols = np.ascontiguousarray(cols, np.int64)
-        if len(rows) != len(cols) or len(rows) != blocks.shape[0]:
+        if len(rows) != len(cols) or len(rows) > blocks.shape[0]:
             raise ValueError("rows/cols/blocks length mismatch")
         if len(rows) == 0:
             return
@@ -460,7 +477,8 @@ class BlockSparseMatrix:
         shape_to_bin = {(int(bm), int(bn)): i for i, (bm, bn) in enumerate(shapes)}
         counts = np.bincount(nb, minlength=len(shapes))
         data_arrs = [
-            mempool.zeros((bucket_size(int(counts[i])), int(bm), int(bn)),
+            mempool.zeros((self.bin_capacity(int(counts[i])), int(bm),
+                           int(bn)),
                           self.dtype)
             for i, (bm, bn) in enumerate(shapes)
         ]
@@ -486,6 +504,12 @@ class BlockSparseMatrix:
             slots = nsl[np.searchsorted(merged, keys_b)]
             if isinstance(arr, np.ndarray):
                 mempool.record_h2d(arr.nbytes)  # staged host blocks
+            if len(slots) < arr.shape[0]:
+                # a device batch of bucketed rows (`stage_device_blocks`):
+                # the rows past its keys land past the bin and drop
+                slots = np.concatenate([slots, np.full(
+                    arr.shape[0] - len(slots), data_arrs[b].shape[0],
+                    slots.dtype)])
             data_arrs[b] = _scatter_staged(
                 data_arrs[b], jnp.asarray(arr),
                 mempool.upload_index("fin_slot", slots), bool(summation)
@@ -758,6 +782,7 @@ class BlockSparseMatrix:
             self.dist,
             self.matrix_type,
         )
+        m.moving_pattern = self.moving_pattern
         m.keys = self.keys.copy()
         m.row_ptr = self.row_ptr.copy()
         m.ent_bin = self.ent_bin.copy()

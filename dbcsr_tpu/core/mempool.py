@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dbcsr_tpu.utils import lockcheck as _lockcheck  # noqa: E402
+from dbcsr_tpu.utils.rounding import bucket_pow2
 
 _lock = _lockcheck.wrap("core.mempool", threading.RLock())
 
@@ -339,6 +340,20 @@ def upload_index(tag: str, arr) -> object:
                 _, old = _mirror.popitem(last=False)
                 _mirror_bytes -= _arr_bytes(old)
     return dev
+
+
+def upload_index_bucketed(tag: str, arr, fill: int) -> object:
+    """`upload_index` of ``arr`` filled up with ``fill`` to the
+    `bucket_pow2` of its length: a program that takes it is keyed by
+    the bucket and not by the count, so a pattern that moves (a
+    tensor's next batch) reuses it.  ``fill`` is an id the program
+    ignores: past the end of a scatter's destination (dropped), or any
+    valid row of a gather whose result lands there."""
+    arr = np.ascontiguousarray(arr)
+    pad = bucket_pow2(len(arr)) - len(arr)
+    if pad > 0:
+        arr = np.concatenate([arr, np.full(pad, fill, arr.dtype)])
+    return upload_index(tag, arr)
 
 
 def alias_bins(m) -> tuple:

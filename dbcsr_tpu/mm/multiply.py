@@ -1129,7 +1129,7 @@ def _rebuild_c(c: BlockSparseMatrix, new_keys: np.ndarray, beta,
     bins = []
     for b_id, (bm, bn) in enumerate(shapes):
         count = int((nb == b_id).sum())
-        cap = bucket_size(count)
+        cap = c.bin_capacity(count)
         data = mempool.zeros((cap, bm, bn), c.dtype)
         in_bin = (nb[pos_old] == b_id) if n_old else np.zeros(0, bool)
 
@@ -1333,6 +1333,7 @@ def _run_stacks(c, a, b, cand_keys, a_ent, b_ent, alpha, plan_key=None,  # lint:
                 # path masks short groups with them
                 a_pad_row=a_bin.count if a_bin.count < a_bin.data.shape[0] else None,
                 b_pad_row=b_bin.count if b_bin.count < b_bin.data.shape[0] else None,
+                moving=c.moving_pattern,
             )
             spans_meta.append((cbin, abin, bbin, m, n, k, s1 - s0, plan))
         cached = _CachedSpans(spans_meta)

@@ -44,19 +44,23 @@ def _same_blocking(a: BlockSparseMatrix, b: BlockSparseMatrix) -> None:
 
 
 # --------------------------------------------------------------- structure
-@functools.partial(jax.jit, static_argnames=("capacity",))
-def _gather_pad(data, slots, capacity):
+@jax.jit
+def _gather_pad(data, slots, count):
+    """Rows ``slots`` of ``data``, those past ``count`` zeroed: ``slots``
+    holds a whole bin's capacity of ids (any valid row past ``count``),
+    so the program is keyed by the capacities and not by the count."""
     out = jnp.take(data, slots, axis=0)
-    pad = capacity - out.shape[0]
-    if pad > 0:
-        out = jnp.concatenate([out, jnp.zeros((pad,) + out.shape[1:], out.dtype)])
-    return out
+    live = jnp.arange(out.shape[0]) < count
+    return jnp.where(live.reshape((-1,) + (1,) * (out.ndim - 1)), out, 0)
 
 
-def _subset_bins(matrix: BlockSparseMatrix, keep: np.ndarray):
+def _subset_bins(matrix: BlockSparseMatrix, keep: np.ndarray,
+                 same_capacity: bool = False):
     """(keys, freshly gathered bins) for the ``keep``-masked entries —
     the slot-ordering contract (sorted slots preserve key order within
-    a bin) lives HERE, shared by compress and get_block_diag."""
+    a bin) lives HERE, shared by compress and get_block_diag.  With
+    ``same_capacity`` each bin keeps its source's capacity instead of
+    the matrix's `bin_capacity` of what it keeps."""
     new_keys = matrix.keys[keep]
     ent_bin = matrix.ent_bin[keep]
     ent_slot = matrix.ent_slot[keep]
@@ -69,18 +73,23 @@ def _subset_bins(matrix: BlockSparseMatrix, keep: np.ndarray):
             # set_structure_from_device; skip the dispatch entirely
             continue
         slots = np.sort(ent_slot[mask])  # preserve key order within bin
+        cap = (b.data.shape[0] if same_capacity
+               else matrix.bin_capacity(count))
+        slots = np.concatenate([slots, np.zeros(cap - count, slots.dtype)])
         data = _gather_pad(b.data, mempool.upload_index("subset", slots),
-                           bucket_size(count))
+                           np.int32(count))
         bins.append(_Bin(b.shape, data, count))
     return new_keys, bins
 
 
-def compress(matrix: BlockSparseMatrix, keep: np.ndarray) -> BlockSparseMatrix:
-    """Drop entries where ``keep`` is False; rebuild bins by device gather."""
+def compress(matrix: BlockSparseMatrix, keep: np.ndarray,
+             same_capacity: bool = False) -> BlockSparseMatrix:
+    """Drop entries where ``keep`` is False; rebuild bins by device
+    gather (``same_capacity``: at the capacities they had)."""
     _require_valid(matrix)
     if keep.all():
         return matrix
-    new_keys, bins = _subset_bins(matrix, keep)
+    new_keys, bins = _subset_bins(matrix, keep, same_capacity)
     matrix.set_structure_from_device(new_keys, bins)
     return matrix
 

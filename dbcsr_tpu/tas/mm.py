@@ -21,6 +21,7 @@ from dbcsr_tpu.core.config import get_config
 from dbcsr_tpu.core.matrix import BlockSparseMatrix
 from dbcsr_tpu.core.timings import timed
 from dbcsr_tpu.mm.multiply import multiply
+from dbcsr_tpu.obs import metrics as _metrics
 from dbcsr_tpu.obs import tracer as _trace
 from dbcsr_tpu.ops.operations import scale
 from dbcsr_tpu.parallel.mesh import optimize_grid
@@ -99,8 +100,20 @@ def tas_multiply(
         # inline are module-scope now: ~µs each, but they sat inside the
         # timed("tas_multiply") hot region of EVERY split-loop multiply)
         def _fresh_opt() -> int:
+            if mesh is None:
+                # one chip: no process groups to balance, and the engine
+                # bounds a product's working set itself (its stacks run
+                # in chunks, `acc.smm.group_chunk_groups`), so a split
+                # the caller did not ask for only multiplies plans,
+                # programs and host passes (on a v5e a chi batch of
+                # `examples/rpa_chi.py` at 32 waters split 64, 64 and 12
+                # ways compiled a hundred fused programs a batch and did
+                # not finish its first in 15 minutes).  A caller whose
+                # result must be built in pieces asks for groups:
+                # ``nsplit``, `TASMatrix.nsplit`, `batched_mm_init`
+                return 1
             long_blks = max(c.nblkrows, c.nblkcols, nblk_k)
-            if mesh is not None and mesh.shape["pr"] == mesh.shape["pc"]:
+            if mesh.shape["pr"] == mesh.shape["pc"]:
                 # (rectangular grids: grouping cannot engage — the
                 # grouped path needs a square Cannon grid — so nsplit
                 # does not move traffic; keep the geometric estimate)
@@ -186,7 +199,12 @@ def tas_multiply(
                 transa, transb, alpha, a, b, beta, c, filter_eps,
                 max(nsplit, 1), long_dim, nblk_k, mesh,
             )
+        groups = _metrics.counter(
+            "dbcsr_tpu_tas_groups_total",
+            "multiplies the one-chip TAS split ran, one a group, by the "
+            "long dimension it split")
         if nsplit <= 1:
+            groups.inc(long_dim=long_dim)
             return multiply(transa, transb, alpha, a, b, beta, c,
                             filter_eps=filter_eps)
 
@@ -213,6 +231,7 @@ def tas_multiply(
         with _mempool.chain() as ch:
             for g0 in range(0, nblk, per):
                 g1 = min(g0 + per, nblk)
+                groups.inc(long_dim=long_dim)
                 with ch.scope():
                     flops += multiply(
                         transa, transb, alpha, a, b, 1.0, c,

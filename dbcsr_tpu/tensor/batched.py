@@ -15,6 +15,8 @@ from __future__ import annotations
 import contextlib
 from typing import Iterator, Optional
 
+from dbcsr_tpu.core.timings import timed
+from dbcsr_tpu.obs import metrics as _metrics
 from dbcsr_tpu.ops.operations import filter_matrix
 from dbcsr_tpu.tensor.types import BlockSparseTensor
 
@@ -45,7 +47,12 @@ def batched_contract_finalize(tensor_c: BlockSparseTensor) -> None:
     batched_mm_finalize(tensor_c.matrix)
     eps = state.get("filter_eps")
     if eps is not None:
-        filter_matrix(tensor_c.matrix, eps)
+        with timed("tensor_batch_filter"):
+            filter_matrix(tensor_c.matrix, eps)
+    _metrics.counter(
+        "dbcsr_tpu_tensor_batches_total",
+        "batched tensor contractions finalized "
+        "(batched_contract_finalize)").inc()
 
 
 @contextlib.contextmanager

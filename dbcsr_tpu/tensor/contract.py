@@ -14,12 +14,12 @@ import functools
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from dbcsr_tpu.core import mempool as _mempool
 from dbcsr_tpu.core.timings import timed
 from dbcsr_tpu.obs import events as _events
+from dbcsr_tpu.obs import metrics as _metrics
 from dbcsr_tpu.obs import tracer as _trace
 from dbcsr_tpu.ops.operations import scale
 from dbcsr_tpu.tas.mm import tas_multiply
@@ -31,10 +31,32 @@ def _remap_rows(bin_data, slots, *, src_shape, comb, dst_shape):
     """Gather + per-block nd transpose + reshape, all on device: the
     block-movement kernel of the reshape path (ref the buffered block
     alltoall in `dbcsr_tensor_reshape.F:288`; here the 'communication'
-    is one fused device gather/permute)."""
-    x = jnp.take(bin_data, slots, axis=0).reshape((slots.shape[0],) + src_shape)
+    is one fused device gather/permute).  Blocks are gathered as whole
+    rows of the bin (one block a row, as `acc.smm._take_rows` does): a
+    gather along the bin's block index moves each block as a few lane
+    pieces, where one of (N, bm, bn) blocks fetched every element on its
+    own.  ``slots`` is bucketed (`mempool.upload_index_bucketed`), so the
+    program is keyed by the bucket and a batch whose counts move
+    reuses it; the rows past the real slots are read and never kept."""
+    rows = bin_data.reshape(bin_data.shape[0], -1)
+    x = rows.at[slots].get(mode="promise_in_bounds")
+    x = x.reshape((slots.shape[0],) + src_shape)
     y = x.transpose((0,) + tuple(1 + i for i in comb))
     return y.reshape((slots.shape[0],) + dst_shape)
+
+
+def _note_remap(role: str, blocks: int, nbytes: int) -> None:
+    """Count what one remap moved: blocks and bytes (each block read
+    and written once), by the operand it laid out."""
+    _metrics.counter(
+        "dbcsr_tpu_tensor_remap_blocks_total",
+        "tensor blocks laid out anew by remap, by role (a/b: the "
+        "contraction's operands, c: its result mapped back)").inc(
+            blocks, role=role)
+    _metrics.counter(
+        "dbcsr_tpu_tensor_remap_bytes_total",
+        "bytes the tensor remaps moved, each block read and written "
+        "once, by role").inc(nbytes, role=role)
 
 
 def _flat_multi(nd_idx: np.ndarray, dims: Sequence[int], nblks) -> np.ndarray:
@@ -50,6 +72,7 @@ def remap(
     row_dims: Sequence[int],
     col_dims: Sequence[int],
     name: Optional[str] = None,
+    role: str = "a",
 ) -> BlockSparseTensor:
     """Same tensor, different nd->2d mapping (ref `dbcsr_t_remap`,
     `dbcsr_tensor.F:1604`).
@@ -59,7 +82,9 @@ def remap(
     staged into the output matrix without any host round-trip of block
     data (the reference moves blocks with a buffered MPI alltoall,
     `dbcsr_tensor_reshape.F:67,288`; the single-controller analog is
-    device gather/scatter)."""
+    device gather/scatter).  ``role`` names what is laid out, for the
+    counters: "a"/"b" an operand of `contract`, "c" its result mapped
+    back (`tensor_copy`)."""
     row_dims, col_dims = tuple(row_dims), tuple(col_dims)
     if (row_dims, col_dims) == (t.row_dims, t.col_dims):
         return t
@@ -82,6 +107,7 @@ def remap(
     comb = tuple(old_perm.index(d) for d in new_perm)
     new_rows = _flat_multi(nd_idx, row_dims, nblks)
     new_cols = _flat_multi(nd_idx, col_dims, nblks)
+    moved = 0
     for g in range(ginv.max() + 1):
         sel = np.nonzero(ginv == g)[0]
         s = shp[sel[0]]
@@ -93,10 +119,13 @@ def remap(
             int(np.prod([s[d] for d in col_dims], dtype=np.int64)),
         )
         dev = _remap_rows(
-            mat.bins[bid].data, jnp.asarray(mat.ent_slot[sel]),
+            mat.bins[bid].data,
+            _mempool.upload_index_bucketed("remap_src", mat.ent_slot[sel], 0),
             src_shape=src_shape, comb=comb, dst_shape=dst_shape,
         )
         out.matrix.stage_device_blocks(new_rows[sel], new_cols[sel], dev)
+        moved += len(sel) * dst_shape[0] * dst_shape[1]
+    _note_remap(role, n, 2 * moved * np.dtype(t.dtype).itemsize)
     return out.finalize()
 
 
@@ -118,7 +147,7 @@ def tensor_copy(
         # would otherwise copy with silently reinterpreted data
         if not np.array_equal(dest.blk_sizes[d], src.blk_sizes[d]):
             raise ValueError(f"tensor dim {d} blockings differ")
-    src2 = remap(src, dest.row_dims, dest.col_dims)
+    src2 = remap(src, dest.row_dims, dest.col_dims, role="c")
     src2.finalize()
     mat = src2.matrix
     nbc = mat.nblkcols
@@ -128,9 +157,11 @@ def tensor_copy(
         sel = np.nonzero(mat.ent_bin == b_id)[0]
         keys_by_slot = np.empty(b.count, np.int64)
         keys_by_slot[mat.ent_slot[sel]] = mat.keys[sel]
+        # the whole bin: its rows past ``count`` are never read, and no
+        # slice program is compiled per count
         dest.matrix.stage_device_blocks(
             keys_by_slot // nbc, keys_by_slot % nbc,
-            b.data[: b.count], summation=summation,
+            b.data, summation=summation,
         )
     return dest.finalize()
 
@@ -172,7 +203,12 @@ def restrict_tensor(
     out = BlockSparseTensor(
         name or t.name, t.blk_sizes, t.row_dims, t.col_dims, t.dtype
     )
-    out.matrix = compress(matrix_copy(t.matrix, name=out.name), mask)
+    # the restricted copy keeps the source's bin capacities: the batches
+    # of a contraction then hand the engine operands of one shape each,
+    # and its programs serve them all (the copy is never larger than
+    # its source)
+    out.matrix = compress(matrix_copy(t.matrix, name=out.name), mask,
+                          same_capacity=True)
     return out
 
 
@@ -277,11 +313,15 @@ def contract(
         # The caller's tensors were created OUTSIDE this chain and are
         # never adopted or freed by it.
         with _mempool.chain() as ch:
-            restricted_a = restrict_tensor(tensor_a, a_bounds)
-            restricted_b = restrict_tensor(tensor_b, b_bounds)
+            with timed("tensor_restrict"):
+                restricted_a = restrict_tensor(tensor_a, a_bounds)
+                restricted_b = restrict_tensor(tensor_b, b_bounds)
             # remap operands into matrix-compatible layouts (ref :1183)
-            a2 = remap(restricted_a, nca, ca, name=tensor_a.name + "_mm")
-            b2 = remap(restricted_b, cb, ncb, name=tensor_b.name + "_mm")
+            with timed("tensor_remap"):
+                a2 = remap(restricted_a, nca, ca, name=tensor_a.name + "_mm",
+                           role="a")
+                b2 = remap(restricted_b, cb, ncb, name=tensor_b.name + "_mm",
+                           role="b")
             # restrict/remap may have passed an operand through
             # unchanged; if the caller aliased C to an operand,
             # multiply would then read A/B while overwriting them —
@@ -314,9 +354,10 @@ def contract(
             # its buffers out of the pool they just fed
             ch.retire(a2.matrix)
             ch.retire(b2.matrix)
-            if beta != 1.0:
-                scale(tensor_c.matrix, beta)
-            tensor_copy(tensor_c, tmp, summation=True)
+            with timed("tensor_map_result"):
+                if beta != 1.0:
+                    scale(tensor_c.matrix, beta)
+                tensor_copy(tensor_c, tmp, summation=True)
             return flops
 
 

@@ -53,6 +53,11 @@ class BlockSparseTensor:
             _mixed_radix_sizes(self.blk_sizes, self.col_dims),
             dtype,
         )
+        # a tensor is cut into batches (bounds), laid out anew (remap)
+        # and refilled batch after batch, its counts moving by more than
+        # 25% a batch: shapes that do not follow them let a batch's
+        # programs serve the batches after it
+        self.matrix.moving_pattern = True
 
     # ------------------------------------------------------------- indexing
     @property

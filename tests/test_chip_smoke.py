@@ -25,14 +25,14 @@ def dense_mixed(monkeypatch):
 def test_single_device_legs_pass_tiny(capsys, dense_mixed):
     out = chip_smoke.run_legs(**_TINY, mesh=False)
     assert set(out) == {"f64", "f64_filtered", "f32", "f64_filtered_mixed",
-                        "sign_chain"}
+                        "sign_chain", "tensor_3c"}
     for leg, res in out.items():
         assert res["leg"] == leg and len(res["steady_s"]) == 2
         assert res["driver_launches"], leg  # names the driver that ran
     assert out["f64_filtered"]["checksum"] == pytest.approx(
         out["f64"]["checksum"], rel=1e-9)
     lines = capsys.readouterr().out.splitlines()
-    assert sum(line.startswith("CHECK ") for line in lines) == 5
+    assert sum(line.startswith("CHECK ") for line in lines) == 6
     # three steps of the sign chain, held to the benchmark's NumPy chain
     chain = out["sign_chain"]
     assert chain["algorithm"] == "stack" and chain["flops"] > 0

@@ -80,8 +80,11 @@ def test_contract_routes_fused_planner():
     (dbcsr_tpu_dispatches_total{mode=fused} increments), not per-span
     dispatches."""
     si, sj, sk, sl = [4, 3], [3, 4], [4, 5, 4, 5], [3, 4]
-    a3 = _rand_tensor("a3", [si, sj, sk], occ=0.9, seed=3)
-    m2 = _rand_tensor("m2", [sk, sl], occ=0.9, seed=4)
+    # under the occupancy at which the planner takes both operands dense
+    # (one chip runs a contraction as one multiply: no split forces the
+    # stack engine)
+    a3 = _rand_tensor("a3", [si, sj, sk], occ=0.6, seed=3)
+    m2 = _rand_tensor("m2", [sk, sl], occ=0.6, seed=4)
     metrics.reset()
     c3 = create_tensor("c3", [si, sj, sl])
     c3.finalize()

@@ -313,8 +313,11 @@ def test_tas_batched_split_reoptimizes_on_sparsity_change():
     the batched pgrid re-optimization, `dbcsr_tensor.F:1964-2186`;
     window = default_nsplit_accept_ratio, `dbcsr_tas_split.F:57`)."""
     from dbcsr_tpu.ops.test_methods import make_random_matrix, to_dense
+    from dbcsr_tpu.parallel import make_grid
     from dbcsr_tpu.tas import batched_mm, tas_multiply
 
+    # on a grid: one chip splits only where the caller asks
+    mesh = make_grid(4)
     rng = np.random.default_rng(43)
     rbs = [3] * 64  # long m: optimum nsplit >> 1
     cbs = [4, 4]
@@ -330,11 +333,11 @@ def test_tas_batched_split_reoptimizes_on_sparsity_change():
         # re-optimized — see test_batched_pgrid_reoptimization.)
         state["nsplit"] = 1
         state["nblks_checked"] = None
-        tas_multiply("N", "N", 1.0, a, b, 1.0, c)
+        tas_multiply("N", "N", 1.0, a, b, 1.0, c, mesh=mesh)
         want += to_dense(a) @ to_dense(b)
         assert state["nsplit"] > 1, "stale nsplit=1 should have been re-chosen"
         assert state.get("resplit_count", 0) == 1
-        tas_multiply("N", "N", 1.0, a, b, 1.0, c)
+        tas_multiply("N", "N", 1.0, a, b, 1.0, c, mesh=mesh)
         want += to_dense(a) @ to_dense(b)
         # second call: cached split now optimal, no further re-split
         assert state.get("resplit_count", 0) == 1
